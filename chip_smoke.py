@@ -1,0 +1,241 @@
+#!/usr/bin/env python
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py               one chip: the main path
+    python chip_smoke.py --multichip   four chips: the mesh paths, and
+                                       what they are compared with
+
+Main path: BERT-base MLM pretraining at full width (12 x 768 x 12 heads x
+3072, vocab 30522) at b128 x s128 with bf16 matmuls, built by
+`bert.build_bert_pretrain_program` and run by `fluid.Executor` under
+`TPUPlace` — the README's quick-start shape. Five optimizer steps, one
+`exe.run` each, on one batch made from a seed (loss finite at every
+step, lower at the last than at the first; parameters and the fetched
+loss on the chip; the Pallas flash kernels in the compiled step), then
+the `exe.run(..., n_steps=4)` window of the same program (twice: the
+first call compiles the scan), then the flash kernels against the einsum
+reference on a small input. No OOM ladder and
+no shrink: a size that does not fit is an error to read.
+
+Everything runs in this one process — a chip belongs to one process at
+a time. Without a TPU the script exits non-zero before any work and
+prints nothing on stdout. Every stdout line is one JSON object; the last
+one is the verdict, `{"ok": true, "device": {...}}`. Times are host-clock
+smoke observations ended by block_until_ready, not benchmark results.
+
+`--tiny` is the rehearsal: the same phases at a toy size on whatever
+backend JAX has (the CPU, in the sandbox). It proves paths and control
+flow, never the chip: it does not report ok, and exits non-zero.
+"""
+import argparse
+import importlib.metadata
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+# where JAX_COMPILATION_CACHE_DIR is unset, the fixed in-checkout cache:
+# the directory is part of the cache key, so it never moves
+CACHE_DIR = os.path.join(ROOT, ".xla_cache")
+
+FULL = dict(batch=128, seq_len=128, lr=1e-4)
+TINY = dict(batch=8, seq_len=16, lr=1e-3,
+            cfg=dict(vocab_size=128, hidden=32, layers=2, heads=4, ffn=64,
+                     max_len=32, type_vocab=2))
+SINGLE_STEPS, WINDOW_STEPS, MESH_STEPS = 5, 4, 3
+
+
+def emit(**row):
+    print(json.dumps(row), flush=True)
+
+
+def cache_entries(cache_dir):
+    return len([f for f in os.listdir(cache_dir) if not f.startswith(".")])
+
+
+class CompileClock:
+    """Seconds JAX spent in backend compiles (a load from the persistent
+    cache counts as a short one), summed between two `take()`s."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.seconds, self.count = 0.0, 0
+        jax.monitoring.register_event_duration_secs_listener(self)
+
+    def __call__(self, event, duration, **kwargs):
+        if event.endswith("backend_compile_duration"):
+            self.seconds += duration
+            self.count += 1
+
+    def take(self):
+        out = dict(compile_seconds=round(self.seconds, 2),
+                   compiles=self.count)
+        self.seconds, self.count = 0.0, 0
+        return out
+
+
+def build(size):
+    from paddle_tpu.fluid import core
+    from paddle_tpu.models import bert
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    cfg = size.get("cfg") or bert.bert_base_config()
+    main, startup, _, fetches = bert.build_bert_pretrain_program(
+        cfg, seq_len=size["seq_len"], dropout=0.0, lr=size["lr"])
+    feed = bert.synthetic_pretrain_batch(cfg, size["batch"],
+                                         size["seq_len"], seed=0)
+    return cfg, (main, startup, fetches), feed
+
+
+def train_one_chip(size, dev, clock):
+    """Startup, SINGLE_STEPS runs of one step, the n_steps window."""
+    import jax
+    import numpy as np
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import core
+
+    cfg, (main, startup, fetches), feed = build(size)
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    scope = core.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        params = [p.name for p in main.global_block().all_parameters()]
+        for name in params:
+            where = scope.find_var(name).get_tensor().array.devices()
+            assert where == {dev}, f"parameter {name} on {where}, not {dev}"
+
+        losses, seconds = [], []
+        for _ in range(SINGLE_STEPS):
+            t0 = time.perf_counter()
+            (loss,) = exe.run(main, feed=feed, fetch_list=fetches,
+                              return_numpy=False)
+            jax.block_until_ready(loss.array)
+            seconds.append(time.perf_counter() - t0)
+            assert loss.array.devices() == {dev}, loss.array.devices()
+            losses.append(float(np.asarray(loss.array).ravel()[0]))
+        assert np.isfinite(losses).all(), losses
+        assert losses[-1] < losses[0], losses
+        emit(phase="steps", batch=size["batch"], seq_len=size["seq_len"],
+             layers=cfg["layers"], hidden=cfg["hidden"],
+             n_parameters_on_device=len(params), losses=losses,
+             # steps 1 and 2 each trace and compile (startup state is
+             # uncommitted, step outputs are committed): the rest is
+             # the steady step
+             step_seconds=[round(s, 4) for s in seconds],
+             smoke_observation_step_ms=round(
+                 float(np.median(seconds[2:])) * 1e3, 2),
+             **clock.take())
+
+        # the executor's own compiled step, asked for its text: are the
+        # Pallas kernels in what ran?
+        from tools.mfu_report import compiled_step_of
+        cb = compiled_step_of(exe)
+        compiled = cb.lowered(
+            scope, {n: scope.find_var(n).get_tensor().array
+                    for n in cb.feed_names}, jax.random.key(0)).compile()
+        n_kernels = compiled.as_text().count(
+            'custom_call_target="tpu_custom_call"')
+        mem = compiled.memory_analysis()
+        emit(phase="executable", tpu_custom_calls=n_kernels,
+             argument_bytes=mem.argument_size_in_bytes,
+             temp_bytes=mem.temp_size_in_bytes, **clock.take())
+        if dev.platform == "tpu":
+            # forward + dK/dV + dQ kernels per layer, none on the einsum path
+            assert n_kernels >= 3 * cfg["layers"], n_kernels
+
+        # the window twice: the first traces and compiles the scan, the
+        # second is the path every bench lane times through
+        wl, window_seconds = [], []
+        for _ in range(2):
+            t0 = time.perf_counter()
+            (window,) = exe.run(main, feed=feed, fetch_list=fetches,
+                                return_numpy=False, n_steps=WINDOW_STEPS)
+            jax.block_until_ready(window.array)
+            window_seconds.append(time.perf_counter() - t0)
+            got = np.asarray(window.array).ravel().tolist()
+            assert len(got) == WINDOW_STEPS and np.isfinite(got).all(), got
+            wl += got
+        assert wl[-1] < wl[0] < losses[0], (wl, losses)
+        emit(phase="window", n_steps=WINDOW_STEPS, losses=wl,
+             window_seconds=[round(s, 4) for s in window_seconds],
+             smoke_observation_window_step_ms=round(
+                 window_seconds[1] / WINDOW_STEPS * 1e3, 2),
+             **clock.take())
+    stats = dev.memory_stats() or {}
+    emit(phase="memory", peak_bytes_in_use=stats.get("peak_bytes_in_use"),
+         bytes_limit=stats.get("bytes_limit"))
+
+
+def flash_parity(tiny):
+    """The flash kernels against the einsum reference, on this backend:
+    Mosaic-compiled on a TPU, through the interpreter in the rehearsal."""
+    from tools.flash_smoke import run_config
+    row = run_config(128, 128, 128, B=2, H=12, steps=2, interpret=tiny)
+    emit(phase="flash_parity",
+         **{k: row.get(k) for k in
+            ("status", "error", "traceback_tail", "max_err_fwd",
+             "max_err_dq", "max_err_dk", "max_err_dv", "seq_len", "heads",
+             "head_dim", "dtype")})
+    assert row["status"] == "ok", row
+
+
+def multichip(size, devices):
+    """The mesh paths on ``devices``, and what each is compared with."""
+    import __graft_entry__ as legs
+    cfg, program, feed = build(size)
+    solo = legs.bert_losses(program, feed, MESH_STEPS)
+    emit(phase="bert_one_device", losses=solo, batch=size["batch"],
+         seq_len=size["seq_len"], layers=cfg["layers"],
+         hidden=cfg["hidden"])
+    for model_parallel in (1, 2):
+        emit(phase="bert_n_vs_1", **legs.bert_n_vs_1(
+            devices, program, cfg, feed, model_parallel, solo))
+    for row in legs.placement_legs(devices):
+        emit(phase="placement", **row)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--multichip", action="store_true",
+                    help="the four-chip mesh paths and nothing else")
+    ap.add_argument("--tiny", action="store_true",
+                    help="rehearsal at a toy size on any backend; never ok")
+    args = ap.parse_args(argv)
+
+    import jax
+    devices = jax.devices()
+    dev = devices[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(devices)}
+    want = 4 if args.multichip else 1
+    if not args.tiny and dev.platform != "tpu":
+        sys.exit(f"chip_smoke: needs a TPU, jax.devices()[0] is "
+                 f"{dev.platform!r} ({dev.device_kind})")
+    if len(devices) < want:
+        sys.exit(f"chip_smoke: needs {want} devices, have {len(devices)}")
+
+    from paddle_tpu.inference import enable_compile_cache
+    cache_dir = enable_compile_cache(CACHE_DIR)
+    emit(phase="start", device=device, jax=jax.__version__,
+         jaxlib=importlib.metadata.version("jaxlib"),
+         libtpu=importlib.metadata.version("libtpu"),
+         compile_cache_dir=cache_dir,
+         cache_entries_before=cache_entries(cache_dir))
+    size = TINY if args.tiny else FULL
+    clock = CompileClock()
+    t0 = time.perf_counter()
+    if args.multichip:
+        multichip(size, devices[:4])
+    else:
+        train_one_chip(size, dev, clock)
+        flash_parity(args.tiny)
+    emit(phase="end", seconds=round(time.perf_counter() - t0, 1),
+         cache_entries_after=cache_entries(cache_dir), **clock.take())
+    if args.tiny:
+        emit(ok=False, rehearsal=True, device=device)
+        sys.exit("chip_smoke: --tiny is a rehearsal, not a chip run")
+    emit(ok=True, device=device)
+
+
+if __name__ == "__main__":
+    main()

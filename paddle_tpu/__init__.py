@@ -8,23 +8,6 @@ sharding over a jax.sharding.Mesh (ICI collectives), not graph rewrites."""
 
 __version__ = "0.1.0"
 
-# jax version compat shims (PR 1 precedent: pltpu.TPUCompilerParams in
-# ops/pallas/flash_attention.py, lax.pvary in parallel/pipeline.py).
-# `from jax import shard_map` is the modern top-level export; on the
-# installed jax 0.4.x it only exists at jax.experimental.shard_map —
-# publish it at the top level so code written against either import
-# works (same call signature: shard_map(f, mesh=, in_specs=,
-# out_specs=)).
-import jax as _jax  # noqa: E402
-
-if not hasattr(_jax, "shard_map"):
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        _jax.shard_map = _shard_map
-    except ImportError:  # even older jax: leave it absent
-        pass
-del _jax
-
 from . import ops          # registers the operator set
 from . import fluid        # the Fluid-compatible front end
 from . import inference    # AnalysisPredictor engine

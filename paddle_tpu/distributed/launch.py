@@ -4,7 +4,10 @@ device group with PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM /
 PADDLE_TRAINER_ENDPOINTS / PADDLE_CURRENT_ENDPOINT set, watches children).
 
 TPU inversion: ONE process per host (jax owns all local chips); multi-host
-scale-out sets one worker per host and jax.distributed handles DCN. Usage:
+scale-out sets one worker per host and jax.distributed handles DCN. A chip
+belongs to one process at a time, so on a TPU host this launcher starts ONE
+worker and itself imports no JAX (it must never hold the chip its worker
+needs); --nproc > 1 is for CPU devices. Usage:
     python -m paddle_tpu.distributed.launch --ips=h1,h2 train.py ...
 Local multi-process testing (CPU devices):
     python -m paddle_tpu.distributed.launch --nproc=2 --devices_per_proc=4 train.py

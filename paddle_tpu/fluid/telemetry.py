@@ -506,34 +506,27 @@ def install_jax_compile_listener() -> bool:
     with _JAX_LISTENER_LOCK:
         if _JAX_LISTENER_INSTALLED:
             return True
-        try:
-            import jax.monitoring as _mon
+        import jax.monitoring as _mon
 
-            counter = REGISTRY.counter(
-                "jax_backend_compiles_total",
-                "XLA backend compiles observed via jax.monitoring")
+        counter = REGISTRY.counter(
+            "jax_backend_compiles_total",
+            "XLA backend compiles observed via jax.monitoring")
 
-            def _on_duration(event: str, duration: float, **kw):
-                if not event.endswith("backend_compile_duration"):
-                    return
-                counter.inc()
-                from . import profiler as _profiler
-                if _profiler.is_profiling():
-                    now = time.perf_counter()
-                    _profiler.record_span(
-                        "compile:backend", now - float(duration), now,
-                        cat="compile",
-                        args={"seconds": round(float(duration), 6)})
+        def _on_duration(event: str, duration: float, **kw):
+            if not event.endswith("backend_compile_duration"):
+                return
+            counter.inc()
+            from . import profiler as _profiler
+            if _profiler.is_profiling():
+                now = time.perf_counter()
+                _profiler.record_span(
+                    "compile:backend", now - float(duration), now,
+                    cat="compile",
+                    args={"seconds": round(float(duration), 6)})
 
-            _mon.register_event_duration_secs_listener(_on_duration)
-            _JAX_LISTENER_INSTALLED = True
-            return True
-        except Exception:  # older jax without monitoring — degrade
-            _LOG.warning("jax.monitoring unavailable — compile spans "
-                         "limited to executor cache-miss sites",
-                         exc_info=True)
-            _JAX_LISTENER_INSTALLED = True  # don't retry every call
-            return False
+        _mon.register_event_duration_secs_listener(_on_duration)
+        _JAX_LISTENER_INSTALLED = True
+        return True
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,7 @@ __all__ = ["Config", "AnalysisConfig", "Predictor", "AnalysisPredictor",
 _COMPILE_CACHE_DIR = None
 
 
-def enable_compile_cache(cache_dir: str):
+def enable_compile_cache(cache_dir: str) -> str:
     """Point XLA's persistent compilation cache at ``cache_dir`` — the
     TPU-native role of the reference's serialized TensorRT engine cache
     (analysis_config.cc SetOptimCacheDir + tensorrt/ engine
@@ -32,44 +32,42 @@ def enable_compile_cache(cache_dir: str):
     XLA compile entirely (the executable is loaded from disk, keyed by
     HLO hash). Process-global; idempotent per dir. Every compile in the
     process benefits (training steps included), which matches how the
-    engine cache removes the reference's cold-start."""
+    engine cache removes the reference's cold-start.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, whoever runs the process
+    has placed the cache: that directory is used and the argument is
+    not. The directory is part of the cache's key, so one that moves
+    never hits. Returns the directory in use."""
     global _COMPILE_CACHE_DIR
     import os
     import jax
-    cache_dir = os.path.abspath(cache_dir)
+    from jax.experimental.compilation_cache import compilation_cache
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    cache_dir = os.path.abspath(placed or cache_dir)
     if _COMPILE_CACHE_DIR == cache_dir:
-        return
+        return cache_dir
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache every executable: the defaults skip small/fast compiles,
     # which is exactly the cold-start this exists to remove
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # LRU-bound the directory: programs change every commit and orphaned
-    # HLO-keyed entries would otherwise accumulate forever
-    try:
+    if not placed:
+        # LRU-bound the directory: programs change every commit and
+        # orphaned HLO-keyed entries would otherwise accumulate forever
+        # (a cache placed from outside is sized from outside too:
+        # JAX_COMPILATION_CACHE_MAX_SIZE)
         jax.config.update("jax_compilation_cache_max_size",
                           4 * 1024 * 1024 * 1024)
-    except Exception:
-        pass  # older jax: no eviction knob
-    # env too, so SUBPROCESS workers (multi-process benches/predictor
-    # pools, the backend probe) inherit the cache
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-    os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
-    os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
     # jax initializes the cache module LAZILY at the first compile and
     # never re-reads the config after that — enabling the cache in a
     # process that already compiled anything (a predictor created after
     # model-building ran, the serving cold-start shape) was a silent
-    # no-op: zero entries ever written. Force a re-init so the NEXT
-    # compile picks the directory up.
-    try:
-        from jax._src import compilation_cache as _cc
-        if getattr(_cc, "is_initialized", None) and _cc.is_initialized():
-            _cc.reset_cache()
-    except Exception:
-        pass  # older/newer jax: first-compile init reads the config
+    # no-op: zero entries ever written. Reset so the NEXT compile picks
+    # the directory up.
+    compilation_cache.reset_cache()
     _COMPILE_CACHE_DIR = cache_dir
+    return cache_dir
 
 
 class AnalysisConfig:

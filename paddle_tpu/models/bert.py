@@ -18,7 +18,8 @@ from ..fluid.layer_helper import LayerHelper
 from ..fluid.param_attr import ParamAttr
 
 __all__ = ["multi_head_attention", "encoder_layer", "encoder",
-           "bert_base_config", "build_bert_pretrain_program"]
+           "bert_base_config", "build_bert_pretrain_program",
+           "synthetic_pretrain_batch"]
 
 
 def bert_base_config():
@@ -180,3 +181,25 @@ def build_bert_pretrain_program(cfg=None, seq_len=128, dropout=0.0,
         opt.minimize(loss)
     return main, startup, \
         [src, pos, sent, mask_pos, mask_label] + extra_feeds, [loss]
+
+
+def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0, mlm_frac=0.15):
+    """One feed dict for `build_bert_pretrain_program` (no input mask),
+    uniform random ids from ``seed`` — what benches, smokes and dry runs
+    train on when no corpus is at hand. A fixed number of predictions
+    per sequence (19 at s128, BERT's max_predictions_per_seq is 20), so
+    the mask feeds split over a data-parallel mesh wherever the batch
+    does."""
+    import numpy as np
+    rng = np.random.RandomState(seed)
+    n_mask = batch * max(1, int(seq_len * mlm_frac))
+    return {
+        "src_ids": rng.randint(0, cfg["vocab_size"],
+                               (batch, seq_len)).astype("int64"),
+        "pos_ids": np.tile(np.arange(seq_len), (batch, 1)).astype("int64"),
+        "sent_ids": np.zeros((batch, seq_len), "int64"),
+        "mask_pos": rng.randint(0, batch * seq_len,
+                                (n_mask, 1)).astype("int64"),
+        "mask_label": rng.randint(0, cfg["vocab_size"],
+                                  (n_mask, 1)).astype("int64"),
+    }

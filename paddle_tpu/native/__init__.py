@@ -4,6 +4,8 @@ the reference's C++ runtime (data feed: framework/data_feed.cc)."""
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -35,16 +37,34 @@ def _module_flags(name: str):
     return [], []
 
 
-def _build(name: str) -> str:
+def _compile(name: str, stem: str, suffix: str, cc, ldflags) -> str:
+    """Build <name>.cpp into ``<stem>.<key><suffix>`` with
+    ``cc <src> -o <out> ldflags``. ``key`` hashes the source's content
+    and the command line — not its mtime: the artifacts are git-ignored
+    and travel with copies of the tree, where a stale library from
+    another checkout can be newer than the source it was not built
+    from. Superseded builds of the same stem go."""
     src = os.path.join(_DIR, name + ".cpp")
-    so = os.path.join(_DIR, "lib" + name + ".so")
-    if (not os.path.exists(so)
-            or os.path.getmtime(so) < os.path.getmtime(src)):
-        cflags, ldflags = _module_flags(name)
-        cmd = (["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-                "-pthread"] + cflags + [src, "-o", so] + ldflags)
-        subprocess.run(cmd, check=True, capture_output=True, text=True)
-    return so
+    with open(src, "rb") as f:
+        key = hashlib.sha256(
+            f.read() + " ".join(cc + ldflags).encode()).hexdigest()[:16]
+    out = os.path.join(_DIR, f"{stem}.{key}{suffix}")
+    if not os.path.exists(out):
+        tmp = f"{out}.tmp{os.getpid()}"
+        subprocess.run(cc + [src, "-o", tmp] + ldflags, check=True,
+                       capture_output=True, text=True)
+        os.replace(tmp, out)  # atomic: a concurrent builder sees all or none
+        for old in glob.glob(os.path.join(_DIR, f"{stem}.*{suffix}")):
+            if old != out:
+                os.remove(old)
+    return out
+
+
+def _build(name: str) -> str:
+    cflags, ldflags = _module_flags(name)
+    return _compile(name, "lib" + name, ".so",
+                    ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
+                     "-pthread"] + cflags, ldflags)
 
 
 class _BuildFailed:
@@ -68,16 +88,11 @@ def build_executable(name: str) -> str:
                 f"build: {cached.err}") from cached.err
         if isinstance(cached, str):
             return cached
-        src = os.path.join(_DIR, name + ".cpp")
-        exe = os.path.join(_DIR, name)
+        cflags, ldflags = _embed_flags(rpath=True)
         try:
-            if (not os.path.exists(exe)
-                    or os.path.getmtime(exe) < os.path.getmtime(src)):
-                cflags, ldflags = _embed_flags(rpath=True)
-                cmd = (["g++", "-O2", "-std=c++17", "-pthread"] + cflags
-                       + [src, "-o", exe] + ldflags)
-                subprocess.run(cmd, check=True, capture_output=True,
-                               text=True)
+            exe = _compile(name, name, ".bin",
+                           ["g++", "-O2", "-std=c++17", "-pthread"] + cflags,
+                           ldflags)
         except Exception as e:
             _LIBS[key] = _BuildFailed(e)
             raise

@@ -18,8 +18,7 @@ import jax.numpy as jnp
 
 from .registry import register_op, register_grad_maker, first, out
 from .math_ops import mxu_available as _mxu_backend
-from .pallas.flash_attention import flash_attention, _pallas_ok, \
-    _ref_attention
+from .pallas.flash_attention import flash_attention, _use_kernels
 
 
 def _keypad_bias(bias, q, k):
@@ -79,7 +78,7 @@ def _fused_attention_qkv(ins, attrs):
     causal = attrs.get("causal", False)
     drop = float(attrs.get("dropout_rate", 0.0) or 0.0)
     kp_bias = _keypad_bias(bias, qh, kh)
-    flash_can = _pallas_ok(qh, kh) and (bias is None or kp_bias is not None)
+    flash_can = _use_kernels() and (bias is None or kp_bias is not None)
     if (bias is None and drop == 0.0) or flash_can:
         seed = None
         if drop > 0.0:
@@ -156,7 +155,7 @@ def _multihead_matmul(ins, attrs):
     # via its in-kernel bias input. Generic [B,H,Sq,Sk] biases keep the
     # einsum path (XLA fuses it).
     kp_bias = _keypad_bias(bias_qk, q, k)
-    if _pallas_ok(q, k) and (bias_qk is None or kp_bias is not None):
+    if _use_kernels() and (bias_qk is None or kp_bias is not None):
         o = flash_attention(q, k, v, alpha, causal=False, bias=kp_bias)
     else:
         # same f32-accumulation contract as the flash path (see

@@ -25,40 +25,26 @@ cheap elementwise+reduce that XLA fuses outside the kernels.
 Causal masking is top-left aligned; fully-masked K blocks are skipped
 with pl.when (upper-triangular blocks cost nothing).
 
-CPU/tests: `interpret_mode(True)` (or PADDLE_TPU_FLASH_INTERPRET=1) runs
-the very same kernels through the Pallas interpreter so the suite
-exercises the real kernel, not a fallback. Ragged lengths (S or Sk not
-divisible by the block) STAY on the kernel: boundary blocks are handled
-by in-kernel bounds masking, with padded tile regions zeroed at load
-(they are uninitialized — NaN under the interpreter — and 0·NaN would
-leak through the contractions). The pure-XLA reference path remains
-only for backends with no Pallas at all.
+CPU/tests: `interpret_mode(True)` / `interpret_guard()` run the very
+same kernels through the Pallas interpreter so the suite exercises the
+real kernel, not a fallback. Ragged lengths (S or Sk not divisible by
+the block) STAY on the kernel: boundary blocks are handled by in-kernel
+bounds masking, with padded tile regions zeroed at load (they are
+uninitialized — NaN under the interpreter — and 0·NaN would leak through
+the contractions). On a TPU backend the kernels are the only path: a
+kernel that does not compile raises. The pure-XLA reference is what the
+CPU backend runs with the interpreter off, and what tests compare with.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-if _HAS_PALLAS:
-    # jax renamed TPUCompilerParams -> CompilerParams (~0.6); accept both,
-    # and degrade to the no-pallas path (like any other pallas
-    # incompatibility) if a future jax drops both names
-    _compiler_params = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams", None)
-    if _compiler_params is None:
-        _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -69,7 +55,7 @@ NEG_INF = -1e30  # finite mask value: avoids inf-inf → NaN in the rescale
 # wire — the same layout jax's own TPU flash kernel uses for l/m.
 LANES = 128
 
-_INTERPRET = os.environ.get("PADDLE_TPU_FLASH_INTERPRET", "") in ("1", "true")
+_INTERPRET = False
 
 
 def interpret_mode(enable: bool):
@@ -107,10 +93,14 @@ def _ref_attention(q, k, v, sm_scale, causal=False):
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _use_kernels() -> bool:
+    """The Pallas kernels serve every call on a TPU backend (one that does
+    not compile raises), and on the CPU backend only through the
+    interpreter (tests); the CPU otherwise runs the XLA reference."""
+    return _on_tpu() or _INTERPRET
 
 
 def _mxu_operand(x):
@@ -329,7 +319,7 @@ def _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_INTERPRET and not _on_tpu(),
     )(*args)
@@ -523,7 +513,7 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
                    pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0))),
         scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
                         pltpu.VMEM((blk_k, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(*kv_args)
@@ -549,7 +539,7 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
         in_specs=q_specs,
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
     )(*q_args)
@@ -579,73 +569,26 @@ def block_override(blk_q, blk_k):
         _BLOCK_OVERRIDE = prev
 
 
-_TUNED = None  # lazy: (seq_len, head_dim) -> (blk_q, blk_k) from sweep
-
-
-def _tuned_blocks(S, D):
-    """Best measured (blk_q, blk_k) for the nearest swept seq length at
-    the SAME head_dim — the hardware sweep (tools/flash_smoke.py) banks
-    its fastest config per (seq, head_dim) bucket, fingerprint-stamped
-    so a kernel edit invalidates it. Returns None (defaults apply) when
-    no valid table exists, the fingerprint mismatches, or no entry
-    matches this head_dim (blocks tuned at another D could blow the
-    VMEM budget here)."""
-    global _TUNED
-    if _TUNED is None:
-        import json
-        table = {}
-        try:
-            from tools.flash_smoke import kernel_fingerprint, tuning_path
-            data = json.load(open(tuning_path()))
-            if data.get("kfp") == kernel_fingerprint():
-                for k, v in (data.get("entries") or {}).items():
-                    s, d = k.split(":")
-                    table[(int(s), int(d))] = (int(v[0]), int(v[1]))
-        except Exception:
-            pass  # no table / stale / not importable: defaults apply
-        _TUNED = table
-    cands = [sd for sd in _TUNED if sd[1] == D]
-    if not cands:
-        return None
-    nearest = min(cands, key=lambda sd: abs(sd[0] - S))
-    return _TUNED[nearest]
-
-
-def _block_sizes(S, Sk, D=64):
+def _block_sizes(S, Sk):
     """Ragged S/Sk are supported via in-kernel bounds masking, so blocks
     need not divide the lengths. Inputs smaller than the default block
     use the EXACT dimension as the block — a block equal to the array
     dim is always Mosaic-legal regardless of (8, 128) alignment, so tiny
-    and tiny-ragged shapes lower without padding games. A banked
-    hardware sweep overrides the defaults (see _tuned_blocks)."""
-    if _BLOCK_OVERRIDE is not None:
-        bq, bk = _BLOCK_OVERRIDE
-        return (S if S <= bq else bq), (Sk if Sk <= bk else bk)
-    tuned = _tuned_blocks(max(S, Sk), D)
-    dq, dk = tuned if tuned else (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
-    blk_q = S if S <= dq else dq
-    blk_k = Sk if Sk <= dk else dk
-    return blk_q, blk_k
-
-
-def _pallas_ok(q, k):
-    # ragged lengths are handled in-kernel (bounds masking); the only
-    # remaining requirement is a Pallas backend (TPU, or the interpreter
-    # for tests). q/k stay in the signature for future shape gating.
-    del q, k
-    return _HAS_PALLAS and (_on_tpu() or _INTERPRET)
+    and tiny-ragged shapes lower without padding games."""
+    bq, bk = _BLOCK_OVERRIDE or (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+    return min(S, bq), min(Sk, bk)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash_pallas(q, k, v, seed, bias, sm_scale, causal, dropout_rate):
-    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2], q.shape[3])
+    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
     o, _ = _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
                        dropout_rate, bias=bias)
     return o
 
 
 def _fp_fwd(q, k, v, seed, bias, sm_scale, causal, dropout_rate):
-    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2], q.shape[3])
+    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
     o, lse = _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
                          dropout_rate, bias=bias)
     # residual: the 2-D row stat, not the 128-lane wire form (128× less
@@ -655,7 +598,7 @@ def _fp_fwd(q, k, v, seed, bias, sm_scale, causal, dropout_rate):
 
 def _fp_bwd(sm_scale, causal, dropout_rate, res, g):
     q, k, v, o, lse, seed, bias = res
-    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2], q.shape[3])
+    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
     dq, dk, dv = _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal,
                              blk_q, blk_k, dropout_rate, bias=bias)
     dseed = np.zeros(seed.shape, jax.dtypes.float0)  # int arg: zero tangent
@@ -665,6 +608,60 @@ def _fp_bwd(sm_scale, causal, dropout_rate, res, g):
 
 _flash_pallas.defvjp(_fp_fwd, _fp_bwd)
 
+_MESH = None  # the device mesh whose step is being traced (mesh_guard)
+
+
+@contextlib.contextmanager
+def mesh_guard(mesh):
+    """While a step is traced for a ("dp", "mp") device mesh, tell the
+    kernels so: XLA cannot partition a Mosaic kernel ("wrap the call in
+    a shard_map"), so under such a mesh `flash_attention` does the
+    partitioning itself. ``None`` (one device) is a no-op."""
+    global _MESH
+    prev = _MESH
+    _MESH = mesh
+    try:
+        yield
+    finally:
+        _MESH = prev
+
+
+def _flash_on_mesh(mesh, q, k, v, seed, bias, sm_scale, causal,
+                   dropout_rate):
+    """`_flash_pallas` under a shard_map over ``mesh``: batch split over
+    "dp" and heads over "mp" where they divide (attention is independent
+    per batch row and head, so no collective is needed), replicated work
+    where they do not. Each shard that holds different rows or heads
+    mixes its mesh position into the dropout seed — the kernel's mask
+    hashes LOCAL row/head indices, and equal seeds would drop the same
+    entries in every shard."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    B, H = q.shape[:2]
+    split = [name if name in mesh.axis_names
+             and dim % mesh.shape[name] == 0 else None
+             for name, dim in (("dp", B), ("mp", H))]
+    qkv = P(*split, None, None)
+
+    def per_shard(q, k, v, seed, bias):
+        shard, stride = jnp.int32(0), 1  # linear index over the split axes
+        for name in filter(None, split):
+            shard = shard + jax.lax.axis_index(name).astype(jnp.int32) * stride
+            stride *= mesh.shape[name]
+        return _flash_pallas(q, k, v, seed + jnp.int32(1000003) * shard,
+                             bias, sm_scale, causal, dropout_rate)
+
+    # check_vma off: neither pallas_call's outputs nor the Pallas
+    # interpreter carry varying-axes types under jax 0.9.0. What that
+    # leaves unchecked — the transpose over an axis the operands are
+    # replicated on — is pinned by the N-vs-1 loss parity in
+    # tests/test_parallel.py.
+    return shard_map(
+        per_shard, mesh=mesh,
+        in_specs=(qkv, qkv, qkv, P(),
+                  None if bias is None else P(split[0], None)),
+        out_specs=qkv, check_vma=False)(q, k, v, seed, bias)
+
 # numpy, NOT jnp: a lazily-created jnp array inside someone's jit trace
 # would cache a tracer in this global and poison every later trace
 _ZERO_SEED = np.zeros((1,), np.int32)
@@ -672,8 +669,9 @@ _ZERO_SEED = np.zeros((1,), np.int32)
 
 def flash_attention(q, k, v, sm_scale, causal=False, dropout_rate=0.0,
                     dropout_seed=None, bias=None):
-    """q,k,v: [B,H,S,D] → [B,H,S,D]. Pallas flash kernel when the backend
-    (or interpret mode) supports it; pure-XLA reference otherwise.
+    """q,k,v: [B,H,S,D] → [B,H,S,D]. The Pallas flash kernels on a TPU
+    backend (and under interpret mode); the pure-XLA reference on the CPU
+    backend otherwise.
     dropout_rate > 0 applies attention-probability dropout INSIDE the
     kernel (mask regenerated in the backward from dropout_seed, an int32
     [1] array — pass a fresh per-step value when training). ``bias`` is
@@ -691,9 +689,12 @@ def flash_attention(q, k, v, sm_scale, causal=False, dropout_rate=0.0,
         # inputs up front instead of failing inside the kernel trace
         ct = jnp.result_type(q.dtype, k.dtype, v.dtype)
         q, k, v = (t.astype(ct) for t in (q, k, v))
-    if _pallas_ok(q, k):
+    if _use_kernels():
         if dropout_seed is None:
             dropout_seed = _ZERO_SEED
+        if _MESH is not None and {"dp", "mp"} & set(_MESH.axis_names):
+            return _flash_on_mesh(_MESH, q, k, v, dropout_seed, bias,
+                                  sm_scale, causal, float(dropout_rate))
         return _flash_pallas(q, k, v, dropout_seed, bias, sm_scale,
                              causal, float(dropout_rate))
     if dropout_rate > 0.0:

@@ -32,9 +32,6 @@ def local_device_count() -> int:
     return len(jax.local_devices())
 
 
-_distributed_initialized = False
-
-
 def init_distributed(coordinator_address: Optional[str] = None) -> bool:
     """Bring up the cross-host runtime from the PADDLE_* env contract
     (reference: the NCCL-id bootstrap c_gen_nccl_id + NCCLCommContext init,
@@ -45,13 +42,8 @@ def init_distributed(coordinator_address: Optional[str] = None) -> bool:
     endpoint from PADDLE_TRAINER_ENDPOINTS (free in this build's collective
     mode — no server binds it). Returns True if a multi-host init ran;
     single-process jobs are a no-op."""
-    global _distributed_initialized
     n = world_size()
-    try:
-        already = jax.distributed.is_initialized()
-    except AttributeError:  # older jax
-        already = _distributed_initialized
-    if n <= 1 or already:
+    if n <= 1 or jax.distributed.is_initialized():
         return False
     addr = (coordinator_address
             or os.getenv("JAX_COORDINATOR_ADDRESS")
@@ -66,11 +58,7 @@ def init_distributed(coordinator_address: Optional[str] = None) -> bool:
     # created; this jaxlib ships gloo, so multi-process CPU meshes (the
     # launch-parity lanes) need it switched on here, not at step time.
     if os.getenv("JAX_PLATFORMS", "").startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older jax: flag absent, single-host CPU still works
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=addr,
                                num_processes=n, process_id=rank())
-    _distributed_initialized = True
     return True

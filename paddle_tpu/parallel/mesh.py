@@ -15,15 +15,11 @@ from typing import Dict, Optional, Sequence
 
 import numpy as np
 import jax
+from jax import shard_map  # noqa: F401  (re-exported for parallel/*)
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXIS = "dp"
 MODEL_AXIS = "mp"
-
-try:
-    from jax import shard_map  # noqa: F401  (re-exported for parallel/*)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
 
 
 def axis_mesh(n: int, axis_name: str, devices=None) -> Mesh:
@@ -53,6 +49,9 @@ def build_mesh(num_devices: Optional[int] = None, model_parallel: int = 1,
                devices=None) -> Mesh:
     devs = list(devices if devices is not None else jax.devices())
     if num_devices is not None:
+        if len(devs) < num_devices:
+            raise ValueError(
+                f"build_mesh needs {num_devices} devices, have {len(devs)}")
         devs = devs[:num_devices]
     n = len(devs)
     mp = max(1, model_parallel)

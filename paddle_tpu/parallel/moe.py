@@ -44,6 +44,9 @@ __all__ = ["EP_AXIS", "expert_mesh", "expert_capacity", "moe_ffn_local",
 def expert_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
     devs = list(devices if devices is not None else jax.devices())
     if n_devices is not None:
+        if len(devs) < n_devices:
+            raise ValueError(
+                f"expert_mesh needs {n_devices} devices, have {len(devs)}")
         devs = devs[:n_devices]
     return Mesh(np.asarray(devs), (EP_AXIS,))
 

@@ -67,6 +67,17 @@ def _shard_stacked(mesh: Mesh, stacked, param_specs=None):
     return jax.tree_util.tree_map(put, stacked, param_specs), param_specs
 
 
+_VMA_OFF_CHECKED_WITH_JAX = "0.9.0"  # see gpipe_het
+
+
+def _vary_over(x, axes, mesh: Mesh):
+    """``x`` marked device-varying over those of ``axes`` it is not
+    varying over yet (lax.pcast refuses axes already in that state)."""
+    missing = tuple(a for a in mesh.axis_names
+                    if a in axes and a not in jax.typeof(x).vma)
+    return lax.pcast(x, missing, to="varying") if missing else x
+
+
 def gpipe(stage_fn: Callable[..., Any], stacked_params, xs, *,
           mesh: Mesh, axis: str = PIPELINE_AXIS, param_specs=None,
           xs_spec: P = P(), with_aux: bool = False,
@@ -160,15 +171,15 @@ def gpipe(stage_fn: Callable[..., Any], stacked_params, xs, *,
                 jnp.zeros((n_micro,) + xs_local.shape[1:],
                           xs_local.dtype),
                 aux0)
-        # carry becomes device-varying after the first tick; mark it so
-        # (older jax < 0.6 has neither primitive — there shard_map's
-        # rep-tracking handles the transition without explicit marking)
-        if hasattr(lax, "pcast"):
-            init = jax.tree_util.tree_map(
-                lambda x: lax.pcast(x, (axis,), to="varying"), init)
-        elif hasattr(lax, "pvary"):
-            init = jax.tree_util.tree_map(
-                lambda x: lax.pvary(x, (axis,)), init)
+        # the carry becomes device-varying after the first tick: over
+        # the pipeline axis (axis_index / ppermute) and over every other
+        # mesh axis the stage body's operands vary on (dp/sp-sharded xs,
+        # expert-sharded params). lax.scan wants the initial carry typed
+        # as the body returns it, so ask the body (abstractly) and cast
+        # each leaf over the axes it does not vary on yet.
+        carry_out = jax.eval_shape(lambda c: tick(c, jnp.int32(0))[0], init)
+        init = jax.tree_util.tree_map(
+            lambda x, out: _vary_over(x, out.vma, mesh), init, carry_out)
         (_, ys, aux_acc), _ = lax.scan(tick, init, jnp.arange(total))
         # ys is only populated on the last stage; zero elsewhere + psum
         # replicates it to every stage (single all-reduce over ICI).
@@ -177,7 +188,8 @@ def gpipe(stage_fn: Callable[..., Any], stacked_params, xs, *,
         if with_aux:
             # total over stages AND the data/sequence shards — the
             # schedule-global count, replicated everywhere
-            return ys, lax.psum(aux_acc, tuple(mesh.axis_names))
+            return ys, lax.psum(_vary_over(aux_acc, mesh.axis_names, mesh),
+                                tuple(mesh.axis_names))
         return ys
 
     out_specs = (xs_spec, P()) if with_aux else xs_spec
@@ -210,8 +222,10 @@ def gpipe_het(stage_fns: Sequence[Callable[[Any, Any], Any]],
 
     shard_map runs with the varying-manual-axes checker OFF: jax 0.9.0's
     vma tracking mis-transposes lax.switch under scan+ppermute (observed:
-    grads off by O(1) or NaN with the checker on, exact to 2e-7 against
-    the sequential oracle with it off).
+    grads off by O(1) with the checker on and the carry pcast to
+    varying, exact to 2e-7 against the sequential oracle with it off).
+    Collective transposes with the checker off are version-sensitive, so
+    the call refuses any other jax (_VMA_OFF_CHECKED_WITH_JAX).
     """
     import numpy as np
     n_stages = mesh.shape[axis]
@@ -274,14 +288,16 @@ def gpipe_het(stage_fns: Sequence[Callable[[Any, Any], Any]],
 
     pspec_params = jax.tree_util.tree_map(lambda x: P(),
                                           list(per_stage_params))
-    try:  # vma checker off — see docstring (jax>=0.7 name, then legacy)
-        fn = shard_map(per_device, mesh=mesh,
-                       in_specs=(pspec_params, P()), out_specs=P(),
-                       check_vma=False)
-    except TypeError:
-        fn = shard_map(per_device, mesh=mesh,
-                       in_specs=(pspec_params, P()), out_specs=P(),
-                       check_rep=False)
+    if jax.__version__ != _VMA_OFF_CHECKED_WITH_JAX:
+        raise RuntimeError(
+            f"gpipe_het runs shard_map with check_vma=False, which was "
+            f"checked against the sequential oracle under jax "
+            f"{_VMA_OFF_CHECKED_WITH_JAX} only (this is {jax.__version__}):"
+            f" re-run tests/test_pipeline.py::test_gpipe_het_matches_"
+            f"sequential with the checker on and off, then move the pin")
+    fn = shard_map(per_device, mesh=mesh,
+                   in_specs=(pspec_params, P()), out_specs=P(),
+                   check_vma=False)
     ys = fn(list(per_stage_params), xs)
     return ys.reshape((n_micro,) + out_shape)
 

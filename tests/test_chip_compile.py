@@ -1,0 +1,160 @@
+"""Compile for the chip, without the chip: the TPU's compiler is installed
+here and compiles for a v5e that is described, not attached
+(/opt/skills/guides/on-chip-measurement §2, rehearsal 3). It refuses what
+interpret mode cannot see — a slice not aligned to the tiling, too much
+VMEM, a program that does not fit 16 GB of HBM, a kernel XLA is asked to
+partition. A compile that passes is not a chip run: nothing executes,
+and no result or time comes out of this file.
+
+Rules this file keeps (the guide gives the reasons): the topology is
+described inside a module-scoped, non-autouse fixture that skips when it
+cannot be — never at import, in a skipif, in parametrize or in
+conftest.py; everything built from it is built in a fixture or a test;
+the compile runs in the test's own process (only one process may hold
+libtpu) with the persistent cache off around it (a described-device
+executable cannot be read back); all such tests live in this ONE file so
+they land on one xdist worker. Code that asks `jax.default_backend()`
+sees the CPU here, so the tests patch the kernels' `_on_tpu` — the
+program has no option for it.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu.fluid import core
+from paddle_tpu.fluid.executor import _CompiledBlock
+from paddle_tpu.models import bert
+from paddle_tpu.ops.pallas import flash_attention as fa
+
+KERNEL = 'custom_call_target="tpu_custom_call"'
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # whatever keeps libtpu from describing a chip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def for_the_chip(monkeypatch):
+    """Take the TPU branches, and keep the persistent compile cache out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("shape,kw", [
+    ((128, 12, 128, 64), {}),                        # chip_smoke / BERT s128
+    ((16, 12, 512, 64), dict(bias=True, dropout=0.1)),  # padded s512 cell
+    ((4, 16, 2048, 64), dict(causal=True)),          # long causal
+    ((8, 12, 500, 64), {}),                          # ragged boundary block
+], ids=["b128_s128", "s512_keypad_dropout", "s2048_causal", "s500_ragged"])
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
+    """Forward + dK/dV + dQ kernels of `_flash_pallas`, bf16, Mosaic."""
+    B, H, S, D = shape
+    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    seed = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    bias = (jax.ShapeDtypeStruct((B, S), jnp.float32, sharding=one_chip)
+            if kw.get("bias") else None)
+
+    def loss(q, k, v, seed, bias):
+        o = fa._flash_pallas(q, k, v, seed, bias, 1.0 / np.sqrt(D),
+                             kw.get("causal", False),
+                             kw.get("dropout", 0.0))
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, seed, bias).compile()
+    assert compiled.as_text().count(KERNEL) == 3
+
+
+def _bert_base_width_step(layers, batch, seq_len, one_chip, mesh=None,
+                          shardings=None):
+    """The executor's own compiled block for a BERT-base-WIDTH pretrain
+    step, and its arguments as shapes, each with where it lives: on
+    ``one_chip``, or placed over ``mesh`` as the executor places them."""
+    cfg = dict(bert.bert_base_config(), layers=layers)
+    main, startup, _, fetches = bert.build_bert_pretrain_program(
+        cfg, seq_len=seq_len, dropout=0.0, lr=1e-4)
+    feed = bert.synthetic_pretrain_batch(cfg, batch, seq_len)
+    exe = fluid.Executor()
+    scope = core.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)  # on the CPU: only the shapes are used
+        cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
+                            scope, seed=0, mesh=mesh,
+                            param_shardings=shardings
+                            and shardings(main, cfg))
+
+        def on(spec):
+            return one_chip if mesh is None else NamedSharding(mesh, spec)
+
+        def state(names):
+            out = {}
+            for n in names:
+                a = scope.find_var(n).get_tensor().array
+                out[n] = jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=on(cb._sharding_for(n, a) or P()))
+            return out
+        mut, ro = state(cb.mut_state), state(cb.ro_state)
+    feeds = {n: jax.ShapeDtypeStruct(  # device integers are 32-bit
+                 a.shape, jnp.int32,
+                 sharding=on(P("dp", *([None] * (a.ndim - 1)))))
+             for n, a in feed.items()}
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=on(P()))
+    return cb, (mut, ro, feeds, rng)
+
+
+@pytest.mark.parametrize("on_mesh", [False, True],
+                         ids=["one_chip", "dp2_mp2"])
+def test_bert_base_width_train_step_compiles_for_v5e(topo, one_chip,
+                                                     for_the_chip, on_mesh):
+    """One whole train step (fwd + bwd + Adam) lowered from the
+    executor's `_CompiledBlock` at BERT-base width (768 x 12 heads x
+    3072, vocab 30522; 2 layers — 12 is a ~40 s by-hand rehearsal), bf16
+    matmuls, b128 x s128: on one chip, and on a dp2 x mp2 mesh with the
+    dry-run's tensor-parallel shardings, where the kernels must have
+    partitioned themselves (XLA refuses to) and the gradients must meet
+    in an all-reduce."""
+    import __graft_entry__ as legs
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        where = {}
+        if on_mesh:
+            where = dict(
+                mesh=Mesh(np.asarray(topo.devices).reshape(2, 2),
+                          ("dp", "mp")),
+                shardings=legs.bert_tp_shardings)
+        cb, args = _bert_base_width_step(2, 128, 128, one_chip, **where)
+        compiled = cb._jitted.lower(*args).compile()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    text = compiled.as_text()
+    # per layer: the forward kernel twice (the forward op, and again
+    # inside the grad op's vjp) + dK/dV + dQ
+    assert text.count(KERNEL) == 4 * 2
+    assert ("all-reduce" in text) == on_mesh
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

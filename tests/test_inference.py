@@ -204,6 +204,7 @@ print(json.dumps({"hit": hit, "y": np.asarray(y).ravel().tolist()}))
     build = build.replace("MODEL_DIR", repr(model_dir)) \
                  .replace("CACHE_DIR", repr(cache_dir))
     env = dict(__import__("os").environ)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)  # cache_dir is the API then
     out1 = subprocess.run([sys.executable, "-c",
                            build.replace("MAKE", "True")],
                           capture_output=True, text=True, env=env,

@@ -10,6 +10,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import core
 from paddle_tpu.fluid.framework import Program, program_guard
 from paddle_tpu.parallel.mesh import build_mesh
+from paddle_tpu.parallel.moe import expert_mesh
 
 
 def _build(seed=11):
@@ -176,3 +177,108 @@ def test_init_distributed_wiring(monkeypatch):
     # single-process: no-op
     monkeypatch.setenv("PADDLE_TRAINERS_NUM", "1")
     assert penv.init_distributed() is False
+
+
+@pytest.mark.parametrize("model_parallel", [1, 2])
+def test_flash_kernels_partition_themselves_under_a_mesh(model_parallel):
+    """XLA cannot partition a Mosaic kernel, so under a ("dp", "mp") mesh
+    `flash_attention` wraps itself in a shard_map (batch over dp, heads
+    over mp). With the kernels on the path (interpreter here, Mosaic in
+    tests/test_chip_compile.py) a tiny BERT's losses on four devices
+    match one device, and the step still holds its all-reduce."""
+    import __graft_entry__ as legs
+    from paddle_tpu.models import bert
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    cfg = legs._tiny_cfg()
+    main, startup, _, fetches = bert.build_bert_pretrain_program(
+        cfg, seq_len=16, lr=1e-3)
+    program = (main, startup, fetches)
+    feed = bert.synthetic_pretrain_batch(cfg, 8, 16)
+    calls = []
+    on_mesh = fa._flash_on_mesh
+    with fa.interpret_guard(), pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "_flash_on_mesh",
+                   lambda *a: calls.append(a[0]) or on_mesh(*a))
+        solo = legs.bert_losses(program, feed, steps=3)
+        assert not calls
+        row = legs.bert_n_vs_1(jax.devices()[:4], program, cfg, feed,
+                               model_parallel, solo)
+    assert calls and all(m.devices.size == 4 for m in calls)
+    assert row["devices"] == 4
+
+
+def test_flash_dropout_under_a_mesh_differs_per_shard():
+    """Each shard mixes its mesh position into the dropout seed: the
+    kernel's mask hashes LOCAL row indices, and with equal seeds every
+    data shard would drop the same entries."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    mesh = build_mesh(devices=jax.devices()[:2])
+    r = np.random.RandomState(0)
+    half = r.normal(size=(1, 2, 16, 8)).astype("float32")
+    q = jnp.asarray(np.concatenate([half, half]))  # two identical rows
+    seed = jnp.asarray([7], jnp.int32)
+    with fa.interpret_guard(), fa.mesh_guard(mesh):
+        o = np.asarray(jax.jit(lambda q: fa.flash_attention(
+            q, q, q, 0.3, dropout_rate=0.5, dropout_seed=seed))(q))
+    assert np.isfinite(o).all()
+    assert not np.array_equal(o[0], o[1])
+
+
+@pytest.mark.parametrize("heads", [4, 3])  # 3: replicated over "mp"
+def test_flash_on_mesh_value_and_grads_match_unpartitioned(heads):
+    """The shard_map runs unchecked (check_vma=False); what that leaves
+    open is the transpose over an axis the operands are replicated on
+    (heads that do not divide "mp"). Value and all three grads must
+    equal the unpartitioned kernel's."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    mesh = build_mesh(model_parallel=2, devices=jax.devices()[:4])
+    r = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(r.normal(size=(2, heads, 16, 8)), jnp.float32)
+               for _ in range(3))
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, 0.35, causal=True) ** 2)
+
+    grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    with fa.interpret_guard():
+        want = grad(q, k, v)
+        with fa.mesh_guard(mesh):
+            got = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+                q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("make", [
+    build_mesh, lambda n: build_mesh(n, model_parallel=2), expert_mesh,
+], ids=["build_mesh", "build_mesh_mp", "expert_mesh"])
+def test_mesh_over_more_devices_than_there_are_raises(make):
+    """A mesh asked for n devices is a mesh of n devices or an error —
+    never the first few that happen to exist (the suite has 8)."""
+    assert make(8).devices.size == 8
+    with pytest.raises(ValueError, match="needs 16 devices, have 8"):
+        make(16)
+
+
+@pytest.mark.parametrize("beyond", [8, 11])
+def test_tpu_place_beyond_the_local_devices_raises(beyond):
+    """TPUPlace(i) is local device i: an id at or past the device count
+    is an error, not device i modulo the count."""
+    assert core.TPUPlace(7).jax_device() == jax.local_devices()[7]
+    with pytest.raises(ValueError, match="8 local cpu device"):
+        core.TPUPlace(beyond).jax_device()
+    main, startup, loss = _build()
+    with fluid.scope_guard(core.Scope()):
+        fluid.Executor().run(startup)
+        with pytest.raises(ValueError, match="8 local cpu device"):
+            fluid.Executor(core.TPUPlace(beyond)).run(
+                main, feed={"x": np.zeros((8, 16), "float32"),
+                            "y": np.zeros((8, 1), "int64")},
+                fetch_list=[loss])

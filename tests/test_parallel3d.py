@@ -62,10 +62,12 @@ def _cfg(**kw):
     return lm3d.LMConfig(**kw)
 
 
-def _run_pair(cfg, steps=3, poison=None):
+def _run_pair(cfg, steps=3, poison=None, resync=False):
     """Run composed + oracle side by side on identical feeds/folds.
-    Returns (losses_composed, losses_oracle, dropped_c, dropped_o,
-    healths_c)."""
+    ``resync``: the composed lane starts every step from the ORACLE's
+    params, so each loss compares one forward pass and not a
+    trajectory. Returns (losses_composed, losses_oracle, dropped_c,
+    dropped_o, healths_c)."""
     mesh = cfg.mesh()
     params = lm3d.init_params(cfg)
     if poison is not None:
@@ -81,6 +83,8 @@ def _run_pair(cfg, steps=3, poison=None):
     for i in range(steps):
         xb, yb = jnp.asarray(w[i, ..., :-1]), jnp.asarray(w[i, ..., 1:])
         k = jax.random.fold_in(key, i)
+        if resync:
+            p1 = lm3d.place_params(cfg, mesh, p2)
         p1, a1, (l1, _, h1, d1) = step(p1, a1, xb, yb, k)
         p2, a2, (l2, _, h2, d2) = ostep(p2, a2, xb, yb, k)
         lc.append(float(l1))
@@ -249,13 +253,22 @@ def test_lm3d_moe_tight_capacity_counts_drops():
 
 @requires8
 def test_lm3d_pp_only_with_dropout_bit_identical_to_oracle():
-    """pp-only composition: same fp ops in the same order (the gpipe
-    output psum adds exact zeros) AND identical dropout masks via the
-    (stage, layer, micro) rng-fold mirror — losses bit-equal."""
+    """pp-only composition: same forward fp ops in the same order (the
+    gpipe output psum adds exact zeros) AND identical dropout masks via
+    the (stage, layer, micro) rng-fold mirror — from the same params
+    every step's loss is bit-equal. The free-running trajectory is held
+    to a few ulp only: the installed XLA:CPU rounds the scan-transposed
+    backward differently from the oracle's straight-line one (param
+    grads differ in the last bit from step 0 on, dropout or not), and a
+    wrong mask would show at 1e-2."""
     cfg = _cfg(dp=1, pp=2, sp=1, batch=4, dropout=0.2, seed=7)
-    lc, lo, _, _, hc = _run_pair(cfg)
+    lc, lo, _, _, hc = _run_pair(cfg, resync=True)
     assert all(hc)
     assert lc == lo, (lc, lo)
+    lc, lo, _, _, hc = _run_pair(cfg)
+    assert all(hc)
+    assert lc[0] == lo[0], (lc, lo)
+    np.testing.assert_allclose(lc, lo, rtol=1e-6, atol=0)
 
 
 @requires8

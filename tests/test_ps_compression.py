@@ -794,7 +794,7 @@ def test_int8_frames_2x_effective_throughput_on_thin_pipe():
     compression plane targets: on an emulated 50 MB/s pipe
     (PADDLE_TPU_PS_RPC_BANDWIDTH_MBPS), int8 frames deliver ≥2× the
     raw-frame effective MB/s at ≥1MB payloads. (Raw loopback is
-    CPU-bound at GB/s — recorded as the caveat lane in BENCH_LOCAL.)"""
+    CPU-bound at GB/s — the caveat lane; CPU, builder-run, not recorded.)"""
     from tools import rpc_microbench
 
     rows = rpc_microbench.run_quant(sizes=[1 << 20, 1 << 22],
