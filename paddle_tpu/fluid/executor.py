@@ -291,6 +291,16 @@ def _normalize_lod(lod):
     return tuple(tuple(int(x) for x in lvl) for lvl in lod)
 
 
+def _op_scope(op) -> str:
+    """`<phase>/<op type>`, the `jax.named_scope` a Fluid op is traced
+    under: `bwd` and `opt` from the op_role bits `append_backward` and
+    the optimizers stamp (backward.py OP_ROLE_*), `fwd` for everything
+    else, the loss op (role 256) included."""
+    role = int(op.attrs.get("op_role", 0) or 0)
+    phase = "bwd" if role & 1 else "opt" if role & 2 else "fwd"
+    return f"{phase}/{op.type}"
+
+
 def _op_needs_lod(op) -> bool:
     if OPS.has(op.type):
         return OPS.get(op.type).needs_lod
@@ -775,115 +785,122 @@ class _CompiledBlock:
         # op list — per-op rng keys fold from GLOBAL indices so a segmented
         # run draws the same streams as the fused compiled run would
         for local_idx, op in enumerate(ops):
-            idx = idx0 + local_idx
-            otype = op.type
-            if otype == "while":
-                self._exec_while(op, env, lod_env, rng)
-                continue
-            if otype in ("conditional_block", "conditional_block_infer"):
-                # Trace the branch unconditionally on an env COPY (both-
-                # branch compute = TPU select idiom), then mask-merge any
-                # write to a pre-existing outer var so the untaken branch
-                # cannot clobber state; fresh vars flow through for
-                # select_input to pick.
-                branch_env = dict(env)
-                self._exec_ops(op.attrs["sub_block"].ops, branch_env,
-                               lod_env, rng)
-                cnames = op.inputs.get("Cond") or []
-                mask = (jnp.reshape(env[cnames[0]], ()) != 0) \
-                    if cnames and cnames[0] in env else None
-                for n, v in branch_env.items():
-                    old = env.get(n)
-                    if old is v:
-                        continue
-                    if old is None or mask is None:
-                        env[n] = v
-                    elif getattr(old, "shape", None) == getattr(v, "shape",
-                                                                None):
-                        env[n] = jnp.where(mask, v, old)
-                    else:
-                        raise NotImplementedError(
-                            f"conditional_block branch changes the shape of "
-                            f"outer var '{n}' ({getattr(old, 'shape', None)}"
-                            f" -> {getattr(v, 'shape', None)}); conditional "
-                            f"shape-changing writes cannot be compiled — "
-                            f"produce a new variable instead")
-                continue
-            if otype == "select_input":
-                mask = jnp.reshape(env[op.inputs["Mask"][0]], ()) != 0
-                xf = env.get(op.inputs["X"][0])
-                xt = env.get(op.inputs["X"][1])
-                if xf is None or xt is None:
-                    picked = xt if xf is None else xf
-                elif xt.shape == xf.shape:
-                    picked = jnp.where(mask, xt, xf)
+            # trace-time metadata only: every HLO instruction the op
+            # lowers to (and the fusion it becomes the root of) carries
+            # `<phase>/<op type>` in its op_name, which is how a device
+            # trace is read by Fluid op (docs/OBSERVABILITY.md)
+            with jax.named_scope(_op_scope(op)):
+                self._exec_op(op, idx0 + local_idx, env, lod_env, rng)
+
+    def _exec_op(self, op, idx, env, lod_env, rng):
+        otype = op.type
+        if otype == "while":
+            self._exec_while(op, env, lod_env, rng)
+            return
+        if otype in ("conditional_block", "conditional_block_infer"):
+            # Trace the branch unconditionally on an env COPY (both-
+            # branch compute = TPU select idiom), then mask-merge any
+            # write to a pre-existing outer var so the untaken branch
+            # cannot clobber state; fresh vars flow through for
+            # select_input to pick.
+            branch_env = dict(env)
+            self._exec_ops(op.attrs["sub_block"].ops, branch_env,
+                           lod_env, rng)
+            cnames = op.inputs.get("Cond") or []
+            mask = (jnp.reshape(env[cnames[0]], ()) != 0) \
+                if cnames and cnames[0] in env else None
+            for n, v in branch_env.items():
+                old = env.get(n)
+                if old is v:
+                    continue
+                if old is None or mask is None:
+                    env[n] = v
+                elif getattr(old, "shape", None) == getattr(v, "shape",
+                                                            None):
+                    env[n] = jnp.where(mask, v, old)
                 else:
                     raise NotImplementedError(
-                        f"cond branches produce different shapes "
-                        f"({xt.shape} vs {xf.shape}) for the same output — "
-                        f"XLA needs matching branch shapes; pad or "
-                        f"restructure the branches")
-                env[op.outputs["Out"][0]] = picked
-                continue
-            ins = {}
-            for slot, names in op.inputs.items():
-                ins[slot] = [env.get(n) for n in names]
-            attrs = op.attrs
-            in_lods = _collect_in_lods(op, lod_env.get)
-            if _op_needs_lod(op):
-                attrs = dict(attrs)
-                attrs["_lod"] = in_lods
-            if OPS.has(otype):
-                info = OPS.get(otype)
-                if info.needs_rng:
-                    attrs = dict(attrs)
-                    if attrs.get("fix_seed", False) or attrs.get("seed", 0):
-                        attrs["_rng"] = jax.random.key(int(attrs.get("seed", 0)))
-                    else:
-                        attrs["_rng"] = jax.random.fold_in(rng, idx)
-                outs = info.kernel(ins, attrs)
-            elif otype.endswith("_grad") and OPS.has(otype[:-5]):
-                base = OPS.get(otype[:-5])
-                if base.needs_rng:
-                    # same key as the forward op (stamped _fwd_idx) so the
-                    # vjp re-run samples identically
-                    attrs = dict(attrs)
-                    attrs["_rng"] = jax.random.fold_in(
-                        rng, int(attrs.get("_fwd_idx", idx)))
-                outs = run_generic_grad(
-                    otype[:-5], ins, attrs,
-                    wanted_grad_slots=list(op.outputs.keys()),
-                    fwd_input_slots=attrs.get("_fwd_in", list(op.inputs.keys())))
-            elif otype.endswith("_grad_grad") and OPS.has(otype[:-10]):
-                # static double grad: vjp THROUGH the generic grad of the
-                # base op (gradient-penalty losses differentiate *_grad
-                # ops; reference imperative/partial_grad_engine.cc role)
-                from ..ops.registry import run_generic_grad_grad
-                if OPS.get(otype[:-10]).needs_rng:
-                    # same key as the forward op, like the *_grad branch:
-                    # the doubly-nested vjp must replay the SAME draws
-                    attrs = dict(attrs)
-                    attrs["_rng"] = jax.random.fold_in(
-                        rng, int(attrs.get("_fwd_idx", idx)))
-                outs = run_generic_grad_grad(
-                    otype[:-10], ins, attrs,
-                    wanted_grad_slots=list(op.outputs.keys()),
-                    gradop_slots=attrs.get("_fwd_in",
-                                           list(op.inputs.keys())))
+                        f"conditional_block branch changes the shape of "
+                        f"outer var '{n}' ({getattr(old, 'shape', None)}"
+                        f" -> {getattr(v, 'shape', None)}); conditional "
+                        f"shape-changing writes cannot be compiled — "
+                        f"produce a new variable instead")
+            return
+        if otype == "select_input":
+            mask = jnp.reshape(env[op.inputs["Mask"][0]], ()) != 0
+            xf = env.get(op.inputs["X"][0])
+            xt = env.get(op.inputs["X"][1])
+            if xf is None or xt is None:
+                picked = xt if xf is None else xf
+            elif xt.shape == xf.shape:
+                picked = jnp.where(mask, xt, xf)
             else:
-                raise NotImplementedError(f"op {otype} not registered")
-            for slot, names in op.outputs.items():
-                vals = outs.get(slot)
-                if vals is None:
-                    continue
-                for n, v in zip(names, vals):
-                    if v is not None and n != "@EMPTY@":
-                        env[n] = v
-            _propagate_lods(
-                op, outs, in_lods,
-                lod_env.__setitem__,
-                lambda n: (env[n].shape[0] if n in env and
-                           getattr(env[n], "ndim", 0) else None))
+                raise NotImplementedError(
+                    f"cond branches produce different shapes "
+                    f"({xt.shape} vs {xf.shape}) for the same output — "
+                    f"XLA needs matching branch shapes; pad or "
+                    f"restructure the branches")
+            env[op.outputs["Out"][0]] = picked
+            return
+        ins = {}
+        for slot, names in op.inputs.items():
+            ins[slot] = [env.get(n) for n in names]
+        attrs = op.attrs
+        in_lods = _collect_in_lods(op, lod_env.get)
+        if _op_needs_lod(op):
+            attrs = dict(attrs)
+            attrs["_lod"] = in_lods
+        if OPS.has(otype):
+            info = OPS.get(otype)
+            if info.needs_rng:
+                attrs = dict(attrs)
+                if attrs.get("fix_seed", False) or attrs.get("seed", 0):
+                    attrs["_rng"] = jax.random.key(int(attrs.get("seed", 0)))
+                else:
+                    attrs["_rng"] = jax.random.fold_in(rng, idx)
+            outs = info.kernel(ins, attrs)
+        elif otype.endswith("_grad") and OPS.has(otype[:-5]):
+            base = OPS.get(otype[:-5])
+            if base.needs_rng:
+                # same key as the forward op (stamped _fwd_idx) so the
+                # vjp re-run samples identically
+                attrs = dict(attrs)
+                attrs["_rng"] = jax.random.fold_in(
+                    rng, int(attrs.get("_fwd_idx", idx)))
+            outs = run_generic_grad(
+                otype[:-5], ins, attrs,
+                wanted_grad_slots=list(op.outputs.keys()),
+                fwd_input_slots=attrs.get("_fwd_in", list(op.inputs.keys())))
+        elif otype.endswith("_grad_grad") and OPS.has(otype[:-10]):
+            # static double grad: vjp THROUGH the generic grad of the
+            # base op (gradient-penalty losses differentiate *_grad
+            # ops; reference imperative/partial_grad_engine.cc role)
+            from ..ops.registry import run_generic_grad_grad
+            if OPS.get(otype[:-10]).needs_rng:
+                # same key as the forward op, like the *_grad branch:
+                # the doubly-nested vjp must replay the SAME draws
+                attrs = dict(attrs)
+                attrs["_rng"] = jax.random.fold_in(
+                    rng, int(attrs.get("_fwd_idx", idx)))
+            outs = run_generic_grad_grad(
+                otype[:-10], ins, attrs,
+                wanted_grad_slots=list(op.outputs.keys()),
+                gradop_slots=attrs.get("_fwd_in",
+                                       list(op.inputs.keys())))
+        else:
+            raise NotImplementedError(f"op {otype} not registered")
+        for slot, names in op.outputs.items():
+            vals = outs.get(slot)
+            if vals is None:
+                continue
+            for n, v in zip(names, vals):
+                if v is not None and n != "@EMPTY@":
+                    env[n] = v
+        _propagate_lods(
+            op, outs, in_lods,
+            lod_env.__setitem__,
+            lambda n: (env[n].shape[0] if n in env and
+                       getattr(env[n], "ndim", 0) else None))
 
     def _place_inputs(self, scope: Scope, feeds: Dict[str, Any], rng,
                       window_names=()):
@@ -944,38 +961,57 @@ class _CompiledBlock:
         mut, ro, feeds, rng = self._place_inputs(scope, feeds, rng)
         return self._jitted.lower(mut, ro, feeds, rng)
 
+    def place_span(self):
+        """The `exe:place` stage span: around gathering the state arrays
+        from the scope (on a mesh, placing them) for a dispatch."""
+        from . import profiler as _profiler
+        return _profiler.RecordEvent(
+            "exe:place", cat="executor",
+            args={"arrays": len(self.mut_state) + len(self.ro_state)})
+
     def run(self, scope: Scope, feeds: Dict[str, Any], rng):
         """One training/inference step: ONE dispatch of the jitted step.
         Returns (fetches, health) — health is the step's fused finite
         scalar (constant True when the guard is off), LAZY on device so
         the happy path costs no host sync."""
-        mut, ro, feeds, rng = self._place_inputs(scope, feeds, rng)
+        with self.place_span():
+            placed = self._place_inputs(scope, feeds, rng)
+        return self.run_placed(scope, placed)
+
+    def run_placed(self, scope: Scope, placed):
+        """`run` from `_place_inputs`' result on: dispatch, write back."""
         from . import profiler as _profiler
+        mut, ro, feeds, rng = placed
         first = not self._dispatched
         if first:
             self._dispatched = True
             _telemetry.count_compile("step")
-        if _profiler.is_profiling():
-            # the whole program is ONE dispatch on TPU — a single span
-            # (per-op timing lives in the device XPlane trace). The
-            # first dispatch additionally carries a cat="compile" span:
-            # that is where jax traces+compiles the step (the backend
-            # listener records the exact compile durations inside it).
-            with _profiler.RecordEvent("compiled_step"):
-                cm = (_profiler.RecordEvent("compile:step",
-                                            cat="compile")
-                      if first else contextlib.nullcontext())
-                with cm:
-                    fetches, new_mut, extra, health = self._jitted(
-                        mut, ro, feeds, rng)
-                    if _profiler.is_session():
-                        # only a real profiler session pays the sync;
-                        # shard-only spans measure dispatch
-                        jax.block_until_ready(fetches)
-        else:
+        # the whole program is ONE dispatch on TPU — a single span
+        # (per-op timing lives in the device trace, by the named scopes
+        # of _exec_ops). The first dispatch additionally carries a
+        # cat="compile" span: that is where jax traces+compiles the step
+        # (the jax.monitoring listener records the exact trace, lower
+        # and compile durations inside it). ONE call site whether or not
+        # anything records: a Pallas kernel carries the Python stack it
+        # was traced under into the compile-cache key.
+        with _profiler.RecordEvent("compiled_step", cat="executor"), \
+                (_profiler.RecordEvent("compile:step", cat="compile")
+                 if first else contextlib.nullcontext()):
             fetches, new_mut, extra, health = self._jitted(mut, ro, feeds,
                                                            rng)
-        self._write_back(scope, new_mut, extra)
+            if _profiler.is_session():
+                # a start_profiler() session reports a step until the
+                # device is done (the reference-style table); nothing
+                # else pays the sync — not shard streaming, not a
+                # jax.profiler trace someone else started
+                jax.block_until_ready(fetches)
+        with _profiler.RecordEvent("exe:write_back", cat="executor"):
+            self._write_back(scope, new_mut, extra)
+            # the donated inputs are dead buffers now and the scope has
+            # let go of them: drop the last ~1000 references here, so
+            # that freeing them is timed as the hand-back it is and not
+            # left to whenever the caller's frame dies
+            mut.clear()
         return fetches, health
 
     def run_window(self, scope: Scope, feeds: Dict[str, Any], rng_base,
@@ -991,21 +1027,19 @@ class _CompiledBlock:
         a bad step's discard selects against THAT step's carry-in, so
         step i+1 of a faulted window continues from step i's pre-fault
         state)."""
-        mut, ro, feeds, rng_base = self._place_inputs(
-            scope, feeds, rng_base, window_names=window_names)
         from . import profiler as _profiler
-        if _profiler.is_profiling():
-            tag = "realdata" if window_names else "broadcast"
-            with _profiler.RecordEvent(f"window[{n_steps}]:{tag}",
-                                       cat="window"):
-                fetches, new_mut, extra, health = self._run_multi(
-                    mut, ro, feeds, rng_base, idx0, n_steps, window_names)
-                if _profiler.is_session():
-                    jax.block_until_ready(fetches)
-        else:
+        with self.place_span():
+            mut, ro, feeds, rng_base = self._place_inputs(
+                scope, feeds, rng_base, window_names=window_names)
+        tag = "realdata" if window_names else "broadcast"
+        with _profiler.RecordEvent(f"window[{n_steps}]:{tag}",
+                                   cat="window"):
             fetches, new_mut, extra, health = self._run_multi(
                 mut, ro, feeds, rng_base, idx0, n_steps, window_names)
-        self._write_back(scope, new_mut, extra)
+            if _profiler.is_session():
+                jax.block_until_ready(fetches)
+        with _profiler.RecordEvent("exe:write_back", cat="executor"):
+            self._write_back(scope, new_mut, extra)
         return fetches, health
 
     def _write_back(self, scope, new_mut, extra):
@@ -1052,14 +1086,10 @@ class _CompiledBlock:
                 jitted = jax.jit(many, donate_argnums=(0,))
                 self._multi_jit[key] = jitted
             from . import profiler as _profiler
-            if fresh and _profiler.is_profiling():
-                with _profiler.RecordEvent(
-                        f"compile:window[{n_steps}]", cat="compile",
-                        args={"n_steps": int(n_steps)}):
-                    ys, new_mut, healths = jitted(mut, ro, bcast, xs,
-                                                  rng_base,
-                                                  jnp.int32(idx0))
-            else:
+            with (_profiler.RecordEvent(
+                    f"compile:window[{n_steps}]", cat="compile",
+                    args={"n_steps": int(n_steps)})
+                  if fresh else contextlib.nullcontext()):
                 ys, new_mut, healths = jitted(mut, ro, bcast, xs,
                                               rng_base, jnp.int32(idx0))
             self._check_no_lod_fetch()  # lods appear during the trace
@@ -1279,7 +1309,7 @@ class _SegmentedBlock(_CompiledBlock):
             seg._cache = {}  # lod-key -> [jitted step, captured out lods]
 
     # -------------------------------------------------------------- step
-    def _seg_dispatch(self, seg, env, lod_env, rng, profiling):
+    def _seg_dispatch(self, seg, env, lod_env, rng):
         """Run one compiled segment: jit-cache keyed by the LoD of its
         inputs (trace-time-static, same contract as the fused path's
         feed-LoD-keyed program cache). When the numeric fault guard is
@@ -1320,17 +1350,13 @@ class _SegmentedBlock(_CompiledBlock):
         jitted, captured = entry
         donated = {n: env[n] for n in seg.donated_names if n in env}
         held = {n: env[n] for n in seg.in_names if n in env}
-        if profiling:
-            from . import profiler as _profiler
-            tag = "compile" if first else "exec"
-            with _profiler.RecordEvent(
-                    f"segment[{seg.start}:{seg.stop}]:{tag}",
-                    cat="segment"):
-                outs, seg_health = jitted(donated, held, rng)
-                if _profiler.is_session():
-                    jax.block_until_ready(outs)
-        else:
+        from . import profiler as _profiler
+        tag = "compile" if first else "exec"
+        with _profiler.RecordEvent(
+                f"segment[{seg.start}:{seg.stop}]:{tag}", cat="segment"):
             outs, seg_health = jitted(donated, held, rng)
+            if _profiler.is_session():
+                jax.block_until_ready(outs)
         env.update(outs)
         for n, lv in captured.items():
             if lv:
@@ -1401,7 +1427,7 @@ class _SegmentedBlock(_CompiledBlock):
                 for seg in self.segments:
                     if seg.kind == "compiled":
                         _outs, flag = self._seg_dispatch(
-                            seg, env, lod_env, rng, profiling)
+                            seg, env, lod_env, rng)
                         if flag is not None:
                             seg_flags.append(
                                 (f"segment[{seg.start}:{seg.stop}]", flag))
@@ -2025,6 +2051,73 @@ class Executor:
                 mon.observe(healthy, step)
         return healthy
 
+    def _find_block(self, program, feed, fetch_names, scope, feed_lods,
+                    mesh, param_shardings, compiled_ok):
+        """(block, hit): the cached `_CompiledBlock` / `_SegmentedBlock`
+        for this program, signature and scope — built on a miss — or
+        None where the block is known to run interpreted. Building does
+        not trace: that happens at the block's first dispatch."""
+        key = (id(program), program._version, tuple(sorted(feed)),
+               tuple(fetch_names), id(scope),
+               tuple(sorted(feed_lods.items())),
+               # the numeric fault guard is BAKED into the trace —
+               # flipping its flags rebuilds the program instead of
+               # silently running an unguarded (or stale-action)
+               # executable
+               (core.globals_["FLAGS_check_nan_inf"],
+                core.globals_["FLAGS_nan_inf_action"]),
+               None if mesh is None else
+               (tuple(mesh.shape.items()), tuple(map(id, mesh.devices.flat))),
+               None if not param_shardings else
+               tuple(sorted((k, str(v))
+                            for k, v in param_shardings.items())))
+        cached = self._compiled_cache.get(key)
+        # guard id() reuse: a dead scope's id can be recycled by a new
+        # scope with different state — every cache entry (including
+        # the "interpreted" unprofitable-key marker) validates a scope
+        # weakref before being trusted
+        cb, rebuild = None, True
+        if isinstance(cached, tuple):  # ("interpreted", scope_ref)
+            if cached[1]() is scope:
+                rebuild = False  # known unprofitable for this scope
+        elif cached is not None and cached._scope_ref() is scope:
+            cb, rebuild = cached, False
+        if rebuild:
+            # static-analysis choke point (docs/ANALYSIS.md): verify
+            # ONCE per program version at its first compile, BEFORE
+            # tracing — a structural defect gets a diagnostic with a
+            # fix hint instead of a deep TracerError. An error-level
+            # failure caches nothing, so a retry re-verifies.
+            _analysis.maybe_verify(
+                program, "executor", feed_names=tuple(sorted(feed)),
+                fetch_names=tuple(fetch_names),
+                param_shardings=param_shardings, scope=scope)
+            seed = (program.random_seed
+                    or core.globals_["FLAGS_seed"])
+            if compiled_ok:
+                cb = _CompiledBlock(program, tuple(sorted(feed)),
+                                    tuple(fetch_names), scope, seed,
+                                    mesh=mesh,
+                                    param_shardings=param_shardings,
+                                    feed_lods=feed_lods)
+            else:
+                cb = self._build_segmented(
+                    program, feed, fetch_names, scope, seed,
+                    feed_lods)
+            if cb is not None and cb.kind == "segmented":
+                # donation-safety cross-check against the plan the
+                # segmented build ACTUALLY produced (own dedup key:
+                # the plan exists only post-build)
+                _analysis.maybe_verify(
+                    program, "executor-plan",
+                    feed_names=tuple(sorted(feed)),
+                    fetch_names=tuple(fetch_names),
+                    segment_plan=cb.segments, scope=scope)
+            self._compiled_cache[key] = (
+                cb if cb is not None
+                else ("interpreted", weakref.ref(scope)))
+        return cb, not rebuild
+
     def run(self, program: Optional[Program] = None, feed=None,
             fetch_list=None, feed_var_name="feed", fetch_var_name="fetch",
             scope: Optional[Scope] = None, return_numpy: bool = True,
@@ -2036,25 +2129,28 @@ class Executor:
         per-dispatch host overhead amortizes to a single dispatch
         — the benchmark/training-loop shape. Interpreted programs run
         the steps sequentially and return the final fetch values."""
+        from . import profiler as _profiler
+        with contextlib.ExitStack() as stack:
+            if _profiler.is_profiling() \
+                    and _telemetry.current_trace() is None:
+                # trace correlation (docs/OBSERVABILITY.md): one root
+                # trace per run() — every span this step records (stage
+                # spans, segments, windows, the PS round's rpc calls and
+                # their pserver handler spans) shares one trace id, which
+                # is what makes a training round followable
+                # trainer→pserver in the merged cluster timeline.
+                # Serving/batch callers that already installed a context
+                # keep theirs. Entered here and not through a second
+                # run() frame: the step is traced under the same Python
+                # stack whether or not anything records.
+                stack.enter_context(_telemetry.trace_scope())
+            return self._run(program, feed, fetch_list, scope, return_numpy,
+                             use_prune, mesh, param_shardings, n_steps)
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy,
+             use_prune, mesh, param_shardings, n_steps):
         from .compiler import CompiledProgram
         from . import profiler as _profiler
-        if _profiler.is_profiling() and _telemetry.current_trace() is None:
-            # trace correlation (docs/OBSERVABILITY.md): one root trace
-            # per run() — every span this step records (segments,
-            # windows, the PS round's rpc calls and their pserver
-            # handler spans) shares one trace id, which is what makes a
-            # training round followable trainer→pserver in the merged
-            # cluster timeline. Serving/batch callers that already
-            # installed a context keep theirs.
-            with _telemetry.trace_scope():
-                return self.run(
-                    program=program, feed=feed, fetch_list=fetch_list,
-                    feed_var_name=feed_var_name,
-                    fetch_var_name=fetch_var_name, scope=scope,
-                    return_numpy=return_numpy,
-                    use_program_cache=use_program_cache,
-                    use_prune=use_prune, mesh=mesh,
-                    param_shardings=param_shardings, n_steps=n_steps)
         self._maybe_enable_compile_cache()
         if program is None:
             program = default_main_program()
@@ -2112,8 +2208,15 @@ class Executor:
             window_names = _window_feed_names(program, feed, n_steps)
 
         mode = core.globals_["FLAGS_executor_mode"]
-        compiled_ok = (mode == "compiled"
-                       and _ops_compilable(program.global_block().ops))
+
+        def compilable():
+            return (mode == "compiled"
+                    and _ops_compilable(program.global_block().ops))
+
+        # only a windowed run has to know BEFORE the feed upload whether
+        # its block compiles (the two fallbacks below); a single step
+        # walks its ops inside the exe:lookup span
+        compiled_ok = compilable() if n_steps > 1 or window_names else None
 
         if window_names and not compiled_ok:
             # Documented per-step fallback for windowed feeds on paths
@@ -2155,85 +2258,38 @@ class Executor:
         use_feed_cache = core.globals_["FLAGS_feed_device_cache"]
         feed_arrays = {}
         feed_lods = {}
-        for name, data in feed.items():
-            t = (self._feed_device_cached(name, data)
-                 if use_feed_cache else None)
-            if t is None:
-                t = _as_lodtensor(data, self.place)
-            scope.var(name).set_value(t)
-            feed_arrays[name] = t.array
-            lv = _normalize_lod(t.lod())
-            if lv:
-                feed_lods[name] = lv
-        # segmented compilation (default when the all-or-nothing check
-        # fails): jitted islands of pure ops around interpreted stateful
-        # ops, instead of interpreting the WHOLE block. Mesh runs keep
-        # their existing paths (compiled or interpreted).
-        try_segmented = (not compiled_ok and mode == "compiled"
-                         and mesh is None
-                         and core.globals_["FLAGS_executor_segmentation"])
-
-        cb = None
-        if compiled_ok or try_segmented:
-            key = (id(program), program._version, tuple(sorted(feed)),
-                   tuple(fetch_names), id(scope),
-                   tuple(sorted(feed_lods.items())),
-                   # the numeric fault guard is BAKED into the trace —
-                   # flipping its flags rebuilds the program instead of
-                   # silently running an unguarded (or stale-action)
-                   # executable
-                   (core.globals_["FLAGS_check_nan_inf"],
-                    core.globals_["FLAGS_nan_inf_action"]),
-                   None if mesh is None else
-                   (tuple(mesh.shape.items()), tuple(map(id, mesh.devices.flat))),
-                   None if not param_shardings else
-                   tuple(sorted((k, str(v))
-                                for k, v in param_shardings.items())))
-            cached = self._compiled_cache.get(key)
-            # guard id() reuse: a dead scope's id can be recycled by a new
-            # scope with different state — every cache entry (including
-            # the "interpreted" unprofitable-key marker) validates a scope
-            # weakref before being trusted
-            cb, rebuild = None, True
-            if isinstance(cached, tuple):  # ("interpreted", scope_ref)
-                if cached[1]() is scope:
-                    rebuild = False  # known unprofitable for this scope
-            elif cached is not None and cached._scope_ref() is scope:
-                cb, rebuild = cached, False
-            if rebuild:
-                # static-analysis choke point (docs/ANALYSIS.md): verify
-                # ONCE per program version at its first compile, BEFORE
-                # tracing — a structural defect gets a diagnostic with a
-                # fix hint instead of a deep TracerError. An error-level
-                # failure caches nothing, so a retry re-verifies.
-                _analysis.maybe_verify(
-                    program, "executor", feed_names=tuple(sorted(feed)),
-                    fetch_names=tuple(fetch_names),
-                    param_shardings=param_shardings, scope=scope)
-                seed = (program.random_seed
-                        or core.globals_["FLAGS_seed"])
-                if compiled_ok:
-                    cb = _CompiledBlock(program, tuple(sorted(feed)),
-                                        tuple(fetch_names), scope, seed,
-                                        mesh=mesh,
-                                        param_shardings=param_shardings,
-                                        feed_lods=feed_lods)
-                else:
-                    cb = self._build_segmented(
-                        program, feed, fetch_names, scope, seed,
-                        feed_lods)
-                if cb is not None and cb.kind == "segmented":
-                    # donation-safety cross-check against the plan the
-                    # segmented build ACTUALLY produced (own dedup key:
-                    # the plan exists only post-build)
-                    _analysis.maybe_verify(
-                        program, "executor-plan",
-                        feed_names=tuple(sorted(feed)),
-                        fetch_names=tuple(fetch_names),
-                        segment_plan=cb.segments, scope=scope)
-                self._compiled_cache[key] = (
-                    cb if cb is not None
-                    else ("interpreted", weakref.ref(scope)))
+        # host batch -> device array; the upload is enqueued, not awaited
+        with _profiler.RecordEvent("exe:feed", cat="executor") as span:
+            for name, data in feed.items():
+                t = (self._feed_device_cached(name, data)
+                     if use_feed_cache else None)
+                if t is None:
+                    t = _as_lodtensor(data, self.place)
+                scope.var(name).set_value(t)
+                feed_arrays[name] = t.array
+                lv = _normalize_lod(t.lod())
+                if lv:
+                    feed_lods[name] = lv
+            span.args = {"arrays": len(feed_arrays),
+                         "bytes": sum(int(getattr(a, "nbytes", 0))
+                                      for a in feed_arrays.values())}
+        # which way the block runs, and the block: compiled whole;
+        # segmented (default when the all-or-nothing check fails: jitted
+        # islands of pure ops around interpreted stateful ops, instead of
+        # interpreting the WHOLE block; mesh runs keep their existing
+        # paths, compiled or interpreted); or interpreted (no block)
+        with _profiler.RecordEvent("exe:lookup", cat="executor") as span:
+            if compiled_ok is None:
+                compiled_ok = compilable()
+            try_segmented = (
+                not compiled_ok and mode == "compiled" and mesh is None
+                and core.globals_["FLAGS_executor_segmentation"])
+            cb, hit = None, False
+            if compiled_ok or try_segmented:
+                cb, hit = self._find_block(
+                    program, feed, fetch_names, scope, feed_lods, mesh,
+                    param_shardings, compiled_ok)
+            span.args = {"hit": hit}
 
         if cb is not None and cb.kind == "compiled":
             if n_steps > 1 or window_names:
@@ -2245,8 +2301,11 @@ class Executor:
                 self._process_health(cb, program, scope, health, idx0,
                                      n_steps)
             else:
-                rng = self._next_rng(scope, program)
-                fetched, health = cb.run(scope, feed_arrays, rng)
+                with cb.place_span():
+                    # deriving the step's key is one small dispatch
+                    rng = self._next_rng(scope, program)
+                    placed = cb._place_inputs(scope, feed_arrays, rng)
+                fetched, health = cb.run_placed(scope, placed)
                 self._process_health(
                     cb, program, scope, health,
                     Executor._rng_counters.get(scope, 1) - 1, 1, rng=rng)
@@ -2299,8 +2358,10 @@ class Executor:
         self._maybe_auto_checkpoint(program, scope)
 
         if fetch_names and return_numpy:
-            return [_restore_fetch_dtype(program, n, _fetch_to_host(f))
-                    for n, f in zip(fetch_names, fetched)]
+            # where the plain loop waits for the device
+            with _profiler.RecordEvent("exe:fetch", cat="executor"):
+                return [_restore_fetch_dtype(program, n, _fetch_to_host(f))
+                        for n, f in zip(fetch_names, fetched)]
         if fetch_names:
             # LoDTensor fetches stay LAZY device arrays (the async
             # training-loop contract — no per-step sync); only a

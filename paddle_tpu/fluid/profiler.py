@@ -66,7 +66,6 @@ def _ring(maxlen_hint: Optional[int] = None) -> deque:
 class _ProfilerState:
     def __init__(self):
         self.enabled = False
-        self.state = "All"
         self.events: deque = _ring(1024)
         self.dropped = 0
         self.lock = threading.Lock()
@@ -117,7 +116,6 @@ def start_profiler(state="All", tracer_option="Default",
         _prof.events = _ring()
         _prof.dropped = 0
     _prof.enabled = True
-    _prof.state = state
     _prof.t0 = time.perf_counter()
     _prof.device_tracing = state in ("GPU", "All")
     if _prof.device_tracing:
@@ -258,7 +256,8 @@ def concurrent_seconds(cat_a: str, cat_b: str, events=None) -> float:
 
 class RecordEvent:
     """RAII span (reference platform/profiler.h:124). Usable as a context
-    manager or decorator; no-op when profiling is off. ``cat`` groups
+    manager or decorator; when profiling is off it is a bare
+    `jax.profiler.TraceAnnotation` and records nothing here. ``cat`` groups
     spans in the chrome trace — the segmented executor emits its
     per-segment compile/exec and island spans under cat='segment' so the
     compiled/interpreted partition of a step is visible at a glance,
@@ -283,23 +282,23 @@ class RecordEvent:
         self._start = 0.0
 
     def __enter__(self):
-        if _prof.enabled:
+        # always a TraceAnnotation, as JAX instruments its own dispatch
+        # path: outside a jax.profiler trace it records nothing (well
+        # under a microsecond), inside one — this module's session, a
+        # benchmark's, an operator's jax.profiler.start_server — the
+        # span is on the device events' clock with no call into the
+        # program. The ring and the FLAGS_trace_dir shard stay gated.
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if is_profiling():
             self._start = time.perf_counter()
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        elif telemetry.shard_active():
-            # FLAGS_trace_dir shard-only mode: record the span without
-            # the jax device-trace annotation (no XPlane session is on)
-            self._start = time.perf_counter()
-            self._ann = None
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb):
-        # gate on the per-span state, not the global flag: a stop_profiler
-        # landing mid-span must not leak the entered TraceAnnotation
+        self._ann.__exit__(exc_type, exc_val, exc_tb)
+        # gate on the per-span state, not the global flag: a span that a
+        # stop_profiler lands in is still recorded whole
         if self._start:
-            if self._ann is not None:
-                self._ann.__exit__(exc_type, exc_val, exc_tb)
             _record(self.name, self._start, time.perf_counter(), self.cat,
                     self.args)
             self._start = 0.0

@@ -44,7 +44,7 @@ __all__ = [
     "new_span_id", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "REGISTRY", "note_clock_offset", "clock_offsets", "set_process_role",
     "process_role", "shard_active", "shard_record", "flush_trace_shard",
-    "trace_shard_path", "start_metrics_server", "maybe_start_metrics_server",
+    "start_metrics_server", "maybe_start_metrics_server",
     "metrics_server_port", "count_compile", "install_jax_compile_listener",
 ]
 
@@ -181,13 +181,6 @@ class _Child:
         with self._lock:
             self._value += n
 
-    def dec(self, n: float = 1) -> None:
-        if self._family.kind != "gauge":
-            raise TypeError(f"{self._family.name}: dec() on a "
-                            f"{self._family.kind}")
-        with self._lock:
-            self._value -= n
-
     def set(self, v: float) -> None:
         if self._family.kind != "gauge":
             raise TypeError(f"{self._family.name}: set() on a "
@@ -271,9 +264,6 @@ class _MetricFamily:
     def inc(self, n: float = 1) -> None:
         self._solo().inc(n)
 
-    def dec(self, n: float = 1) -> None:
-        self._solo().dec(n)
-
     def set(self, v: float) -> None:
         self._solo().set(v)
 
@@ -296,12 +286,6 @@ class Counter(_MetricFamily):
 class Gauge(_MetricFamily):
     def __init__(self, name, help="", labelnames=()):
         super().__init__(name, "gauge", help, labelnames)
-        self._fn: Optional[Callable[[], float]] = None
-
-    def set_function(self, fn: Callable[[], float]) -> "Gauge":
-        """Compute the (label-less) gauge at scrape time."""
-        self._fn = fn
-        return self
 
 
 class Histogram(_MetricFamily):
@@ -427,11 +411,6 @@ class MetricsRegistry:
                 else:
                     entry["samples"].append(
                         (dict(ch.labels_dict), ch.value()))
-            if isinstance(fam, Gauge) and fam._fn is not None:
-                try:
-                    entry["samples"].append(({}, fam._fn()))
-                except Exception:
-                    _LOG.exception("gauge function %s failed", fam.name)
         for prefix, fn, labels, _h in views:
             try:
                 stats = fn() or {}
@@ -493,38 +472,86 @@ def count_compile(kind: str, retrace: bool = False) -> None:
 _JAX_LISTENER_LOCK = threading.Lock()
 _JAX_LISTENER_INSTALLED = False
 
+# What jax.monitoring reports while JAX compiles (names as jax 0.9.0 has
+# them, jax/_src/dispatch.py and compiler.py): event -> (counter, help,
+# the cat="compile" span or instant recorded beside it).
+_JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": (
+        "jax_trace_seconds_total",
+        "seconds tracing Python into jaxprs; a jit traced inside another "
+        "counts in its own event and in its caller's",
+        "compile:trace"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": (
+        "jax_lower_seconds_total",
+        "seconds lowering jaxprs to MLIR modules", "compile:lower"),
+    "/jax/core/compile/backend_compile_duration": (
+        "jax_backend_compile_seconds_total",
+        "seconds in XLA backend compiles, or in loading them from the "
+        "persistent cache", "compile:backend"),
+}
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": (
+        "jax_compile_cache_hits_total",
+        "executables loaded from the persistent compilation cache",
+        "compile:cache_hit"),
+    "/jax/compilation_cache/cache_misses": (
+        "jax_compile_cache_misses_total",
+        "executables compiled and written to the persistent compilation "
+        "cache (those under its size or time thresholds are not counted)",
+        "compile:cache_miss"),
+}
+
 
 def install_jax_compile_listener() -> bool:
-    """Register a jax.monitoring duration listener ONCE per process:
-    every backend compile bumps ``jax_backend_compiles_total`` and
-    (when the profiler records) emits a cat="compile" span — ground
-    truth that catches retraces the executor's explicit cache counters
-    cannot see (shape-driven retraces inside one jit). Zero cost on
-    the steady-state path: jax only calls listeners when a compile
-    actually happens."""
+    """Register the jax.monitoring listeners ONCE per process. They
+    count where JAX compiles — seconds tracing, lowering and in the
+    backend, the number of backend compiles, persistent-cache hits and
+    misses (the tables above) — and, when the profiler records, emit a
+    cat="compile" span or instant for each: ground truth that catches
+    what the executor's explicit cache counters cannot see (the step's
+    second signature, shape-driven retraces inside one jit, whether a
+    "compile" was a load). Zero cost on the steady-state path: jax only
+    calls listeners when it compiles."""
     global _JAX_LISTENER_INSTALLED
     with _JAX_LISTENER_LOCK:
         if _JAX_LISTENER_INSTALLED:
             return True
         import jax.monitoring as _mon
 
-        counter = REGISTRY.counter(
-            "jax_backend_compiles_total",
-            "XLA backend compiles observed via jax.monitoring")
+        compiles = ("jax_backend_compiles_total",
+                    "XLA backend compiles observed via jax.monitoring")
+        # created now, so that a scrape before the first compile reads 0
+        REGISTRY.counter(*compiles)
+        for name, help_, _span in (*_JAX_DURATIONS.values(),
+                                   *_JAX_EVENTS.values()):
+            REGISTRY.counter(name, help_)
 
         def _on_duration(event: str, duration: float, **kw):
-            if not event.endswith("backend_compile_duration"):
+            known = _JAX_DURATIONS.get(event)
+            if known is None:
                 return
-            counter.inc()
+            name, help_, span = known
+            REGISTRY.counter(name, help_).inc(float(duration))
+            if span == "compile:backend":
+                REGISTRY.counter(*compiles).inc()
             from . import profiler as _profiler
             if _profiler.is_profiling():
                 now = time.perf_counter()
                 _profiler.record_span(
-                    "compile:backend", now - float(duration), now,
-                    cat="compile",
+                    span, now - float(duration), now, cat="compile",
                     args={"seconds": round(float(duration), 6)})
 
+        def _on_event(event: str, **kw):
+            known = _JAX_EVENTS.get(event)
+            if known is None:
+                return
+            name, help_, instant = known
+            REGISTRY.counter(name, help_).inc()
+            from . import profiler as _profiler
+            _profiler.record_instant(instant, cat="compile")
+
         _mon.register_event_duration_secs_listener(_on_duration)
+        _mon.register_event_listener(_on_event)
         _JAX_LISTENER_INSTALLED = True
         return True
 
@@ -755,11 +782,6 @@ def flush_trace_shard() -> Optional[str]:
         return None
     w.flush()
     return w.path
-
-
-def trace_shard_path() -> Optional[str]:
-    w = _shard()
-    return None if w is None else w.path
 
 
 def reset_trace_shard() -> None:

@@ -322,6 +322,7 @@ def _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_INTERPRET and not _on_tpu(),
+        name="flash_fwd",
     )(*args)
     # lse stays in its (B·H, S, LANES) wire form — the backward consumes
     # it as-is, so no slice-then-rebroadcast materialization
@@ -516,6 +517,7 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
+        name="flash_bwd_dkv",
     )(*kv_args)
 
     q_specs = [
@@ -542,6 +544,7 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
+        name="flash_bwd_dq",
     )(*q_args)
 
     shape = (B, H, S, D)

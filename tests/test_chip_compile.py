@@ -86,6 +86,27 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         qkv, qkv, qkv, seed, bias).compile()
     assert compiled.as_text().count(KERNEL) == 3
+    assert _kernel_names(compiled.as_text()) == {
+        (None, "flash_fwd"): 1, (None, "flash_bwd_dkv"): 1,
+        (None, "flash_bwd_dq"): 1}
+
+
+def _kernel_names(text):
+    """{(Fluid-op scope, kernel name): custom calls} of a compiled
+    module's text: what a device trace of the chip is read by
+    (benchmark/trace_scopes.py). The name is what stands before
+    `/pallas_call` in the op_name, out of the `jvp(...)` the grad op's
+    vjp wraps it in."""
+    import re
+    found = {}
+    for line in text.splitlines():
+        if KERNEL in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            scope = re.search(r"(?:^|[/(])((?:fwd|bwd|opt)/\w+)", op_name)
+            kernel = re.search(r"(\w+)\)*/pallas_call$", op_name).group(1)
+            key = (scope and scope.group(1), kernel)
+            found[key] = found.get(key, 0) + 1
+    return found
 
 
 def _bert_base_width_step(layers, batch, seq_len, one_chip, mesh=None,
@@ -155,6 +176,11 @@ def test_bert_base_width_train_step_compiles_for_v5e(topo, one_chip,
     # per layer: the forward kernel twice (the forward op, and again
     # inside the grad op's vjp) + dK/dV + dQ
     assert text.count(KERNEL) == 4 * 2
+    assert _kernel_names(text) == {
+        ("fwd/fused_attention_qkv", "flash_fwd"): 2,
+        ("bwd/fused_attention_qkv_grad", "flash_fwd"): 2,
+        ("bwd/fused_attention_qkv_grad", "flash_bwd_dkv"): 2,
+        ("bwd/fused_attention_qkv_grad", "flash_bwd_dq"): 2}
     assert ("all-reduce" in text) == on_mesh
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

@@ -551,6 +551,76 @@ def test_executor_compile_and_retrace_counters():
         assert reg.counter("jax_backend_compiles_total").value() > 0
 
 
+def test_jax_compile_counters_move_when_jax_compiles_and_only_then(
+        tmp_path):
+    """The listener's counters where JAX compiles: seconds tracing,
+    lowering and in the backend, persistent-cache hits and misses. They
+    move on the step's first compile AND on its second signature (step
+    2 runs on committed state: a compile `executor_compiles_total`
+    never sees), stand still from then on, and each leaves a
+    cat="compile" span or instant in a recording profiler."""
+    import jax
+    import paddle_tpu.fluid as fluid
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.fluid import core, profiler, telemetry
+
+    def snap():
+        return {n: telemetry.REGISTRY.counter(n).value() for n in (
+            "jax_trace_seconds_total", "jax_lower_seconds_total",
+            "jax_backend_compile_seconds_total",
+            "jax_backend_compiles_total", "jax_compile_cache_hits_total",
+            "jax_compile_cache_misses_total")}
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.data("x", shape=[4], dtype="float32")
+        loss = fluid.layers.mean(fluid.layers.fc(x, 3))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    exe, scope = fluid.Executor(), core.Scope()  # installs the listener
+    feed = {"x": np.ones((2, 4), np.float32)}
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    profiler.start_profiler(state="CPU")
+    try:
+        exe.run(startup, scope=scope)
+        before = snap()
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        first = snap()
+        moved = {n for n in first if first[n] > before[n]}
+        assert moved == set(first) - {"jax_compile_cache_hits_total"}
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        second = snap()
+        assert second["jax_backend_compiles_total"] \
+            > first["jax_backend_compiles_total"]
+        for _ in range(2):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        assert snap() == second
+        spans = {e["name"] for e in profiler.snapshot_events()
+                 if e["cat"] == "compile"}
+        assert {"compile:trace", "compile:lower", "compile:backend",
+                "compile:cache_miss", "compile:step"} <= spans
+        # the same step from a second executor: compiled again by jax,
+        # found in the persistent cache this time
+        other = fluid.Executor()
+        other.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        assert snap()["jax_compile_cache_hits_total"] \
+            > second["jax_compile_cache_hits_total"]
+        assert snap()["jax_compile_cache_misses_total"] \
+            == second["jax_compile_cache_misses_total"]
+    finally:
+        profiler.stop_profiler(profile_path="")
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
 # ======================================================================
 # trace shards + timeline merge
 # ======================================================================

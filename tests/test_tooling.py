@@ -2,6 +2,7 @@
 contrib estimators (reference: platform/profiler.h, tools/timeline.py,
 operators/benchmark/op_tester.cc, fluid/debugger.py, contrib/
 memory_usage_calc.py, op_frequence.py, extend_optimizer/)."""
+import contextlib
 import json
 import subprocess
 import sys
@@ -77,6 +78,124 @@ def test_profiler_eager_per_op_spans(tmp_path):
     names = {e.name for e in _prof.events}
     profiler.stop_profiler(profile_path="")
     assert "mul" in names or "elementwise_add" in names
+
+
+def _train_program(optimizer):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.data("x", shape=[8], dtype="float32")
+        y = fluid.data("y", shape=[1], dtype="int64")
+        h = fluid.layers.fc(x, 16, act="relu")
+        p = fluid.layers.fc(h, 4, act="softmax")
+        loss = fluid.layers.mean(fluid.layers.cross_entropy(p, y))
+        optimizer.minimize(loss)
+    feed = {"x": np.ones((4, 8), "float32"), "y": np.zeros((4, 1), "int64")}
+    return main, startup, loss, feed
+
+
+def _step_block(exe):
+    return [c for c in exe._compiled_cache.values()
+            if getattr(c, "kind", None) == "compiled"][-1]
+
+
+@pytest.mark.parametrize("optimizer,op", [
+    (lambda: fluid.optimizer.Adam(1e-3), "adam"),
+    (lambda: fluid.optimizer.Momentum(0.1, 0.9), "momentum")])
+def test_compiled_step_carries_fluid_op_scopes(optimizer, op):
+    """Every Fluid op is traced under `<phase>/<op type>`: the compiled
+    module's instructions, fusions included, say in their op_name which
+    op of which phase they came from (docs/OBSERVABILITY.md)."""
+    import re
+    import jax
+    main, startup, loss, feed = _train_program(optimizer())
+    exe, scope = fluid.Executor(), core.Scope()
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    text = _step_block(exe).lowered(
+        scope, {k: jax.numpy.asarray(v) for k, v in feed.items()},
+        jax.random.key(0)).compile().as_text()
+    scopes = set(re.findall(r'op_name="jit\(_step\)/((?:fwd|bwd|opt)/\w+)',
+                            text))
+    assert {"fwd/mul", "fwd/relu", "fwd/softmax", "fwd/cross_entropy",
+            "fwd/mean", "bwd/mul_grad", "bwd/relu_grad", "bwd/softmax_grad",
+            "opt/" + op} <= scopes
+    # the loss op (role 256) is forward; no grad op is, no optimizer op
+    assert not [s for s in scopes if s.startswith("fwd/")
+                and (s.endswith("_grad") or s == "fwd/" + op)]
+    fusions = [ln for ln in text.splitlines() if " fusion(" in ln]
+    assert fusions and all("op_name=" in ln for ln in fusions)
+
+
+def test_profiler_session_does_not_change_the_lowered_step():
+    """One call site, no extra frame: the step's lowered text WITH debug
+    info (the Python stack it was traced under, which a Pallas kernel
+    carries into the compile-cache key) is the same inside a profiler
+    session and outside one."""
+    def lowered_under(session):
+        with fluid.unique_name.guard():  # the same variable names twice
+            main, startup, loss, feed = _train_program(
+                fluid.optimizer.SGD(0.1))
+        exe, scope = fluid.Executor(), core.Scope()
+        exe.run(startup, scope=scope)
+        texts = []
+
+        def two_steps():
+            # the first traces the step, under the stack in question;
+            # the second goes through the same frames to where the
+            # executor calls the jitted step, and lowers it there
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+            block = _step_block(exe)
+            jitted = block._jitted
+
+            def lowering(*args):
+                texts.append(jitted.lower(*args).as_text(debug_info=True))
+                return jitted(*args)
+            block._jitted = lowering
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        with (profiler.profiler(state="CPU", profile_path="") if session
+              else contextlib.nullcontext()):
+            two_steps()  # one line: the test's own frame is in the text
+        return texts[0]
+
+    # one call site for all three (its line and column are in the text);
+    # the first warms jax.numpy's own trace caches, which number the
+    # locations of a cold trace differently
+    _, plain, in_session = [lowered_under(s) for s in (False, False, True)]
+    assert "executor.py" in plain  # the debug info is there
+    assert plain == in_session
+
+
+def test_stage_spans_once_a_step_nested_in_one_trace(tmp_path):
+    """The six stage spans of the compiled one-dispatch path, in a
+    session's events: once a step, in order, inside one another's gaps
+    and never overlapping, one trace id a step."""
+    main, startup, loss, feed = _train_program(fluid.optimizer.SGD(0.1))
+    exe, scope = fluid.Executor(), core.Scope()
+    exe.run(startup, scope=scope)
+    for _ in range(2):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    stages = ["exe:feed", "exe:lookup", "exe:place", "compiled_step",
+              "exe:write_back", "exe:fetch"]
+    with profiler.profiler(state="CPU", profile_path=""):
+        for _ in range(3):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        events = [e for e in profiler.snapshot_events()
+                  if e["cat"] == "executor"]
+    assert sorted(e["name"] for e in events) == sorted(stages * 3)
+    events.sort(key=lambda e: e["start"])
+    assert [e["name"] for e in events] == stages * 3
+    assert all(a["end"] <= b["start"] for a, b in zip(events, events[1:]))
+    by_trace = {}
+    for e in events:
+        by_trace.setdefault(e["trace_id"], []).append(e["name"])
+    assert None not in by_trace and list(by_trace.values()) == [stages] * 3
+    feed_span = events[0]
+    assert feed_span["args"] == {"arrays": 2, "bytes": 4 * 8 * 4 + 4 * 4}
+    assert events[1]["args"] == {"hit": True}
+    assert events[2]["args"]["arrays"] >= 4
+    # outside a session nothing is recorded, and nothing syncs
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    assert not profiler.is_profiling()
 
 
 # ----------------------------------------------------------------- timeline
