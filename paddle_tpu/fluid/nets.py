@@ -82,7 +82,7 @@ def scaled_dot_product_attention(queries, keys, values, num_heads=1,
     """Multi-head scaled dot-product attention over [B, S, D] tensors
     (reference nets.py scaled_dot_product_attention). On TPU the whole
     expression fuses into the jitted step; the Pallas flash-attention path
-    serves the fused multihead_matmul op."""
+    serves the fused attention ops past one kernel block (s128)."""
     if queries.shape[-1] != keys.shape[-1]:
         raise ValueError("queries and keys must have the same hidden size")
     if keys.shape[-2] != values.shape[-2] if len(keys.shape) > 2 else False:

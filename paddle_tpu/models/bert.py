@@ -4,9 +4,10 @@ python/paddle/fluid/tests/unittests/test_imperative_transformer* and the
 ERNIE/BERT configs named in BASELINE.md; fused attention replaces the
 reference's fused/multihead_matmul_op.cu).
 
-Attention goes through the `multihead_matmul` op, which dispatches to the
-Pallas flash-attention kernel on TPU (ops/pallas/flash_attention.py) and a
-plain jax composition elsewhere."""
+Attention goes through the `fused_attention_qkv` op, which on a TPU runs
+the Pallas flash-attention kernels (ops/pallas/flash_attention.py) for
+sequences past one kernel block (128) and XLA's dense attention for
+shorter ones and everywhere else (ops/attention_ops._use_flash)."""
 from __future__ import annotations
 
 import math
@@ -29,7 +30,7 @@ def bert_base_config():
 
 def fused_multihead_attention(q, k, v, n_head, dropout_rate=0.0,
                               attn_bias=None, causal=False):
-    """One fused attention op (Pallas on TPU). q/k/v: [B, S, H];
+    """One fused attention op (Pallas on TPU past s128). q/k/v: [B, S, H];
     attn_bias: optional additive mask broadcastable to [B, H, Sq, Sk]."""
     helper = LayerHelper("multihead_matmul")
     out = helper.create_variable_for_type_inference(q.dtype)

@@ -1,13 +1,17 @@
 """Fused attention ops.
 
 ``fused_attention_qkv``: the TPU-native fused attention op used by
-models/bert.py — Q/K/V [B, S, H·D] → context [B, S, H·D], dispatching to
-the Pallas flash-attention kernel on TPU.
+models/bert.py — Q/K/V [B, S, H·D] → context [B, S, H·D].
 
 ``multihead_matmul``: wire-compatible with the reference's fused inference
 op (reference: operators/fused/multihead_matmul_op.cu — Input [B,S,3,H,D]
 packed QKV + BiasQK additive mask), so reference-transpiled inference
 programs run.
+
+Both compute the same attention one of two ways, chosen per call by
+``_use_flash`` from the call's bias form and sequence lengths: the Pallas
+flash kernels (ops/pallas/flash_attention.py) where there are K/V blocks
+to stream, ``_dense_attention`` (plain XLA ops) everywhere else.
 """
 from __future__ import annotations
 
@@ -20,6 +24,16 @@ from .registry import register_op, register_grad_maker, first, out
 from .math_ops import mxu_available as _mxu_backend
 from .pallas.flash_attention import flash_attention, _use_kernels
 
+# Longest sequence (queries AND keys) that takes XLA's dense attention
+# where the flash kernels could serve the call. 128 is the kernels' block
+# (DEFAULT_BLOCK_Q / DEFAULT_BLOCK_K): at or below it a head's whole score
+# tile is ONE block — nothing is streamed, the online softmax has one step
+# and the grid is (B·H, 1, 1) — and on the v5e the kernels take 3.5 times
+# the dense computation (tools/attention_paths.py; PERF.md §6, PR 27).
+# It moves only with that table run again AND a benchmark cell above it:
+# dense holds an S×S tensor a head for the backward, the kernels do not.
+DENSE_MAX_SEQ = 128
+
 
 def _keypad_bias(bias, q, k):
     """[B, Sk] view of ``bias`` iff it is EXACTLY the key-padding form
@@ -31,6 +45,46 @@ def _keypad_bias(bias, q, k):
             and bias.shape[3] == k.shape[2]:
         return bias.reshape(bias.shape[0], bias.shape[3])
     return None
+
+
+def _use_flash(bias, kp_bias, sq, sk):
+    """The one choice between the two paths, read by both ops: the
+    kernels serve a call they have a form for — no bias, or the exact
+    key-padding bias ``kp_bias`` (``_keypad_bias``) — on a backend that
+    runs them (``_use_kernels``), once either length passes
+    ``DENSE_MAX_SEQ``. Every other call is ``_dense_attention``'s."""
+    return (_use_kernels() and (bias is None or kp_bias is not None)
+            and max(sq, sk) > DENSE_MAX_SEQ)
+
+
+def _dense_attention(q, k, v, sm_scale, bias=None, causal=False,
+                     dropout_rate=0.0, rng=None):
+    """softmax(scale·QKᵀ + bias)·V in XLA's own ops; q, k, v [B, H, S, D],
+    ``bias`` broadcastable to [B, H, Sq, Sk], result f32 [B, H, Sq, D].
+
+    The flash kernels' f32-accumulation contract: bf16 MXU tiles
+    accumulate in f32 (preferred_element_type), so the softmax
+    statistics see f32 scores — NOT scores rounded to bf16 by a
+    bf16-output dot (r5 advisor finding: the two paths diverged for the
+    same program) — and the probabilities are cast to the operands'
+    dtype for P·V. Causal masking is top-left aligned. Dropout draws
+    its mask from ``rng`` (the kernels hash theirs in-kernel: another
+    stream of the same distribution). A row whose keys are ALL masked
+    by a finite bias attends uniformly here; the kernels emit zeros."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * sm_scale
+    if bias is not None:
+        s = s + bias.astype(jnp.float32)
+    if causal:
+        idx_q = jnp.arange(q.shape[2])[:, None]
+        idx_k = jnp.arange(k.shape[2])[None, :]
+        s = jnp.where(idx_q >= idx_k, s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    if dropout_rate > 0.0:
+        keep = jax.random.bernoulli(rng, 1.0 - dropout_rate, p.shape)
+        p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0).astype(p.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                      preferred_element_type=jnp.float32)
 
 
 def _split_heads(x, n_head):
@@ -52,12 +106,13 @@ def _fused_attention_qkv(ins, attrs):
     """Optional Bias: additive attention mask broadcastable to
     [B, H, Sq, Sk] (e.g. padding mask [B, 1, 1, Sk] with -inf/0).
 
-    Dispatch: the Pallas flash kernel serves the no-bias case AND the
-    exact key-padding bias form [B, 1, 1, Sk] (in-kernel); attention
-    dropout runs INSIDE the kernel (mask regenerated in the backward,
-    seeded per step from the executor rng). The einsum path (XLA fuses
-    it) serves every other bias shape and shapes the kernel doesn't
-    cover. Causal masking is TOP-LEFT aligned (query i sees keys <= i)
+    Dispatch (``_use_flash``): above ``DENSE_MAX_SEQ`` the Pallas flash
+    kernels serve the no-bias case AND the exact key-padding bias form
+    [B, 1, 1, Sk] (in-kernel), with attention dropout INSIDE the kernel
+    (mask regenerated in the backward, seeded per step from the executor
+    rng). ``_dense_attention`` serves every other bias shape, every call
+    whose score tile fits one kernel block, and a backend without the
+    kernels. Causal masking is TOP-LEFT aligned (query i sees keys <= i)
     on both paths."""
     q = first(ins, "Q")
     k = first(ins, "K")
@@ -78,37 +133,17 @@ def _fused_attention_qkv(ins, attrs):
     causal = attrs.get("causal", False)
     drop = float(attrs.get("dropout_rate", 0.0) or 0.0)
     kp_bias = _keypad_bias(bias, qh, kh)
-    flash_can = _use_kernels() and (bias is None or kp_bias is not None)
-    if (bias is None and drop == 0.0) or flash_can:
+    if _use_flash(bias, kp_bias, qh.shape[2], kh.shape[2]):
         seed = None
         if drop > 0.0:
             seed = jax.random.randint(attrs["_rng"], (1,), 0,
                                       2 ** 31 - 1, dtype=jnp.int32)
         o = flash_attention(qh, kh, vh, sm_scale, causal,
                             dropout_rate=drop, dropout_seed=seed,
-                            bias=kp_bias if flash_can else None)
+                            bias=kp_bias)
     else:
-        # f32-accumulation contract shared with the flash kernel: bf16
-        # MXU tiles accumulate in f32 (preferred_element_type), so the
-        # softmax statistics see f32 scores — NOT scores rounded to bf16
-        # by a bf16-output dot. Without this the two dispatch paths
-        # diverge numerically for the same program depending on bias
-        # shape (r5 advisor finding).
-        s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                       preferred_element_type=jnp.float32) * sm_scale
-        if bias is not None:
-            s = s + bias.astype(jnp.float32)
-        if causal:
-            S, Sk = qh.shape[2], kh.shape[2]
-            idx_q = jnp.arange(S)[:, None]
-            idx_k = jnp.arange(Sk)[None, :]
-            s = jnp.where(idx_q >= idx_k, s, jnp.finfo(jnp.float32).min)
-        p = jax.nn.softmax(s, axis=-1).astype(qh.dtype)
-        if drop > 0.0:
-            keep = jax.random.bernoulli(attrs["_rng"], 1.0 - drop, p.shape)
-            p = jnp.where(keep, p / (1.0 - drop), 0.0).astype(p.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p, vh,
-                       preferred_element_type=jnp.float32)
+        o = _dense_attention(qh, kh, vh, sm_scale, bias, causal, drop,
+                             attrs.get("_rng"))
     return out(Out=_merge_heads(o).astype(out_dtype))
 
 
@@ -124,7 +159,13 @@ def _multihead_matmul(ins, attrs):
     multihead_matmul_fuse_pass_v2 packs, ir/multihead_matmul_fuse_pass.cc:470);
     the op does QKV projection + alpha·QKᵀ + BiasQK + softmax + PV + merge
     in one fused computation. Pre-projected packed-QKV inputs
-    ([B,S,3,H,D] / [B,S,3HD] without W) are also accepted."""
+    ([B,S,3,H,D] / [B,S,3HD] without W) are also accepted.
+
+    Dispatch is ``fused_attention_qkv``'s (``_use_flash``): the flash
+    kernels above ``DENSE_MAX_SEQ`` for no BiasQK or the exact
+    key-padding form [B,1,1,Sk] (the common BERT inference padding
+    mask); ``_dense_attention`` for short sequences and generic
+    [B,H,Sq,Sk] biases."""
     x = first(ins, "Input")
     w = first(ins, "W")
     b = first(ins, "Bias")
@@ -149,22 +190,9 @@ def _multihead_matmul(ins, attrs):
         q = jnp.transpose(x5[:, :, 0], (0, 2, 1, 3))
         k = jnp.transpose(x5[:, :, 1], (0, 2, 1, 3))
         v = jnp.transpose(x5[:, :, 2], (0, 2, 1, 3))
-    # Fast path (the reference op IS its fast path — multihead_matmul_op.cu):
-    # no bias, or the exact key-padding BiasQK form [B,1,1,Sk] (the common
-    # BERT inference padding mask), dispatches to the Pallas flash kernel
-    # via its in-kernel bias input. Generic [B,H,Sq,Sk] biases keep the
-    # einsum path (XLA fuses it).
     kp_bias = _keypad_bias(bias_qk, q, k)
-    if _use_kernels() and (bias_qk is None or kp_bias is not None):
+    if _use_flash(bias_qk, kp_bias, q.shape[2], k.shape[2]):
         o = flash_attention(q, k, v, alpha, causal=False, bias=kp_bias)
     else:
-        # same f32-accumulation contract as the flash path (see
-        # _fused_attention_qkv above)
-        s_mat = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                           preferred_element_type=jnp.float32) * alpha
-        if bias_qk is not None:
-            s_mat = s_mat + bias_qk.astype(jnp.float32)
-        p = jax.nn.softmax(s_mat, axis=-1).astype(q.dtype)
-        o = jnp.einsum("bhqk,bhkd->bhqd", p, v,
-                       preferred_element_type=jnp.float32).astype(q.dtype)
+        o = _dense_attention(q, k, v, alpha, bias_qk).astype(q.dtype)
     return out(Out=_merge_heads(o))

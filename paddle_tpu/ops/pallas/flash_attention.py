@@ -31,9 +31,16 @@ real kernel, not a fallback. Ragged lengths (S or Sk not divisible by
 the block) STAY on the kernel: boundary blocks are handled by in-kernel
 bounds masking, with padded tile regions zeroed at load (they are
 uninitialized — NaN under the interpreter — and 0·NaN would leak through
-the contractions). On a TPU backend the kernels are the only path: a
-kernel that does not compile raises. The pure-XLA reference is what the
-CPU backend runs with the interpreter off, and what tests compare with.
+the contractions). On a TPU backend `flash_attention()` IS the kernels: a
+kernel that does not compile raises, and this module holds no rule about
+shapes — a caller that asks for the kernels gets them
+(parallel/ring_attention.py, tools/flash_smoke.py, the kernel tests).
+Whether a call should ask is the attention ops' to decide
+(ops/attention_ops._use_flash): they come here only where there is more
+than one block to stream, and compute dense attention themselves at or
+under DEFAULT_BLOCK_Q x DEFAULT_BLOCK_K. The pure-XLA reference is what
+the CPU backend runs with the interpreter off, and what tests compare
+with.
 """
 from __future__ import annotations
 
@@ -97,9 +104,10 @@ def _on_tpu() -> bool:
 
 
 def _use_kernels() -> bool:
-    """The Pallas kernels serve every call on a TPU backend (one that does
-    not compile raises), and on the CPU backend only through the
-    interpreter (tests); the CPU otherwise runs the XLA reference."""
+    """Whether this backend runs the Pallas kernels: a TPU (a kernel that
+    does not compile raises), or the CPU through the interpreter (tests);
+    the CPU otherwise runs the XLA reference. Which CALLS they serve is
+    `ops/attention_ops._use_flash`'s rule, not this module's."""
     return _on_tpu() or _INTERPRET
 
 

@@ -29,6 +29,7 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import core
 from paddle_tpu.fluid.executor import _CompiledBlock
 from paddle_tpu.models import bert
+from paddle_tpu.ops import attention_ops
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
@@ -150,15 +151,21 @@ def _bert_base_width_step(layers, batch, seq_len, one_chip, mesh=None,
 
 @pytest.mark.parametrize("on_mesh", [False, True],
                          ids=["one_chip", "dp2_mp2"])
+@pytest.mark.parametrize("batch,seq_len", [(128, 128), (64, 256)],
+                         ids=["b128_s128", "b64_s256"])
 def test_bert_base_width_train_step_compiles_for_v5e(topo, one_chip,
-                                                     for_the_chip, on_mesh):
+                                                     for_the_chip, on_mesh,
+                                                     batch, seq_len):
     """One whole train step (fwd + bwd + Adam) lowered from the
     executor's `_CompiledBlock` at BERT-base width (768 x 12 heads x
     3072, vocab 30522; 2 layers — 12 is a ~40 s by-hand rehearsal), bf16
-    matmuls, b128 x s128: on one chip, and on a dp2 x mp2 mesh with the
-    dry-run's tensor-parallel shardings, where the kernels must have
-    partitioned themselves (XLA refuses to) and the gradients must meet
-    in an all-reduce."""
+    matmuls, 16,384 tokens: on one chip, and on a dp2 x mp2 mesh with
+    the dry-run's tensor-parallel shardings, where the gradients must
+    meet in an all-reduce. On each side of the attention ops' rule
+    (`attention_ops.DENSE_MAX_SEQ`): at s128 a head's score tile is one
+    kernel block, the step is XLA's own ops and holds NO Mosaic kernel;
+    at s256 it holds the flash kernels, which on the mesh must have
+    partitioned themselves (XLA refuses to)."""
     import __graft_entry__ as legs
     core.set_flag("FLAGS_use_bf16_matmul", True)
     try:
@@ -168,19 +175,23 @@ def test_bert_base_width_train_step_compiles_for_v5e(topo, one_chip,
                 mesh=Mesh(np.asarray(topo.devices).reshape(2, 2),
                           ("dp", "mp")),
                 shardings=legs.bert_tp_shardings)
-        cb, args = _bert_base_width_step(2, 128, 128, one_chip, **where)
+        cb, args = _bert_base_width_step(2, batch, seq_len, one_chip,
+                                         **where)
         compiled = cb._jitted.lower(*args).compile()
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", False)
     text = compiled.as_text()
-    # per layer: the forward kernel twice (the forward op, and again
-    # inside the grad op's vjp) + dK/dV + dQ
-    assert text.count(KERNEL) == 4 * 2
-    assert _kernel_names(text) == {
-        ("fwd/fused_attention_qkv", "flash_fwd"): 2,
-        ("bwd/fused_attention_qkv_grad", "flash_fwd"): 2,
-        ("bwd/fused_attention_qkv_grad", "flash_bwd_dkv"): 2,
-        ("bwd/fused_attention_qkv_grad", "flash_bwd_dq"): 2}
+    if seq_len <= attention_ops.DENSE_MAX_SEQ:
+        assert KERNEL not in text
+    else:
+        # per layer: the forward kernel twice (the forward op, and again
+        # inside the grad op's vjp) + dK/dV + dQ
+        assert text.count(KERNEL) == 4 * 2
+        assert _kernel_names(text) == {
+            ("fwd/fused_attention_qkv", "flash_fwd"): 2,
+            ("bwd/fused_attention_qkv_grad", "flash_fwd"): 2,
+            ("bwd/fused_attention_qkv_grad", "flash_bwd_dkv"): 2,
+            ("bwd/fused_attention_qkv_grad", "flash_bwd_dq"): 2}
     assert ("all-reduce" in text) == on_mesh
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

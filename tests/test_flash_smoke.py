@@ -52,6 +52,29 @@ def test_run_config_never_raises_on_compile_error(monkeypatch):
     assert "mosaic says no" in row["error"]
 
 
+def test_attention_paths_rehearsal_forces_each_path(monkeypatch, capsys):
+    """tools/attention_paths.py (the on-chip table the attention ops'
+    DENSE_MAX_SEQ rests on) on the CPU: the `flash` row really traced
+    the kernels (forward op + the grad op's vjp), the `dense` row never
+    did, both computed the same thing, the bound is restored, and no
+    device number is printed off the chip."""
+    from paddle_tpu.ops import attention_ops
+    from tools import attention_paths
+    calls = []
+    real = attention_ops.flash_attention
+    monkeypatch.setattr(attention_ops, "flash_attention",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    attention_paths.main(["--tiny", "--seqs", "16"])
+    flash, dense = (json.loads(line)
+                    for line in capsys.readouterr().out.splitlines())
+    assert (flash["path"], dense["path"]) == ("flash", "dense")
+    assert len(calls) == 2
+    assert attention_ops.DENSE_MAX_SEQ == 128
+    assert flash["batch"] * flash["seq"] == 256 and flash["temp_bytes"] > 0
+    assert max(flash["max_diff_out_dq_dk_dv"]) < 1e-5
+    assert "device_ms" not in flash and "device_ms" not in dense
+
+
 def test_run_config_restores_interpret_mode():
     from paddle_tpu.ops.pallas import flash_attention as fa
     before = fa._INTERPRET

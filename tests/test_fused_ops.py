@@ -255,7 +255,7 @@ def test_fused_attention_broadcastable_bias_routes_to_einsum():
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.registry import OPS
     r = np.random.RandomState(0)
-    B, S, H, D = 2, 128, 2, 32
+    B, S, H, D = 2, 256, 2, 32  # above DENSE_MAX_SEQ: no-bias = kernels
     q = jnp.asarray(r.normal(size=(B, S, H * D)), jnp.float32)
     k = jnp.asarray(r.normal(size=(B, S, H * D)), jnp.float32)
     v = jnp.asarray(r.normal(size=(B, S, H * D)), jnp.float32)
@@ -290,7 +290,7 @@ def test_multihead_matmul_keypad_bias_takes_flash_path(monkeypatch):
     from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.registry import OPS
-    B, S, H, D = 2, 128, 2, 32
+    B, S, H, D = 2, 256, 2, 32  # above DENSE_MAX_SEQ
     x = _mhm_qkv_packed(B, S, H, D)
     pad = np.zeros((B, 1, 1, S), np.float32)
     pad[:, :, :, S // 2:] = -1e9  # mask the right half of the keys
@@ -325,7 +325,7 @@ def test_multihead_matmul_generic_bias_keeps_einsum(monkeypatch):
     from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.registry import OPS
-    B, S, H, D = 2, 128, 2, 32
+    B, S, H, D = 2, 256, 2, 32  # above DENSE_MAX_SEQ: the bias decides
     x = _mhm_qkv_packed(B, S, H, D)
     bias_qk = jnp.asarray(
         np.random.RandomState(1).uniform(-1, 0, (B, H, S, S)), jnp.float32)
@@ -342,13 +342,15 @@ def test_multihead_matmul_generic_bias_keeps_einsum(monkeypatch):
     assert np.isfinite(o).all()
 
 
-def test_fused_attention_bf16_matmul_flag(monkeypatch):
+@pytest.mark.parametrize("path", ["dense", "kernels"])
+def test_fused_attention_bf16_matmul_flag(monkeypatch, path):
     """FLAGS_use_bf16_matmul casts the attention matmuls to bf16 (MXU
     native rate — same contract as math_ops._mm) while keeping the f32
     output dtype; result stays inside bf16 tolerance of the f32 path,
-    and gradients still flow. The cast is gated to non-CPU backends
-    (emulated bf16 is a pessimization without an MXU), so the test
-    spoofs a TPU backend to exercise it."""
+    and gradients still flow, on the dense path this short sequence
+    takes and on the kernels (the bound patched down). The cast is
+    gated to non-CPU backends (emulated bf16 is a pessimization without
+    an MXU), so the test spoofs a TPU backend to exercise it."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.fluid import core
@@ -367,6 +369,8 @@ def test_fused_attention_bf16_matmul_flag(monkeypatch):
     core.set_flag("FLAGS_use_bf16_matmul", True)
     from paddle_tpu.ops import attention_ops as ao
     monkeypatch.setattr(ao, "_mxu_backend", lambda: True)
+    if path == "kernels":
+        monkeypatch.setattr(ao, "DENSE_MAX_SEQ", 0)
     try:
         with fa.interpret_guard():  # spoofed TPU backend, CPU execution
             got = kern({"Q": [q], "K": [k], "V": [v], "Bias": [None]},
@@ -419,24 +423,159 @@ def test_bf16_dispatch_paths_share_f32_accumulation(monkeypatch):
         calls.append(1)
         return real(*a, **kw)
     monkeypatch.setattr(ao, "flash_attention", counting)
+    # S = 64 is under the bound: patched down, so that the no-bias call
+    # is the kernels' (through the interpreter)
+    monkeypatch.setattr(ao, "DENSE_MAX_SEQ", 0)
     try:
-        # no bias -> flash path (on CPU its dispatch target is
-        # _ref_attention, which carries the same f32-accumulation
-        # contract as the Mosaic kernel)
-        o_flash = np.asarray(kern(
-            {"Q": [q], "K": [k], "V": [v], "Bias": [None]},
-            dict(attrs))["Out"][0])
-        assert calls, "no-bias call must take the flash path"
-        del calls[:]
-        # an all-zero GENERIC bias shape forces the einsum path while
-        # leaving the math identical to no-bias
-        zero_bias = jnp.zeros((B, H, S, S), jnp.float32)
-        o_einsum = np.asarray(kern(
-            {"Q": [q], "K": [k], "V": [v], "Bias": [zero_bias]},
-            dict(attrs))["Out"][0])
-        assert not calls, "generic bias must route to the einsum path"
+        with fa.interpret_guard():
+            o_flash = np.asarray(kern(
+                {"Q": [q], "K": [k], "V": [v], "Bias": [None]},
+                dict(attrs))["Out"][0])
+            assert calls, "no-bias call must take the flash path"
+            del calls[:]
+            # an all-zero GENERIC bias shape forces the einsum path
+            # while leaving the math identical to no-bias
+            zero_bias = jnp.zeros((B, H, S, S), jnp.float32)
+            o_einsum = np.asarray(kern(
+                {"Q": [q], "K": [k], "V": [v], "Bias": [zero_bias]},
+                dict(attrs))["Out"][0])
+            assert not calls, "generic bias must route to the einsum path"
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", prev)
     # 2 bf16 ulps at this output scale; the bf16-rounded-scores bug sat
     # at ~0.08 here
     assert np.max(np.abs(o_flash - o_einsum)) < 0.03
+
+
+# --------------------------------------------------------------------------
+# the rule: which calls are the flash kernels' (attention_ops._use_flash)
+# --------------------------------------------------------------------------
+def _count_flash_calls(monkeypatch):
+    """[] that grows by one with every call the ops make to the kernels'
+    entry point (which still runs)."""
+    from paddle_tpu.ops import attention_ops
+    calls = []
+    real = attention_ops.flash_attention
+    monkeypatch.setattr(attention_ops, "flash_attention",
+                        lambda *a, **kw: calls.append(1) or real(*a, **kw))
+    return calls
+
+
+def _attention_bias(form, B, H, Sq, Sk):
+    import jax.numpy as jnp
+    if form == "keypad":          # the exact in-kernel form
+        pad = np.zeros((B, 1, 1, Sk), np.float32)
+        pad[:, :, :, Sk // 2:] = -1e9
+        return jnp.asarray(pad)
+    if form == "generic":         # no in-kernel form
+        return jnp.asarray(np.random.RandomState(1).uniform(
+            -1, 0, (B, H, Sq, Sk)), jnp.float32)
+    return None
+
+
+_RULE_CASES = [  # Sq, Sk, bias form, calls to the kernels
+    (16, 16, None, 0),
+    (128, 128, None, 0),          # AT the bound: one kernel block, dense
+    (128, 128, "keypad", 0),
+    (129, 129, None, 1),          # one past it
+    (256, 256, "keypad", 1),
+    (256, 256, "generic", 0),     # above, but no kernel form for the bias
+    (64, 256, None, 1),           # cross attention: either length decides
+    (256, 64, "keypad", 1),
+]
+
+
+@pytest.mark.parametrize("op,Sq,Sk,bias_form,kernel_calls", [
+    (op,) + case for case in _RULE_CASES
+    for op in ("fused_attention_qkv", "multihead_matmul")
+    # multihead_matmul's packed QKV has one length
+    if op == "fused_attention_qkv" or case[0] == case[1]])
+def test_attention_path_is_chosen_by_sequence_length(monkeypatch, op, Sq, Sk,
+                                                     bias_form, kernel_calls):
+    """Where a head's whole score tile fits one kernel block (both
+    lengths <= DENSE_MAX_SEQ) the kernels stream nothing and both ops
+    compute dense attention; one past it, the calls the kernels have a
+    form for are theirs. The same rule for both ops, read from the
+    call's shapes and bias form alone."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.registry import OPS
+    assert attention_ops.DENSE_MAX_SEQ == 128
+    B, H, D = 1, 2, 8
+    r = np.random.RandomState(0)
+    bias = _attention_bias(bias_form, B, H, Sq, Sk)
+    calls = _count_flash_calls(monkeypatch)
+    with fa.interpret_guard():
+        if op == "fused_attention_qkv":
+            q = jnp.asarray(r.normal(size=(B, Sq, H * D)), jnp.float32)
+            k, v = (jnp.asarray(r.normal(size=(B, Sk, H * D)), jnp.float32)
+                    for _ in range(2))
+            o = OPS.get(op).kernel(
+                {"Q": [q], "K": [k], "V": [v], "Bias": [bias]},
+                {"num_heads": H, "dropout_rate": 0.0,
+                 "causal": False})["Out"][0]
+        else:
+            o = OPS.get(op).kernel(
+                {"Input": [_mhm_qkv_packed(B, Sq, H, D)], "W": [None],
+                 "Bias": [None], "BiasQK": [bias]},
+                {"head_number": H, "alpha": 1.0 / np.sqrt(D)})["Out"][0]
+    assert len(calls) == kernel_calls
+    assert o.shape == (B, Sq, H * D) and np.isfinite(np.asarray(o)).all()
+
+
+@pytest.mark.parametrize("S", [64, 256], ids=["below_bound", "above_bound"])
+@pytest.mark.parametrize("causal", [False, True])
+def test_attention_paths_agree_on_each_side_of_the_bound(monkeypatch, S,
+                                                         causal):
+    """The rule chooses an implementation, not a result: with bf16
+    operands (f32 scores and softmax on both paths) the op's output and
+    its three gradients are the same, within bf16's rounding of the
+    output, whether the bound sends the call to the kernels or to dense
+    attention — at a shape that is dense's by default and at one that
+    is the kernels'."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.fluid import core
+    from paddle_tpu.ops import attention_ops as ao
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    from paddle_tpu.ops.registry import OPS
+    B, H, D = 2, 2, 32
+    r = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(r.normal(size=(B, S, H * D)) * 2.0, jnp.float32)
+               for _ in range(3))
+    w = jnp.asarray(r.normal(size=(B, S, H * D)), jnp.float32)
+    kern = OPS.get("fused_attention_qkv").kernel
+    attrs = {"num_heads": H, "dropout_rate": 0.0, "causal": causal}
+
+    calls = _count_flash_calls(monkeypatch)
+
+    def value_and_grads(bound):
+        del calls[:]
+        monkeypatch.setattr(ao, "DENSE_MAX_SEQ", bound)
+
+        def loss(q, k, v):
+            o = kern({"Q": [q], "K": [k], "V": [v], "Bias": [None]},
+                     dict(attrs))["Out"][0]
+            return jnp.sum(o * w), o
+        with fa.interpret_guard():
+            (_, o), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return bool(calls), [np.asarray(t) for t in (o,) + grads]
+
+    prev = core.globals_["FLAGS_use_bf16_matmul"]
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    monkeypatch.setattr(ao, "_mxu_backend", lambda: True)
+    try:
+        kernels = value_and_grads(0)
+        dense = value_and_grads(S)
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", prev)
+    assert kernels[0] and not dense[0]
+    for a, b in zip(kernels[1], dense[1]):
+        # the kernels round their output and gradients to bf16, dense
+        # keeps f32: 4 bf16 ulps of the tensor's largest element (read:
+        # at most 2; test_bf16_dispatch_paths_share_f32_accumulation's
+        # 0.03 at an output of order 1 is the same room)
+        assert np.max(np.abs(a - b)) < 2 ** -6 * max(1.0, np.abs(b).max())
+

@@ -714,7 +714,7 @@ def test_multihead_fused_op_hits_flash_kernel_for_keypad_mask():
     from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    H, D, N, S = 2, 64, 8, 128
+    H, D, N, S = 2, 64, 8, 256  # above attention_ops.DENSE_MAX_SEQ
 
     def build():
         x = fluid.data("x", shape=[S, N], dtype="float32")

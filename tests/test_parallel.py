@@ -180,14 +180,19 @@ def test_init_distributed_wiring(monkeypatch):
 
 
 @pytest.mark.parametrize("model_parallel", [1, 2])
-def test_flash_kernels_partition_themselves_under_a_mesh(model_parallel):
+@pytest.mark.parametrize("path", ["kernels", "dense"])
+def test_attention_under_a_mesh_matches_one_device(model_parallel, path):
     """XLA cannot partition a Mosaic kernel, so under a ("dp", "mp") mesh
     `flash_attention` wraps itself in a shard_map (batch over dp, heads
     over mp). With the kernels on the path (interpreter here, Mosaic in
-    tests/test_chip_compile.py) a tiny BERT's losses on four devices
-    match one device, and the step still holds its all-reduce."""
+    tests/test_chip_compile.py; the attention ops' bound patched down,
+    this tiny BERT's s16 being dense's otherwise) a tiny BERT's losses
+    on four devices match one device, and the step still holds its
+    all-reduce. On the dense side of the rule the same parity holds and
+    no shard_map is entered: XLA partitions its own ops."""
     import __graft_entry__ as legs
     from paddle_tpu.models import bert
+    from paddle_tpu.ops import attention_ops
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     cfg = legs._tiny_cfg()
@@ -200,11 +205,16 @@ def test_flash_kernels_partition_themselves_under_a_mesh(model_parallel):
     with fa.interpret_guard(), pytest.MonkeyPatch.context() as mp:
         mp.setattr(fa, "_flash_on_mesh",
                    lambda *a: calls.append(a[0]) or on_mesh(*a))
+        if path == "kernels":
+            mp.setattr(attention_ops, "DENSE_MAX_SEQ", 0)
         solo = legs.bert_losses(program, feed, steps=3)
         assert not calls
         row = legs.bert_n_vs_1(jax.devices()[:4], program, cfg, feed,
                                model_parallel, solo)
-    assert calls and all(m.devices.size == 4 for m in calls)
+    if path == "kernels":
+        assert calls and all(m.devices.size == 4 for m in calls)
+    else:
+        assert not calls
     assert row["devices"] == 4
 
 
