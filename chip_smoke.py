@@ -4,6 +4,9 @@
     python chip_smoke.py               one chip: the main path
     python chip_smoke.py --multichip   four chips: the mesh paths, and
                                        what they are compared with
+    python chip_smoke.py --qwen3-next  one chip: Qwen3-Next-80B-A3B's step
+                                       at its cell's sizes against the
+                                       plain float32 reference
 
 Main path: BERT-base MLM pretraining at full width (12 x 768 x 12 heads x
 3072, vocab 30522) at b128 x s128 with bf16 matmuls, built by
@@ -183,6 +186,161 @@ def flash_parity(tiny):
     assert row["status"] == "ok", row
 
 
+# `qwen3_next_parity`: the largest error allowed, of each compared tensor,
+# as max |program - reference| over max |reference| (the loss: absolute).
+# Two programs are held to the one float32 reference (PERF.md §6, PR 28,
+# has the chip's readings each limit lies between):
+# "float32": the program with FLAGS_use_bf16_matmul off, traced under
+#   jax.default_matmul_precision("highest"): the same arithmetic as the
+#   reference in another order (chunks for tokens, a grouped product for
+#   a loop over experts, flash kernels for S x S scores). These limits
+#   are the tight ones: the reference with every activation rounded to
+#   bf16 (`round_to`), the nearest precision below the configuration's,
+#   has to fail at least one of them, and that is asserted.
+# "bf16_operands": the cell's own precision. bf16 matmul operands move a
+#   router's input by ~1e-3, which swaps a token's tenth expert for its
+#   eleventh wherever the two lie closer than that, a token in twenty a
+#   layer: a discrete change no rounding bound covers, so these limits
+#   only fence the readings (PR 27 measured 9.5e-4 of a tensor's scale
+#   for ONE attention op; here four layers and the routing lie between).
+QWEN3_NEXT_LIMITS = {
+    # readings (my chip runs, PR 28): the float32 program 9.5e-7 and
+    # 4.0e-6 .. 3.1e-5; bf16 activations 7.1e-5 and 3.2e-2 .. 2.1e-1
+    "float32": {
+        "loss": 1e-5,
+        "layers.0.gdn.w_qkvz@GRAD": 1e-3,
+        "layers.0.gdn.a_log@GRAD": 1e-3,
+        "layers.3.attn.w_q@GRAD": 1e-3,
+        "layers.0.moe.w_router@GRAD": 1e-3,
+        "layers.0.moe.w_down@GRAD": 1e-3,
+        "layers.0.moe.shared_gate@GRAD": 1e-3,
+        "embed_tokens@GRAD": 1e-3,
+    },
+    # readings: 1.8e-4 / 2.2e-4 and, in this order, 0.042, 0.050, 0.059,
+    # 0.19, 0.23, 0.046, 0.040 (two runs within 7% of each other)
+    "bf16_operands": {
+        "loss": 1e-3,
+        "layers.0.gdn.w_qkvz@GRAD": 0.1,
+        "layers.0.gdn.a_log@GRAD": 0.1,
+        "layers.3.attn.w_q@GRAD": 0.12,
+        "layers.0.moe.w_router@GRAD": 0.4,
+        "layers.0.moe.w_down@GRAD": 0.5,
+        "layers.0.moe.shared_gate@GRAD": 0.1,
+        "embed_tokens@GRAD": 0.1,
+    },
+}
+
+
+def _by_path(path):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "_smoke_" + os.path.basename(path)[:-3], path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def qwen3_next_parity(tiny, clock):
+    """One step of `qwen3_next_80b_a3b.b1_s4096`'s program (its sizes,
+    its traffic, built by its configuration's files) against the plain
+    reference at the same weights and batch: the fetched loss and the
+    gradient of one parameter of each kind, fetched as `@GRAD`, once at
+    the cell's precision and once with float32 operands. The weights
+    are pulled from the scope after start-up (the seed makes them the
+    same in both programs); a program's state leaves the chip before
+    the next thing runs, so that each fits."""
+    import contextlib
+    import gc
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import core
+
+    configs = os.path.join(ROOT, "benchmark", "configs")
+    model = _by_path(os.path.join(configs, "qwen3_next_80b_a3b.py"))
+    reference = _by_path(
+        os.path.join(configs, "qwen3_next_80b_a3b_reference.py"))
+    with open(os.path.join(configs, "qwen3_next_80b_a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "qwen3_next_80b_a3b.b1_s4096.json")) as f:
+        traffic = json.load(f)["traffic"]
+    if tiny:
+        config, traffic = model.tiny(config, traffic)
+    cfg = model.model_cfg(config)
+    feed = model.make_batches(config, traffic, 28, 1)[0]
+    names = list(QWEN3_NEXT_LIMITS["float32"])
+    wanted = [n for n in names if n != "loss"]
+
+    def program_step(bf16_operands):
+        """(weights after start-up, {name: fetched}) of a fresh program."""
+        core.set_flag("FLAGS_use_bf16_matmul", bf16_operands)
+        main, startup, fetches = model.build(config, traffic)
+        main.random_seed = startup.random_seed = 28
+        exe = fluid.Executor(fluid.TPUPlace(0))
+        scope = core.Scope()
+        exe.run(startup, scope=scope)
+        weights = {
+            p.name: np.asarray(scope.find_var(p.name).get_tensor().array)
+            for p in main.global_block().all_parameters()}
+        t0 = time.perf_counter()
+        with (contextlib.nullcontext() if bf16_operands
+              else jax.default_matmul_precision("highest")):
+            got = exe.run(main, feed=feed, scope=scope,
+                          fetch_list=[fetches[0].name] + wanted)
+        got = dict(zip(names, (np.asarray(g) for g in got)))
+        stats = jax.devices()[0].memory_stats() or {}
+        emit(phase="qwen3_next_step", bf16_operands=bf16_operands,
+             loss=float(got["loss"].ravel()[0]),
+             parameters=int(sum(w.size for w in weights.values())),
+             seconds=round(time.perf_counter() - t0, 1),
+             peak_bytes_in_use=stats.get("peak_bytes_in_use"),
+             **clock.take())
+        return weights, got
+
+    try:
+        programs = {}
+        for kind, bf16_operands in (("bf16_operands", True),
+                                    ("float32", False)):
+            weights, programs[kind] = program_step(bf16_operands)
+            gc.collect()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", True)
+
+    params = {n: jnp.asarray(w) for n, w in weights.items()}
+    ids, labels = jnp.asarray(feed["ids"]), jnp.asarray(feed["labels"][..., 0])
+
+    @jax.jit
+    def read(params, rounded):
+        """The reference's loss and gradients; `rounded` (a traced flag,
+        so that both readings share one compile) rounds every activation
+        to bf16."""
+        loss, grads = reference.loss_and_grads(
+            params, ids, labels, cfg, [n[:-len("@GRAD")] for n in wanted],
+            # not a pair of converts: XLA may drop those as excess precision
+            lambda x: jnp.where(rounded, jax.lax.reduce_precision(x, 8, 7), x))
+        return {"loss": loss, **{n + "@GRAD": g for n, g in grads.items()}}
+
+    def errors(a, b):
+        return {n: float(np.abs(np.asarray(a[n]) - np.asarray(b[n])).max()
+                         / (1.0 if n == "loss" else np.abs(b[n]).max()))
+                for n in names}
+
+    t0 = time.perf_counter()
+    ref = {n: np.asarray(v) for n, v in read(params, False).items()}
+    got = {kind: errors(programs[kind], ref) for kind in programs}
+    below = errors(read(params, True), ref)
+    emit(phase="qwen3_next_parity", reference_loss=float(ref["loss"]),
+         errors=got, bf16_activations=below, limits=QWEN3_NEXT_LIMITS,
+         seconds=round(time.perf_counter() - t0, 1), **clock.take())
+    over = {f"{kind}: {n}": e for kind in got for n, e in got[kind].items()
+            if e > QWEN3_NEXT_LIMITS[kind][n]}
+    assert not over, f"over their limits: {over}"
+    assert any(e > QWEN3_NEXT_LIMITS["float32"][n] for n, e in below.items()), \
+        "bf16 activations pass every float32 limit: the limits tell nothing"
+
+
 def multichip(size, devices):
     """The mesh paths on ``devices``, and what each is compared with."""
     import __graft_entry__ as legs
@@ -202,6 +360,9 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--multichip", action="store_true",
                     help="the four-chip mesh paths and nothing else")
+    ap.add_argument("--qwen3-next", action="store_true",
+                    help="Qwen3-Next's step against its float32 reference "
+                         "and nothing else")
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal at a toy size on any backend; never ok")
     args = ap.parse_args(argv)
@@ -230,6 +391,8 @@ def main(argv=None):
     t0 = time.perf_counter()
     if args.multichip:
         multichip(size, devices[:4])
+    elif args.qwen3_next:
+        qwen3_next_parity(args.tiny, clock)
     else:
         train_one_chip(size, dev, clock)
         flash_parity(args.tiny)

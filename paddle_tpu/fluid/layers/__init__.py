@@ -33,6 +33,8 @@ from . import detection as _det_mod
 from .detection import *       # noqa: F401,F403
 from . import rnn as _rnn_mod
 from .rnn import *             # noqa: F401,F403
+from . import decoder as _decoder_mod
+from .decoder import *          # noqa: F401,F403
 from . import distributions  # noqa: F401
 from .distributions import (Uniform, Normal, Categorical,  # noqa: F401
                             MultivariateNormalDiag)  # noqa: F401

@@ -1,0 +1,128 @@
+"""Layer functions of hybrid linear-attention / sparse-expert decoder LMs
+(ops/decoder_ops.py holds the kernels and the equations)."""
+from __future__ import annotations
+
+from ..initializer import Constant
+from ..layer_helper import LayerHelper
+
+__all__ = ["rms_norm", "rotary_embedding", "causal_conv1d",
+           "gated_delta_rule", "moe_router", "moe_expert_ffn"]
+
+
+def _like(helper, x, shape=None, dtype=None):
+    out = helper.create_variable_for_type_inference(dtype or x.dtype)
+    out.shape = tuple(x.shape if shape is None else shape)
+    return out
+
+
+def rms_norm(input, group_size=None, gate=None, zero_centered=False,
+             epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over groups of ``group_size`` of the last dim (all of it by
+    default; a head's dims for a per-head norm), the weight [group_size]
+    shared by the groups. ``zero_centered``: y * (1 + w), w from 0; else
+    y * w, w from 1. ``gate``: y * SiLU(gate), gate shaped like input."""
+    helper = LayerHelper("rms_norm", **locals())
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[group_size or input.shape[-1]],
+        dtype=input.dtype,
+        default_initializer=Constant(0.0 if zero_centered else 1.0))
+    inputs = {"X": [input], "Scale": [scale]}
+    if gate is not None:
+        inputs["Gate"] = [gate]
+    out = _like(helper, input)
+    helper.append_op(type="rms_norm", inputs=inputs, outputs={"Out": [out]},
+                     attrs={"epsilon": epsilon,
+                            "zero_centered": zero_centered})
+    return out
+
+
+def rotary_embedding(x, num_heads, rotary_dim, theta, name=None):
+    """Rotate-half rotary embedding on the first ``rotary_dim`` dims of
+    each head of x [B, S, num_heads * D]; position = index in S."""
+    helper = LayerHelper("rotary_embedding", **locals())
+    out = _like(helper, x)
+    helper.append_op(type="rotary_embedding", inputs={"X": [x]},
+                     outputs={"Out": [out]},
+                     attrs={"num_heads": num_heads, "rotary_dim": rotary_dim,
+                            "theta": float(theta)})
+    return out
+
+
+def causal_conv1d(x, kernel_size, param_attr=None, name=None):
+    """Depthwise causal convolution along S of x [B, S, C]; no bias."""
+    helper = LayerHelper("causal_conv1d", **locals())
+    w = helper.create_parameter(attr=helper.param_attr,
+                                shape=[x.shape[-1], kernel_size],
+                                dtype=x.dtype)
+    out = _like(helper, x)
+    helper.append_op(type="causal_conv1d", inputs={"X": [x], "Filter": [w]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def gated_delta_rule(q, k, v, a, b, num_key_heads, num_value_heads,
+                     chunk_size=64, a_log_attr=None, dt_bias_attr=None,
+                     name=None):
+    """The gated delta rule over q, k [B, S, Hk*dk], v [B, S, Hv*dv] with
+    the gates' pre-activations a, b [B, S, Hv]; creates the per-head
+    parameters A_log and dt_bias. -> [B, S, Hv*dv]."""
+    helper = LayerHelper("gated_delta_rule", **locals())
+    a_log = helper.create_parameter(attr=a_log_attr, shape=[num_value_heads],
+                                    dtype=v.dtype)
+    dt_bias = helper.create_parameter(attr=dt_bias_attr,
+                                      shape=[num_value_heads], dtype=v.dtype,
+                                      default_initializer=Constant(1.0))
+    out = _like(helper, v)
+    helper.append_op(
+        type="gated_delta_rule",
+        inputs={"Q": [q], "K": [k], "V": [v], "A": [a], "B": [b],
+                "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"num_key_heads": num_key_heads,
+               "num_value_heads": num_value_heads, "chunk_size": chunk_size,
+               "site": helper.name})
+    return out
+
+
+def moe_router(x, num_experts, top_k, param_attr=None, name=None):
+    """Softmax over ``num_experts`` in float32, top-k, weights
+    renormalised: (expert ids [.., k] int32, weights [.., k], the
+    layer's load-balancing auxiliary loss [1])."""
+    helper = LayerHelper("moe_router", **locals())
+    w = helper.create_parameter(attr=helper.param_attr,
+                                shape=[x.shape[-1], num_experts],
+                                dtype=x.dtype)
+    picked = tuple(x.shape[:-1]) + (top_k,)
+    idx = _like(helper, x, picked, "int32")
+    idx.stop_gradient = True
+    weight = _like(helper, x, picked)
+    aux = _like(helper, x, (1,))
+    helper.append_op(type="moe_router", inputs={"X": [x], "W": [w]},
+                     outputs={"TopkIdx": [idx], "TopkWeight": [weight],
+                              "AuxLoss": [aux]},
+                     attrs={"top_k": top_k})
+    return idx, weight, aux
+
+
+def moe_expert_ffn(x, topk_idx, topk_weight, experts_held, expert_width,
+                   expert_start=0, gate_up_attr=None, down_attr=None,
+                   name=None):
+    """The part of a routed gated FFN that experts ``expert_start ..
+    expert_start + experts_held - 1`` give, with no assignment dropped;
+    one parameter a projection, [held, D, 2 * width] (gate then up) and
+    [held, width, D]."""
+    helper = LayerHelper("moe_expert_ffn", **locals())
+    d = x.shape[-1]
+    w_gate_up = helper.create_parameter(
+        attr=gate_up_attr, shape=[experts_held, d, 2 * expert_width],
+        dtype=x.dtype)
+    w_down = helper.create_parameter(
+        attr=down_attr, shape=[experts_held, expert_width, d], dtype=x.dtype)
+    out = _like(helper, x)
+    helper.append_op(
+        type="moe_expert_ffn",
+        inputs={"X": [x], "TopkIdx": [topk_idx], "TopkWeight": [topk_weight],
+                "WGateUp": [w_gate_up], "WDown": [w_down]},
+        outputs={"Out": [out]},
+        attrs={"expert_start": expert_start, "site": helper.name})
+    return out

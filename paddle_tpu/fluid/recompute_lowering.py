@@ -115,6 +115,11 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
     # stats, counters) — a segment-local write would otherwise be
     # silently dropped and the old value written back every step
     writeback = set(cb.mut_state) | set(cb.extra_writeback)
+    # the trained loss: the backward's seed (the fill_constant at fwd_end)
+    # writes its @GRAD outside every span, so its segment must hand it
+    # out even where another var (a part of it) is what is fetched
+    seeded = {n[:-len("@GRAD")] for n in rest[0].output_arg_names} \
+        if rest else set()
     fwd_reads: Dict[int, set] = {}
     for i, op in enumerate(ops[:fwd_end]):
         fwd_reads[i] = set(op.input_arg_names)
@@ -143,7 +148,7 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
         # by the vjp, which recomputes those values (that IS the
         # rematerialization); a non-replaced rest op reading an internal
         # is checked at the end.
-        outside = set(cb.fetch_names) | writeback
+        outside = set(cb.fetch_names) | writeback | seeded
         for i in range(fwd_end):
             if lo <= i < hi:
                 continue

@@ -29,21 +29,22 @@ def bert_base_config():
 
 
 def fused_multihead_attention(q, k, v, n_head, dropout_rate=0.0,
-                              attn_bias=None, causal=False):
+                              attn_bias=None, causal=False, n_kv_head=None):
     """One fused attention op (Pallas on TPU past s128). q/k/v: [B, S, H];
-    attn_bias: optional additive mask broadcastable to [B, H, Sq, Sk]."""
+    attn_bias: optional additive mask broadcastable to [B, H, Sq, Sk];
+    n_kv_head: grouped queries, k/v [B, S, n_kv_head * D]."""
     helper = LayerHelper("multihead_matmul")
     out = helper.create_variable_for_type_inference(q.dtype)
     out.shape = q.shape
     ins = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         ins["Bias"] = [attn_bias]
-    helper.append_op(type="fused_attention_qkv",
-                     inputs=ins,
-                     outputs={"Out": [out]},
-                     attrs={"num_heads": n_head,
-                            "dropout_rate": dropout_rate,
-                            "causal": causal})
+    attrs = {"num_heads": n_head, "dropout_rate": dropout_rate,
+             "causal": causal}
+    if n_kv_head is not None:
+        attrs["num_kv_heads"] = n_kv_head
+    helper.append_op(type="fused_attention_qkv", inputs=ins,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -13,6 +13,7 @@ from . import framework_ops  # noqa: F401
 from . import nn_extra_ops   # noqa: F401
 from . import collective_ops  # noqa: F401
 from . import attention_ops  # noqa: F401
+from . import decoder_ops  # noqa: F401
 from . import sequence_ops   # noqa: F401
 from . import rnn_ops        # noqa: F401
 from . import distributed_ops  # noqa: F401

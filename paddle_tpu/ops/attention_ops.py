@@ -100,11 +100,14 @@ def _merge_heads(x):
 
 @register_op("fused_attention_qkv", inputs=("Q", "K", "V", "Bias"),
              diff_inputs=("Q", "K", "V"), needs_rng=True,
-             attr_defaults={"num_heads": 1, "dropout_rate": 0.0,
-                            "causal": False})
+             attr_defaults={"num_heads": 1, "num_kv_heads": 0,
+                            "dropout_rate": 0.0, "causal": False})
 def _fused_attention_qkv(ins, attrs):
     """Optional Bias: additive attention mask broadcastable to
     [B, H, Sq, Sk] (e.g. padding mask [B, 1, 1, Sk] with -inf/0).
+    Grouped queries: with ``num_kv_heads`` (a divisor of ``num_heads``)
+    K and V are [B, S, Hkv·D] and each of their heads serves
+    num_heads / num_kv_heads consecutive query heads.
 
     Dispatch (``_use_flash``): above ``DENSE_MAX_SEQ`` the Pallas flash
     kernels serve the no-bias case AND the exact key-padding bias form
@@ -129,7 +132,11 @@ def _fused_attention_qkv(ins, attrs):
         # QK^T/PV matmuls — softmax statistics stay f32 inside both the
         # flash kernel and the einsum path; output restored to f32
         q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
-    qh, kh, vh = (_split_heads(t, h) for t in (q, k, v))
+    h_kv = attrs.get("num_kv_heads", 0) or h
+    qh, kh, vh = _split_heads(q, h), _split_heads(k, h_kv), \
+        _split_heads(v, h_kv)
+    if h_kv != h:
+        kh, vh = (jnp.repeat(t, h // h_kv, axis=1) for t in (kh, vh))
     causal = attrs.get("causal", False)
     drop = float(attrs.get("dropout_rate", 0.0) or 0.0)
     kp_bias = _keypad_bias(bias, qh, kh)

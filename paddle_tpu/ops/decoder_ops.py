@@ -1,0 +1,283 @@
+"""Ops of hybrid linear-attention / sparse-expert decoder LMs (the
+Qwen3-Next family; layer equations and departures:
+benchmark/configs/qwen3_next_80b_a3b_reference.py).
+
+``rms_norm``            RMSNorm over groups of the last dim, zero-centred
+                        weight or plain, optionally gated by SiLU(Gate)
+``rotary_embedding``    partial rotate-half rotary embedding a head
+``causal_conv1d``       depthwise causal convolution along the sequence
+``gated_delta_rule``    the gated delta rule, in chunks (WY form)
+``moe_router``          softmax over all experts, top-k, auxiliary loss
+``moe_expert_ffn``      the held experts' part of a routed gated FFN
+
+One pure JAX kernel each; gradients are the registry's vjp of it. Matmul
+operands are bf16 under FLAGS_use_bf16_matmul on a backend with an MXU
+(``math_ops._mm``'s gate); the router, every norm, the gates and the
+delta rule's chunk state stay float32.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .registry import register_op, first, out
+from .math_ops import mxu_available
+
+
+def _operands(*xs):
+    """``_mm``'s gate for the products below: float32 operands go to the
+    MXU as bf16 and accumulate in float32."""
+    from ..fluid import core as _core
+    if _core.globals_["FLAGS_use_bf16_matmul"] and mxu_available():
+        return tuple(x.astype(jnp.bfloat16) if x.dtype == jnp.float32 else x
+                     for x in xs)
+    return xs
+
+
+def _einsum(spec, a, b):
+    return jnp.einsum(spec, *_operands(a, b),
+                      preferred_element_type=jnp.float32)
+
+
+def _gauge(name, help_, site, value):
+    """A count of one traced op, by the layer that built it: a program
+    is traced several times (the grad op's vjp, a rematerialised
+    segment, the step's second signature), and a gauge a site reads the
+    same each time; the sites of a process sum to its program's step."""
+    from ..fluid import telemetry
+    telemetry.REGISTRY.gauge(name, help_, labelnames=("site",)).labels(
+        site=site).set(value)
+
+
+def _silu(x):
+    return x * jax.nn.sigmoid(x)
+
+
+# --------------------------------------------------------------------------
+@register_op("rms_norm", inputs=("X", "Scale", "Gate"),
+             diff_inputs=("X", "Scale", "Gate"),
+             attr_defaults={"epsilon": 1e-6, "zero_centered": False})
+def _rms_norm(ins, attrs):
+    """y = x * rsqrt(mean(x^2) + eps) * w over each group of len(Scale)
+    of the last dim (a head's dims, or the whole of it), w = 1 + Scale
+    where ``zero_centered``; with Gate, y * SiLU(Gate). Float32 inside."""
+    x, scale, gate = first(ins, "X"), first(ins, "Scale"), first(ins, "Gate")
+    d = scale.shape[-1]
+    xr = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, d))
+    y = xr * lax.rsqrt(jnp.mean(xr * xr, -1, keepdims=True)
+                       + attrs.get("epsilon", 1e-6))
+    w = scale.astype(jnp.float32)
+    y = (y * (1.0 + w if attrs.get("zero_centered", False) else w)
+         ).reshape(x.shape)
+    if gate is not None:
+        y = y * _silu(gate.astype(jnp.float32))
+    return out(Out=y.astype(x.dtype))
+
+
+@register_op("rotary_embedding", inputs=("X",),
+             attr_defaults={"num_heads": 1, "rotary_dim": 0, "theta": 1e4})
+def _rotary_embedding(ins, attrs):
+    """Rotate-half rotary embedding on the first ``rotary_dim`` dims of
+    each of ``num_heads`` heads; X [B, S, H*D], a token's position is
+    its index in the sequence."""
+    x = first(ins, "X")
+    b, s, hd = x.shape
+    h = attrs.get("num_heads", 1)
+    r = attrs.get("rotary_dim", 0) or hd // h
+    xh = x.reshape(b, s, h, hd // h)
+    inv = attrs.get("theta", 1e4) ** (
+        -jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.tile(jnp.cos(angle), (1, 2))[None, :, None, :]
+    sin = jnp.tile(jnp.sin(angle), (1, 2))[None, :, None, :]
+    rot, rest = xh[..., :r], xh[..., r:]
+    half = jnp.concatenate([-rot[..., r // 2:], rot[..., :r // 2]], -1)
+    y = jnp.concatenate([rot * cos + half * sin, rest], -1)
+    return out(Out=y.reshape(x.shape).astype(x.dtype))
+
+
+@register_op("causal_conv1d", inputs=("X", "Filter"))
+def _causal_conv1d(ins, attrs):
+    """Depthwise causal convolution: X [B, S, C], Filter [C, K],
+    y[t, c] = sum_j Filter[c, j] * x[t - (K - 1) + j, c], zeros before
+    the sequence; no bias."""
+    x, w = first(ins, "X"), first(ins, "Filter")
+    k, s = w.shape[1], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = padded[:, 0:s] * w[:, 0]
+    for j in range(1, k):
+        y = y + padded[:, j:j + s] * w[:, j]
+    return out(Out=y)
+
+
+# --------------------------------------------------------------------------
+def _chunked_delta_rule(q, k, v, g, beta, chunk):
+    """The gated delta rule in chunks. q, k [B, H, S, dk] (normalised, q
+    scaled), v [B, H, S, dv], g [B, H, S] the log of the decay (<= 0),
+    beta [B, H, S]; -> [B, H, S, dv].
+
+    Token form: S_t = a_t S_{t-1} + k_t u_t^T, u_t = beta_t (v_t -
+    a_t S_{t-1}^T k_t), o_t = S_t^T q_t. Within a chunk of C positions
+    with G_i = sum_{j<=i} g_j and D_ij = exp(G_i - G_j) (i >= j), the
+    u_i solve (I + L) U = beta V - (beta K e^G) S_0, L_ij = beta_i
+    (k_i . k_j) D_ij (i > j): one triangular solve gives T = (I + L)^-1,
+    the WY form's two products T (beta V) and T (beta K e^G) are made
+    for every chunk at once, and only the chunk states are scanned, in
+    float32: S_0 -> S_C = e^{G_C} S_0 + (K e^{G_C - G})^T U."""
+    b, h, s, dk = q.shape
+    dv = v.shape[-1]
+    pad = -s % chunk
+    if pad:  # padded positions: k = v = beta = 0 write nothing
+        q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for t in (q, k, v))
+        g, beta = (jnp.pad(t, ((0, 0), (0, 0), (0, pad))) for t in (g, beta))
+    n = (s + pad) // chunk
+    q, k, v = (t.reshape(b, h, n, chunk, -1) for t in (q, k, v))
+    g, beta = (t.reshape(b, h, n, chunk) for t in (g, beta))
+    gc = jnp.cumsum(g, -1)
+    lower = jnp.tril(jnp.ones((chunk, chunk), bool))
+    strictly_lower = jnp.tril(lower, -1)
+    # exp of a masked difference: the upper triangle would overflow
+    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                              -jnp.inf))
+    k_beta = k * beta[..., None]
+    kk = _einsum("bhnid,bhnjd->bhnij", k_beta, k) * decay
+    eye = jnp.eye(chunk, dtype=jnp.float32)
+    system = eye + jnp.where(strictly_lower, kk, 0.0)
+    t_inv = jax.scipy.linalg.solve_triangular(
+        system, jnp.broadcast_to(eye, system.shape), lower=True)
+    u = _einsum("bhnij,bhnjd->bhnid", t_inv, v * beta[..., None])
+    w = _einsum("bhnij,bhnjd->bhnid", t_inv, k_beta * jnp.exp(gc)[..., None])
+    qk = jnp.where(lower, _einsum("bhnid,bhnjd->bhnij", q, k) * decay, 0.0)
+    q_in = q * jnp.exp(gc)[..., None]
+    k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]
+    carry_decay = jnp.exp(gc[..., -1])
+
+    def step(state, xs):
+        qk_i, u_i, w_i, q_i, k_i, decay_i = xs
+        v_new = u_i - _einsum("bhid,bhdv->bhiv", w_i, state)
+        o = _einsum("bhid,bhdv->bhiv", q_i, state) \
+            + _einsum("bhij,bhjv->bhiv", qk_i, v_new)
+        state = state * decay_i[..., None, None] \
+            + _einsum("bhid,bhiv->bhdv", k_i, v_new)
+        return state, o
+
+    xs = tuple(jnp.moveaxis(t, 2, 0)
+               for t in (qk, u, w, q_in, k_out, carry_decay))
+    _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)[:, :, :s]
+
+
+@register_op("gated_delta_rule",
+             inputs=("Q", "K", "V", "A", "B", "ALog", "DtBias"),
+             attr_defaults={"num_key_heads": 1, "num_value_heads": 1,
+                            "chunk_size": 64, "site": ""})
+def _gated_delta_rule(ins, attrs):
+    """Gated DeltaNet's mixing: Q, K [B, S, Hk*dk], V [B, S, Hv*dv],
+    A, B [B, S, Hv] (the decay's and the write strength's
+    pre-activations), ALog, DtBias [Hv] -> Out [B, S, Hv*dv]. Key heads
+    are repeated to the value heads, q and k L2-normalised a head, q
+    scaled by dk^-1/2, beta = sigmoid(B), log decay = -exp(ALog) *
+    softplus(A + DtBias); then ``_chunked_delta_rule``."""
+    q, k, v = first(ins, "Q"), first(ins, "K"), first(ins, "V")
+    hk, hv = attrs["num_key_heads"], attrs["num_value_heads"]
+    chunk = attrs.get("chunk_size", 64)
+    b, s, _ = q.shape
+
+    def heads(t, n):
+        return jnp.moveaxis(t.astype(jnp.float32).reshape(b, s, n, -1), 1, 2)
+
+    q, k = (jnp.repeat(heads(t, hk), hv // hk, axis=1) for t in (q, k))
+    q, k = (t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+            for t in (q, k))
+    q = q * q.shape[-1] ** -0.5
+    beta = jax.nn.sigmoid(first(ins, "B").astype(jnp.float32))
+    g = -jnp.exp(first(ins, "ALog").astype(jnp.float32)) * jax.nn.softplus(
+        first(ins, "A").astype(jnp.float32) + first(ins, "DtBias"))
+    o = _chunked_delta_rule(q, k, heads(v, hv), jnp.moveaxis(g, 1, 2),
+                            jnp.moveaxis(beta, 1, 2), chunk)
+    _gauge("gdn_chunks_per_step",
+           "chunks of the sequence the delta rule's state scan steps "
+           "over, a sequence of the batch each", attrs.get("site", ""),
+           b * -(-s // chunk))
+    return out(Out=jnp.moveaxis(o, 1, 2).reshape(b, s, -1).astype(v.dtype))
+
+
+# --------------------------------------------------------------------------
+@register_op("moe_router", inputs=("X", "W"), diff_inputs=("X", "W"),
+             attr_defaults={"top_k": 1})
+def _moe_router(ins, attrs):
+    """X [.., D], W [D, E] -> TopkIdx [.., k] int32, TopkWeight [.., k]
+    (the chosen experts' probabilities, renormalised to sum 1), AuxLoss
+    [1] = E * sum_e (assignments_e / tokens) * mean_t p_{t,e}. Logits and
+    softmax in float32 at the highest matmul precision whatever the
+    flags say: a rounded logit changes which expert is chosen."""
+    x, w = first(ins, "X"), first(ins, "W")
+    k, e = attrs.get("top_k", 1), w.shape[1]
+    probs = jax.nn.softmax(
+        jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST), -1)
+    top_p, top_i = lax.top_k(probs, k)
+    top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    tokens = probs.size // e
+    share = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0) \
+        / tokens
+    aux = e * jnp.sum(share * jnp.mean(probs.reshape(tokens, e), 0))
+    return out(TopkIdx=top_i.astype(jnp.int32), TopkWeight=top_p,
+               AuxLoss=aux.reshape(1))
+
+
+def _ragged(rows, weights, group_sizes):
+    return lax.ragged_dot(*_operands(rows, weights), group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+@register_op("moe_expert_ffn",
+             inputs=("X", "TopkIdx", "TopkWeight", "WGateUp", "WDown"),
+             diff_inputs=("X", "TopkWeight", "WGateUp", "WDown"),
+             attr_defaults={"expert_start": 0, "site": ""})
+def _moe_expert_ffn(ins, attrs):
+    """The held experts' part of a routed gated FFN, no assignment
+    dropped: Out[t] = sum over the k experts e chosen for token t that
+    are held here, ``expert_start <= e < expert_start + held``, of
+    TopkWeight[t, e] * (SiLU(x W_g,e) * x W_u,e) W_d,e. WGateUp
+    [held, D, 2F] (gate then up), WDown [held, F, D]; what the experts
+    held elsewhere would add is left out.
+
+    Static shapes: the T*k assignments are sorted by held expert (those
+    of absent experts last) and the first T * min(k, held) of them, the
+    most that can be held here, are rows of one grouped product an
+    expert's projection (``lax.ragged_dot``, the groups' sizes counted
+    from the router's choice). Rows past the last group are masked on
+    both sides of each product: what a grouped product leaves there is
+    not defined."""
+    x, idx, weight = (first(ins, "X"), first(ins, "TopkIdx"),
+                      first(ins, "TopkWeight"))
+    w_gate_up, w_down = first(ins, "WGateUp"), first(ins, "WDown")
+    held, d = w_gate_up.shape[0], x.shape[-1]
+    k = idx.shape[-1]
+    tokens = idx.size // k
+    local = idx.reshape(-1) - attrs.get("expert_start", 0)
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    rows = tokens * min(k, held)
+    order = jnp.argsort(key, stable=True)[:rows]
+    valid = (key[order] < held)[:, None]
+    token = order // k
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    # the operands' cast before the gather: the same values, half the rows'
+    # bytes
+    x_rows = jnp.where(valid, _operands(x.reshape(tokens, d))[0][token], 0)
+    h = jnp.where(valid, _ragged(x_rows, w_gate_up, sizes), 0.0)
+    f = w_down.shape[1]
+    act = _silu(h[:, :f]) * h[:, f:]
+    y = jnp.where(valid, _ragged(act, w_down, sizes), 0.0) \
+        * weight.reshape(-1)[order][:, None]
+    o = jnp.zeros((tokens, d), jnp.float32).at[token].add(y)
+    site = attrs.get("site", "")
+    _gauge("moe_experts_held", "experts whose weights the layer holds",
+           site, held)
+    _gauge("moe_rows_per_step",
+           "rows the grouped expert products run over: tokens x min(k, "
+           "experts held), the most a no-drop layer can be sent", site, rows)
+    return out(Out=o.reshape(x.shape).astype(x.dtype))

@@ -69,7 +69,9 @@ def for_the_chip(monkeypatch):
     ((16, 12, 512, 64), dict(bias=True, dropout=0.1)),  # padded s512 cell
     ((4, 16, 2048, 64), dict(causal=True)),          # long causal
     ((8, 12, 500, 64), {}),                          # ragged boundary block
-], ids=["b128_s128", "s512_keypad_dropout", "s2048_causal", "s500_ragged"])
+    ((1, 16, 4096, 256), dict(causal=True)),         # Qwen3-Next's head
+], ids=["b128_s128", "s512_keypad_dropout", "s2048_causal", "s500_ragged",
+        "s4096_causal_d256"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
     """Forward + dK/dV + dQ kernels of `_flash_pallas`, bf16, Mosaic."""
     B, H, S, D = shape
@@ -195,3 +197,66 @@ def test_bert_base_width_train_step_compiles_for_v5e(topo, one_chip,
     assert ("all-reduce" in text) == on_mesh
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
+    """The step of `qwen3_next_80b_a3b.b1_s4096` as the executor lowers
+    it, at the published widths and the cell's cut (4 layers, 32 of 512
+    experts held, 18,992 rows of vocabulary), 1 x 4096 tokens, bf16
+    matmul operands, recomputation a layer: it fits the chip's 16 GB
+    (12 bytes a parameter of arguments: the gradients are temporaries),
+    the attention layer runs the flash kernels at D = 256 causal (the
+    forward in the forward pass and again in the recomputed segment,
+    then dK/dV and dQ), every expert product is XLA's own grouped
+    kernel, and the chunk scans are the only loops. Start-up runs on the
+    CPU for the shapes alone (7.5 GB of host memory, ~5 s)."""
+    from paddle_tpu.models import qwen3_next
+    cfg = dict(qwen3_next.qwen3_next_config(), layers=4, experts_held=32,
+               vocab_size=18992)
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        main, startup, _, fetches = \
+            qwen3_next.build_qwen3_next_pretrain_program(cfg, seq_len=4096)
+        feed = qwen3_next.synthetic_pretrain_batch(cfg, 1, 4096)
+        scope = core.Scope()
+        fluid.Executor().run(startup, scope=scope)
+        cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
+                            scope, seed=0)
+        assert cb._remat_plan is not None and len(
+            cb._remat_plan.segments) == 5  # four layers and the head
+
+        def state(names):
+            arrays = {n: scope.find_var(n).get_tensor().array for n in names}
+            return {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+                    for n, a in arrays.items()}
+        mut, ro = state(cb.mut_state), state(cb.ro_state)
+        del scope
+        feeds = {n: jax.ShapeDtypeStruct(a.shape, jnp.int32,
+                                         sharding=one_chip)
+                 for n, a in feed.items()}
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+        compiled = cb._jitted.lower(mut, ro, feeds, rng).compile()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    text = compiled.as_text()
+    # XLA's own grouped-product kernels carry no Pallas name
+    grouped = [line for line in text.splitlines()
+               if KERNEL in line and "ragged-dot" in line]
+    flash = _kernel_names("\n".join(
+        line for line in text.splitlines() if line not in grouped))
+    # 4 layers x 2 projections x (forward, again, backward's two)
+    assert sum("ragged-dot-none" in line for line in grouped) == 4 * 2 * 4
+    assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2,
+                     ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
+                     ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1}
+    import re
+    assert len(re.findall(r" while\(", text)) == 9  # 3 scans x fwd, again, bwd
+    mem = compiled.memory_analysis()
+    parameters = 625.7e6
+    assert abs(mem.argument_size_in_bytes / (12 * parameters) - 1) < 0.01
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    assert 4e9 < total < 14e9, total

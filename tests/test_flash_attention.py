@@ -36,10 +36,12 @@ def test_forward_matches_reference(S, causal):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_grads_match_reference(causal):
-    q, k, v = _rand_qkv(1, 1, 256, 32, seed=1)
-    sm = 1.0 / np.sqrt(32)
+@pytest.mark.parametrize("causal,D", [(False, 32), (True, 32), (True, 256)],
+                         ids=["full", "causal", "causal_d256"])
+def test_grads_match_reference(causal, D):
+    """D = 256 causal is Qwen3-Next's gated attention head."""
+    q, k, v = _rand_qkv(1, 1, 256, D, seed=1)
+    sm = 1.0 / np.sqrt(D)
     w = jnp.asarray(np.random.RandomState(2).normal(
         size=q.shape).astype(np.float32))
 

@@ -1088,6 +1088,48 @@ STRAGGLERS = [
       "KernelH": 3, "KernelW": 3},
      {"lod": {"X_in": [[16]], "ROW_in": [[4]], "COLUMN_in": [[4]]},
       "wrt": ["X", "W"], "seq_outs": ["Col"]}),
+    # ops/decoder_ops.py (forward + gradients against the float32
+    # reference: tests/test_qwen3_next.py)
+    ("rms_norm",
+     {"X": rng.uniform(-1, 1, (2, 3, 8)).astype(np.float32),
+      "Scale": rng.uniform(-0.5, 0.5, (4,)).astype(np.float32),
+      "Gate": rng.uniform(-1, 1, (2, 3, 8)).astype(np.float32)},
+     {"epsilon": 1e-6, "zero_centered": True},
+     {"wrt": ["X", "Scale", "Gate"]}),
+    ("rotary_embedding",
+     {"X": rng.uniform(-1, 1, (1, 4, 8)).astype(np.float32)},
+     {"num_heads": 2, "rotary_dim": 2, "theta": 100.0}, {"wrt": ["X"]}),
+    ("causal_conv1d",
+     {"X": rng.uniform(-1, 1, (1, 5, 3)).astype(np.float32),
+      "Filter": rng.uniform(-0.5, 0.5, (3, 4)).astype(np.float32)},
+     {}, {"wrt": ["X", "Filter"]}),
+    ("gated_delta_rule",
+     {"Q": rng.uniform(-1, 1, (1, 6, 4)).astype(np.float32),
+      "K": rng.uniform(-1, 1, (1, 6, 4)).astype(np.float32),
+      "V": rng.uniform(-1, 1, (1, 6, 8)).astype(np.float32),
+      "A": rng.uniform(-1, 1, (1, 6, 2)).astype(np.float32),
+      "B": rng.uniform(-1, 1, (1, 6, 2)).astype(np.float32),
+      "ALog": np.asarray([0.0, 1.0], np.float32),
+      "DtBias": np.ones((2,), np.float32)},
+     {"num_key_heads": 1, "num_value_heads": 2, "chunk_size": 4},
+     {"wrt": ["Q", "K", "V", "A", "B", "ALog", "DtBias"]}),
+    # logits 0.6 apart: a finite difference moves no token's choice
+    ("moe_router",
+     {"X": np.ones((1, 3, 2), np.float32)
+      + rng.uniform(-0.05, 0.05, (1, 3, 2)).astype(np.float32),
+      "W": np.asarray([[0.0, 0.6, 1.2, 1.8, 2.4],
+                       [0.1, -0.1, 0.05, 0.0, -0.05]], np.float32)},
+     {"top_k": 2},
+     {"out": "AuxLoss", "wrt": ["X", "W"],
+      "seq_outs": ["TopkIdx", "TopkWeight"]}),
+    ("moe_expert_ffn",
+     {"X": rng.uniform(-1, 1, (1, 4, 6)).astype(np.float32),
+      "TopkIdx": np.asarray([[[1, 4], [3, 2], [0, 1], [3, 1]]], np.int32),
+      "TopkWeight": rng.uniform(0.2, 0.8, (1, 4, 2)).astype(np.float32),
+      "WGateUp": rng.uniform(-0.5, 0.5, (3, 6, 8)).astype(np.float32),
+      "WDown": rng.uniform(-0.5, 0.5, (3, 4, 6)).astype(np.float32)},
+     {"expert_start": 1},
+     {"wrt": ["X", "TopkWeight", "WGateUp", "WDown"]}),
 ]
 
 
