@@ -513,6 +513,10 @@ class _CompiledBlock:
         # step telemetry (docs/OBSERVABILITY.md): first dispatch of the
         # single-step jit bumps executor_compiles_total{kind="step"}
         self._dispatched = False
+        # state name → NamedSharding on ``mesh`` (_planned_sharding), and
+        # how many state arrays the last _place_inputs call had to place
+        self._placement_plan: Dict[str, Any] = {}
+        self._placed = 0
 
     # ---------------------------------------------- numeric fault guard
     def _init_guard(self, program: Program, scope: Scope,
@@ -904,32 +908,52 @@ class _CompiledBlock:
 
     def _place_inputs(self, scope: Scope, feeds: Dict[str, Any], rng,
                       window_names=()):
-        """State from the scope + feeds, device-placed for the step (mesh
-        sharding applied when data-parallel). Shared by run() and by
-        HLO-inspection helpers (lowered()). Feeds named in
-        ``window_names`` are [K, batch, ...] window STACKS: their batch
-        dim is dim 1, so the mesh placement shards THAT dim over "dp"
-        and leaves the window dim whole for the scan (one device_put
-        per window — docs/INPUT_PIPELINE.md)."""
+        """State from the scope + feeds, as the step takes them. Shared by
+        run() and by HLO-inspection helpers (lowered()).
+
+        On a mesh each state name has ONE sharding for the life of the
+        block (``_planned_sharding``: the placement plan, filled the first
+        time the block places its inputs). A state array that already
+        lies on the mesh the way the plan says — what the jitted step
+        handed back the step before — is passed through as the object it
+        is: nothing is moved and no ``device_put`` is called. Anything
+        else (the start-up program's single-device arrays on step 1, a
+        numpy value a checkpoint load or a ``set_value`` put in the
+        scope) is placed to the plan's sharding, and counted: the
+        `exe:place` span's ``placed``, the registry's
+        ``executor_state_arrays_placed_total``. State the step overwrites
+        comes back placed through its write-back; read-only state, which
+        no step writes back, is left in the scope as placed. In a steady
+        step only the feeds and the rng key are left to place.
+
+        Feeds named in ``window_names`` are [K, batch, ...] window STACKS:
+        their batch dim is dim 1, so the mesh placement shards THAT dim
+        over "dp" and leaves the window dim whole for the scan (one
+        device_put per window — docs/INPUT_PIPELINE.md)."""
         mut = {n: scope.find_var(n).get_tensor().array for n in self.mut_state}
         ro = {n: scope.find_var(n).get_tensor().array for n in self.ro_state}
+        self._placed = 0
         if self.mesh is not None:
             # data-parallel placement: params/state replicated, feed batch
             # sharded on the dp axis. XLA's sharding propagation inserts the
             # grad all-reduces over ICI (replaces reference allreduce
             # op-handles — multi_devices_graph_pass.cc:604).
             from ..parallel.mesh import replicated, shard_feed
-            from jax.sharding import NamedSharding
-            repl = replicated(self.mesh)
-
             multiproc = jax.process_count() > 1
 
             def place(n, a):
-                spec = self._sharding_for(n, a)
-                sh = repl if spec is None else NamedSharding(self.mesh, spec)
+                sh = self._planned_sharding(n, a)
+                # already right, or already global (no one process could
+                # place it again): what the step wrote back. Equivalence
+                # decides: XLA may spell an equal sharding otherwise;
+                # `==` first because it is the cheaper yes
+                if isinstance(a, jax.Array) and (
+                        a.sharding == sh
+                        or a.sharding.is_equivalent_to(sh, a.ndim)
+                        or not a.is_fully_addressable):
+                    return a
+                self._placed += 1
                 if multiproc:
-                    if isinstance(a, jax.Array) and not a.is_fully_addressable:
-                        return a  # already global (written back last step)
                     # device_put can't target non-addressable devices; every
                     # process holds the full value (startup ran identically
                     # on all ranks), so assemble the global array from the
@@ -942,7 +966,17 @@ class _CompiledBlock:
                         sh, host, global_shape=host.shape)
                 return jax.device_put(a, sh)
             mut = {n: place(n, a) for n, a in mut.items()}
-            ro = {n: place(n, a) for n, a in ro.items()}
+            for n, a in ro.items():
+                ro[n] = placed = place(n, a)
+                if placed is not a:
+                    # no step writes read-only state back: leave the
+                    # placed array in the scope, or every step places
+                    # it again (the learning rate; a forward program's
+                    # every parameter)
+                    var = scope.find_var(n)
+                    var.set_value(LoDTensor(placed, var.get_tensor().lod()))
+            if self._placed:
+                _telemetry.count_state_placed(self._placed)
             feeds = {n: shard_feed(self.mesh, n, a,
                                    window=n in window_names)
                      for n, a in feeds.items()}
@@ -950,8 +984,23 @@ class _CompiledBlock:
                 # multi-process: leave the key uncommitted — identical on
                 # every rank, jit replicates it (key arrays can't go
                 # through make_array_from_process_local_data)
-                rng = jax.device_put(rng, repl)
+                rng = jax.device_put(rng, replicated(self.mesh))
         return mut, ro, feeds, rng
+
+    def _planned_sharding(self, name: str, a):
+        """The placement plan's entry for a state name: its
+        ``NamedSharding`` on the block's mesh (``_sharding_for``'s spec,
+        else replicated), decided the first time the name is placed —
+        the accumulator rule needs the array's ``ndim`` — and kept."""
+        sh = self._placement_plan.get(name)
+        if sh is None:
+            from jax.sharding import NamedSharding
+            from ..parallel.mesh import replicated
+            spec = self._sharding_for(name, a)
+            sh = self._placement_plan[name] = (
+                replicated(self.mesh) if spec is None
+                else NamedSharding(self.mesh, spec))
+        return sh
 
     def lowered(self, scope: Scope, feeds: Dict[str, Any], rng):
         """jax lowering of the single-step function over the CURRENT scope
@@ -961,13 +1010,18 @@ class _CompiledBlock:
         mut, ro, feeds, rng = self._place_inputs(scope, feeds, rng)
         return self._jitted.lower(mut, ro, feeds, rng)
 
+    @contextlib.contextmanager
     def place_span(self):
         """The `exe:place` stage span: around gathering the state arrays
-        from the scope (on a mesh, placing them) for a dispatch."""
+        from the scope for a dispatch. ``arrays``: how many the step
+        takes; ``placed``: how many of them `_place_inputs` had to put on
+        the mesh in this call (0 off a mesh, and on one in a steady
+        step)."""
         from . import profiler as _profiler
-        return _profiler.RecordEvent(
-            "exe:place", cat="executor",
-            args={"arrays": len(self.mut_state) + len(self.ro_state)})
+        with _profiler.RecordEvent("exe:place", cat="executor") as span:
+            yield
+            span.args = {"arrays": len(self.mut_state) + len(self.ro_state),
+                         "placed": self._placed}
 
     def run(self, scope: Scope, feeds: Dict[str, Any], rng):
         """One training/inference step: ONE dispatch of the jitted step.

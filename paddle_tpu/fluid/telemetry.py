@@ -469,6 +469,18 @@ def count_compile(kind: str, retrace: bool = False) -> None:
             labelnames=("kind",)).labels(kind=kind).inc()
 
 
+def count_state_placed(n: int) -> None:
+    """State arrays a compiled block's `_place_inputs` had to put on its
+    mesh (the start-up program's arrays on step 1, a value a checkpoint
+    load or a ``set_value`` left in the scope): flat in steady state,
+    where the step's own outputs already lie as the plan says."""
+    REGISTRY.counter(
+        "executor_state_arrays_placed_total",
+        "state arrays placed onto the mesh before a dispatch; those "
+        "already placed as the block's plan says pass through uncounted"
+    ).inc(n)
+
+
 _JAX_LISTENER_LOCK = threading.Lock()
 _JAX_LISTENER_INSTALLED = False
 
