@@ -151,7 +151,7 @@ def train_one_chip(size, dev, clock):
             assert n_kernels == 0, n_kernels
 
         # the window twice: the first traces and compiles the scan, the
-        # second is the path every bench lane times through
+        # second runs the compiled one
         wl, window_seconds = [], []
         for _ in range(2):
             t0 = time.perf_counter()

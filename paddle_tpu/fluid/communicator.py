@@ -191,7 +191,7 @@ def dgc_compressor() -> DGCCompressor:
 def active_dgc_stats() -> dict:
     """Compression counters of the live compressor ({} when DGC never
     ran in this process) — the subprocess-evidence surface the WAN
-    scenario and bench lanes collect."""
+    scenario collects."""
     d = _dgc
     return {} if d is None else d.stats()
 

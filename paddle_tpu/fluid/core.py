@@ -1396,8 +1396,24 @@ def _switch_scope(scope: Scope) -> Scope:
 # FLAGS — env-backed global config (reference: platform/flags.cc, the ~106
 # gflags settable via FLAGS_* env and pybind global_value_getter_setter.cc)
 # --------------------------------------------------------------------------
+# Flags of the reference's GPU and CPU runtimes that ported scripts set
+# and nothing here reads (allocation, threads and collectives are XLA's):
+# set_flags / get_flags / the environment take them, and that is all.
+_ACCEPTED_AND_IGNORED = (
+    ("FLAGS_cpu_deterministic", False),
+    ("FLAGS_benchmark", False),
+    ("FLAGS_eager_delete_tensor_gb", 0.0),
+    ("FLAGS_allocator_strategy", "xla"),
+    ("FLAGS_fraction_of_gpu_memory_to_use", 1.0),
+    ("FLAGS_paddle_num_threads", 1),
+    ("FLAGS_use_pinned_memory", True),
+    ("FLAGS_sync_nccl_allreduce", True),
+)
+
+
 class _GlobalFlags:
     _DEFAULTS: Dict[str, Any] = {
+        **dict(_ACCEPTED_AND_IGNORED),
         "FLAGS_check_nan_inf": False,
         # what the numeric fault plane DOES when FLAGS_check_nan_inf
         # finds a non-finite step (docs/FAULT_TOLERANCE.md "Numeric
@@ -1440,13 +1456,6 @@ class _GlobalFlags:
         # sync round to quiesce (pending grads applied, barrier empty)
         # before aborting the drain with the source still serving
         "FLAGS_ps_drain_quiesce_deadline": 60.0,
-        "FLAGS_cpu_deterministic": False,
-        "FLAGS_benchmark": False,
-        "FLAGS_eager_delete_tensor_gb": 0.0,
-        "FLAGS_allocator_strategy": "xla",  # allocation is XLA's job on TPU
-        "FLAGS_fraction_of_gpu_memory_to_use": 1.0,
-        "FLAGS_paddle_num_threads": 1,
-        "FLAGS_use_pinned_memory": True,
         # RPC fault tolerance (fluid/ps_rpc.py VarClient.call): per-call
         # deadline in MILLISECONDS (reference FLAGS_rpc_deadline), and how
         # many times a transient ConnectionError/OSError is retried with
@@ -1532,7 +1541,6 @@ class _GlobalFlags:
         # grads smaller than this many elements ship dense — top-k
         # bookkeeping on a bias vector costs more than it saves
         "FLAGS_dgc_min_elements": 512,
-        "FLAGS_sync_nccl_allreduce": True,   # no-op: ICI collectives are compiled
         # static-analysis plane (docs/ANALYSIS.md; fluid/analysis.py):
         # verify Programs at the choke points — Executor first compile of
         # a program version, the transpiler's own trainer-program output,
@@ -1603,12 +1611,6 @@ class _GlobalFlags:
         "FLAGS_ps_shrink_every_steps": 0,
         "FLAGS_ps_shrink_decay": 0.98,
         "FLAGS_ps_shrink_threshold": 0.5,
-        # reuse the device copy when the SAME ndarray object with the
-        # SAME content fingerprint is fed again (skips the per-step
-        # device_put — the dominant host cost of a small step); the
-        # fingerprint makes this safe under in-place mutation, so it is
-        # ON by default
-        "FLAGS_feed_device_cache": True,
         # opt-in persistent XLA executable cache: non-empty -> every
         # Executor routes compiles through
         # jax_compilation_cache_dir=<dir> (inference.enable_compile_cache)
@@ -1643,8 +1645,8 @@ class _GlobalFlags:
         "FLAGS_profiler_max_events": 1_000_000,
         # opt-in lightweight /metrics sidecar (Prometheus text format
         # over the telemetry registry): >0 binds 127.0.0.1:<port> at
-        # pserver/ingress/executor startup so bench.py and the chaos/
-        # loadgen tools scrape instead of poking process internals.
+        # pserver/ingress/executor startup so the chaos/loadgen tools
+        # scrape instead of poking process internals.
         # 0 (default) = off; the serving ingress additionally always
         # serves GET /metrics on its own port.
         "FLAGS_metrics_port": 0,

@@ -1659,8 +1659,7 @@ class Executor:
         _telemetry.install_jax_compile_listener()
         _telemetry.maybe_start_metrics_server()
         # how the LAST run executed: "compiled" | "segmented" |
-        # "interpreted" (observability for tests/bench — e.g. the
-        # compiled_metric flag in bench.py wide_deep rows)
+        # "interpreted" (observability for tests)
         self._last_run_mode: Optional[str] = None
         # periodic atomic checkpointing (set_auto_checkpoint /
         # resume_from — docs/FAULT_TOLERANCE.md)
@@ -2309,16 +2308,12 @@ class Executor:
 
         # materialize program vars' metadata for persistables (create slots)
         # feeds → device
-        use_feed_cache = core.globals_["FLAGS_feed_device_cache"]
         feed_arrays = {}
         feed_lods = {}
         # host batch -> device array; the upload is enqueued, not awaited
         with _profiler.RecordEvent("exe:feed", cat="executor") as span:
             for name, data in feed.items():
-                t = (self._feed_device_cached(name, data)
-                     if use_feed_cache else None)
-                if t is None:
-                    t = _as_lodtensor(data, self.place)
+                t = _as_lodtensor(data, self.place)
                 scope.var(name).set_value(t)
                 feed_arrays[name] = t.array
                 lv = _normalize_lod(t.lod())
@@ -2678,66 +2673,6 @@ class Executor:
             stacked.append(
                 LoDTensor(jnp.stack([s[k].array for s in per_step])))
         return stacked
-
-    # feeds above this size pay more for the content scan than the
-    # device_put it could skip; they always re-upload
-    _FEED_CACHE_MAX_BYTES = 4 << 20
-    # a name whose identity keeps changing (fresh dataloader array each
-    # step) stops being fingerprinted after this many straight misses
-    _FEED_CACHE_MISS_LIMIT = 8
-
-    @staticmethod
-    def _feed_fingerprint(a: np.ndarray) -> Optional[int]:
-        """Content fingerprint: CRC32 over the raw buffer — POSITION-
-        SENSITIVE, so the common in-place mutations (row shuffles,
-        element swaps) that a plain word-sum misses are detected. C
-        speed, no copy for contiguous buffers."""
-        if not a.flags.c_contiguous:
-            return None
-        import zlib
-        return zlib.crc32(a.view(np.uint8).reshape(-1).data)
-
-    def _feed_device_cached(self, name: str, data) -> Optional[LoDTensor]:
-        """Identity+content-keyed feed→device cache
-        (FLAGS_feed_device_cache, ON by default): when the SAME ndarray
-        object (same buffer address) is fed again AND its CRC32 matches
-        the upload-time value, reuse the device array and skip the
-        per-step device_put — the dominant host cost of a small training
-        step. The stored array object is pinned, so the CRC must be
-        captured at upload time (a later in-place mutation changes the
-        shared buffer). Names fed a fresh array every step stop paying
-        the scan after a short miss streak."""
-        if not isinstance(data, np.ndarray) \
-                or data.nbytes > Executor._FEED_CACHE_MAX_BYTES:
-            return None
-        cache = getattr(self, "_feed_cache", None)
-        if cache is None:
-            cache = self._feed_cache = {}
-        entry = cache.get(name)
-        if entry == "uncacheable":
-            return None
-        prefix = (id(data), data.__array_interface__["data"][0],
-                  data.shape, data.dtype.str)
-        fp = Executor._feed_fingerprint(data)
-        if fp is None:
-            return None
-        if entry is not None and entry[0] == prefix and fp == entry[1]:
-            entry[4][0] = 0
-            return entry[3]
-        if entry is not None and entry[0] != prefix:
-            misses = entry[4]
-            misses[0] += 1
-            if misses[0] >= Executor._FEED_CACHE_MISS_LIMIT:
-                cache[name] = "uncacheable"
-                return None
-        else:
-            misses = [0]
-        t = _as_lodtensor(data, self.place)
-        # pin the source ndarray: while the entry lives, its id/buffer
-        # address cannot be recycled by a new array (which would
-        # otherwise falsely hit this prefix)
-        cache[name] = (prefix, fp, data, t, misses)
-        return t
 
     def _run_block_eager(self, block, scope: Scope, rng_base,
                          check_nan: Optional[bool] = None):

@@ -204,8 +204,8 @@ def record_instant(name: str, cat: str = "host", args=None) -> None:
 def snapshot_events():
     """Thread-safe copy of the recorded host events as plain dicts
     (name/start/end/tid/cat/args + trace correlation ids) — for tests
-    and bench lanes that compute evidence from a live profile (e.g. the
-    async-overlap concurrency check) without stopping the profiler."""
+    that compute evidence from a live profile (e.g. the async-overlap
+    concurrency check) without stopping the profiler."""
     with _prof.lock:
         return [{"name": e.name, "start": e.start, "end": e.end,
                  "tid": e.tid, "cat": e.cat, "args": e.args,

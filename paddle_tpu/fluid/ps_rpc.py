@@ -529,9 +529,8 @@ def _pickle_wire_forced() -> bool:
     """PADDLE_TPU_PS_PICKLE_WIRE=1 is the LEGACY DATA-PLANE mode: the
     pre-throughput-overhaul behavior end to end — v1 pickle frames, one
     connection per endpoint, serial shard walks, no duplicate-id dedup,
-    no coalesced flushes (docs/PS_DATA_PLANE.md; the paired lane of
-    `bench.py wide_deep_1b`). Checked dynamically so tests can flip it
-    per client."""
+    no coalesced flushes (docs/PS_DATA_PLANE.md). Checked dynamically so
+    tests can flip it per client."""
     return os.environ.get("PADDLE_TPU_PS_PICKLE_WIRE", "") == "1"
 
 

@@ -357,11 +357,9 @@ class AnalysisPredictor:
                 for n in self._feed_names}
 
     def try_shrink_memory(self):
-        """Drop cached executables/feed copies (reference
-        TryShrinkMemory); the next run re-jits."""
+        """Drop cached executables (reference TryShrinkMemory); the
+        next run re-jits."""
         self._exe._compiled_cache.clear()
-        if hasattr(self._exe, "_feed_cache"):
-            self._exe._feed_cache.clear()
 
     def clone(self, share_weights: bool = True) -> "AnalysisPredictor":
         """Reference Clone(): the clone serves from the SAME params scope

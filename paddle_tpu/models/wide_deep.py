@@ -71,8 +71,7 @@ def build_wide_deep_program(num_dense=13, num_slots=26, sparse_dim=int(1e4),
     update), so the executor runs the block SEGMENTED — fwd+bwd+update as
     compiled jitted segments, auc as an interpreted island
     (fluid/executor.py _SegmentedBlock). ``with_auc=False`` drops the
-    metric for a fully-compiled step — the A/B pair that isolates the
-    segmentation overhead in bench.py. Returns auc_var=None then."""
+    metric for a fully-compiled step. Returns auc_var=None then."""
     import paddle_tpu.fluid as fluid
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):

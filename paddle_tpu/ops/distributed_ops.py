@@ -43,7 +43,7 @@ _FANOUT_LOCK = threading.Lock()
 def _legacy_dataplane() -> bool:
     """PADDLE_TPU_PS_PICKLE_WIRE=1 = the full legacy data plane (serial
     shard walks, no dedup, no batched RPCs) — one source of truth in
-    ps_rpc so the bench lanes can't drift."""
+    ps_rpc."""
     from ..fluid.ps_rpc import _pickle_wire_forced
     return _pickle_wire_forced()
 
@@ -928,8 +928,7 @@ def _distributed_lookup_table_grad(ins, attrs):
         # pre-merge duplicate rows client-side: the server applies ONE
         # row per distinct id (sum of the duplicates), the payload
         # shrinks by the duplication factor. NOT gated by the legacy
-        # lane: merging changes fp accumulation ORDER, and the paired
-        # bench rows assert bit-exact loss parity across lanes — every
+        # lane: merging changes fp accumulation ORDER, and every
         # legacy-gated difference must be numerics-exact
         # (wire/fan-out/pool/coalescing/lookup-dedup all are)
         uniq, inv = np.unique(ids, return_inverse=True)

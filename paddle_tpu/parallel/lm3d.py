@@ -31,11 +31,11 @@ microbatch index its tick computes — so the single-device oracle
 (`make_oracle_step`: same params, same folds, python loop over stages
 and microbatches, degenerate n=1 collectives) draws identical masks.
 
-Parity contract (tests/test_parallel3d.py, docs/PERF.md): per-step
-losses of the composed lane match the oracle within documented fp32
-tolerance (the dp/sp partial-sum orders differ from the oracle's
-single-device reductions by last-ulp rounding; a pp-only composition is
-observed bit-identical). Evidence lane: ``bench.py lm3d``.
+Parity contract (tests/test_parallel3d.py): per-step losses of the
+composed lane match the oracle within documented fp32 tolerance (the
+dp/sp partial-sum orders differ from the oracle's single-device
+reductions by last-ulp rounding; a pp-only composition is observed
+bit-identical).
 """
 from __future__ import annotations
 
@@ -52,10 +52,10 @@ from .moe import expert_capacity, moe_ffn_local
 from .pipeline import gpipe
 from .ring_attention import ring_attention_local
 
-__all__ = ["LMConfig", "mesh3d", "init_params", "param_count",
+__all__ = ["LMConfig", "mesh3d", "init_params",
            "place_params", "place_window", "init_amp_state",
            "sample_window", "make_train_step", "make_window_step",
-           "make_oracle_step", "make_oracle_window", "flops_per_step"]
+           "make_oracle_step", "make_oracle_window"]
 
 # dynamic loss-scaling hyperparams (PR 5 defaults, reference
 # update_loss_scaling contract — fluid/executor._amp_scale_update)
@@ -173,36 +173,6 @@ def _stage_specs(cfg: LMConfig, stages: Dict[str, Any]):
             return P("pp", None, "dp")
         return P("pp")
     return {k: spec(k, v) for k, v in stages.items()}
-
-
-def param_count(params) -> int:
-    return int(sum(np.prod(x.shape)
-                   for x in jax.tree_util.tree_leaves(params)))
-
-
-def flops_per_step(cfg: LMConfig, n_params: int) -> Dict[str, float]:
-    """The longctx-lane methodology (bench.py): model FLOPs per
-    optimizer step estimated as 6·N per trained token (2N fwd + 4N bwd)
-    — the headline "achieved TFLOPs" numerator — plus the attention
-    quadratic term (causal ⇒ halved; ×3.5 fwd+bwd) reported alongside.
-    For MoE, top-1 routing activates ONE expert per token, so the
-    active-parameter count (experts averaged to one) is what 6·N
-    sees."""
-    tokens = cfg.batch * cfg.seq_len
-    n_active = n_params
-    if cfg.n_experts:
-        st_shape = dict(w1=(cfg.d_model, cfg.d_ff), b1=(cfg.d_ff,),
-                        w2=(cfg.d_ff, cfg.d_model), b2=(cfg.d_model,))
-        per_expert = sum(int(np.prod(s)) for s in st_shape.values())
-        n_active = n_params - cfg.n_layers * (cfg.n_experts - 1) \
-            * per_expert
-    model = 6.0 * n_active * tokens
-    Dh = cfg.d_model // cfg.n_heads
-    attn = (4.0 * cfg.batch * cfg.n_heads * cfg.seq_len ** 2 * Dh
-            / 2.0 * 3.5) * cfg.n_layers
-    return {"tokens": float(tokens), "model_flops": model,
-            "attn_flops": attn, "n_params": float(n_params),
-            "n_active_params": float(n_active)}
 
 
 # ------------------------------------------------------------------ model

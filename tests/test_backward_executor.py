@@ -260,103 +260,6 @@ def test_use_prune_skips_untargeted_branches():
     assert after_full == 1.0, "full run executes the side branch"
 
 
-def test_feed_device_cache_correctness():
-    """FLAGS_feed_device_cache reuses the device copy only for the SAME
-    ndarray object; a different object (even equal-shaped) must trigger a
-    fresh transfer and fresh results."""
-    import numpy as np
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = fluid.data("x", shape=[4], dtype="float32")
-        y = fluid.layers.scale(x, scale=2.0)
-    exe = fluid.Executor()
-    scope = core.Scope()
-    X1 = np.random.rand(3, 4).astype("float32")
-    X2 = (X1 * 5.0).copy()
-    old = core.globals_["FLAGS_feed_device_cache"]
-    core.set_flag("FLAGS_feed_device_cache", True)
-    try:
-        with fluid.scope_guard(scope):
-            (o1,) = exe.run(main, feed={"x": X1}, fetch_list=[y])
-            (o1b,) = exe.run(main, feed={"x": X1}, fetch_list=[y])
-            (o2,) = exe.run(main, feed={"x": X2}, fetch_list=[y])
-        np.testing.assert_allclose(o1, X1 * 2.0, rtol=1e-6)
-        np.testing.assert_allclose(o1b, o1, rtol=1e-6)
-        np.testing.assert_allclose(o2, X2 * 2.0, rtol=1e-6)
-    finally:
-        core.set_flag("FLAGS_feed_device_cache", old)
-
-
-def test_feed_device_cache_default_on_and_mutation_safe():
-    """The feed→device cache is ON by default and must be SAFE: an
-    in-place mutation of a previously-fed ndarray changes the content
-    fingerprint, so the stale device copy is not reused (round-2 weak
-    item: the cache was opt-in precisely because mutation was
-    undetectable)."""
-    import numpy as np
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core
-
-    assert core.globals_["FLAGS_feed_device_cache"] is True
-
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = fluid.data("x", shape=[3], dtype="float32")
-        out = fluid.layers.scale(x, scale=2.0)
-    exe = fluid.Executor()
-    scope = core.Scope()
-    X = np.asarray([[1.0, 2.0, 3.0]], np.float32)
-    with fluid.scope_guard(scope):
-        (a,) = exe.run(main, feed={"x": X}, fetch_list=[out])
-        # cache hit: same object, same content → same device tensor
-        t1 = exe._feed_device_cached("x", X)
-        t2 = exe._feed_device_cached("x", X)
-        assert t1 is t2
-        X[0, 0] = 100.0      # in-place mutation
-        (b,) = exe.run(main, feed={"x": X}, fetch_list=[out])
-    np.testing.assert_allclose(np.asarray(a)[0], [2.0, 4.0, 6.0])
-    np.testing.assert_allclose(np.asarray(b)[0], [200.0, 4.0, 6.0])
-
-
-def test_feed_device_cache_detects_inplace_shuffle():
-    """A row shuffle / element swap leaves a word-SUM unchanged — the
-    CRC32 fingerprint must catch it (review finding: permutation-
-    invariant fingerprints silently reuse stale device data under the
-    classic np.random.shuffle(X) training loop)."""
-    import numpy as np
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core
-
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
-        x = fluid.data("x", shape=[2], dtype="float64")
-        out = fluid.layers.scale(x, scale=1.0)
-    exe = fluid.Executor()
-    scope = core.Scope()
-    X = np.asarray([[1.0, 2.0], [3.0, 4.0]], np.float64)
-    with fluid.scope_guard(scope):
-        (a,) = exe.run(main, feed={"x": X}, fetch_list=[out])
-        X[[0, 1]] = X[[1, 0]]          # in-place row swap, sum unchanged
-        (b,) = exe.run(main, feed={"x": X}, fetch_list=[out])
-    np.testing.assert_allclose(np.asarray(a), [[1., 2.], [3., 4.]])
-    np.testing.assert_allclose(np.asarray(b), [[3., 4.], [1., 2.]])
-
-
-def test_feed_device_cache_gives_up_on_fresh_arrays():
-    """A name fed a fresh ndarray each step (dataloader pattern) must
-    stop being fingerprinted after a short miss streak."""
-    import numpy as np
-    import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core
-
-    exe = fluid.Executor()
-    for i in range(20):
-        exe._feed_device_cached("x", np.full((4,), float(i), np.float32))
-    assert exe._feed_cache.get("x") == "uncacheable"
-
-
 def _train_two_steps(build_mid):
     """fc1 → <mid> → fc2 → loss, SGD, 2 steps; returns fc1's weight
     before/after (the canary for grads flowing PAST a custom-grad op)."""

@@ -1,112 +1,117 @@
-"""FLAGS_feed_device_cache coverage (ISSUE 2 satellite: hit skips
-re-upload, stale in-place mutations are detected, off-path unchanged)
-and the FLAGS_compilation_cache_dir persistent-executable smoke test."""
+"""The Executor's feed stage (`exe:feed` is `_as_lodtensor` alone: a host
+array is uploaded every run, a `jax.Array` is wrapped where it lies) and
+the FLAGS_compilation_cache_dir persistent-executable smoke test."""
 import contextlib
 import json
 import os
 import subprocess
 import sys
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.fluid import core, executor as executor_mod
+from paddle_tpu.fluid import core
+from paddle_tpu.parallel.mesh import build_mesh
 
 
 @contextlib.contextmanager
-def _feed_cache(enabled):
-    prev = core.globals_["FLAGS_feed_device_cache"]
-    core.set_flag("FLAGS_feed_device_cache", enabled)
+def _flags(**flags):
+    prev = {k: core.globals_[k] for k in flags}
+    fluid.set_flags(flags)
     try:
         yield
     finally:
-        core.set_flag("FLAGS_feed_device_cache", prev)
+        fluid.set_flags(prev)
 
 
-@contextlib.contextmanager
-def _count_uploads():
-    """Count _as_lodtensor calls from Executor.run's feed path — a feed
-    cache HIT returns the pinned device tensor without calling it."""
-    calls = []
-    orig = executor_mod._as_lodtensor
-
-    def counting(data, place):
-        calls.append(1)
-        return orig(data, place)
-    executor_mod._as_lodtensor = counting
-    try:
-        yield calls
-    finally:
-        executor_mod._as_lodtensor = orig
-
-
-def _build_scale():
+def _build_scale(island=False):
+    """out = 2 * x; with ``island`` a host op (py_func) sits between two
+    compiled ops, so the block cannot compile whole."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         x = fluid.data("x", shape=[4], dtype="float32")
-        out = fluid.layers.scale(x, scale=2.0)
-    return main, startup, out
+        if island:
+            a = fluid.layers.scale(x, scale=4.0)
+            b = main.global_block().create_var(name="feed_pyf_out",
+                                               dtype="float32")
+            fluid.layers.py_func(lambda t: t, a, b)
+            out = fluid.layers.scale(b, scale=0.5)
+        else:
+            out = fluid.layers.scale(x, scale=2.0)
+    return main, out
 
 
-def test_feed_cache_hit_skips_reupload():
-    main, startup, out = _build_scale()
-    exe = fluid.Executor()
-    scope = core.Scope()
-    x = np.ones((2, 4), np.float32)
-    with _feed_cache(True), fluid.scope_guard(scope):
-        with _count_uploads() as calls:
-            exe.run(main, feed={"x": x}, fetch_list=[out])
-            first = len(calls)
-            assert first >= 1
-            exe.run(main, feed={"x": x}, fetch_list=[out])
-            assert len(calls) == first  # same array, same content: HIT
-        # the cache pinned the device tensor for this name
-        assert exe._feed_cache["x"][2] is x
+RUN_MODES = {
+    "compiled": dict(FLAGS_executor_mode="compiled"),
+    "segmented": dict(FLAGS_executor_mode="compiled",
+                      FLAGS_executor_segmentation=True,
+                      FLAGS_executor_seg_min_ops=1),
+    "interpreted": dict(FLAGS_executor_mode="interpreted"),
+}
 
 
-def test_feed_cache_detects_inplace_mutation():
-    """The CRC fingerprint catches a stale entry: mutating the SAME
-    ndarray in place must re-upload and compute on the new contents."""
-    main, startup, out = _build_scale()
-    exe = fluid.Executor()
-    scope = core.Scope()
-    x = np.ones((2, 4), np.float32)
-    with _feed_cache(True), fluid.scope_guard(scope):
+@pytest.mark.parametrize("mode", sorted(RUN_MODES))
+def test_same_ndarray_changed_in_place_is_uploaded_again(mode):
+    """Nothing between the caller's array and the device remembers an
+    earlier upload: the SAME ndarray object fed again after an in-place
+    change (an element, then a row swap that keeps every sum) gives the
+    new content, whichever way the block runs."""
+    main, out = _build_scale(island=(mode == "segmented"))
+    exe, scope = fluid.Executor(), core.Scope()
+    x = np.asarray([[1., 2., 3., 4.], [5., 6., 7., 8.]], np.float32)
+    with _flags(**RUN_MODES[mode]), fluid.scope_guard(scope):
         (r1,) = exe.run(main, feed={"x": x}, fetch_list=[out])
-        np.testing.assert_allclose(r1, 2.0)
-        x[:] = 3.0  # in-place: same id, same buffer address
+        assert exe._last_run_mode == mode
+        x[0, 0] = 100.0
         (r2,) = exe.run(main, feed={"x": x}, fetch_list=[out])
-        np.testing.assert_allclose(r2, 6.0)  # stale device copy NOT used
+        x[[0, 1]] = x[[1, 0]]
+        (r3,) = exe.run(main, feed={"x": x}, fetch_list=[out])
+    np.testing.assert_allclose(r1, [[2, 4, 6, 8], [10, 12, 14, 16]])
+    np.testing.assert_allclose(r2, [[200, 4, 6, 8], [10, 12, 14, 16]])
+    np.testing.assert_allclose(r3, [[10, 12, 14, 16], [200, 4, 6, 8]])
 
 
-def test_feed_cache_off_path_uploads_every_run():
-    main, startup, out = _build_scale()
-    exe = fluid.Executor()
-    scope = core.Scope()
-    x = np.ones((2, 4), np.float32)
-    with _feed_cache(False), fluid.scope_guard(scope):
-        with _count_uploads() as calls:
-            exe.run(main, feed={"x": x}, fetch_list=[out])
-            exe.run(main, feed={"x": x}, fetch_list=[out])
-            assert len(calls) == 2  # no cache: one upload per run
-        assert not hasattr(exe, "_feed_cache") or \
-            "x" not in getattr(exe, "_feed_cache", {})
+def _train_program():
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 7
+    with fluid.program_guard(main, startup):
+        x = fluid.data("x", shape=[16], dtype="float32")
+        y = fluid.data("y", shape=[1], dtype="int64")
+        pred = fluid.layers.fc(x, 4, act="softmax")
+        loss = fluid.layers.mean(fluid.layers.cross_entropy(pred, y))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    return main, startup, loss
 
 
-def test_feed_cache_fresh_arrays_stop_fingerprinting():
-    """Names fed a fresh ndarray every step (the dataloader shape) go
-    'uncacheable' after a short miss streak instead of CRC-scanning
-    forever."""
-    main, startup, out = _build_scale()
-    exe = fluid.Executor()
-    scope = core.Scope()
-    with _feed_cache(True), fluid.scope_guard(scope):
-        for i in range(executor_mod.Executor._FEED_CACHE_MISS_LIMIT + 2):
-            exe.run(main, feed={"x": np.full((2, 4), float(i),
-                                             np.float32)},
-                    fetch_list=[out])
-        assert exe._feed_cache["x"] == "uncacheable"
+@pytest.mark.parametrize("mesh_name", ["no_mesh", "dp4"])
+def test_a_jax_array_feed_is_the_device_resident_feed(mesh_name):
+    """The way to feed without an upload is to feed a `jax.Array`: off a
+    mesh the scope holds the very object that was fed (no host copy, no
+    second device array), and on a mesh or off it the losses are the
+    numpy feed's."""
+    mesh = None if mesh_name == "no_mesh" else build_mesh(num_devices=4)
+    rng = np.random.RandomState(0)
+    host = {"x": rng.rand(64, 16).astype("float32"),
+            "y": rng.randint(0, 4, (64, 1)).astype("int64")}
+    resident = {k: jnp.asarray(v) for k, v in host.items()}
+
+    def losses(feed):
+        main, startup, loss = _train_program()
+        exe, scope = fluid.Executor(fluid.CPUPlace()), core.Scope()
+        exe.run(startup, scope=scope)
+        got = [np.asarray(exe.run(main, feed=feed, fetch_list=[loss],
+                                  scope=scope, mesh=mesh)[0]).ravel()[0]
+               for _ in range(3)]
+        return got, scope
+
+    from_host, _ = losses(host)
+    from_device, scope = losses(resident)
+    assert from_device == from_host and from_host[2] < from_host[0]
+    if mesh is None:
+        for name, arr in resident.items():
+            assert scope.find_var(name).get_tensor().array is arr
 
 
 # ------------------------------------------ persistent compile cache
@@ -258,8 +263,8 @@ def test_cache_placed_from_outside_is_not_moved(tmp_path):
 def test_cache_default_is_the_fixed_in_checkout_dir_when_unset(tmp_path):
     """Unset, the argument is the cache (FLAGS_compilation_cache_dir /
     set_optim_cache_dir stay the user's API) and the variable stays
-    unset; what bench.py and chip_smoke.py pass is the fixed
-    <checkout>/.xla_cache — no temporary name, pid or time in it."""
+    unset; what chip_smoke.py passes is the fixed <checkout>/.xla_cache:
+    no temporary name, pid or time in it."""
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     mine = str(tmp_path / "mine")
@@ -268,8 +273,8 @@ def test_cache_default_is_the_fixed_in_checkout_dir_when_unset(tmp_path):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
-        [sys.executable, "-c", "import bench, chip_smoke; "
-         "print(bench.CACHE_DIR); print(chip_smoke.CACHE_DIR)"],
+        [sys.executable, "-c",
+         "import chip_smoke; print(chip_smoke.CACHE_DIR)"],
         capture_output=True, text=True, env=env, timeout=120, cwd=root)
     assert out.returncode == 0, out.stderr[-2000:]
-    assert out.stdout.split() == [os.path.join(root, ".xla_cache")] * 2
+    assert out.stdout.split() == [os.path.join(root, ".xla_cache")]

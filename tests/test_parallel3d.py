@@ -2,7 +2,7 @@
 composition hooks + the executor's window×pipeline scan path) on the
 virtual 8-device CPU mesh.
 
-Oracle contract (docs/PERF.md "Composed 3D lane"): the dp×pp×sp(+MoE)
+Oracle contract (parallel/lm3d.py's docstring): the dp×pp×sp(+MoE)
 composed step must match the single-device oracle — bit-identically for
 pp-only compositions (same fp ops in the same order; the gpipe psum
 adds exact zeros), within documented fp32 tolerance (2e-5 rel on
@@ -302,7 +302,7 @@ def test_lm3d_window_scan_bit_identical_to_step_loop():
     assert _tree_equal(pw, p)
     # steady state: a second window with fresh data retraces NOTHING
     # (params pre-placed at their steady-state shardings + the window's
-    # post-scan output constraint — docs/PERF.md "Composed 3D lane")
+    # post-scan output constraint — lm3d.place_params / _stage_specs)
     w2 = lm3d.sample_window(cfg, K, K)
     pw, aw, _ = win(pw, aw, lm3d.place_window(cfg, mesh, w2), key,
                     jnp.int32(K))

@@ -5,7 +5,7 @@ entry creation, decay-based shrink, and the streaming handoff/checkpoint
 legs that never materialize a spilled table in RAM.
 
 Marker: ``capacity`` (docs/ci.md). Everything here is in-process and
-fast; the multiprocess spill lane is bench.py wide_deep_spill."""
+fast."""
 import json
 import os
 import socket

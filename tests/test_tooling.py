@@ -4,8 +4,11 @@ operators/benchmark/op_tester.cc, fluid/debugger.py, contrib/
 memory_usage_calc.py, op_frequence.py, extend_optimizer/)."""
 import contextlib
 import json
+import os
+import re
 import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -434,3 +437,84 @@ def test_mfu_report_xla_cost_analysis():
         assert out["xla_bytes_accessed"] > 0
         assert out["flops_per_byte"] > 0
     json.dumps(out)
+
+
+# ------------------------------------------------ one peaks table, read
+def test_the_tools_divide_by_the_benchmarks_peaks_table():
+    """tools/ keeps no peak of its own: for every kind the benchmark
+    knows, `bf16_peak_flops` is `benchmark/peaks.py`'s number."""
+    from tools import device_peaks
+    peaks = device_peaks._benchmark_peaks()
+    assert peaks.PEAKS
+    for kind in peaks.PEAKS:
+        assert device_peaks.bf16_peak_flops(
+            types.SimpleNamespace(device_kind=kind)) == \
+            peaks.peak(kind, "bf16_flops_per_s")
+
+
+def test_a_device_kind_without_a_published_peak_is_an_error():
+    """And the error sends its reader to the one table."""
+    from tools.device_peaks import bf16_peak_flops
+    with pytest.raises(SystemExit, match=r"add it to benchmark/peaks\.py"):
+        bf16_peak_flops(types.SimpleNamespace(device_kind="TPU v0"))
+
+
+# --------------------------------- the documents describe the tree as it is
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DOCUMENTS = ["README.md"] + sorted(
+    "docs/" + f for f in os.listdir(os.path.join(ROOT, "docs"))
+    if f.endswith(".md"))
+# a reader's own script in a command line, not a file of this repo
+NOT_OURS = {"train.py"}
+_NOT_THE_TREE = {".git", "chiprun_out", ".chipcheck", "__pycache__",
+                 ".pytest_cache"}
+
+
+@pytest.fixture(scope="module")
+def tree_files():
+    files = set()
+    for d, dirs, names in os.walk(ROOT):
+        dirs[:] = [x for x in dirs if x not in _NOT_THE_TREE
+                   and not x.startswith(".xla_cache")]
+        files.update(os.path.relpath(os.path.join(d, n), ROOT)
+                     for n in names)
+    return files
+
+
+def _code_spans(text):
+    """What a document sets as code: inline spans, and the lines of
+    fenced blocks."""
+    fenced = False
+    for line in text.splitlines():
+        if line.lstrip().startswith("```"):
+            fenced = not fenced
+        elif fenced:
+            yield line
+        else:
+            yield from re.findall(r"`([^`]+)`", line)
+
+
+def _cited_paths(text):
+    """The `*.py` / `*.md` paths among them, `:line` and `::test` cut
+    off; a pattern (`<name>.py`, `test_*.py`) is no path."""
+    for span in _code_spans(text):
+        for token in re.split(r"[\s(),;=\[\]|]+", span):
+            token = token.split(":", 1)[0]
+            if token.startswith("./"):
+                token = token[2:]
+            if re.fullmatch(r"[\w./-]+\.(?:py|md)", token):
+                yield token
+
+
+@pytest.mark.parametrize("document", DOCUMENTS)
+def test_every_file_a_document_cites_exists(document, tree_files):
+    """A path a document cites is a file of this tree: as written from
+    the root (`tools/op_bench.py`, `PERF.md`), or as the tail of one
+    (`fluid/executor.py`, `ps_rpc.py`). A deleted harness or page that a
+    document still sends its reader to fails here."""
+    with open(os.path.join(ROOT, document)) as f:
+        cited = set(_cited_paths(f.read())) - NOT_OURS
+    assert cited, f"{document}: the rule found no path at all"
+    missing = sorted(p for p in cited if p not in tree_files
+                     and not any(f.endswith("/" + p) for f in tree_files))
+    assert not missing, f"{document} cites files that do not exist: {missing}"

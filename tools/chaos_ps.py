@@ -809,8 +809,7 @@ def run_stream_worker():
             pub.close()
     # wall of the training loop INCLUDING the async plane's stop-drain
     # (the sync leg pays its barriers inline; excluding the drain would
-    # flatter async) and any step_sleep pacing — bench.py stream_ctr
-    # records steps*step_sleep alongside so the pacing is attributable
+    # flatter async) and any step_sleep pacing
     json.dump({"losses": losses, "offset": loader.stream_offset,
                "train_wall_s": round(time.time() - t_loop, 4)},
               open(outfile, "w"))

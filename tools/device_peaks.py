@@ -1,14 +1,25 @@
-"""The chip a measurement needs, and its published peak — the one table
-`bench.py` and `tools/mfu_report.py` divide by.
+"""The chip a measurement needs, and its published peak.
 
-Keyed by the `device_kind` JAX reports. A device that is not in the
-table is an error, never a default: an MFU against the wrong peak is a
-wrong number with a right-looking name."""
+The peaks are `benchmark/peaks.py`'s table, read and never copied: the
+yardstick lives with the benchmark. A device that is not in that table
+is an error, never a default: an MFU against the wrong peak is a wrong
+number with a right-looking name."""
 from __future__ import annotations
 
-# Google Cloud documentation, "TPU v5e" (system architecture): 197 TFLOP/s
-# bf16 per chip. jax reports that chip as device_kind "TPU v5 lite".
-BF16_PEAK_FLOPS = {"TPU v5 lite": 197e12}
+import importlib.util
+import os
+
+_PEAKS_PY = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark", "peaks.py")
+
+
+def _benchmark_peaks():
+    """`benchmark/peaks.py`, by its path: `benchmark/` is no package."""
+    spec = importlib.util.spec_from_file_location(
+        "_benchmark_peaks_py", _PEAKS_PY)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def require_tpu(what: str):
@@ -34,10 +45,4 @@ def device_stamp() -> dict:
 
 
 def bf16_peak_flops(device) -> float:
-    try:
-        return BF16_PEAK_FLOPS[device.device_kind]
-    except KeyError:
-        raise SystemExit(
-            f"no published bf16 peak for device_kind "
-            f"{device.device_kind!r}: add it to tools/device_peaks.py "
-            f"with its source") from None
+    return _benchmark_peaks().peak(device.device_kind, "bf16_flops_per_s")

@@ -10,9 +10,8 @@ operators/fused/multihead_matmul_op.cu is the reference's fused fast
 path; operators/benchmark/op_tester.cc is its measure-don't-assert
 harness.
 
-Usage (needs a TPU; both exit non-zero without one):
+Usage (needs a TPU; exits non-zero without one):
     python -m tools.flash_smoke            # full sweep
-    python bench.py flash                  # same, through the bench entry
 
 Per-config JSON row fields: seq_len, blk_q, blk_k, dtype, causal,
 dropout, fwd_ms, fwdbwd_ms, tflops_fwd, vmem_kb_est, max_err_fwd,

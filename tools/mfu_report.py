@@ -54,7 +54,7 @@ def _build(model):
     if model == "bert":
         from paddle_tpu.models import bert
         cfg = bert.bert_base_config()
-        batch, seq_len = 128, 128  # bench.py bert's size
+        batch, seq_len = 128, 128  # the bert_base.b128_s128 cell's size
         main, startup, feeds, fetches = bert.build_bert_pretrain_program(
             cfg, seq_len=seq_len, dropout=0.0, lr=1e-4)
         return (main, startup,

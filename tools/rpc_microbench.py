@@ -2,8 +2,8 @@
 
 Starts a VarServer with an echo handler on 127.0.0.1 and sweeps payload
 sizes through one VarClient per wire generation, printing MB/s for the
-round trip (send + echo receive). This isolates the framing cost the
-wide_deep_1b PS lane pays per tensor: the legacy wire pickles every
+round trip (send + echo receive). This isolates the framing cost a PS
+trainer pays per tensor: the legacy wire pickles every
 ndarray into the message blob (two full copies plus pickle overhead per
 direction); the binary wire ships a small pickled header plus the raw
 buffer via sendall(memoryview)/recv_into (docs/PS_DATA_PLANE.md).

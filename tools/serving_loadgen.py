@@ -1,12 +1,12 @@
 """Serving-plane load generator — closed- and open-loop traffic against
 a ServingEngine, in-process or over the HTTP ingress
-(docs/SERVING.md "Bench methodology" + "Ingress & overload").
+(docs/SERVING.md "Load-generator methodology" + "Ingress & overload").
 
-Library (bench.py + tests/test_serving*.py import these):
+Library (tests/test_serving*.py import these):
   * ``run_closed_loop(predict, feeds, clients, duration_s)`` — N client
     threads, each submits its next request the moment the previous one
     completes (throughput-under-concurrency; latency EXCLUDES client
-    think time). The shape bench.py's serving lanes measure.
+    think time).
   * ``run_open_loop(submit, feeds, rate_qps, duration_s)`` — one pacing
     thread fires async submits on a fixed-rate schedule regardless of
     completions (latency-under-load; queueing delay INCLUDED — the
@@ -645,10 +645,9 @@ def free_port() -> int:
 
 
 def build_mlp_serving_model(n_feeds: int = 64):
-    """The mnist-shaped serving model every mnist lane measures — ONE
-    builder so the CLI loadgen and bench.py serve_mnist stay comparable
-    by construction. Returns (program, scope, out_name, feeds) with
-    params initialized and ``feeds`` a list of single-row feed dicts."""
+    """The mnist-shaped serving model every mnist lane measures.
+    Returns (program, scope, out_name, feeds) with params initialized
+    and ``feeds`` a list of single-row feed dicts."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import core
 
@@ -675,9 +674,9 @@ def run_overload_scenario(clients: int = 16, duration_s: float = 2.0,
                           overload_factor: float = 4.0,
                           workers: int = 2) -> Dict[str, object]:
     """The ISSUE 9 overload acceptance shape, as a library function
-    (CLI ``--scenario overload`` and ``bench.py serve_http_overload``
-    both run it): measure 1× capacity closed-loop over HTTP, then
-    drive open-loop at 1× and ``overload_factor``×. Reports
+    (CLI ``--scenario overload`` runs it): measure 1× capacity
+    closed-loop over HTTP, then drive open-loop at 1× and
+    ``overload_factor``×. Reports
     accepted-request p99 at both loads, the shed rate, the status
     histogram (every non-200 must be a TYPED 429/504/503 — "5xx"/
     "transport" entries are the failure signal), and the engine's
