@@ -255,7 +255,8 @@ def qwen3_next_parity(tiny, clock):
     import jax.numpy as jnp
     import numpy as np
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core
+    from paddle_tpu.fluid import core, telemetry
+    from paddle_tpu.models import qwen3_next
 
     configs = os.path.join(ROOT, "benchmark", "configs")
     model = _by_path(os.path.join(configs, "qwen3_next_80b_a3b.py"))
@@ -284,15 +285,21 @@ def qwen3_next_parity(tiny, clock):
         weights = {
             p.name: np.asarray(scope.find_var(p.name).get_tensor().array)
             for p in main.global_block().all_parameters()}
+        passes = qwen3_next.expert_passes(main)
         t0 = time.perf_counter()
         with (contextlib.nullcontext() if bf16_operands
               else jax.default_matmul_precision("highest")):
             got = exe.run(main, feed=feed, scope=scope,
-                          fetch_list=[fetches[0].name] + wanted)
+                          fetch_list=[fetches[0].name] + wanted
+                          + list(passes.values()))
+        ran = [int(np.asarray(g)[0]) for g in got[len(names):]]
         got = dict(zip(names, (np.asarray(g) for g in got)))
         stats = jax.devices()[0].memory_stats() or {}
+        rows = telemetry.REGISTRY.get("moe_rows_per_step")
         emit(phase="qwen3_next_step", bf16_operands=bf16_operands,
-             loss=float(got["loss"].ravel()[0]),
+             loss=float(got["loss"].ravel()[0]), passes=ran,
+             moe_rows_per_step=int(sum(rows.value(site=site)
+                                       for site in passes)),
              parameters=int(sum(w.size for w in weights.values())),
              seconds=round(time.perf_counter() - t0, 1),
              peak_bytes_in_use=stats.get("peak_bytes_in_use"),

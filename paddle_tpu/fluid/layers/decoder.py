@@ -105,12 +105,22 @@ def moe_router(x, num_experts, top_k, param_attr=None, name=None):
 
 
 def moe_expert_ffn(x, topk_idx, topk_weight, experts_held, expert_width,
-                   expert_start=0, gate_up_attr=None, down_attr=None,
-                   name=None):
+                   expert_start=0, num_experts=0, gate_up_attr=None,
+                   down_attr=None, name=None):
     """The part of a routed gated FFN that experts ``expert_start ..
     expert_start + experts_held - 1`` give, with no assignment dropped;
     one parameter a projection, [held, D, 2 * width] (gate then up) and
-    [held, width, D]."""
+    [held, width, D].
+
+    ``num_experts`` is the width of the router that chose ``topk_idx``.
+    Given it, the grouped products run over ``decoder_ops.row_bound``
+    rows a pass: twice the share of the assignments the held experts
+    expect (tokens * k * held / num_experts), far above what a balanced
+    router sends, instead of the tokens * min(k, held) a no-drop layer
+    could be sent. A step routed more than the bound is exact all the
+    same: it costs further passes, as many as its rows need. The op's
+    int32 output ``Passes`` [1] (``<layer's name>.passes``, fetchable)
+    says how many ran. 0 = unknown: one pass over the most."""
     helper = LayerHelper("moe_expert_ffn", **locals())
     d = x.shape[-1]
     w_gate_up = helper.create_parameter(
@@ -119,10 +129,13 @@ def moe_expert_ffn(x, topk_idx, topk_weight, experts_held, expert_width,
     w_down = helper.create_parameter(
         attr=down_attr, shape=[experts_held, expert_width, d], dtype=x.dtype)
     out = _like(helper, x)
+    passes = helper.create_variable(name=helper.name + ".passes", shape=(1,),
+                                    dtype="int32", stop_gradient=True)
     helper.append_op(
         type="moe_expert_ffn",
         inputs={"X": [x], "TopkIdx": [topk_idx], "TopkWeight": [topk_weight],
                 "WGateUp": [w_gate_up], "WDown": [w_down]},
-        outputs={"Out": [out]},
-        attrs={"expert_start": expert_start, "site": helper.name})
+        outputs={"Out": [out], "Passes": [passes]},
+        attrs={"expert_start": expert_start, "num_experts": num_experts,
+               "site": helper.name})
     return out

@@ -24,7 +24,7 @@ from ..fluid.param_attr import ParamAttr
 from .bert import fused_multihead_attention
 
 __all__ = ["qwen3_next_config", "build_qwen3_next_pretrain_program",
-           "synthetic_pretrain_batch"]
+           "expert_passes", "synthetic_pretrain_batch"]
 
 
 def qwen3_next_config():
@@ -105,7 +105,7 @@ def sparse_moe(x, prefix, cfg):
         param_attr=_attr(prefix + "w_router", cfg))
     routed = layers.moe_expert_ffn(
         x, idx, weight, cfg["experts_held"], cfg["expert_width"],
-        expert_start=cfg["expert_start"],
+        expert_start=cfg["expert_start"], num_experts=cfg["num_experts"],
         gate_up_attr=_attr(prefix + "w_gate_up", cfg),
         down_attr=_attr(prefix + "w_down", cfg))
     shared = layers.elementwise_mul(
@@ -164,6 +164,15 @@ def build_qwen3_next_pretrain_program(cfg=None, seq_len=4096, lr=1e-4,
             opt._set_checkpoints(checkpoints)
         opt.minimize(loss)
     return main, startup, [ids, labels], [ce]
+
+
+def expert_passes(program):
+    """{an expert layer's ``site`` (its gauges' label): the name to fetch
+    for the passes of its row bound it ran that step, [1] int32}, in
+    layer order; 1 wherever the routing fitted twice the held share."""
+    return {op.attr("site"): op.output("Passes")[0]
+            for op in program.global_block().ops
+            if op.type == "moe_expert_ffn"}
 
 
 def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0):

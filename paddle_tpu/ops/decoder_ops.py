@@ -17,6 +17,8 @@ delta rule's chunk state stay float32.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -233,25 +235,119 @@ def _ragged(rows, weights, group_sizes):
                           preferred_element_type=jnp.float32)
 
 
+# Rows a tile of the grouped kernel, the unit ``row_bound`` rounds up to:
+# a module constant beside ``attention_ops.DENSE_MAX_SEQ``, not a flag.
+ROW_TILE = 512
+
+
+def row_bound(tokens, k, held, num_experts):
+    """Rows a pass of ``moe_expert_ffn``'s grouped products runs over,
+    from shapes alone. ``full`` = tokens * min(k, held) is the most a
+    no-drop layer can be sent; the layer IS sent a binomial count around
+    ``expected`` = tokens * k * held / num_experts, the share of the
+    router it holds. The bound is twice that, rounded up to ROW_TILE,
+    and never above ``full``: 2 x is tens of sigma above the mean at a
+    uniform router and well above the 1.2-1.5 x a balanced trained
+    router puts on a rank. A router's width of 0 is unknown: ``full``,
+    as is a layer that holds every expert."""
+    full = tokens * min(k, held)
+    if not num_experts:
+        return full
+    tiles = -(-2 * tokens * k * held // (num_experts * ROW_TILE))
+    return min(full, tiles * ROW_TILE)
+
+
+def _window(o, x, weight, w_gate_up, w_down, order, lo, sizes, k):
+    """``o`` [T, D] + what the sorted assignments lo .. lo + len(order)
+    - 1 give: ``order`` their indices into the T*k assignments, ``sizes``
+    [held] the whole layer's group sizes, clipped here to the window."""
+    bound, f = order.shape[0], w_down.shape[1]
+    ends = jnp.cumsum(sizes)
+    valid = (lo + jnp.arange(bound) < ends[-1])[:, None]
+    in_window = jnp.clip(jnp.minimum(ends, lo + bound)
+                         - jnp.maximum(ends - sizes, lo), 0)
+    token = order // k
+    x_rows = jnp.where(valid, x[token], 0)
+    h = jnp.where(valid, _ragged(x_rows, w_gate_up, in_window), 0.0)
+    act = _silu(h[:, :f]) * h[:, f:]
+    y = jnp.where(valid, _ragged(act, w_down, in_window), 0.0) \
+        * weight[order][:, None]
+    return o.at[token].add(y)
+
+
+def _passes_run(sizes, bound):
+    return -(-jnp.sum(sizes) // bound)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _windows(x, weight, w_gate_up, w_down, order, sizes, k):
+    """The windows ``order`` [passes, bound] of ``_window``, as many as
+    hold a routed assignment, one after the other: a loop of that many
+    trips, so a window past the routed count costs nothing. Its own
+    vjp: JAX differentiates no loop of a traced number of trips, and
+    through a scan of conds it stacks every pass's residuals, the
+    weights with them (3.99 -> 9.69 GB of temporaries at the Qwen
+    cell's sizes). The backward runs the same trips, each the vjp of
+    one window made anew, and sums them."""
+    zero = jnp.zeros(x.shape, jnp.float32)
+    return lax.fori_loop(
+        0, _passes_run(sizes, order.shape[1]),
+        lambda p, o: _window(o, x, weight, w_gate_up, w_down, order[p],
+                             p * order.shape[1], sizes, k), zero)
+
+
+def _windows_fwd(x, weight, w_gate_up, w_down, order, sizes, k):
+    return (_windows(x, weight, w_gate_up, w_down, order, sizes, k),
+            (x, weight, w_gate_up, w_down, order, sizes))
+
+
+def _windows_bwd(k, residuals, d_out):
+    x, weight, w_gate_up, w_down, order, sizes = residuals
+    primals = (x, weight, w_gate_up, w_down)
+    zero = jnp.zeros(x.shape, jnp.float32)
+
+    def one_pass(p, sums):
+        _, vjp = jax.vjp(
+            lambda *args: _window(zero, *args, order[p], p * order.shape[1],
+                                  sizes, k), *primals)
+        return tuple(map(jnp.add, sums, vjp(d_out)))
+
+    # summed as a window's gradient comes, in its primal's dtype: an
+    # expert's rows lie side by side, so all but the two windows its
+    # group may straddle add exact zeros to its weights' gradient
+    return lax.fori_loop(
+        0, _passes_run(sizes, order.shape[1]), one_pass,
+        tuple(map(jnp.zeros_like, primals))) + (None, None)
+
+
+_windows.defvjp(_windows_fwd, _windows_bwd)
+
+
 @register_op("moe_expert_ffn",
              inputs=("X", "TopkIdx", "TopkWeight", "WGateUp", "WDown"),
              diff_inputs=("X", "TopkWeight", "WGateUp", "WDown"),
-             attr_defaults={"expert_start": 0, "site": ""})
+             attr_defaults={"expert_start": 0, "num_experts": 0, "site": ""})
 def _moe_expert_ffn(ins, attrs):
     """The held experts' part of a routed gated FFN, no assignment
     dropped: Out[t] = sum over the k experts e chosen for token t that
     are held here, ``expert_start <= e < expert_start + held``, of
     TopkWeight[t, e] * (SiLU(x W_g,e) * x W_u,e) W_d,e. WGateUp
     [held, D, 2F] (gate then up), WDown [held, F, D]; what the experts
-    held elsewhere would add is left out.
+    held elsewhere would add is left out. Passes [1] int32: the passes
+    that ran (below), 1 wherever the routing fits the bound.
 
     Static shapes: the T*k assignments are sorted by held expert (those
-    of absent experts last) and the first T * min(k, held) of them, the
-    most that can be held here, are rows of one grouped product an
-    expert's projection (``lax.ragged_dot``, the groups' sizes counted
-    from the router's choice). Rows past the last group are masked on
-    both sides of each product: what a grouped product leaves there is
-    not defined."""
+    of absent experts last); the first T * min(k, held) of them are the
+    most that can be held here. They are cut in windows of ``row_bound``
+    rows (twice the share of the router's width ``num_experts`` that is
+    held; all of them where the width is not given), and a window is the
+    rows of one grouped product an expert's projection
+    (``lax.ragged_dot``, each group's size clipped to the window). Only
+    the windows that hold a routed assignment run, so a step whose
+    routing fits runs ONE pass of ``row_bound`` rows and an overflow is
+    exact: it costs a further pass a window, never an assignment. Rows
+    past the last group are masked on both sides of each product: what a
+    grouped product leaves there is not defined."""
     x, idx, weight = (first(ins, "X"), first(ins, "TopkIdx"),
                       first(ins, "TopkWeight"))
     w_gate_up, w_down = first(ins, "WGateUp"), first(ins, "WDown")
@@ -260,24 +356,33 @@ def _moe_expert_ffn(ins, attrs):
     tokens = idx.size // k
     local = idx.reshape(-1) - attrs.get("expert_start", 0)
     key = jnp.where((local >= 0) & (local < held), local, held)
-    rows = tokens * min(k, held)
-    order = jnp.argsort(key, stable=True)[:rows]
-    valid = (key[order] < held)[:, None]
-    token = order // k
+    full = tokens * min(k, held)
+    bound = row_bound(tokens, k, held, attrs.get("num_experts", 0))
+    passes = -(-full // bound)
+    order = jnp.argsort(key, stable=True)[:full]
     sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
     # the operands' cast before the gather: the same values, half the rows'
-    # bytes
-    x_rows = jnp.where(valid, _operands(x.reshape(tokens, d))[0][token], 0)
-    h = jnp.where(valid, _ragged(x_rows, w_gate_up, sizes), 0.0)
-    f = w_down.shape[1]
-    act = _silu(h[:, :f]) * h[:, f:]
-    y = jnp.where(valid, _ragged(act, w_down, sizes), 0.0) \
-        * weight.reshape(-1)[order][:, None]
-    o = jnp.zeros((tokens, d), jnp.float32).at[token].add(y)
+    # bytes; the weights' before the passes: once, not a pass
+    x_rows, w_gate_up, w_down = _operands(x.reshape(tokens, d), w_gate_up,
+                                          w_down)
+    operands = (x_rows, weight.reshape(-1), w_gate_up, w_down)
+    if passes == 1:
+        o = _window(jnp.zeros((tokens, d), jnp.float32), *operands, order,
+                    0, sizes, k)
+    else:
+        order = jnp.pad(order, (0, passes * bound - full))
+        o = _windows(*operands, order.reshape(passes, bound), sizes, k)
     site = attrs.get("site", "")
     _gauge("moe_experts_held", "experts whose weights the layer holds",
            site, held)
     _gauge("moe_rows_per_step",
-           "rows the grouped expert products run over: tokens x min(k, "
-           "experts held), the most a no-drop layer can be sent", site, rows)
-    return out(Out=o.reshape(x.shape).astype(x.dtype))
+           "rows the grouped expert products run over in a step whose "
+           "routing fits one pass: row_bound, twice the held share of "
+           "the router rounded up to ROW_TILE, at most tokens x min(k, "
+           "experts held)", site, bound)
+    _gauge("moe_row_passes_max",
+           "passes of moe_rows_per_step rows the most a no-drop layer "
+           "can be sent would take", site, passes)
+    return out(Out=o.reshape(x.shape).astype(x.dtype),
+               Passes=jnp.maximum(1, _passes_run(sizes, bound))
+               .astype(jnp.int32).reshape(1))

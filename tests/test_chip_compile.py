@@ -208,8 +208,11 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
     the attention layer runs the flash kernels at D = 256 causal (the
     forward in the forward pass and again in the recomputed segment,
     then dK/dV and dQ), every expert product is XLA's own grouped
-    kernel, and the chunk scans are the only loops. Start-up runs on the
-    CPU for the shapes alone (7.5 GB of host memory, ~5 s)."""
+    kernel over `row_bound` = 5,120 rows (twice the 2,560 the 32 held
+    experts of 512 expect; never the 40,960 a layer could be sent), and
+    the only loops are the chunk scans and the expert layers' windows.
+    Start-up runs on the CPU for the shapes alone (7.5 GB of host
+    memory, ~5 s)."""
     from paddle_tpu.models import qwen3_next
     cfg = dict(qwen3_next.qwen3_next_config(), layers=4, experts_held=32,
                vocab_size=18992)
@@ -246,13 +249,19 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
                if KERNEL in line and "ragged-dot" in line]
     flash = _kernel_names("\n".join(
         line for line in text.splitlines() if line not in grouped))
-    # 4 layers x 2 projections x (forward, again, backward's two)
+    # 4 layers x 2 projections x (forward, again in the backward's own
+    # loop, backward's two)
     assert sum("ragged-dot-none" in line for line in grouped) == 4 * 2 * 4
+    assert "[5120,1024]" in text and "[40960,1024]" not in text \
+        and "[40960,2048]" not in text
     assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2,
                      ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
                      ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1}
     import re
-    assert len(re.findall(r" while\(", text)) == 9  # 3 scans x fwd, again, bwd
+    # 3 chunk scans x (forward, again, backward) + 4 expert layers x
+    # (forward, backward): the loops over the windows of `row_bound` rows
+    # (the recomputed segment's is dead code: nothing reads its output)
+    assert len(re.findall(r" while\(", text)) == 9 + 8
     mem = compiled.memory_analysis()
     parameters = 625.7e6
     assert abs(mem.argument_size_in_bytes / (12 * parameters) - 1) < 0.01

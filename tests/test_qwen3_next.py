@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import core, telemetry
 from paddle_tpu.models import qwen3_next as qn
+from paddle_tpu.ops import decoder_ops
 from paddle_tpu.ops.registry import OPS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -214,12 +215,28 @@ def _moe_weights(seed, held=E):
                 shared_gate=normal(seed + 6, D, 1))
 
 
-def _routed(x, p, start, held):
+def _expert_layer(x, p, start, held, **attrs):
+    """(the router's outputs, the expert op's) for experts start ..
+    start + held - 1; ``attrs``: the op's further attributes."""
     r = kernel("moe_router", {"top_k": K_TOP}, X=x, W=p["w_router"])
-    return kernel("moe_expert_ffn", {"expert_start": start},
-                  X=x, TopkIdx=r["TopkIdx"], TopkWeight=r["TopkWeight"],
-                  WGateUp=p["w_gate_up"][start:start + held],
-                  WDown=p["w_down"][start:start + held])["Out"], r["AuxLoss"]
+    return r, kernel("moe_expert_ffn", dict(attrs, expert_start=start),
+                     X=x, TopkIdx=r["TopkIdx"], TopkWeight=r["TopkWeight"],
+                     WGateUp=p["w_gate_up"][start:start + held],
+                     WDown=p["w_down"][start:start + held])
+
+
+def _routed(x, p, start, held, **attrs):
+    r, o = _expert_layer(x, p, start, held, **attrs)
+    return o["Out"], r["AuxLoss"]
+
+
+def _gauge(name, site):
+    return telemetry.REGISTRY.get(name).value(site=site)
+
+
+def _scalar(f):
+    """A layer's (output, auxiliary loss) as one number to differentiate."""
+    return lambda x, p: (lambda y, aux: jnp.sum(y ** 2) + aux)(*f(x, p))
 
 
 def _shared(x, p):
@@ -261,27 +278,36 @@ def test_held_experts_part_and_its_gradients(start, held):
     for got, want in zip(program(x, p), reference(x, p)):
         close(got, want, OP_TOL)
 
-    def scalar(f):
-        return lambda x, p: (lambda y, aux: jnp.sum(y ** 2) + aux)(*f(x, p))
-
-    got = jax.grad(scalar(program), (0, 1))(x, p)
-    want = jax.grad(scalar(reference), (0, 1))(x, p)
+    got = jax.grad(_scalar(program), (0, 1))(x, p)
+    want = jax.grad(_scalar(reference), (0, 1))(x, p)
     close(got[0], want[0], OP_TOL)
     for name in p:
         close(got[1][name], want[1][name], OP_TOL)
 
 
+@pytest.mark.parametrize("num_experts", [0, E],
+                         ids=["width_unknown", "bounded"])
 @pytest.mark.parametrize("ranks", [16, 4, 1])
-def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(ranks):
+def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(
+        ranks, num_experts, monkeypatch):
     """THE SHARE TEST: the routed parts that `ranks` expert-parallel
     ranks compute, each holding E / ranks experts, plus the shared
     expert counted once, are what the reference gives for the whole
-    layer with every expert held."""
+    layer with every expert held; also where each rank knows the
+    router's width and bounds its rows (at a tile of 4 rows: 8 of 20, 32
+    of 60, and, for the one rank that holds everything, all 60)."""
+    monkeypatch.setattr(decoder_ops, "ROW_TILE", 4)
     x, p = normal(60, 2, 10, D), _moe_weights(61)
     held = E // ranks
-    parts = [_routed(x, p, r * held, held)[0] for r in range(ranks)]
+    parts = [_routed(x, p, r * held, held, num_experts=num_experts,
+                     site="t_share")[0] for r in range(ranks)]
     whole, _ = ref.moe(p, x, {"experts_per_tok": K_TOP, "expert_start": 0})
     close(sum(parts) + _shared(x, p), whole, OP_TOL)
+    full = 20 * min(K_TOP, held)
+    assert _gauge("moe_rows_per_step", "t_share") == (
+        {16: 8, 4: 32, 1: full}[ranks] if num_experts else full)
+    assert _gauge("moe_row_passes_max", "t_share") == (
+        {16: 3, 4: 2, 1: 1}[ranks] if num_experts else 1)
     # and a rank whose experts nobody chose adds exactly nothing
     x = x.at[..., 0].set(1.0)  # a constant feature: a bias on the logits
     nobody = dict(p, w_router=p["w_router"].at[:, :4].set(0.0)
@@ -313,17 +339,103 @@ def test_a_router_forced_onto_one_expert_loses_no_assignment(held):
     assert mine[..., 0].all() and np.abs(np.asarray(got)).min(-1).min() > 0
 
 
-def test_expert_layer_counts_its_rows_and_experts_by_site():
+@pytest.mark.parametrize("tokens,k,held,num_experts,want", [
+    (4096, 10, 32, 512, 5120),    # the cell: 2 x 2,560 expected, of 40,960
+    (4096, 10, 32, 0, 40960),     # width unknown: the most, as before
+    (4096, 10, 512, 512, 40960),  # one rank holds every expert: the most
+    (4096, 10, 128, 512, 20480),  # an EP4 rank
+    (4096, 10, 4, 512, 1024),     # 2 x 320 = 640: two tiles
+    (4096, 10, 2, 512, 512),      # 2 x 160 = 320: one tile
+    (20, 3, 2, 16, 40),           # a tile is more than the most: the most
+    (4000, 10, 32, 512, 5120),    # 2 x 2,500 = 5,000, rounded up to a tile
+])
+def test_row_bound_is_twice_the_held_share_in_whole_tiles(
+        tokens, k, held, num_experts, want):
+    assert decoder_ops.ROW_TILE == 512
+    assert decoder_ops.row_bound(tokens, k, held, num_experts) == want
+
+
+@pytest.mark.parametrize("checkpoint", [False, True],
+                         ids=["plain", "checkpoint"])
+@pytest.mark.parametrize("held,tile,ran,most", [(1, 8, 3, 3), (2, 16, 2, 3),
+                                                (4, 32, 2, 2)])
+def test_a_forced_overflow_runs_further_passes_and_loses_no_assignment(
+        held, tile, ran, most, checkpoint, monkeypatch):
+    """Every token sent to expert 5, the layer told the router's width:
+    64 rows and more where 12 a held expert are expected and twice that
+    is the bound. The windows past the first run (three of 24 rows for
+    one expert; two of 48 and a skipped third for two; 96 and the 5 rows
+    left over for four), and output and every gradient, the router's
+    too, are the reference's, also under jax.checkpoint."""
+    monkeypatch.setattr(decoder_ops, "ROW_TILE", tile)
+    x = normal(70, 4, 16, D).at[..., 0].set(1.0)
+    p = dict(_moe_weights(71),
+             w_router=normal(72, D, E, scale=0.01).at[0, 5].set(50.0))
+    cfg = {"experts_per_tok": K_TOP, "expert_start": 5}
+
+    def cut(p):
+        return dict(p, w_gate_up=p["w_gate_up"][5:5 + held],
+                    w_down=p["w_down"][5:5 + held])
+
+    def program(x, p):
+        r, o = _expert_layer(x, p, 5, held, num_experts=E, site="t_forced")
+        return o["Out"] + _shared(x, p), r["AuxLoss"][0]
+
+    def reference(x, p):
+        return ref.moe(cut(p), x, cfg)
+
+    r, o = _expert_layer(x, p, 5, held, num_experts=E)
+    idx = np.asarray(r["TopkIdx"])
+    routed = int(((idx >= 5) & (idx < 5 + held)).sum())
+    bound = decoder_ops.row_bound(64, K_TOP, held, E)
+    assert (idx[..., 0] == 5).all() and bound == 12 * held * 2
+    assert -(-routed // bound) == ran and -(-64 * min(K_TOP, held)
+                                            // bound) == most
+    assert o["Passes"].dtype == jnp.int32 and o["Passes"].shape == (1,)
+    assert int(o["Passes"][0]) == ran
+    assert _gauge("moe_row_passes_max", "") == most
+
+    if checkpoint:
+        program = jax.checkpoint(program)
+    for got, want in zip(program(x, p), reference(x, p)):
+        close(got, want, OP_TOL)
+
+    got = jax.jit(jax.grad(_scalar(program), (0, 1)))(x, p)
+    want = jax.grad(_scalar(reference), (0, 1))(x, p)
+    close(got[0], want[0], OP_TOL)
+    for name in p:
+        close(got[1][name], want[1][name], OP_TOL)
+
+
+@pytest.mark.parametrize("tile", [4, 512])
+def test_a_uniform_router_fits_one_pass(tile, monkeypatch):
+    """At a router that spreads its tokens, twice the share holds what
+    is routed: Passes reads 1, whether the bound is below the most (a
+    tile of 4: 32 rows of 60) or the most itself."""
+    monkeypatch.setattr(decoder_ops, "ROW_TILE", tile)
+    x, p = normal(60, 2, 10, D), _moe_weights(61)
+    for start in range(0, E, 4):
+        _, o = _expert_layer(x, p, start, 4, num_experts=E, site="t_fit")
+        assert int(o["Passes"][0]) == 1
+    assert _gauge("moe_rows_per_step", "t_fit") == (32 if tile == 4 else 60)
+
+
+@pytest.mark.parametrize("num_experts,rows,most", [(0, 40, 1), (16, 16, 3)],
+                         ids=["width_unknown", "bounded"])
+def test_expert_layer_counts_its_rows_and_experts_by_site(num_experts, rows,
+                                                         most, monkeypatch):
+    """20 tokens, top-3, experts 4 and 5 of 16 held. Without the
+    router's width: 20 x min(3, 2) = 40 rows, one pass, as before. With
+    it, at a tile of 8 rows: 7.5 assignments expected, 15 doubled, 16 in
+    whole tiles, and the 40 a no-drop layer could be sent are three
+    passes of them."""
+    monkeypatch.setattr(decoder_ops, "ROW_TILE", 8)
     x, p = normal(80, 2, 10, D), _moe_weights(81)
-    r = kernel("moe_router", {"top_k": K_TOP}, X=x, W=p["w_router"])
-    kernel("moe_expert_ffn", {"expert_start": 4, "site": "t_moe"}, X=x,
-           TopkIdx=r["TopkIdx"], TopkWeight=r["TopkWeight"],
-           WGateUp=p["w_gate_up"][4:6], WDown=p["w_down"][4:6])
-    # 20 tokens x min(top-3, 2 held): the most a no-drop layer can be sent
-    assert telemetry.REGISTRY.get("moe_rows_per_step").value(
-        site="t_moe") == 20 * 2
-    assert telemetry.REGISTRY.get("moe_experts_held").value(
-        site="t_moe") == 2
+    site = f"t_rows_{num_experts}"
+    _expert_layer(x, p, 4, 2, num_experts=num_experts, site=site)
+    assert _gauge("moe_rows_per_step", site) == rows
+    assert _gauge("moe_row_passes_max", site) == most
+    assert _gauge("moe_experts_held", site) == 2
 
 
 # ------------------------------------------------------ the whole model
@@ -340,22 +452,38 @@ def _toy_step(recompute, seq_len=80, batch=2):
     return main, exe, scope, fetches, names, params, feed
 
 
+@pytest.mark.parametrize("row_tile", [512, 64], ids=["one_pass", "windows"])
 @pytest.mark.parametrize("recompute", [True, False],
                          ids=["recompute", "stored"])
-def test_toy_model_loss_and_every_gradient_against_the_reference(recompute):
+def test_toy_model_loss_and_every_gradient_against_the_reference(
+        recompute, row_tile, monkeypatch):
     """Four layers (three Gated DeltaNet, one gated attention), experts
     4-7 of 16 held, S = 80 (not a multiple of the chunk): the fetched
     loss and the gradient of EVERY parameter, fetched as @GRAD from the
     one `exe.run` that also applies Adam. Under recomputation the
     checkpoints must lower onto jax.checkpoint segments with no
     fallback (a warning is an error here), although the fetched loss
-    (cross entropy) is not the trained one."""
+    (cross entropy) is not the trained one. At the kernel's row tile
+    the 480 rows a layer could be sent are one pass; at a tile of 64
+    they are two windows of 256 (120 expected), the second skipped:
+    each layer's fetched `Passes` reads 1."""
+    monkeypatch.setattr(decoder_ops, "ROW_TILE", row_tile)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         main, exe, scope, fetches, names, params, feed = _toy_step(recompute)
+        passes = qn.expert_passes(main)
         out = exe.run(main, feed=feed, scope=scope,
                       fetch_list=[fetches[0].name]
-                      + [n + "@GRAD" for n in names])
+                      + [n + "@GRAD" for n in names]
+                      + list(passes.values()))
+    assert len(passes) == 4
+    for site, ran in zip(passes, out[1 + len(names):]):
+        assert ran.dtype == np.int32 and ran.tolist() == [1]
+        assert _gauge("moe_rows_per_step", site) == (
+            480 if row_tile == 512 else 256)
+        assert _gauge("moe_row_passes_max", site) == (
+            1 if row_tile == 512 else 2)
+    out = out[:1 + len(names)]
     from tools.mfu_report import compiled_step_of
     assert (compiled_step_of(exe)._remat_plan is not None) == recompute
     ce, grads = ref.loss_and_grads(params, jnp.asarray(feed["ids"]),
