@@ -7,6 +7,10 @@
     python chip_smoke.py --qwen3-next  one chip: Qwen3-Next-80B-A3B's step
                                        at its cell's sizes against the
                                        plain float32 reference
+    python chip_smoke.py --phi4-flash  one chip: Phi-4-mini-flash-reasoning's
+                                       step at its cell's sizes (6 layers,
+                                       published widths, 1 x 4096) against
+                                       its plain float32 reference
 
 Main path: BERT-base MLM pretraining at full width (12 x 768 x 12 heads x
 3072, vocab 30522) at b128 x s128 with bf16 matmuls, built by
@@ -123,9 +127,8 @@ def train_one_chip(size, dev, clock):
         emit(phase="steps", batch=size["batch"], seq_len=size["seq_len"],
              layers=cfg["layers"], hidden=cfg["hidden"],
              n_parameters_on_device=len(params), losses=losses,
-             # steps 1 and 2 each trace and compile (startup state is
-             # uncommitted, step outputs are committed): the rest is
-             # the steady step
+             # step 1 traces and compiles; the median leaves out the
+             # first two all the same
              step_seconds=[round(s, 4) for s in seconds],
              smoke_observation_step_ms=round(
                  float(np.median(seconds[2:])) * 1e3, 2),
@@ -186,7 +189,7 @@ def flash_parity(tiny):
     assert row["status"] == "ok", row
 
 
-# `qwen3_next_parity`: the largest error allowed, of each compared tensor,
+# `reference_parity`: the largest error allowed, of each compared tensor,
 # as max |program - reference| over max |reference| (the loss: absolute).
 # Two programs are held to the one float32 reference (PERF.md §6, PR 28,
 # has the chip's readings each limit lies between):
@@ -231,6 +234,40 @@ QWEN3_NEXT_LIMITS = {
 }
 
 
+# Phi-4-mini-flash: the same two programs against its reference. No
+# router here, so bf16 operands change nothing discrete: their fences
+# are rounding's (six layers of ~1e-2 a tensor), set above the readings.
+PHI4_FLASH_LIMITS = {
+    # readings (my chip runs, PR 32): in PERF.md §6
+    "float32": {
+        "loss": 1e-5,
+        "layers.0.mamba.w_in@GRAD": 1e-3,
+        "layers.0.mamba.a_log@GRAD": 1e-3,
+        "layers.1.attn.w_qkv@GRAD": 1e-3,
+        "layers.2.mamba.w_x@GRAD": 1e-3,      # the memory layer: GMU's part
+        "layers.3.attn.w_qkv@GRAD": 1e-3,     # K, V: cross-attention's part
+        "layers.4.gmu.w_in@GRAD": 1e-3,
+        "layers.5.cross.w_q@GRAD": 1e-3,
+        "layers.5.cross.lambda_q1@GRAD": 1e-3,
+        "layers.0.mlp.w_down@GRAD": 1e-3,
+        "embed_tokens@GRAD": 1e-3,            # tied: the lookup's + the head's
+    },
+    "bf16_operands": {
+        "loss": 1e-3,
+        "layers.0.mamba.w_in@GRAD": 0.1,
+        "layers.0.mamba.a_log@GRAD": 0.1,
+        "layers.1.attn.w_qkv@GRAD": 0.1,
+        "layers.2.mamba.w_x@GRAD": 0.1,
+        "layers.3.attn.w_qkv@GRAD": 0.1,
+        "layers.4.gmu.w_in@GRAD": 0.1,
+        "layers.5.cross.w_q@GRAD": 0.1,
+        "layers.5.cross.lambda_q1@GRAD": 0.1,
+        "layers.0.mlp.w_down@GRAD": 0.1,
+        "embed_tokens@GRAD": 0.1,
+    },
+}
+
+
 def _by_path(path):
     import importlib.util
     spec = importlib.util.spec_from_file_location(
@@ -240,38 +277,90 @@ def _by_path(path):
     return module
 
 
-def qwen3_next_parity(tiny, clock):
-    """One step of `qwen3_next_80b_a3b.b1_s4096`'s program (its sizes,
-    its traffic, built by its configuration's files) against the plain
-    reference at the same weights and batch: the fetched loss and the
-    gradient of one parameter of each kind, fetched as `@GRAD`, once at
-    the cell's precision and once with float32 operands. The weights
-    are pulled from the scope after start-up (the seed makes them the
-    same in both programs); a program's state leaves the chip before
-    the next thing runs, so that each fits."""
+def _gauge_by_site(name, sites):
+    from paddle_tpu.fluid import telemetry
+    family = telemetry.REGISTRY.get(name)
+    return [int(family.value(site=site)) for site in sites] if family else None
+
+
+def _qwen3_next_counters(main):
+    """(further names to fetch, what to say of them and of the gauges):
+    each expert layer's passes and the rows a step's products run."""
+    from paddle_tpu.models import qwen3_next
+    passes = qwen3_next.expert_passes(main)
+
+    def say(fetched):
+        import numpy as np
+        return dict(
+            passes=[int(np.asarray(g)[0]) for g in fetched],
+            moe_rows_per_step=sum(_gauge_by_site("moe_rows_per_step",
+                                                 passes)))
+    return list(passes.values()), say
+
+
+def _phi4_flash_counters(main):
+    """Nothing further to fetch; the gauges the new ops set: the block
+    pairs each attention layer's forward kernel visits (window, full,
+    cross) and the chunks each scan steps over."""
+    from paddle_tpu.models import phi4_flash
+    sites = phi4_flash.attention_sites(main)
+    scans = [op.attr("site") for op in main.global_block().ops
+             if op.type == "selective_scan"]
+
+    def say(fetched):
+        return dict(
+            attention_windows=list(sites.values()),
+            attn_kv_blocks_per_step=_gauge_by_site(
+                "attn_kv_blocks_per_step", sites),
+            ssm_chunks_per_step=_gauge_by_site("ssm_chunks_per_step", scans))
+    return [], say
+
+
+PARITY = {
+    "qwen3_next": dict(config="qwen3_next_80b_a3b",
+                       cell="qwen3_next_80b_a3b.b1_s4096",
+                       limits=QWEN3_NEXT_LIMITS,
+                       counters=_qwen3_next_counters),
+    "phi4_flash": dict(config="phi4_mini_flash",
+                       cell="phi4_mini_flash.b1_s4096",
+                       limits=PHI4_FLASH_LIMITS,
+                       counters=_phi4_flash_counters),
+}
+
+
+def reference_parity(which, tiny, clock):
+    """One step of a decoder cell's program (its sizes, its traffic,
+    built by its configuration's files) against the plain reference at
+    the same weights and batch: the fetched loss and the gradient of one
+    parameter of each kind, fetched as `@GRAD`, once at the cell's
+    precision and once with float32 operands. The weights are pulled
+    from the scope after start-up (the seed makes them the same in both
+    programs); a program's state leaves the chip before the next thing
+    runs, so that each fits."""
     import contextlib
     import gc
     import jax
     import jax.numpy as jnp
     import numpy as np
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core, telemetry
-    from paddle_tpu.models import qwen3_next
+    from paddle_tpu.fluid import core
 
+    spec = PARITY[which]
+    limits = spec["limits"]
     configs = os.path.join(ROOT, "benchmark", "configs")
-    model = _by_path(os.path.join(configs, "qwen3_next_80b_a3b.py"))
+    model = _by_path(os.path.join(configs, spec["config"] + ".py"))
     reference = _by_path(
-        os.path.join(configs, "qwen3_next_80b_a3b_reference.py"))
-    with open(os.path.join(configs, "qwen3_next_80b_a3b.json")) as f:
+        os.path.join(configs, spec["config"] + "_reference.py"))
+    with open(os.path.join(configs, spec["config"] + ".json")) as f:
         config = json.load(f)
     with open(os.path.join(ROOT, "benchmark", "workloads",
-                           "qwen3_next_80b_a3b.b1_s4096.json")) as f:
+                           spec["cell"] + ".json")) as f:
         traffic = json.load(f)["traffic"]
     if tiny:
         config, traffic = model.tiny(config, traffic)
     cfg = model.model_cfg(config)
     feed = model.make_batches(config, traffic, 28, 1)[0]
-    names = list(QWEN3_NEXT_LIMITS["float32"])
+    names = list(limits["float32"])
     wanted = [n for n in names if n != "loss"]
 
     def program_step(bf16_operands):
@@ -285,21 +374,17 @@ def qwen3_next_parity(tiny, clock):
         weights = {
             p.name: np.asarray(scope.find_var(p.name).get_tensor().array)
             for p in main.global_block().all_parameters()}
-        passes = qwen3_next.expert_passes(main)
+        further, say = spec["counters"](main)
         t0 = time.perf_counter()
         with (contextlib.nullcontext() if bf16_operands
               else jax.default_matmul_precision("highest")):
             got = exe.run(main, feed=feed, scope=scope,
-                          fetch_list=[fetches[0].name] + wanted
-                          + list(passes.values()))
-        ran = [int(np.asarray(g)[0]) for g in got[len(names):]]
+                          fetch_list=[fetches[0].name] + wanted + further)
+        said = say(got[len(names):])
         got = dict(zip(names, (np.asarray(g) for g in got)))
         stats = jax.devices()[0].memory_stats() or {}
-        rows = telemetry.REGISTRY.get("moe_rows_per_step")
-        emit(phase="qwen3_next_step", bf16_operands=bf16_operands,
-             loss=float(got["loss"].ravel()[0]), passes=ran,
-             moe_rows_per_step=int(sum(rows.value(site=site)
-                                       for site in passes)),
+        emit(phase=which + "_step", bf16_operands=bf16_operands,
+             loss=float(got["loss"].ravel()[0]), **said,
              parameters=int(sum(w.size for w in weights.values())),
              seconds=round(time.perf_counter() - t0, 1),
              peak_bytes_in_use=stats.get("peak_bytes_in_use"),
@@ -338,13 +423,13 @@ def qwen3_next_parity(tiny, clock):
     ref = {n: np.asarray(v) for n, v in read(params, False).items()}
     got = {kind: errors(programs[kind], ref) for kind in programs}
     below = errors(read(params, True), ref)
-    emit(phase="qwen3_next_parity", reference_loss=float(ref["loss"]),
-         errors=got, bf16_activations=below, limits=QWEN3_NEXT_LIMITS,
+    emit(phase=which + "_parity", reference_loss=float(ref["loss"]),
+         errors=got, bf16_activations=below, limits=limits,
          seconds=round(time.perf_counter() - t0, 1), **clock.take())
     over = {f"{kind}: {n}": e for kind in got for n, e in got[kind].items()
-            if e > QWEN3_NEXT_LIMITS[kind][n]}
+            if e > limits[kind][n]}
     assert not over, f"over their limits: {over}"
-    assert any(e > QWEN3_NEXT_LIMITS["float32"][n] for n, e in below.items()), \
+    assert any(e > limits["float32"][n] for n, e in below.items()), \
         "bf16 activations pass every float32 limit: the limits tell nothing"
 
 
@@ -370,6 +455,9 @@ def main(argv=None):
     ap.add_argument("--qwen3-next", action="store_true",
                     help="Qwen3-Next's step against its float32 reference "
                          "and nothing else")
+    ap.add_argument("--phi4-flash", action="store_true",
+                    help="Phi-4-mini-flash's step against its float32 "
+                         "reference and nothing else")
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal at a toy size on any backend; never ok")
     args = ap.parse_args(argv)
@@ -398,8 +486,9 @@ def main(argv=None):
     t0 = time.perf_counter()
     if args.multichip:
         multichip(size, devices[:4])
-    elif args.qwen3_next:
-        qwen3_next_parity(args.tiny, clock)
+    elif args.qwen3_next or args.phi4_flash:
+        reference_parity("qwen3_next" if args.qwen3_next else "phi4_flash",
+                         args.tiny, clock)
     else:
         train_one_chip(size, dev, clock)
         flash_parity(args.tiny)

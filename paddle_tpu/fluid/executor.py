@@ -168,6 +168,14 @@ def _as_lodtensor(data, place) -> LoDTensor:
     return t
 
 
+def _committed(a):
+    """An uncommitted single-device ``jax.Array`` as a committed one over
+    the same buffer; anything else as it is."""
+    if isinstance(a, jax.Array) and not a.committed:
+        return jax.device_put(a, next(iter(a.devices())))
+    return a
+
+
 def _initialized_tensor(scope, name) -> Optional[LoDTensor]:
     """The scope var's holder when it exists and is an initialized dense
     LoDTensor; None otherwise. THE numeric-fault-plane state predicate:
@@ -933,6 +941,17 @@ class _CompiledBlock:
         mut = {n: scope.find_var(n).get_tensor().array for n in self.mut_state}
         ro = {n: scope.find_var(n).get_tensor().array for n in self.ro_state}
         self._placed = 0
+        if self.mesh is None and not self._dispatched:
+            # one signature for the first step and those after it. A
+            # start-up program takes no feed, so it leaves its state
+            # uncommitted; a step hands the state it overwrites back
+            # committed (its feeds are), and jit keys on that. Without
+            # this the second step is a second trace, lowering and
+            # compile of the same computation, and a second entry in
+            # the compile cache. New handles on the same device
+            # buffers: nothing is copied. Read-only state stays as the
+            # scope holds it, which is what every later step is given.
+            mut = {n: _committed(a) for n, a in mut.items()}
         if self.mesh is not None:
             # data-parallel placement: params/state replicated, feed batch
             # sharded on the dp axis. XLA's sharding propagation inserts the

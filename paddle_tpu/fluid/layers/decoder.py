@@ -1,12 +1,14 @@
-"""Layer functions of hybrid linear-attention / sparse-expert decoder LMs
-(ops/decoder_ops.py holds the kernels and the equations)."""
+"""Layer functions of hybrid decoder LMs: linear attention, sparse
+experts, state-space scans, differential attention (ops/decoder_ops.py
+holds the kernels and the equations)."""
 from __future__ import annotations
 
 from ..initializer import Constant
 from ..layer_helper import LayerHelper
 
 __all__ = ["rms_norm", "rotary_embedding", "causal_conv1d",
-           "gated_delta_rule", "moe_router", "moe_expert_ffn"]
+           "gated_delta_rule", "selective_scan", "differential_combine",
+           "moe_router", "moe_expert_ffn"]
 
 
 def _like(helper, x, shape=None, dtype=None):
@@ -81,6 +83,50 @@ def gated_delta_rule(q, k, v, a, b, num_key_heads, num_value_heads,
         attrs={"num_key_heads": num_key_heads,
                "num_value_heads": num_value_heads, "chunk_size": chunk_size,
                "site": helper.name})
+    return out
+
+
+def selective_scan(x, dt, b, c, chunk_size=64, a_log_attr=None, d_attr=None,
+                   dt_bias_attr=None, name=None):
+    """Mamba's selective scan over x, dt [B, S, C] (dt the step's
+    pre-activation) with the maps b, c [B, S, N]; creates A_log [C, N],
+    D [C] (from 1) and dt_bias [C]. -> [B, S, C]."""
+    helper = LayerHelper("selective_scan", **locals())
+    channels, d_state = x.shape[-1], b.shape[-1]
+    a_log = helper.create_parameter(attr=a_log_attr,
+                                    shape=[channels, d_state], dtype=x.dtype)
+    d = helper.create_parameter(attr=d_attr, shape=[channels], dtype=x.dtype,
+                                default_initializer=Constant(1.0))
+    dt_bias = helper.create_parameter(attr=dt_bias_attr, shape=[channels],
+                                      dtype=x.dtype,
+                                      default_initializer=Constant(0.0))
+    out = _like(helper, x)
+    helper.append_op(
+        type="selective_scan",
+        inputs={"X": [x], "Dt": [dt], "B": [b], "C": [c], "ALog": [a_log],
+                "D": [d], "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"chunk_size": chunk_size, "site": helper.name})
+    return out
+
+
+def differential_combine(x, num_groups, head_dim, lambda_init,
+                         lambda_attrs=None, name=None):
+    """Differential attention's A_1 V - lambda A_2 V from the attention
+    op's output x [B, S, G * 2 * J * Dv] (heads laid out [group, map,
+    differential head]); creates the four lambda vectors [head_dim]
+    (``lambda_attrs``: q1, k1, q2, k2). -> [B, S, G * J * Dv]."""
+    helper = LayerHelper("differential_combine", **locals())
+    vectors = [helper.create_parameter(attr=attr, shape=[head_dim],
+                                       dtype=x.dtype)
+               for attr in (lambda_attrs or [None] * 4)]
+    out = _like(helper, x, tuple(x.shape[:-1]) + (x.shape[-1] // 2,))
+    helper.append_op(
+        type="differential_combine",
+        inputs={"X": [x], "LambdaQ1": [vectors[0]], "LambdaK1": [vectors[1]],
+                "LambdaQ2": [vectors[2]], "LambdaK2": [vectors[3]]},
+        outputs={"Out": [out]},
+        attrs={"num_groups": num_groups, "lambda_init": float(lambda_init)})
     return out
 
 

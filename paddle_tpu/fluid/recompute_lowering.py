@@ -12,15 +12,25 @@ rematerialization barrier), and the segment's backward ops are replaced
 by the ``jax.vjp`` of that wrapped function — only the segment-boundary
 values stay live between forward and backward.
 
+A value one segment produces and several later ones read (a memory, a
+kept K/V), and a parameter two segments read (a tied embedding), are
+carried like any boundary value: each reader's vjp gives its own part
+of the gradient, and the parts meet where the program's backward sums
+them. A grad op belongs to the segment of its forward op (whose outputs'
+gradients it reads); the ``sum`` ops the backward inserts at a fan-in
+are glue, dropped with the span they lie in or left to run between the
+spans. A span's vjp then writes each input's gradient under the name
+the span's ops would have left it under (``grad_sinks``), added to the
+parts that reached the span from outside, and reads each output's
+cotangent from the names that reach the span from outside
+(``cot_sources``).
+
 Lowering preconditions (else fused fallback with a warning — same
 numerics, more memory):
   * checkpoints are produced in the main block, no control flow inside
     a segment
-  * every external input of a segment (params, earlier activations)
-    receives its gradient ONLY from that segment's backward span, and
-    the spans are contiguous per segment in reverse order — shared
-    params across segments would fan-in through rename/sum ops the span
-    classifier cannot split
+  * the spans are contiguous per segment, in reverse segment order, and
+    no op outside them reads a segment's internals
 """
 from __future__ import annotations
 
@@ -30,7 +40,6 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from .backward import grad_var_name
 
 
 class Segment:
@@ -48,6 +57,11 @@ class RematPlan:
         self.spans: List[List] = []  # per segment: replaced bwd ops
         self.between: List[List] = []  # rest ops between spans (reverse)
         self.span_order: List[int] = []  # segment index per span, in order
+        # per segment: {output: the gradient names that reach its span
+        # from outside} and {input: (the name its gradient leaves the
+        # span under, the names of its parts that reach the span)}
+        self.cot_sources: List[Dict[str, List[str]]] = []
+        self.grad_sinks: List[Dict[str, tuple]] = []
         self.tail_ops = []           # pre-segment bwd + optimizer ops
 
 
@@ -136,12 +150,16 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
                 # segments and diverge from the fused run's keys
                 return _fallback(
                     f"rng op '{op.type}' inside a segment")
-        written = set()
+        # a dict for its order: `outs` is the order of the segment's
+        # results in the traced step, and a set's order changes with the
+        # process's hash seed: another module, so another entry in the
+        # compile cache, every run
+        written = {}
         for op in seg.ops:
             for n in op.input_arg_names:
                 if n not in written and n not in seg.ins:
                     seg.ins.append(n)
-            written.update(op.output_arg_names)
+            written.update(dict.fromkeys(op.output_arg_names))
         # outputs = the segment BOUNDARY: vars consumed by other FORWARD
         # ops, fetched, or state/persistable writebacks. Backward reads
         # of internals don't count — the segment's grad ops are replaced
@@ -159,54 +177,48 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
         plan.segments.append(seg)
 
     # ---- classify the backward spans ------------------------------------
-    def grad_names_of(names):
-        g = set()
-        for v in names:
-            g.add(grad_var_name(v))
-        return g
+    def base_of(name):
+        """The forward var a gradient name (renamed at a fan-in or not)
+        is the gradient of; None for any other name."""
+        i = name.find("@GRAD")
+        return name[:i] if i >= 0 else None
 
-    span_sets = []
-    for seg in plan.segments:
-        written = set()
+    written_by: Dict[str, int] = {}
+    for k, seg in enumerate(plan.segments):
         for op in seg.ops:
-            written.update(op.output_arg_names)
-        # a segment's span produces grads of its INTERNALS and INPUTS;
-        # its outputs' grads come from the CONSUMER segment's span (or
-        # the loss head), so they are not owned here
-        owned = (written - set(seg.outs)) | set(seg.ins)
-        span_sets.append(grad_names_of(owned))
+            for n in op.output_arg_names:
+                written_by.setdefault(n, k)
 
-    grad_owner: Dict[str, int] = {}
-    for k, gset in enumerate(span_sets):
-        for g in gset:
-            if g in grad_owner and grad_owner[g] != k:
-                return _fallback(
-                    f"grad name '{g}' claimed by two segments")
-            grad_owner[g] = k
+    def is_glue(op):
+        """A fan-in's ``sum`` of gradient parts: it belongs to no forward
+        op, and lies right after the op that wrote the last part."""
+        return op.type == "sum" and all(
+            base_of(n) is not None
+            for n in op.input_arg_names + op.output_arg_names)
 
     def owner_of(op):
-        hits = set()
-        for n in op.output_arg_names:
-            # fan-in renames look like '<primal>@GRAD@RENAME@...' —
-            # normalize to the base grad name for the dict lookup
-            base = n
-            i = n.find("@GRAD")
-            if i >= 0:
-                base = n[:i + 5]
-            k = grad_owner.get(base)
-            if k is not None:
-                hits.add(k)
-        return hits
+        """The segments whose forward outputs' gradients ``op`` reads:
+        the one segment of its forward op, for a grad op."""
+        return {written_by[base_of(n)] for n in op.input_arg_names
+                if base_of(n) in written_by}
 
     idxs: Dict[int, List[int]] = {k: [] for k in range(len(plan.segments))}
+    glue = []
     for i, op in enumerate(rest):
+        if is_glue(op):
+            glue.append(i)
+            continue
         hits = owner_of(op)
         if len(hits) > 1:
             return _fallback(
-                f"grad op '{op.type}' mixes segments {sorted(hits)} "
-                f"(shared params across segments)")
+                f"grad op '{op.type}' reads gradients of segments "
+                f"{sorted(hits)}")
         if hits:
             idxs[hits.pop()].append(i)
+    for i in glue:  # inside a span: dropped with it; else it runs
+        for k in idxs:
+            if idxs[k] and min(idxs[k]) < i < max(idxs[k]):
+                idxs[k].append(i)
     live = [k for k in idxs if idxs[k]]
     if not live:
         return _fallback("no segment gradient ops found")
@@ -220,12 +232,9 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
         if any(i not in idxs[k] for i in range(lo, hi + 1)):
             return _fallback(f"segment {k} backward span not contiguous")
         marks.append((k, lo, hi))
-    # a segment input's grad must come ONLY from its own span: every
-    # grad-of-input write outside the span falls back (fan-in)
     plan.rest_head = rest[:marks[0][1]]
     plan.spans = [None] * len(plan.segments)
     plan.between = []
-    cur = None
     for j, (k, lo, hi) in enumerate(marks):
         plan.spans[k] = rest[lo:hi + 1]
         nxt_lo = marks[j + 1][1] if j + 1 < len(marks) else None
@@ -234,14 +243,27 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
         plan.between.append(seg_after)
     plan.span_order = [k for k, _, _ in marks]
     plan.tail_ops = plan.between.pop() if plan.between else []
+    # what flows into and out of each span, by gradient name
+    plan.cot_sources = [{} for _ in plan.segments]
+    plan.grad_sinks = [{} for _ in plan.segments]
+    for k, seg in enumerate(plan.segments):
+        span = plan.spans[k] or []
+        reads = [n for op in span for n in op.input_arg_names]
+        writes = [n for op in span for n in op.output_arg_names]
+        flows_in = [n for n in dict.fromkeys(reads) if n not in writes]
+        leaves = [n for n in dict.fromkeys(writes) if n not in reads]
+        for v in seg.outs:
+            plan.cot_sources[k][v] = [n for n in flows_in
+                                      if base_of(n) == v]
+        for v in seg.ins:
+            names = [n for n in leaves if base_of(n) == v]
+            if names:
+                plan.grad_sinks[k][v] = (
+                    names, [n for n in flows_in if base_of(n) == v])
     # every rest op that SURVIVES (not in a replaced span) must not read
     # a segment internal — those values are never materialized in env
-    internals = set()
-    for seg in plan.segments:
-        w = set()
-        for op in seg.ops:
-            w.update(op.output_arg_names)
-        internals |= (w - set(seg.outs))
+    internals = {n for n, k in written_by.items()
+                 if n not in plan.segments[k].outs}
     replaced = {id(op) for span in plan.spans if span for op in span}
     for op in rest:
         if id(op) in replaced:
@@ -285,16 +307,20 @@ def exec_plan(cb, plan: RematPlan, env: Dict[str, Any], lod_env, rng):
                 # integer/bool boundary: vjp wants a float0 tangent
                 cots.append(_np.zeros(out_val.shape, jax.dtypes.float0))
                 continue
-            g = env.get(grad_var_name(n))
-            if g is None:
-                cots.append(jnp.zeros_like(out_val))
-            else:
-                cots.append(g.astype(out_val.dtype)
-                            if g.dtype != out_val.dtype else g)
+            parts = [env[g].astype(out_val.dtype)
+                     for g in plan.cot_sources[k][n] if env.get(g) is not None]
+            cots.append(sum(parts[1:], parts[0]) if parts
+                        else jnp.zeros_like(out_val))
         (d_ins,) = vjps[k](tuple(cots))
         for n, g in zip(seg.ins, d_ins):
-            if g is not None:
-                env[grad_var_name(n)] = g
+            if g is None or n not in plan.grad_sinks[k]:
+                continue  # a feed, or a var no one wants the gradient of
+            names, parts = plan.grad_sinks[k][n]
+            for part in parts:  # what other readers sent back before
+                g = g + env[part].astype(g.dtype)
+            env[names[0]] = g
+            for other in names[1:]:  # parts a later sum adds to the first
+                env[other] = jnp.zeros_like(g)
         after = plan.between[j] if j < len(plan.between) else []
         cb._exec_ops(after, env, lod_env, rng)
 

@@ -450,6 +450,16 @@ class MetricsRegistry:
 REGISTRY = MetricsRegistry()
 
 
+def set_site_gauge(name: str, help: str, site: str, value: float) -> None:
+    """A count of one traced op, by the layer that built it (its
+    ``site``): a program is traced several times (the grad op's vjp, a
+    rematerialised segment, a feed of another shape), and a gauge a
+    site reads the same each time; the sites of a process sum to its
+    program's step."""
+    REGISTRY.gauge(name, help, labelnames=("site",)).labels(
+        site=site).set(value)
+
+
 # executor compile/retrace counters (docs/OBSERVABILITY.md "Step
 # telemetry"): bumped at the executor's EXPLICIT jit-cache-miss sites.
 # A "compile" is the first entry of a cache; a "retrace" is a later
@@ -520,9 +530,9 @@ def install_jax_compile_listener() -> bool:
     backend, the number of backend compiles, persistent-cache hits and
     misses (the tables above) — and, when the profiler records, emit a
     cat="compile" span or instant for each: ground truth that catches
-    what the executor's explicit cache counters cannot see (the step's
-    second signature, shape-driven retraces inside one jit, whether a
-    "compile" was a load). Zero cost on the steady-state path: jax only
+    what the executor's explicit cache counters cannot see (a second
+    signature of one step, shape-driven retraces inside one jit, whether
+    a "compile" was a load). Zero cost on the steady-state path: jax only
     calls listeners when it compiles."""
     global _JAX_LISTENER_INSTALLED
     with _JAX_LISTENER_LOCK:

@@ -29,20 +29,29 @@ def bert_base_config():
 
 
 def fused_multihead_attention(q, k, v, n_head, dropout_rate=0.0,
-                              attn_bias=None, causal=False, n_kv_head=None):
+                              attn_bias=None, causal=False, n_kv_head=None,
+                              n_v_head=None, window=0):
     """One fused attention op (Pallas on TPU past s128). q/k/v: [B, S, H];
     attn_bias: optional additive mask broadcastable to [B, H, Sq, Sk];
-    n_kv_head: grouped queries, k/v [B, S, n_kv_head * D]."""
+    n_kv_head: grouped queries, k/v [B, S, n_kv_head * D]; n_v_head: v
+    [B, S, n_v_head * Dv] with heads of its own count and width, the
+    result [B, S, n_head * Dv]; window w > 0: query t sees keys
+    t - w < j <= t. k and v may be another layer's (cross-attention)."""
     helper = LayerHelper("multihead_matmul")
     out = helper.create_variable_for_type_inference(q.dtype)
-    out.shape = q.shape
+    out.shape = q.shape if n_v_head is None else \
+        tuple(q.shape[:-1]) + (n_head * (v.shape[-1] // n_v_head),)
     ins = {"Q": [q], "K": [k], "V": [v]}
     if attn_bias is not None:
         ins["Bias"] = [attn_bias]
     attrs = {"num_heads": n_head, "dropout_rate": dropout_rate,
-             "causal": causal}
+             "causal": causal, "site": helper.name}
     if n_kv_head is not None:
         attrs["num_kv_heads"] = n_kv_head
+    if n_v_head is not None:
+        attrs["num_v_heads"] = n_v_head
+    if window:
+        attrs["window"] = window
     helper.append_op(type="fused_attention_qkv", inputs=ins,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -9,9 +9,12 @@ packed QKV + BiasQK additive mask), so reference-transpiled inference
 programs run.
 
 Both compute the same attention one of two ways, chosen per call by
-``_use_flash`` from the call's bias form and sequence lengths: the Pallas
+``_use_flash`` from the call's mask and sequence lengths: the Pallas
 flash kernels (ops/pallas/flash_attention.py) where there are K/V blocks
-to stream, ``_dense_attention`` (plain XLA ops) everywhere else.
+to stream, ``_dense_attention`` (plain XLA ops) everywhere else. What a
+query may see is one descriptor both take, ``flash_attention.Mask``:
+causal | window(w) | an additive bias (the kernels: the key-padding form
+alone).
 """
 from __future__ import annotations
 
@@ -22,7 +25,9 @@ import jax.numpy as jnp
 
 from .registry import register_op, register_grad_maker, first, out
 from .math_ops import mxu_available as _mxu_backend
-from .pallas.flash_attention import flash_attention, _use_kernels
+from .pallas.flash_attention import (
+    NEG_INF, Mask, flash_attention, _block_sizes, _use_kernels,
+    visited_blocks)
 
 # Longest sequence (queries AND keys) that takes XLA's dense attention
 # where the flash kernels could serve the call. 128 is the kernels' block
@@ -47,39 +52,58 @@ def _keypad_bias(bias, q, k):
     return None
 
 
-def _use_flash(bias, kp_bias, sq, sk):
+def _use_flash(mask, sq, sk):
     """The one choice between the two paths, read by both ops: the
-    kernels serve a call they have a form for — no bias, or the exact
-    key-padding bias ``kp_bias`` (``_keypad_bias``) — on a backend that
-    runs them (``_use_kernels``), once either length passes
+    kernels serve a call they have a form for — ``mask`` with no bias,
+    or with the key-padding bias [B, Sk] (``_keypad_bias``) — on a
+    backend that runs them (``_use_kernels``), once either length passes
     ``DENSE_MAX_SEQ``. Every other call is ``_dense_attention``'s."""
-    return (_use_kernels() and (bias is None or kp_bias is not None)
+    return (_use_kernels() and (mask.bias is None or mask.bias.ndim == 2)
             and max(sq, sk) > DENSE_MAX_SEQ)
 
 
-def _dense_attention(q, k, v, sm_scale, bias=None, causal=False,
-                     dropout_rate=0.0, rng=None):
-    """softmax(scale·QKᵀ + bias)·V in XLA's own ops; q, k, v [B, H, S, D],
-    ``bias`` broadcastable to [B, H, Sq, Sk], result f32 [B, H, Sq, D].
+def _dense_attention(q, k, v, sm_scale, mask=Mask(), dropout_rate=0.0,
+                     rng=None):
+    """softmax(scale·QKᵀ + mask)·V in XLA's own ops; q, k [B, H, S, D],
+    v [B, H, Sk, Dv], result f32 [B, H, Sq, Dv]. ``mask``: a ``Mask`` (a
+    bool stands for its causal flag); its bias is the kernels'
+    key-padding form [B, Sk] or anything broadcastable to
+    [B, H, Sq, Sk]. The one dense computation: the ops' own path, what
+    ``flash_attention`` runs on a backend without the kernels, and what
+    the kernels' tests compare with.
 
     The flash kernels' f32-accumulation contract: bf16 MXU tiles
     accumulate in f32 (preferred_element_type), so the softmax
     statistics see f32 scores — NOT scores rounded to bf16 by a
     bf16-output dot (r5 advisor finding: the two paths diverged for the
     same program) — and the probabilities are cast to the operands'
-    dtype for P·V. Causal masking is top-left aligned. Dropout draws
+    dtype for P·V. Causal and window masking are top-left aligned (row
+    t sees keys j <= t, under a window w also j > t - w). Dropout draws
     its mask from ``rng`` (the kernels hash theirs in-kernel: another
     stream of the same distribution). A row whose keys are ALL masked
-    by a finite bias attends uniformly here; the kernels emit zeros."""
+    by a key-padding bias [B, Sk] gives zeros, as in the kernels; by a
+    finite bias of another shape it attends uniformly."""
+    mask = Mask.of(mask)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * sm_scale
+    bias = mask.bias
+    keypad = bias is not None and bias.ndim == 2
     if bias is not None:
-        s = s + bias.astype(jnp.float32)
-    if causal:
+        bias = bias.astype(jnp.float32)
+        s = s + (jnp.maximum(bias, NEG_INF)[:, None, None, :] if keypad
+                 else bias)
+    if mask.causal or mask.window:
         idx_q = jnp.arange(q.shape[2])[:, None]
         idx_k = jnp.arange(k.shape[2])[None, :]
-        s = jnp.where(idx_q >= idx_k, s, jnp.finfo(jnp.float32).min)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+        seen = idx_q >= idx_k
+        if mask.window:
+            seen = seen & (idx_k > idx_q - mask.window)
+        s = jnp.where(seen, s, jnp.finfo(jnp.float32).min)
+    p = jax.nn.softmax(s, axis=-1)
+    if keypad:
+        dead = jnp.max(s, axis=-1, keepdims=True) <= NEG_INF * 0.5
+        p = jnp.where(dead, 0.0, p)
+    p = p.astype(q.dtype)
     if dropout_rate > 0.0:
         keep = jax.random.bernoulli(rng, 1.0 - dropout_rate, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout_rate), 0.0).astype(p.dtype)
@@ -101,13 +125,19 @@ def _merge_heads(x):
 @register_op("fused_attention_qkv", inputs=("Q", "K", "V", "Bias"),
              diff_inputs=("Q", "K", "V"), needs_rng=True,
              attr_defaults={"num_heads": 1, "num_kv_heads": 0,
-                            "dropout_rate": 0.0, "causal": False})
+                            "num_v_heads": 0, "dropout_rate": 0.0,
+                            "causal": False, "window": 0, "site": ""})
 def _fused_attention_qkv(ins, attrs):
     """Optional Bias: additive attention mask broadcastable to
     [B, H, Sq, Sk] (e.g. padding mask [B, 1, 1, Sk] with -inf/0).
     Grouped queries: with ``num_kv_heads`` (a divisor of ``num_heads``)
     K and V are [B, S, Hkv·D] and each of their heads serves
-    num_heads / num_kv_heads consecutive query heads.
+    num_heads / num_kv_heads consecutive query heads; with
+    ``num_v_heads`` V is [B, S, Hv·Dv] with heads of its own count and
+    width (differential attention: two key heads share one value head
+    twice as wide) and Out is [B, S, H·Dv]. K and V may come from
+    another layer (cross-attention). ``window`` w > 0: query t sees keys
+    t - w < j <= t (causal with it).
 
     Dispatch (``_use_flash``): above ``DENSE_MAX_SEQ`` the Pallas flash
     kernels serve the no-bias case AND the exact key-padding bias form
@@ -116,7 +146,8 @@ def _fused_attention_qkv(ins, attrs):
     rng). ``_dense_attention`` serves every other bias shape, every call
     whose score tile fits one kernel block, and a backend without the
     kernels. Causal masking is TOP-LEFT aligned (query i sees keys <= i)
-    on both paths."""
+    on both paths. On the kernels' path the gauge
+    ``attn_kv_blocks_per_step`` is set, the op's ``site`` each."""
     q = first(ins, "Q")
     k = first(ins, "K")
     v = first(ins, "V")
@@ -133,24 +164,36 @@ def _fused_attention_qkv(ins, attrs):
         # flash kernel and the einsum path; output restored to f32
         q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
     h_kv = attrs.get("num_kv_heads", 0) or h
+    h_v = attrs.get("num_v_heads", 0) or h_kv
     qh, kh, vh = _split_heads(q, h), _split_heads(k, h_kv), \
-        _split_heads(v, h_kv)
+        _split_heads(v, h_v)
     if h_kv != h:
-        kh, vh = (jnp.repeat(t, h // h_kv, axis=1) for t in (kh, vh))
-    causal = attrs.get("causal", False)
+        kh = jnp.repeat(kh, h // h_kv, axis=1)
+    if h_v != h:
+        vh = jnp.repeat(vh, h // h_v, axis=1)
     drop = float(attrs.get("dropout_rate", 0.0) or 0.0)
     kp_bias = _keypad_bias(bias, qh, kh)
-    if _use_flash(bias, kp_bias, qh.shape[2], kh.shape[2]):
+    mask = Mask(attrs.get("causal", False), attrs.get("window", 0),
+                bias if kp_bias is None else kp_bias)
+    sq, sk = qh.shape[2], kh.shape[2]
+    if _use_flash(mask, sq, sk):
         seed = None
         if drop > 0.0:
             seed = jax.random.randint(attrs["_rng"], (1,), 0,
                                       2 ** 31 - 1, dtype=jnp.int32)
-        o = flash_attention(qh, kh, vh, sm_scale, causal,
-                            dropout_rate=drop, dropout_seed=seed,
-                            bias=kp_bias)
+        o = flash_attention(qh, kh, vh, sm_scale, mask, dropout_rate=drop,
+                            dropout_seed=seed)
+        from ..fluid import telemetry
+        telemetry.set_site_gauge(
+            "attn_kv_blocks_per_step",
+            "(Q block, K block) pairs whose scores the forward flash "
+            "kernel computes a step, all heads and sequences: the pairs "
+            "its mask keeps", attrs.get("site", ""),
+            qh.shape[0] * h * visited_blocks(sq, sk, *_block_sizes(sq, sk),
+                                             mask))
     else:
-        o = _dense_attention(qh, kh, vh, sm_scale, bias, causal, drop,
-                             attrs.get("_rng"))
+        o = _dense_attention(qh, kh, vh, sm_scale, mask._replace(bias=bias),
+                             drop, attrs.get("_rng"))
     return out(Out=_merge_heads(o).astype(out_dtype))
 
 
@@ -198,8 +241,10 @@ def _multihead_matmul(ins, attrs):
         k = jnp.transpose(x5[:, :, 1], (0, 2, 1, 3))
         v = jnp.transpose(x5[:, :, 2], (0, 2, 1, 3))
     kp_bias = _keypad_bias(bias_qk, q, k)
-    if _use_flash(bias_qk, kp_bias, q.shape[2], k.shape[2]):
-        o = flash_attention(q, k, v, alpha, causal=False, bias=kp_bias)
+    mask = Mask(bias=bias_qk if kp_bias is None else kp_bias)
+    if _use_flash(mask, q.shape[2], k.shape[2]):
+        o = flash_attention(q, k, v, alpha, mask)
     else:
-        o = _dense_attention(q, k, v, alpha, bias_qk).astype(q.dtype)
+        o = _dense_attention(q, k, v, alpha,
+                             Mask(bias=bias_qk)).astype(q.dtype)
     return out(Out=_merge_heads(o))

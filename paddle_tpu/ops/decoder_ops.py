@@ -1,19 +1,24 @@
-"""Ops of hybrid linear-attention / sparse-expert decoder LMs (the
+"""Ops of hybrid decoder LMs: linear attention and sparse experts (the
 Qwen3-Next family; layer equations and departures:
-benchmark/configs/qwen3_next_80b_a3b_reference.py).
+benchmark/configs/qwen3_next_80b_a3b_reference.py), state-space scans
+and differential attention (the SambaY family:
+benchmark/configs/phi4_mini_flash_reference.py).
 
 ``rms_norm``            RMSNorm over groups of the last dim, zero-centred
                         weight or plain, optionally gated by SiLU(Gate)
 ``rotary_embedding``    partial rotate-half rotary embedding a head
 ``causal_conv1d``       depthwise causal convolution along the sequence
 ``gated_delta_rule``    the gated delta rule, in chunks (WY form)
+``selective_scan``      Mamba's diagonal state-space recurrence, in chunks
+``differential_combine`` A_1 V - lambda A_2 V of differential attention
 ``moe_router``          softmax over all experts, top-k, auxiliary loss
 ``moe_expert_ffn``      the held experts' part of a routed gated FFN
 
-One pure JAX kernel each; gradients are the registry's vjp of it. Matmul
+One pure JAX kernel each; gradients are the registry's vjp of it (the
+scan and the expert passes bring their own under it). Matmul
 operands are bf16 under FLAGS_use_bf16_matmul on a backend with an MXU
 (``math_ops._mm``'s gate); the router, every norm, the gates and the
-delta rule's chunk state stay float32.
+delta rule's chunk state and the scan's state and decay stay float32.
 """
 from __future__ import annotations
 
@@ -43,13 +48,8 @@ def _einsum(spec, a, b):
 
 
 def _gauge(name, help_, site, value):
-    """A count of one traced op, by the layer that built it: a program
-    is traced several times (the grad op's vjp, a rematerialised
-    segment, the step's second signature), and a gauge a site reads the
-    same each time; the sites of a process sum to its program's step."""
     from ..fluid import telemetry
-    telemetry.REGISTRY.gauge(name, help_, labelnames=("site",)).labels(
-        site=site).set(value)
+    telemetry.set_site_gauge(name, help_, site, value)
 
 
 def _silu(x):
@@ -204,6 +204,127 @@ def _gated_delta_rule(ins, attrs):
            "over, a sequence of the batch each", attrs.get("site", ""),
            b * -(-s // chunk))
     return out(Out=jnp.moveaxis(o, 1, 2).reshape(b, s, -1).astype(v.dtype))
+
+
+# --------------------------------------------------------------------------
+def _scan_chunk(state, u, dt, b, c, a):
+    """One chunk of the recurrence, a position a step: state [B, C, N]
+    before the chunk, u, dt [B, T, C], b, c [B, T, N], a [C, N] ->
+    (the state after it, y [B, T, C]). The decay exp(dt a) and the write
+    (dt u) b of all T positions are made at once; only the T updates
+    s <- decay_t s + write_t and the readout s . c_t are sequential."""
+    decay = jnp.exp(dt[..., None] * a)
+    write = (dt * u)[..., None] * b[:, :, None, :]
+
+    def step(s, xs):
+        decay_t, write_t, c_t = xs
+        s = decay_t * s + write_t
+        return s, jnp.sum(s * c_t[:, None, :], -1)
+
+    state, y = lax.scan(step, state, tuple(
+        jnp.moveaxis(t, 1, 0) for t in (decay, write, c)))
+    return state, jnp.moveaxis(y, 0, 1)
+
+
+@jax.custom_vjp
+def _chunked_scan(u, dt, b, c, a):
+    """s_t = exp(dt_t a) s_{t-1} + (dt_t u_t) b_t, y_t = s_t . c_t from
+    s_0 = 0, over chunks [n, B, T, .] of the sequence; float32. Its own
+    vjp: the forward keeps the state at each chunk's START only (n of
+    them, not S: the whole history at s4096 x 5120 x 16 is 1.34 GB a
+    layer), and the backward walks the chunks last to first, each the
+    vjp of ``_scan_chunk`` made anew from its boundary state."""
+    return _chunked_scan_fwd(u, dt, b, c, a)[0]
+
+
+def _chunked_scan_fwd(u, dt, b, c, a):
+    def chunk(state, xs):
+        after, y = _scan_chunk(state, *xs, a)
+        return after, (state, y)
+
+    zero = jnp.zeros((u.shape[1], u.shape[3], a.shape[1]), jnp.float32)
+    _, (starts, y) = lax.scan(chunk, zero, (u, dt, b, c))
+    return y, (u, dt, b, c, a, starts)
+
+
+def _chunked_scan_bwd(residuals, d_y):
+    u, dt, b, c, a, starts = residuals
+
+    def chunk(carry, xs):
+        d_state, d_a = carry
+        start, d_y_i, *inputs = xs
+        _, vjp = jax.vjp(_scan_chunk, start, *inputs, a)
+        d_start, *d_inputs, d_a_i = vjp((d_state, d_y_i))
+        return (d_start, d_a + d_a_i), tuple(d_inputs)
+
+    (_, d_a), d_inputs = lax.scan(
+        chunk, (jnp.zeros_like(starts[0]), jnp.zeros_like(a)),
+        (starts, d_y, u, dt, b, c), reverse=True)
+    return d_inputs + (d_a,)
+
+
+_chunked_scan.defvjp(_chunked_scan_fwd, _chunked_scan_bwd)
+
+
+@register_op("selective_scan",
+             inputs=("X", "Dt", "B", "C", "ALog", "D", "DtBias"),
+             attr_defaults={"chunk_size": 64, "site": ""})
+def _selective_scan(ins, attrs):
+    """Mamba's selective state-space scan: X [B, S, C] (the channels
+    after their convolution and SiLU), Dt [B, S, C] (the step's
+    pre-activation), B, C [B, S, N] (the input and output maps), ALog
+    [C, N], D, DtBias [C] -> Out [B, S, C]. Delta = softplus(Dt +
+    DtBias), A = -exp(ALog), a channel's state in R^N from zero:
+    s_t = exp(Delta_t A) s_{t-1} + (Delta_t x_t) B_t, y_t = s_t . C_t +
+    D x_t; ``_chunked_scan`` at ``chunk_size`` positions a chunk (a
+    padded position has Delta 0: it neither decays nor writes)."""
+    x = first(ins, "X")
+    batch, s, ch = x.shape
+    chunk = min(attrs.get("chunk_size", 64), s)
+    u = x.astype(jnp.float32)
+    dt = jax.nn.softplus(first(ins, "Dt").astype(jnp.float32)
+                         + first(ins, "DtBias"))
+    a = -jnp.exp(first(ins, "ALog").astype(jnp.float32))
+    pad = -s % chunk
+    n = (s + pad) // chunk
+
+    def chunks(t):
+        t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+        return jnp.moveaxis(t.reshape(batch, n, chunk, -1), 1, 0)
+
+    y = _chunked_scan(chunks(u), chunks(dt),
+                      chunks(first(ins, "B").astype(jnp.float32)),
+                      chunks(first(ins, "C").astype(jnp.float32)), a)
+    y = jnp.moveaxis(y, 0, 1).reshape(batch, n * chunk, ch)[:, :s]
+    site = attrs.get("site", "")
+    _gauge("ssm_chunks_per_step",
+           "chunks of the sequence the state-space scan steps over, a "
+           "sequence of the batch each", site, batch * n)
+    _gauge("ssm_state_bytes",
+           "bytes of scan state kept from the forward for the backward: "
+           "a float32 [channels, d_state] state a chunk and sequence",
+           site, batch * n * ch * a.shape[1] * 4)
+    return out(Out=(y + first(ins, "D") * u).astype(x.dtype))
+
+
+@register_op("differential_combine",
+             inputs=("X", "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"),
+             attr_defaults={"num_groups": 1, "lambda_init": 0.0})
+def _differential_combine(ins, attrs):
+    """The two softmax maps' difference of differential attention. X
+    [B, S, G * 2 * J * Dv]: the attention op's output with its heads
+    laid out [group, c, j] (c the map, j the differential head of the
+    group), the four Lambda vectors [d] -> Out [B, S, G * J * Dv] =
+    X[c = 0] - lambda X[c = 1], lambda = exp(LambdaQ1 . LambdaK1) -
+    exp(LambdaQ2 . LambdaK2) + ``lambda_init``."""
+    x = first(ins, "X")
+    lq1, lk1, lq2, lk2 = (first(ins, n).astype(jnp.float32) for n in (
+        "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"))
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) \
+        + attrs.get("lambda_init", 0.0)
+    maps = x.reshape(x.shape[:2] + (attrs.get("num_groups", 1), 2, -1))
+    o = maps[:, :, :, 0] - lam.astype(x.dtype) * maps[:, :, :, 1]
+    return out(Out=o.reshape(x.shape[:2] + (-1,)))
 
 
 # --------------------------------------------------------------------------

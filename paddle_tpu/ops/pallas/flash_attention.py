@@ -22,8 +22,17 @@ both recomputing P = exp(scale·QKᵀ − lse) tile-by-tile from the stored
 lse — no O(S²) materialization anywhere. delta = rowsum(dO ∘ O) is a
 cheap elementwise+reduce that XLA fuses outside the kernels.
 
-Causal masking is top-left aligned; fully-masked K blocks are skipped
-with pl.when (upper-triangular blocks cost nothing).
+What a query may see is one descriptor, ``Mask`` (causal | window(w) |
+key-padding bias), which the kernels, ``flash_attention`` and the dense
+computation (ops/attention_ops._dense_attention, also what the CPU
+backend runs with the interpreter off, and what tests compare with) all
+take. Causal masking is top-left aligned; fully-masked K blocks are
+skipped with pl.when (upper-triangular blocks cost no compute). Under a
+window the grids themselves are cut: a Q block's K axis runs over the
+blocks that hold a key of ``(t - w, t]`` for one of its rows and no
+other (``_window_blocks``), so a block outside the window is neither
+fetched nor computed, and the edge blocks are masked. V's head may be
+wider than Q's and K's (differential attention reads [v_1 | v_2]).
 
 CPU/tests: `interpret_mode(True)` / `interpret_guard()` run the very
 same kernels through the Pallas interpreter so the suite exercises the
@@ -38,14 +47,13 @@ shapes — a caller that asks for the kernels gets them
 Whether a call should ask is the attention ops' to decide
 (ops/attention_ops._use_flash): they come here only where there is more
 than one block to stream, and compute dense attention themselves at or
-under DEFAULT_BLOCK_Q x DEFAULT_BLOCK_K. The pure-XLA reference is what
-the CPU backend runs with the interpreter off, and what tests compare
-with.
+under DEFAULT_BLOCK_Q x DEFAULT_BLOCK_K.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -82,21 +90,58 @@ def interpret_guard():
         _INTERPRET = prev
 
 
-def _ref_attention(q, k, v, sm_scale, causal=False):
-    """Pure-jax reference: q,k,v [B,H,S,D]. Matches the kernel's
-    f32-accumulation contract: bf16 operands accumulate in f32
-    (preferred_element_type), so softmax statistics are f32 — this is
-    also the CPU dispatch target of the flash path, and the einsum path
-    in ops/attention_ops.py follows the same contract."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * sm_scale
-    if causal:
-        S, Sk = q.shape[2], k.shape[2]
-        mask = jnp.arange(S)[:, None] >= jnp.arange(Sk)[None, :]
-        s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+class Mask(NamedTuple):
+    """What a query row may see, for kernels and dense computation
+    alike. ``causal``: keys j <= t (top-left aligned). ``window`` w > 0:
+    keys t - w < j <= t (causal with it). ``bias``: an additive bias;
+    the kernels take the key-padding form [B, Sk] alone, the dense
+    computation also anything broadcastable to [B, H, Sq, Sk]."""
+    causal: bool = False
+    window: int = 0
+    bias: Any = None
+
+    @classmethod
+    def of(cls, mask):
+        """``mask`` itself, or the causal flag a bool stands for."""
+        return mask if isinstance(mask, cls) else cls(causal=bool(mask))
+
+
+def _inner_span(i, blk_outer, blk_inner, n_inner_blocks, window,
+                outer_is_q):
+    """(first, last) block of the inner grid axis that causal outer block
+    ``i`` shares a visible score with. Queries outside, keys inside: row
+    t sees keys j <= t, under a window also j > t - w. Keys outside,
+    queries inside: key j is seen by rows t >= j, under a window also
+    t < j + w. (``_inner_block`` is the first of them, traced.)"""
+    start, end = i * blk_outer, i * blk_outer + blk_outer - 1
+    if outer_is_q:
+        lo, hi = (max(start - window + 1, 0) if window else 0), end
+    else:
+        lo, hi = start, (end + window - 1 if window else None)
+    last = n_inner_blocks - 1
+    return lo // blk_inner, last if hi is None else min(hi // blk_inner, last)
+
+
+def _window_count(n_outer, n_inner, blk_outer, blk_inner, window,
+                  outer_is_q):
+    """Under a window, the most blocks of the inner grid axis any block
+    of the outer one needs: the extent of that axis of the grid."""
+    spans = (_inner_span(i, blk_outer, blk_inner, -(-n_inner // blk_inner),
+                         window, outer_is_q)
+             for i in range(-(-n_outer // blk_outer)))
+    return max(hi - lo + 1 for lo, hi in spans)
+
+
+def visited_blocks(sq, sk, blk_q, blk_k, mask):
+    """(Q block, K block) pairs whose scores the forward kernel computes,
+    a head: every pair without a mask, the pairs at or under the diagonal
+    when causal, those that hold a key of some row's window under one."""
+    nq, nk = -(-sq // blk_q), -(-sk // blk_k)
+    if not (mask.causal or mask.window):
+        return nq * nk
+    spans = (_inner_span(i, blk_q, blk_k, nk, mask.window, True)
+             for i in range(nq))
+    return sum(hi - lo + 1 for lo, hi in spans)
 
 
 def _on_tpu() -> bool:
@@ -138,10 +183,35 @@ def _valid_rows(q_start, blk_q, blk_k, s_len):
     return rows < s_len
 
 
-def _mask_scores(s, q_start, k_start, blk_q, blk_k):
+def _mask_scores(s, q_start, k_start, blk_q, blk_k, window=0):
     rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
     cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    return jnp.where(rows >= cols, s, NEG_INF)
+    seen = rows >= cols
+    if window:
+        seen = seen & (cols > rows - window)
+    return jnp.where(seen, s, NEG_INF)
+
+
+def _inner_block(outer, inner, blk_outer, blk_inner, window, outer_is_q):
+    """The inner grid axis's block the kernel is at: ``inner`` itself, or
+    under a window the ``inner``-th of the blocks outer block ``outer``
+    needs (the index maps' own rule, ``_window_blocks``)."""
+    if not window:
+        return inner
+    lo = outer * blk_outer - (window - 1 if outer_is_q else 0)
+    return jnp.maximum(lo, 0) // blk_inner + inner
+
+
+def _block_runs(q_start, k_start, blk_q, blk_k, window, inner_blk, n_inner):
+    """Whether a causal (Q block, K block) pair holds a visible score:
+    not above the diagonal, and under a window not wholly before it nor
+    past the inner axis's end (where the index map repeats its last
+    block)."""
+    runs = k_start <= q_start + blk_q - 1
+    if window:
+        runs = runs & (k_start + blk_k - 1 > q_start - window) \
+            & (inner_blk < n_inner)
+    return runs
 
 
 def _keep_mask(seed, bh, q_start, k_start, blk_q, blk_k, rate):
@@ -194,18 +264,20 @@ def _with_optional_bias(kernel, n_named, has_bias):
     return _inner
 
 
-def _append_bias_input(in_specs, args, bias, H, blk_k, k_axis):
+def _append_bias_input(in_specs, args, bias, H, blk_k, k_axis,
+                       k_block=lambda i, j: j):
     """Append the key-padding bias input as [B, 1, Sk] (cast once to
     f32) — the middle singleton makes the block's second-to-last dim
     equal to the array dim, which Mosaic accepts for any size.
     ``k_axis``: which grid dimension indexes K blocks (1 for the bwd-kv
-    kernel, 2 for fwd/bwd-q)."""
+    kernel, 2 for fwd/bwd-q, whose K block is ``k_block(i, j)``)."""
     if bias is None:
         return
     if k_axis == 1:
         spec = pl.BlockSpec((1, 1, blk_k), lambda b, j, i: (b // H, 0, j))
     else:
-        spec = pl.BlockSpec((1, 1, blk_k), lambda b, i, j: (b // H, 0, j))
+        spec = pl.BlockSpec((1, 1, blk_k),
+                            lambda b, i, j: (b // H, 0, k_block(i, j)))
     in_specs.append(spec)
     args.append(bias.astype(jnp.float32).reshape(bias.shape[0], 1,
                                                  bias.shape[-1]))
@@ -217,7 +289,7 @@ def _append_bias_input(in_specs, args, bias, H, blk_k, k_axis):
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, sm_scale, causal, blk_q, blk_k, dropout_rate,
-                has_bias, sk_len=0):
+                has_bias, sk_len=0, window=0, n_k_blocks=0):
     bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -228,7 +300,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     q_start = qi * blk_q
-    k_start = ki * blk_k
+    k_blk = _inner_block(qi, ki, blk_q, blk_k, window, True)
+    k_start = k_blk * blk_k
 
     def _block():
         q = _mxu_operand(q_ref[0])
@@ -246,7 +319,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
             # past the true length (padded bias/K values are overridden)
             s = _mask_cols(s, k_start, blk_q, blk_k, sk_len)
         if causal:
-            s = _mask_scores(s, q_start, k_start, blk_q, blk_k)
+            s = _mask_scores(s, q_start, k_start, blk_q, blk_k, window)
         m_prev = m_ref[:, :1]                             # [blk_q, 1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -270,7 +343,8 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
 
     if causal:
         # blocks strictly above the diagonal are fully masked: skip them
-        @pl.when(k_start <= q_start + blk_q - 1)
+        @pl.when(_block_runs(q_start, k_start, blk_q, blk_k, window, k_blk,
+                             n_k_blocks))
         def _():
             _block()
     else:
@@ -284,7 +358,7 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         # key-padding bias masking ALL keys): emit zeros, and poison the
         # lse to +1e30 so the backward's exp(s - lse) underflows to 0 —
         # zero grads instead of data-dependent garbage. Same semantics
-        # as _ref_attention_bias.
+        # as the dense computation on a key-padding bias.
         dead = m <= NEG_INF * 0.5
         safe_l = jnp.where(dead, 1.0, l)
         o_ref[0] = jnp.where(dead, 0.0,
@@ -293,37 +367,59 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref,
         lse_ref[0] = jnp.broadcast_to(lse_col, lse_ref.shape[1:])
 
 
-def _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
-                dropout_rate=0.0, bias=None):
+def _inner_index(window, blk_outer, blk_inner, n_inner_blocks, outer_is_q):
+    """For the index maps: (outer block, step of the inner grid axis) ->
+    the inner block to fetch. The step itself, or under a window the
+    step-th block of the outer block's span (the last block again past
+    the axis's end: no new fetch, and ``_block_runs`` skips the
+    compute)."""
+    if not window:
+        return lambda outer, step: step
+    return lambda outer, step: jnp.minimum(
+        _inner_block(outer, step, blk_outer, blk_inner, window, outer_is_q),
+        n_inner_blocks - 1)
+
+
+def _pallas_fwd(q, k, v, seed, sm_scale, mask, blk_q, blk_k,
+                dropout_rate=0.0):
+    mask = Mask.of(mask)
+    bias, window = mask.bias, mask.window
     B, H, S, D = q.shape
-    Sk = k.shape[2]
-    qf, kf, vf = (t.reshape(B * H, t.shape[2], D) for t in (q, k, v))
-    grid = (B * H, pl.cdiv(S, blk_q), pl.cdiv(Sk, blk_k))
+    Sk, Dv = k.shape[2], v.shape[3]
+    qf, kf, vf = (t.reshape(B * H, t.shape[2], t.shape[3])
+                  for t in (q, k, v))
+    nk = pl.cdiv(Sk, blk_k)
+    grid = (B * H, pl.cdiv(S, blk_q),
+            _window_count(S, Sk, blk_q, blk_k, window, True)
+            if window else nk)
+    kb = _inner_index(window, blk_q, blk_k, nk, True)
     has_bias = bias is not None
-    kern = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
+    kern = functools.partial(_fwd_kernel, sm_scale=sm_scale,
+                             causal=mask.causal or bool(window),
                              blk_q=blk_q, blk_k=blk_k,
                              dropout_rate=dropout_rate, has_bias=has_bias,
-                             sk_len=0 if Sk % blk_k == 0 else Sk)
+                             sk_len=0 if Sk % blk_k == 0 else Sk,
+                             window=window, n_k_blocks=nk)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                # seed
         pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, kb(i, j), 0)),
+        pl.BlockSpec((1, blk_k, Dv), lambda b, i, j: (b, kb(i, j), 0)),
     ]
     args = [seed, qf, kf, vf]
-    _append_bias_input(in_specs, args, bias, H, blk_k, k_axis=2)
+    _append_bias_input(in_specs, args, bias, H, blk_k, 2, kb)
 
     o, lse = pl.pallas_call(
         _with_optional_bias(kern, 4, has_bias),
-        out_shape=(jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B * H, S, Dv), q.dtype),
                    jax.ShapeDtypeStruct((B * H, S, LANES), jnp.float32)),
         grid=grid,
         in_specs=in_specs,
-        out_specs=(pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
+        out_specs=(pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),
                    pl.BlockSpec((1, blk_q, LANES),
                                 lambda b, i, j: (b, i, 0))),
         scratch_shapes=[
-            pltpu.VMEM((blk_q, D), jnp.float32),
+            pltpu.VMEM((blk_q, Dv), jnp.float32),
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, 128), jnp.float32),
         ],
@@ -334,7 +430,7 @@ def _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
     )(*args)
     # lse stays in its (B·H, S, LANES) wire form — the backward consumes
     # it as-is, so no slice-then-rebroadcast materialization
-    return o.reshape(B, H, S, D), lse
+    return o.reshape(B, H, S, Dv), lse
 
 
 # --------------------------------------------------------------------------
@@ -343,7 +439,7 @@ def _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
 def _bwd_kv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, bias_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                    *, sm_scale, causal, blk_q, blk_k, dropout_rate,
-                   has_bias, s_len=0, sk_len=0):
+                   has_bias, s_len=0, sk_len=0, window=0, n_q_blocks=0):
     bh, ki, qi = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nq = pl.num_programs(2)
 
@@ -352,7 +448,8 @@ def _bwd_kv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_start = qi * blk_q
+    q_blk = _inner_block(ki, qi, blk_k, blk_q, window, False)
+    q_start = q_blk * blk_q
     k_start = ki * blk_k
 
     def _block():
@@ -371,7 +468,7 @@ def _bwd_kv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         if has_bias:
             s = s + jnp.maximum(bias_ref[0], NEG_INF)
         if causal:
-            s = _mask_scores(s, q_start, k_start, blk_q, blk_k)
+            s = _mask_scores(s, q_start, k_start, blk_q, blk_k, window)
         p = jnp.exp(s - lse)                              # [blk_q, blk_k]
         if s_len:
             # ragged S: padded Q/dO/lse/delta rows would contribute
@@ -405,7 +502,8 @@ def _bwd_kv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32)           # dsᵀ·Q
 
     if causal:
-        @pl.when(k_start <= q_start + blk_q - 1)
+        @pl.when(_block_runs(q_start, k_start, blk_q, blk_k, window, q_blk,
+                             n_q_blocks))
         def _():
             _block()
     else:
@@ -420,7 +518,7 @@ def _bwd_kv_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _bwd_q_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                   delta_ref, bias_ref, dq_ref, dq_acc,
                   *, sm_scale, causal, blk_q, blk_k, dropout_rate,
-                  has_bias, sk_len=0):
+                  has_bias, sk_len=0, window=0, n_k_blocks=0):
     bh, qi, ki = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -429,7 +527,8 @@ def _bwd_q_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     q_start = qi * blk_q
-    k_start = ki * blk_k
+    k_blk = _inner_block(qi, ki, blk_q, blk_k, window, True)
+    k_start = k_blk * blk_k
 
     def _block():
         q = _mxu_operand(q_ref[0])
@@ -450,7 +549,7 @@ def _bwd_q_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             # ragged Sk: padded K/V columns must not leak into dq
             s = _mask_cols(s, k_start, blk_q, blk_k, sk_len)
         if causal:
-            s = _mask_scores(s, q_start, k_start, blk_q, blk_k)
+            s = _mask_scores(s, q_start, k_start, blk_q, blk_k, window)
         p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(
             do, vv, (((1,), (1,)), ((), ())),
@@ -465,7 +564,8 @@ def _bwd_q_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                                preferred_element_type=jnp.float32)
 
     if causal:
-        @pl.when(k_start <= q_start + blk_q - 1)
+        @pl.when(_block_runs(q_start, k_start, blk_q, blk_k, window, k_blk,
+                             n_k_blocks))
         def _():
             _block()
     else:
@@ -476,12 +576,14 @@ def _bwd_q_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
-                dropout_rate=0.0, bias=None):
+def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, mask, blk_q, blk_k,
+                dropout_rate=0.0):
+    mask = Mask.of(mask)
+    bias, window = mask.bias, mask.window
     B, H, S, D = q.shape
-    Sk = k.shape[2]
+    Sk, Dv = k.shape[2], v.shape[3]
     BH = B * H
-    qf, kf, vf, of, gf = (t.reshape(BH, t.shape[2], D)
+    qf, kf, vf, of, gf = (t.reshape(BH, t.shape[2], t.shape[3])
                           for t in (q, k, v, o, g))
     # row statistics enter the kernels with the broadcast 128-lane minor
     # dim (see LANES), materialized HERE as transients — the residual
@@ -494,18 +596,23 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
     has_bias = bias is not None
     ragged_s = 0 if S % blk_q == 0 else S
     ragged_sk = 0 if Sk % blk_k == 0 else Sk
-    common = dict(sm_scale=sm_scale, causal=causal, blk_q=blk_q,
-                  blk_k=blk_k, dropout_rate=dropout_rate,
-                  has_bias=has_bias)
+    nq, nk = pl.cdiv(S, blk_q), pl.cdiv(Sk, blk_k)
+    common = dict(sm_scale=sm_scale, causal=mask.causal or bool(window),
+                  blk_q=blk_q, blk_k=blk_k, dropout_rate=dropout_rate,
+                  has_bias=has_bias, window=window)
+    qb = _inner_index(window, blk_k, blk_q, nq, False)  # of K block j
+    kb = _inner_index(window, blk_q, blk_k, nk, True)   # of Q block i
 
     kv_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                    # seed
-        pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, i, 0)),   # q
+        pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, qb(j, i), 0)),   # q
         pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),   # k
-        pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),   # v
-        pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, i, 0)),   # do
-        pl.BlockSpec((1, blk_q, LANES), lambda b, j, i: (b, i, 0)),  # lse
-        pl.BlockSpec((1, blk_q, LANES), lambda b, j, i: (b, i, 0)),  # delta
+        pl.BlockSpec((1, blk_k, Dv), lambda b, j, i: (b, j, 0)),  # v
+        pl.BlockSpec((1, blk_q, Dv), lambda b, j, i: (b, qb(j, i), 0)),  # do
+        pl.BlockSpec((1, blk_q, LANES),
+                     lambda b, j, i: (b, qb(j, i), 0)),           # lse
+        pl.BlockSpec((1, blk_q, LANES),
+                     lambda b, j, i: (b, qb(j, i), 0)),           # delta
     ]
     kv_args = [seed, qf, kf, vf, gf, lsef, delta]
     _append_bias_input(kv_specs, kv_args, bias, H, blk_k, k_axis=1)
@@ -513,15 +620,17 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
     dk, dv = pl.pallas_call(
         _with_optional_bias(
             functools.partial(_bwd_kv_kernel, s_len=ragged_s,
-                              sk_len=ragged_sk, **common), 7, has_bias),
+                              sk_len=ragged_sk, n_q_blocks=nq, **common),
+            7, has_bias),
         out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, D), v.dtype)),
-        grid=(BH, pl.cdiv(Sk, blk_k), pl.cdiv(S, blk_q)),
+                   jax.ShapeDtypeStruct((BH, Sk, Dv), v.dtype)),
+        grid=(BH, nk, _window_count(Sk, S, blk_k, blk_q, window, False)
+              if window else nq),
         in_specs=kv_specs,
         out_specs=(pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0))),
+                   pl.BlockSpec((1, blk_k, Dv), lambda b, j, i: (b, j, 0))),
         scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
-                        pltpu.VMEM((blk_k, D), jnp.float32)],
+                        pltpu.VMEM((blk_k, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interp,
@@ -531,21 +640,23 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
     q_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                    # seed
         pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),   # q
-        pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),   # k
-        pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, j, 0)),   # v
-        pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),   # do
+        pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, kb(i, j), 0)),   # k
+        pl.BlockSpec((1, blk_k, Dv), lambda b, i, j: (b, kb(i, j), 0)),  # v
+        pl.BlockSpec((1, blk_q, Dv), lambda b, i, j: (b, i, 0)),  # do
         pl.BlockSpec((1, blk_q, LANES), lambda b, i, j: (b, i, 0)),  # lse
         pl.BlockSpec((1, blk_q, LANES), lambda b, i, j: (b, i, 0)),  # delta
     ]
     q_args = [seed, qf, kf, vf, gf, lsef, delta]
-    _append_bias_input(q_specs, q_args, bias, H, blk_k, k_axis=2)
+    _append_bias_input(q_specs, q_args, bias, H, blk_k, 2, kb)
 
     dq = pl.pallas_call(
         _with_optional_bias(
-            functools.partial(_bwd_q_kernel, sk_len=ragged_sk, **common),
+            functools.partial(_bwd_q_kernel, sk_len=ragged_sk,
+                              n_k_blocks=nk, **common),
             7, has_bias),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        grid=(BH, pl.cdiv(S, blk_q), pl.cdiv(Sk, blk_k)),
+        grid=(BH, nq, _window_count(S, Sk, blk_q, blk_k, window, True)
+              if window else nk),
         in_specs=q_specs,
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
@@ -555,8 +666,8 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal, blk_q, blk_k,
         name="flash_bwd_dq",
     )(*q_args)
 
-    shape = (B, H, S, D)
-    return dq.reshape(shape), dk.reshape(B, H, Sk, D), dv.reshape(B, H, Sk, D)
+    return (dq.reshape(B, H, S, D), dk.reshape(B, H, Sk, D),
+            dv.reshape(B, H, Sk, Dv))
 
 
 # --------------------------------------------------------------------------
@@ -591,27 +702,32 @@ def _block_sizes(S, Sk):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _flash_pallas(q, k, v, seed, bias, sm_scale, causal, dropout_rate):
+def _flash_pallas(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
+    """``mask``: a ``Mask`` without its bias (static: a bool stands for
+    its causal flag); the key-padding ``bias`` is an operand."""
     blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
-    o, _ = _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
-                       dropout_rate, bias=bias)
+    o, _ = _pallas_fwd(q, k, v, seed, sm_scale,
+                       Mask.of(mask)._replace(bias=bias), blk_q, blk_k,
+                       dropout_rate)
     return o
 
 
-def _fp_fwd(q, k, v, seed, bias, sm_scale, causal, dropout_rate):
+def _fp_fwd(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
     blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
-    o, lse = _pallas_fwd(q, k, v, seed, sm_scale, causal, blk_q, blk_k,
-                         dropout_rate, bias=bias)
+    o, lse = _pallas_fwd(q, k, v, seed, sm_scale,
+                         Mask.of(mask)._replace(bias=bias), blk_q, blk_k,
+                         dropout_rate)
     # residual: the 2-D row stat, not the 128-lane wire form (128× less
     # memory held across fwd→bwd; the bwd re-broadcasts transiently)
     return o, (q, k, v, o, lse[:, :, 0], seed, bias)
 
 
-def _fp_bwd(sm_scale, causal, dropout_rate, res, g):
+def _fp_bwd(sm_scale, mask, dropout_rate, res, g):
     q, k, v, o, lse, seed, bias = res
     blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
-    dq, dk, dv = _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, causal,
-                             blk_q, blk_k, dropout_rate, bias=bias)
+    dq, dk, dv = _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale,
+                             Mask.of(mask)._replace(bias=bias), blk_q, blk_k,
+                             dropout_rate)
     dseed = np.zeros(seed.shape, jax.dtypes.float0)  # int arg: zero tangent
     dbias = None if bias is None else jnp.zeros_like(bias)  # mask input
     return dq, dk, dv, dseed, dbias
@@ -637,7 +753,7 @@ def mesh_guard(mesh):
         _MESH = prev
 
 
-def _flash_on_mesh(mesh, q, k, v, seed, bias, sm_scale, causal,
+def _flash_on_mesh(mesh, q, k, v, seed, bias, sm_scale, mask,
                    dropout_rate):
     """`_flash_pallas` under a shard_map over ``mesh``: batch split over
     "dp" and heads over "mp" where they divide (attention is independent
@@ -660,7 +776,7 @@ def _flash_on_mesh(mesh, q, k, v, seed, bias, sm_scale, causal,
             shard = shard + jax.lax.axis_index(name).astype(jnp.int32) * stride
             stride *= mesh.shape[name]
         return _flash_pallas(q, k, v, seed + jnp.int32(1000003) * shard,
-                             bias, sm_scale, causal, dropout_rate)
+                             bias, sm_scale, mask, dropout_rate)
 
     # check_vma off: neither pallas_call's outputs nor the Pallas
     # interpreter carry varying-axes types under jax 0.9.0. What that
@@ -678,16 +794,18 @@ def _flash_on_mesh(mesh, q, k, v, seed, bias, sm_scale, causal,
 _ZERO_SEED = np.zeros((1,), np.int32)
 
 
-def flash_attention(q, k, v, sm_scale, causal=False, dropout_rate=0.0,
-                    dropout_seed=None, bias=None):
-    """q,k,v: [B,H,S,D] → [B,H,S,D]. The Pallas flash kernels on a TPU
-    backend (and under interpret mode); the pure-XLA reference on the CPU
-    backend otherwise.
+def flash_attention(q, k, v, sm_scale, mask=Mask(), dropout_rate=0.0,
+                    dropout_seed=None):
+    """q, k [B,H,S,D], v [B,H,Sk,Dv] → [B,H,S,Dv]. The Pallas flash
+    kernels on a TPU backend (and under interpret mode); the dense
+    computation on the CPU backend otherwise. ``mask``: a ``Mask`` (a
+    bool stands for its causal flag); its bias is the additive
+    key-padding form [B, Sk] broadcast over query rows (the reference
+    BiasQK padding form), a constant wrt gradients.
     dropout_rate > 0 applies attention-probability dropout INSIDE the
     kernel (mask regenerated in the backward from dropout_seed, an int32
-    [1] array — pass a fresh per-step value when training). ``bias`` is
-    an additive key-padding mask [B, Sk] broadcast over query rows (the
-    reference BiasQK padding form); it is a constant wrt gradients."""
+    [1] array — pass a fresh per-step value when training)."""
+    mask = Mask.of(mask)
     if dropout_rate > 0.0 and dropout_seed is None:
         # a silent default seed would drop the SAME attention entries
         # every step — training bias with no symptom
@@ -703,29 +821,15 @@ def flash_attention(q, k, v, sm_scale, causal=False, dropout_rate=0.0,
     if _use_kernels():
         if dropout_seed is None:
             dropout_seed = _ZERO_SEED
+        static = mask._replace(bias=None)
         if _MESH is not None and {"dp", "mp"} & set(_MESH.axis_names):
-            return _flash_on_mesh(_MESH, q, k, v, dropout_seed, bias,
-                                  sm_scale, causal, float(dropout_rate))
-        return _flash_pallas(q, k, v, dropout_seed, bias, sm_scale,
-                             causal, float(dropout_rate))
+            return _flash_on_mesh(_MESH, q, k, v, dropout_seed, mask.bias,
+                                  sm_scale, static, float(dropout_rate))
+        return _flash_pallas(q, k, v, dropout_seed, mask.bias, sm_scale,
+                             static, float(dropout_rate))
     if dropout_rate > 0.0:
         raise NotImplementedError(
             "attention dropout requires the Pallas path (a TPU backend "
             "or interpret_mode(True))")
-    o = _ref_attention(q, k, v, sm_scale, causal) if bias is None else \
-        _ref_attention_bias(q, k, v, sm_scale, causal, bias)
-    return o
-
-
-def _ref_attention_bias(q, k, v, sm_scale, causal, bias):
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
-    s = s + jnp.maximum(bias.astype(jnp.float32), NEG_INF)[:, None, None, :]
-    if causal:
-        S, Sk = q.shape[2], k.shape[2]
-        mask = jnp.arange(S)[:, None] >= jnp.arange(Sk)[None, :]
-        s = jnp.where(mask, s, -jnp.inf)
-    # fully-masked rows → zeros (matches the Pallas kernel's finalize)
-    dead = jnp.max(s, axis=-1, keepdims=True) <= NEG_INF * 0.5
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    p = jnp.where(dead, 0.0, p).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    from ..attention_ops import _dense_attention
+    return _dense_attention(q, k, v, sm_scale, mask).astype(q.dtype)

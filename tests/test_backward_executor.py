@@ -56,6 +56,27 @@ def test_sgd_training_converges():
     assert losses[-1] < losses[0] * 0.7, (losses[0], losses[-1])
 
 
+@pytest.mark.parametrize("opt_name", ["SGD", "Momentum", "Adam"])
+def test_the_first_step_and_the_second_are_one_signature(opt_name):
+    """The start-up program's state goes into step 1 committed, as the
+    state a step hands back is: jit traces the step once, not once more
+    on step 2 (a second compile of the same computation, a second entry
+    in the compile cache)."""
+    main, startup, x, label, loss = _build_mlp()
+    with program_guard(main, startup):
+        kwargs = {"momentum": 0.9} if opt_name == "Momentum" else {}
+        getattr(fluid.optimizer, opt_name)(learning_rate=0.1,
+                                           **kwargs).minimize(loss)
+    scope = core.Scope()
+    exe = fluid.Executor(fluid.CPUPlace())
+    feed = {"x": np.ones((4, 8), "float32"), "y": np.zeros((4, 1), "int64")}
+    exe.run(startup, scope=scope)
+    for _ in range(3):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    step = list(exe._compiled_cache.values())[-1]
+    assert step._jitted._cache_size() == 1
+
+
 @pytest.mark.parametrize("opt_name", ["Adam", "Momentum", "Adagrad",
                                       "RMSProp", "Lamb", "Adamax",
                                       "Adadelta", "DecayedAdagrad", "Ftrl",
@@ -518,9 +539,9 @@ def test_recompute_optimizer_remat_segments():
     """RecomputeOptimizer checkpoints lower onto jax.checkpoint + vjp
     span replacement (reference optimizer.py:3850 rematerialization):
     per-step losses and trained weights must match the plain run, the
-    compiled step must carry remat barriers in its jaxpr, and a shape
-    the planner can't split (params shared across segments) must fall
-    back with a warning instead of mistraining."""
+    compiled step must carry remat barriers in its jaxpr, and a weight
+    two segments share is lowered too (since PR 32: each segment's vjp
+    gives its part, summed where the backward's fan-in sums them)."""
     import warnings as _w
     import numpy as np
     import jax
@@ -568,9 +589,9 @@ def test_recompute_optimizer_remat_segments():
                 (l,) = exe.run(main, feed={"x": X, "y": Y},
                                fetch_list=[loss])
                 out.append(float(np.asarray(l).ravel()[0]))
-            w = np.asarray(scope.find_var("rm_1_w")
-                           .get_tensor().array).copy() \
-                if scope.find_var("rm_1_w") else None
+            name = next(n for n in ("rm_1_w", "rm_shared_w")
+                        if scope.find_var(n))
+            w = np.asarray(scope.find_var(name).get_tensor().array).copy()
         return out, w, exe, scope
 
     plain, w_plain, _, _ = train(*build(False))
@@ -590,13 +611,14 @@ def test_recompute_optimizer_remat_segments():
     jaxpr = jax.make_jaxpr(cb._step)(mut, ro, feeds, jax.random.key(0))
     assert "remat" in str(jaxpr)
 
-    # tied weights across segments -> fused fallback with warning
-    with _w.catch_warnings(record=True) as rec:
-        _w.simplefilter("always")
-        tied_losses, _, exe2, _ = train(*build(True, tied=True))
-    assert any("not lowerable" in str(r.message) for r in rec), \
-        [str(r.message) for r in rec]
-    assert all(np.isfinite(tied_losses))
+    # tied weights across segments: lowered, and trained as the plain run
+    tied_plain, w_tied_plain, _, _ = train(*build(False, tied=True))
+    with _w.catch_warnings():
+        _w.simplefilter("error")
+        tied_losses, w_tied, exe2, _ = train(*build(True, tied=True))
+    assert list(exe2._compiled_cache.values())[-1]._remat_plan is not None
+    np.testing.assert_allclose(tied_losses, tied_plain, rtol=2e-5, atol=1e-6)
+    np.testing.assert_allclose(w_tied, w_tied_plain, rtol=2e-5, atol=1e-6)
 
 
 def test_recompute_segment_keeps_state_writebacks():

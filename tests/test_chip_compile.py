@@ -269,3 +269,69 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
     assert 4e9 < total < 14e9, total
+
+
+def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
+    """The step of `phi4_mini_flash.b1_s4096` as the executor lowers it,
+    at the published widths and the cell's cut (six layers, one of each
+    kind; 25,008 rows of vocabulary), 1 x 4096 tokens, bf16 matmul
+    operands, recomputation a layer: the plan is not the fallback (seven
+    segments, the memory and the kept K, V carried between them, the
+    tied embedding read before the first and by the last), it fits the
+    chip's 16 GB, each of the three attention layers runs the flash
+    kernels (D 64, values 128 wide: forward, again in the recomputed
+    segment, dK/dV and dQ), and the window layer's grids are cut to the
+    blocks its window holds. Start-up runs on the CPU for the shapes
+    alone (8.4 GB of host memory, ~4 s)."""
+    from paddle_tpu.fluid import telemetry
+    from paddle_tpu.models import phi4_flash
+    cfg = dict(phi4_flash.phi4_flash_config(), vocab_size=25008,
+               layer_kinds=["mamba", "sliding", "mamba_memory", "full",
+                            "gmu", "cross"],
+               published_index=[0, 1, 16, 17, 18, 19])
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        main, startup, _, fetches = \
+            phi4_flash.build_phi4_flash_pretrain_program(cfg, seq_len=4096)
+        feed = phi4_flash.synthetic_pretrain_batch(cfg, 1, 4096)
+        scope = core.Scope()
+        fluid.Executor().run(startup, scope=scope)
+        cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
+                            scope, seed=0)
+        plan = cb._remat_plan
+        assert plan is not None and len(plan.segments) == 7
+        assert [len(s.outs) for s in plan.segments] == [1, 1, 2, 3, 1, 1, 1]
+
+        def state(names):
+            arrays = {n: scope.find_var(n).get_tensor().array for n in names}
+            return {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=one_chip)
+                    for n, a in arrays.items()}
+        mut, ro = state(cb.mut_state), state(cb.ro_state)
+        del scope
+        feeds = {n: jax.ShapeDtypeStruct(a.shape, jnp.int32,
+                                         sharding=one_chip)
+                 for n, a in feed.items()}
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+        compiled = cb._jitted.lower(mut, ro, feeds, rng).compile()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    text = compiled.as_text()
+    assert _kernel_names(text) == {
+        ("fwd/fused_attention_qkv", "flash_fwd"): 2 * 3,
+        ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 3,
+        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 3}
+    # block pairs a forward kernel computes: 40 heads x 150 under the
+    # window, x 528 causal (full and cross)
+    blocks = telemetry.REGISTRY.get("attn_kv_blocks_per_step")
+    sites = phi4_flash.attention_sites(main)
+    assert [blocks.value(site=s) for s in sites] == [6000, 21120, 21120]
+    assert list(sites.values()) == [512, 0, 0]
+    mem = compiled.memory_analysis()
+    parameters = 697.1e6
+    assert abs(mem.argument_size_in_bytes / (12 * parameters) - 1) < 0.01
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    assert 4e9 < total < 14.5e9, total

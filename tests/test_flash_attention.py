@@ -1,6 +1,6 @@
 """The Pallas flash-attention kernel itself, run through the Pallas
 interpreter on CPU — so the suite exercises the REAL kernel (forward,
-lse, and both backward kernels), not the `_ref_attention` fallback
+lse, and both backward kernels), not the `_dense_attention` fallback
 (reference behavior contract: operators/fused/multihead_matmul_op.cu).
 """
 import numpy as np
@@ -9,6 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.attention_ops import _dense_attention
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 
@@ -31,7 +32,7 @@ def test_forward_matches_reference(S, causal):
     sm = 1.0 / 8.0
     assert fa._use_kernels(), "kernel path must be taken under interpret"
     out = fa.flash_attention(q, k, v, sm, causal)
-    ref = fa._ref_attention(q, k, v, sm, causal)
+    ref = _dense_attention(q, k, v, sm, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -49,7 +50,7 @@ def test_grads_match_reference(causal, D):
         return jnp.sum(fa.flash_attention(q, k, v, sm, causal) * w)
 
     def loss_ref(q, k, v):
-        return jnp.sum(fa._ref_attention(q, k, v, sm, causal) * w)
+        return jnp.sum(_dense_attention(q, k, v, sm, causal) * w)
 
     g_fl = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     g_rf = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -65,7 +66,7 @@ def test_multi_kblock_online_softmax():
     # spike late keys so the running max actually changes between blocks
     k = k.at[:, :, 200:].mul(5.0)
     out = fa.flash_attention(q, k, v, 0.125, False)
-    ref = fa._ref_attention(q, k, v, 0.125, False)
+    ref = _dense_attention(q, k, v, 0.125, False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -88,7 +89,7 @@ def test_bf16_inputs():
     q, k, v = _rand_qkv(1, 2, 128, 64, seed=5, dtype=np.float32)
     q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
     out = fa.flash_attention(q, k, v, 0.125, True)
-    ref = fa._ref_attention(q, k, v, 0.125, True)
+    ref = _dense_attention(q, k, v, 0.125, True)
     assert out.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
@@ -107,7 +108,7 @@ def test_ragged_shapes_stay_on_kernel(S, Sk):
     v = jnp.asarray(r.normal(size=(1, 2, Sk, 16)).astype(np.float32))
     assert fa._use_kernels()
     out = fa.flash_attention(q, k, v, 0.25, False)
-    ref = fa._ref_attention(q, k, v, 0.25, False)
+    ref = _dense_attention(q, k, v, 0.25, False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -115,7 +116,7 @@ def test_ragged_shapes_stay_on_kernel(S, Sk):
         return jnp.sum(fa.flash_attention(q, k, v, 0.25, False) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(fa._ref_attention(q, k, v, 0.25, False) ** 2)
+        return jnp.sum(_dense_attention(q, k, v, 0.25, False) ** 2)
 
     g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -126,8 +127,8 @@ def test_ragged_shapes_stay_on_kernel(S, Sk):
 
 def test_ragged_causal_matches_reference():
     q, k, v = _rand_qkv(1, 2, 100, 16, seed=8)
-    out = fa.flash_attention(q, k, v, 0.25, causal=True)
-    ref = fa._ref_attention(q, k, v, 0.25, causal=True)
+    out = fa.flash_attention(q, k, v, 0.25, True)
+    ref = _dense_attention(q, k, v, 0.25, True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -208,8 +209,8 @@ def test_bias_matches_reference():
     bias[0, 100:] = -1e9
     bias[1, 64:] = -1e9
     bias = jnp.asarray(bias)
-    out = fa.flash_attention(q, k, v, 0.125, False, bias=bias)
-    ref = fa._ref_attention_bias(q, k, v, 0.125, False, bias)
+    out = fa.flash_attention(q, k, v, 0.125, fa.Mask(bias=bias))
+    ref = _dense_attention(q, k, v, 0.125, fa.Mask(bias=bias))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
 
@@ -238,8 +239,8 @@ def test_bias_grads_and_causal_dropout_combo():
 
     def loss_flash(q, k, v):
         return jnp.sum(fa.flash_attention(
-            q, k, v, 0.25, True, dropout_rate=0.1, dropout_seed=seed,
-            bias=bias) * w)
+            q, k, v, 0.25, fa.Mask(True, bias=bias), dropout_rate=0.1,
+            dropout_seed=seed) * w)
 
     def loss_ref(q, k, v):
         return jnp.sum(masked_ref(q, k, v) * w)
@@ -266,8 +267,8 @@ def test_fully_masked_rows_emit_zeros_on_both_paths():
     bias[0, :] = -1e30  # batch row 0: every key masked
     bias = jnp.asarray(bias)
 
-    o_pallas = fa.flash_attention(q, k, v, 0.125, bias=bias)
-    o_ref = fa._ref_attention_bias(q, k, v, 0.125, False, bias)
+    o_pallas = fa.flash_attention(q, k, v, 0.125, fa.Mask(bias=bias))
+    o_ref = _dense_attention(q, k, v, 0.125, fa.Mask(bias=bias))
     np.testing.assert_array_equal(np.asarray(o_pallas[0]), 0.0)
     np.testing.assert_array_equal(np.asarray(o_ref[0]), 0.0)
     # unmasked batch row is untouched and the two paths agree
@@ -277,7 +278,7 @@ def test_fully_masked_rows_emit_zeros_on_both_paths():
 
     def loss_pallas(q, k, v):
         return jnp.sum(fa.flash_attention(
-            q, k, v, 0.125, bias=bias).astype(jnp.float32) ** 2)
+            q, k, v, 0.125, fa.Mask(bias=bias)).astype(jnp.float32) ** 2)
 
     dq, dk, dv = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
     for g in (dq, dk, dv):

@@ -1113,6 +1113,24 @@ STRAGGLERS = [
       "DtBias": np.ones((2,), np.float32)},
      {"num_key_heads": 1, "num_value_heads": 2, "chunk_size": 4},
      {"wrt": ["Q", "K", "V", "A", "B", "ALog", "DtBias"]}),
+    ("selective_scan",
+     {"X": rng.uniform(-1, 1, (1, 6, 3)).astype(np.float32),
+      "Dt": rng.uniform(-1, 1, (1, 6, 3)).astype(np.float32),
+      "B": rng.uniform(-1, 1, (1, 6, 2)).astype(np.float32),
+      "C": rng.uniform(-1, 1, (1, 6, 2)).astype(np.float32),
+      "ALog": np.log(np.tile(np.arange(1, 3, dtype=np.float32), (3, 1))),
+      "D": np.ones((3,), np.float32),
+      "DtBias": np.asarray([-1.0, 0.0, 0.5], np.float32)},
+     {"chunk_size": 4},
+     {"wrt": ["X", "Dt", "B", "C", "ALog", "D", "DtBias"]}),
+    ("differential_combine",
+     {"X": rng.uniform(-1, 1, (1, 3, 2 * 2 * 2 * 4)).astype(np.float32),
+      "LambdaQ1": rng.uniform(-0.5, 0.5, (4,)).astype(np.float32),
+      "LambdaK1": rng.uniform(-0.5, 0.5, (4,)).astype(np.float32),
+      "LambdaQ2": rng.uniform(-0.5, 0.5, (4,)).astype(np.float32),
+      "LambdaK2": rng.uniform(-0.5, 0.5, (4,)).astype(np.float32)},
+     {"num_groups": 2, "lambda_init": 0.36},
+     {"wrt": ["X", "LambdaQ1", "LambdaK1", "LambdaQ2", "LambdaK2"]}),
     # logits 0.6 apart: a finite difference moves no token's choice
     ("moe_router",
      {"X": np.ones((1, 3, 2), np.float32)

@@ -84,8 +84,9 @@ def test_bert_tiny_trains():
 def test_flash_attention_matches_reference():
     """Pallas/jax flash_attention vs naive softmax attention."""
     import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.flash_attention import (flash_attention,
-                                                       _ref_attention)
+    from paddle_tpu.ops.attention_ops import \
+        _dense_attention as _ref_attention
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.rand(2, 3, 16, 8).astype("float32"))
     k = jnp.asarray(rng.rand(2, 3, 16, 8).astype("float32"))

@@ -252,7 +252,7 @@ def test_flash_on_mesh_value_and_grads_match_unpartitioned(heads):
                for _ in range(3))
 
     def loss(q, k, v):
-        return jnp.sum(fa.flash_attention(q, k, v, 0.35, causal=True) ** 2)
+        return jnp.sum(fa.flash_attention(q, k, v, 0.35, True) ** 2)
 
     grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
     with fa.interpret_guard():

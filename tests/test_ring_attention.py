@@ -6,7 +6,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.ops.pallas.flash_attention import _ref_attention
+from paddle_tpu.ops.attention_ops import _dense_attention as _ref_attention
 from paddle_tpu.parallel.ring_attention import (
     ring_attention, sequence_mesh, ulysses_attention)
 

@@ -555,10 +555,11 @@ def test_jax_compile_counters_move_when_jax_compiles_and_only_then(
         tmp_path):
     """The listener's counters where JAX compiles: seconds tracing,
     lowering and in the backend, persistent-cache hits and misses. They
-    move on the step's first compile AND on its second signature (step
-    2 runs on committed state: a compile `executor_compiles_total`
-    never sees), stand still from then on, and each leaves a
-    cat="compile" span or instant in a recording profiler."""
+    move on the step's first compile and stand still from then on: step
+    2, on the state step 1 handed back, is the signature step 1 ran on
+    (the start-up program's state goes in committed, as a step's does).
+    Each leaves a cat="compile" span or instant in a recording
+    profiler."""
     import jax
     import paddle_tpu.fluid as fluid
     from jax.experimental.compilation_cache import compilation_cache
@@ -595,13 +596,9 @@ def test_jax_compile_counters_move_when_jax_compiles_and_only_then(
         first = snap()
         moved = {n for n in first if first[n] > before[n]}
         assert moved == set(first) - {"jax_compile_cache_hits_total"}
-        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-        second = snap()
-        assert second["jax_backend_compiles_total"] \
-            > first["jax_backend_compiles_total"]
-        for _ in range(2):
+        for _ in range(3):
             exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
-        assert snap() == second
+        assert snap() == first
         spans = {e["name"] for e in profiler.snapshot_events()
                  if e["cat"] == "compile"}
         assert {"compile:trace", "compile:lower", "compile:backend",
@@ -611,9 +608,9 @@ def test_jax_compile_counters_move_when_jax_compiles_and_only_then(
         other = fluid.Executor()
         other.run(main, feed=feed, fetch_list=[loss], scope=scope)
         assert snap()["jax_compile_cache_hits_total"] \
-            > second["jax_compile_cache_hits_total"]
+            > first["jax_compile_cache_hits_total"]
         assert snap()["jax_compile_cache_misses_total"] \
-            == second["jax_compile_cache_misses_total"]
+            == first["jax_compile_cache_misses_total"]
     finally:
         profiler.stop_profiler(profile_path="")
         for k, v in saved.items():

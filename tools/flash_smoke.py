@@ -79,6 +79,7 @@ def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, dtype="bfloat16",
     dispatch_ms = single-dispatch wall time); never raises."""
     import jax
     import jax.numpy as jnp
+    from paddle_tpu.ops.attention_ops import _dense_attention
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     row = {"seq_len": S, "blk_q": blk_q, "blk_k": blk_k, "dtype": dtype,
@@ -101,7 +102,7 @@ def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, dtype="bfloat16",
             seed = jnp.asarray([1234], jnp.int32)
 
             def flash(q, k, v):
-                return fa.flash_attention(q, k, v, scale, causal=causal,
+                return fa.flash_attention(q, k, v, scale, causal,
                                           dropout_rate=dropout,
                                           dropout_seed=seed)
 
@@ -118,11 +119,11 @@ def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, dtype="bfloat16",
 
             if dropout == 0.0:
                 o_ref = np.asarray(
-                    fa._ref_attention(q, k, v, scale, causal), np.float32)
+                    _dense_attention(q, k, v, scale, causal), np.float32)
 
                 def loss_ref(q, k, v):
-                    return jnp.sum(fa._ref_attention(
-                        q, k, v, scale, causal).astype(jnp.float32) ** 2)
+                    return jnp.sum(_dense_attention(
+                        q, k, v, scale, causal) ** 2)
 
                 rq, rk, rv = (np.asarray(t, np.float32) for t in
                               jax.jit(jax.grad(loss_ref,
