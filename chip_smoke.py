@@ -301,7 +301,8 @@ def _qwen3_next_counters(main):
 def _phi4_flash_counters(main):
     """Nothing further to fetch; the gauges the new ops set: the block
     pairs each attention layer's forward kernel visits (window, full,
-    cross) and the chunks each scan steps over."""
+    cross) and the steps of its grid, at the blocks chosen for the call,
+    and the chunks each scan steps over."""
     from paddle_tpu.models import phi4_flash
     sites = phi4_flash.attention_sites(main)
     scans = [op.attr("site") for op in main.global_block().ops
@@ -312,6 +313,8 @@ def _phi4_flash_counters(main):
             attention_windows=list(sites.values()),
             attn_kv_blocks_per_step=_gauge_by_site(
                 "attn_kv_blocks_per_step", sites),
+            attn_grid_steps_per_step=_gauge_by_site(
+                "attn_grid_steps_per_step", sites),
             ssm_chunks_per_step=_gauge_by_site("ssm_chunks_per_step", scans))
     return [], say
 

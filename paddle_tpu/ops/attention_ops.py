@@ -26,14 +26,14 @@ import jax.numpy as jnp
 from .registry import register_op, register_grad_maker, first, out
 from .math_ops import mxu_available as _mxu_backend
 from .pallas.flash_attention import (
-    NEG_INF, Mask, flash_attention, _block_sizes, _use_kernels,
+    NEG_INF, Mask, flash_attention, _blocks_of, _use_kernels, grid_steps,
     visited_blocks)
 
 # Longest sequence (queries AND keys) that takes XLA's dense attention
-# where the flash kernels could serve the call. 128 is the kernels' block
-# (DEFAULT_BLOCK_Q / DEFAULT_BLOCK_K): at or below it a head's whole score
-# tile is ONE block — nothing is streamed, the online softmax has one step
-# and the grid is (B·H, 1, 1) — and on the v5e the kernels take 3.5 times
+# where the flash kernels could serve the call. 128 is one 128 x 128 tile
+# of scores, the kernels' smallest block: at or below it a head's whole
+# score tile is ONE block — nothing is streamed, the online softmax has one
+# step and the grid is (B·H, 1, 1) — and on the v5e the kernels take 3.5 times
 # the dense computation (tools/attention_paths.py; PERF.md §6, PR 27).
 # It moves only with that table run again AND a benchmark cell above it:
 # dense holds an S×S tensor a head for the backward, the kernels do not.
@@ -146,8 +146,9 @@ def _fused_attention_qkv(ins, attrs):
     rng). ``_dense_attention`` serves every other bias shape, every call
     whose score tile fits one kernel block, and a backend without the
     kernels. Causal masking is TOP-LEFT aligned (query i sees keys <= i)
-    on both paths. On the kernels' path the gauge
-    ``attn_kv_blocks_per_step`` is set, the op's ``site`` each."""
+    on both paths. On the kernels' path the gauges
+    ``attn_kv_blocks_per_step`` and ``attn_grid_steps_per_step`` are set,
+    the op's ``site`` each."""
     q = first(ins, "Q")
     k = first(ins, "K")
     v = first(ins, "V")
@@ -184,13 +185,20 @@ def _fused_attention_qkv(ins, attrs):
         o = flash_attention(qh, kh, vh, sm_scale, mask, dropout_rate=drop,
                             dropout_seed=seed)
         from ..fluid import telemetry
+        blocks = _blocks_of("flash_fwd", qh, kh, vh, mask)
+        site, calls = attrs.get("site", ""), qh.shape[0] * h
         telemetry.set_site_gauge(
             "attn_kv_blocks_per_step",
             "(Q block, K block) pairs whose scores the forward flash "
             "kernel computes a step, all heads and sequences: the pairs "
-            "its mask keeps", attrs.get("site", ""),
-            qh.shape[0] * h * visited_blocks(sq, sk, *_block_sizes(sq, sk),
-                                             mask))
+            "its mask keeps, at the blocks chosen for the call", site,
+            calls * visited_blocks(sq, sk, *blocks, mask))
+        telemetry.set_site_gauge(
+            "attn_grid_steps_per_step",
+            "steps of the forward flash kernel's grid a step, all heads "
+            "and sequences: Q blocks x the K axis's extent, the steps a "
+            "causal mask skips included", site,
+            calls * grid_steps(sq, sk, *blocks, mask))
     else:
         o = _dense_attention(qh, kh, vh, sm_scale, mask._replace(bias=bias),
                              drop, attrs.get("_rng"))

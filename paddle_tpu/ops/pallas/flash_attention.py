@@ -10,8 +10,8 @@ online-softmax state (f32 accumulator, running row-max m, normalizer l)
 lives in VMEM scratch and is carried across K blocks. Only one
 (blk_q × D) Q tile and one (blk_k × D) K/V tile are resident per step, so
 sequence length is NOT bounded by VMEM (the round-1 full-K/V-in-VMEM
-S≤2048 restriction is gone); VMEM per step is ~4·blk·D·4B ≈ 400KB at
-blk=128, D=64. Score tiles hit the MXU via jnp.dot with
+S≤2048 restriction is gone); what a step holds is ``_working_set``'s to
+say. Score tiles hit the MXU via jnp.dot with
 preferred_element_type=f32; softmax runs in f32 on the VPU. The kernel
 also emits the log-sum-exp per row, the residual the backward needs.
 
@@ -46,8 +46,16 @@ shapes — a caller that asks for the kernels gets them
 (parallel/ring_attention.py, tools/flash_smoke.py, the kernel tests).
 Whether a call should ask is the attention ops' to decide
 (ops/attention_ops._use_flash): they come here only where there is more
-than one block to stream, and compute dense attention themselves at or
-under DEFAULT_BLOCK_Q x DEFAULT_BLOCK_K.
+than one 128 x 128 tile of scores to stream, and compute dense attention
+themselves at or under that.
+
+Block geometry is chosen a call and a kernel by ``_block_sizes`` from the
+call's shape alone (head widths, lengths, window, item size): the pair of
+a short candidate list that a two-term cost reckons cheapest (grid steps
+x a step's fixed cost + score elements computed x an element's cost,
+both measured on the chip: PERF.md §6, PR 33) among those whose working
+set (``_working_set``) fits ``VMEM_BUDGET``. The same estimate is the
+kernels' ``vmem_limit_bytes`` where it passes Mosaic's default.
 """
 from __future__ import annotations
 
@@ -61,8 +69,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30  # finite mask value: avoids inf-inf → NaN in the rescale
 # Mosaic requires the last two dims of every block shape to be divisible
 # by (8, 128) or equal to the array dims. Row-statistics arrays (lse,
@@ -142,6 +148,28 @@ def visited_blocks(sq, sk, blk_q, blk_k, mask):
     spans = (_inner_span(i, blk_q, blk_k, nk, mask.window, True)
              for i in range(nq))
     return sum(hi - lo + 1 for lo, hi in spans)
+
+
+def _grid(kernel, sq, sk, blk_q, blk_k, window):
+    """(blocks of ``kernel``'s outer grid axis, extent of the inner one):
+    Q outside and K inside, the other way round for dK/dV; the inner
+    extent is every block, or under a window the most any outer block
+    needs (``_window_count``)."""
+    outer, inner, outer_is_q = (sq, blk_q), (sk, blk_k), True
+    if kernel == "flash_bwd_dkv":
+        outer, inner, outer_is_q = inner, outer, False
+    extent = (_window_count(outer[0], inner[0], outer[1], inner[1], window,
+                            outer_is_q)
+              if window else -(-inner[0] // inner[1]))
+    return -(-outer[0] // outer[1]), extent
+
+
+def grid_steps(sq, sk, blk_q, blk_k, mask, kernel="flash_fwd"):
+    """Steps of ``kernel``'s grid, a head. The steps ``pl.when`` skips
+    above a causal diagonal are counted: they are stepped, and their
+    blocks fetched."""
+    outer, extent = _grid(kernel, sq, sk, blk_q, blk_k, mask.window)
+    return outer * extent
 
 
 def _on_tpu() -> bool:
@@ -389,9 +417,7 @@ def _pallas_fwd(q, k, v, seed, sm_scale, mask, blk_q, blk_k,
     qf, kf, vf = (t.reshape(B * H, t.shape[2], t.shape[3])
                   for t in (q, k, v))
     nk = pl.cdiv(Sk, blk_k)
-    grid = (B * H, pl.cdiv(S, blk_q),
-            _window_count(S, Sk, blk_q, blk_k, window, True)
-            if window else nk)
+    grid = (B * H, *_grid("flash_fwd", S, Sk, blk_q, blk_k, window))
     kb = _inner_index(window, blk_q, blk_k, nk, True)
     has_bias = bias is not None
     kern = functools.partial(_fwd_kernel, sm_scale=sm_scale,
@@ -423,8 +449,8 @@ def _pallas_fwd(q, k, v, seed, sm_scale, mask, blk_q, blk_k,
             pltpu.VMEM((blk_q, 128), jnp.float32),
             pltpu.VMEM((blk_q, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params("flash_fwd", blk_q, blk_k, D, Dv,
+                                         q.dtype.itemsize, has_bias),
         interpret=_INTERPRET and not _on_tpu(),
         name="flash_fwd",
     )(*args)
@@ -576,34 +602,39 @@ def _bwd_q_kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, mask, blk_q, blk_k,
-                dropout_rate=0.0):
-    mask = Mask.of(mask)
-    bias, window = mask.bias, mask.window
-    B, H, S, D = q.shape
-    Sk, Dv = k.shape[2], v.shape[3]
-    BH = B * H
+def _bwd_operands(q, k, v, o, lse, g):
+    """What both backward kernels read, heads flattened: q, k, v, dO and
+    the two row statistics. The statistics enter the kernels with the
+    broadcast 128-lane minor dim (see LANES), materialized HERE as
+    transients — the residual held from forward to backward is the 2-D
+    (BH, S) slice, 1/128th the memory (at S=2048 the lane form would pin
+    32 MB per layer)."""
+    BH, S = q.shape[0] * q.shape[1], q.shape[2]
     qf, kf, vf, of, gf = (t.reshape(BH, t.shape[2], t.shape[3])
                           for t in (q, k, v, o, g))
-    # row statistics enter the kernels with the broadcast 128-lane minor
-    # dim (see LANES), materialized HERE as transients — the residual
-    # held from forward to backward is the 2-D (BH, S) slice, 1/128th
-    # the memory (at S=2048 the lane form would pin 32 MB per layer).
     lsef = jnp.broadcast_to(lse.reshape(BH, S)[:, :, None], (BH, S, LANES))
     delta2 = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32), -1)
     delta = jnp.broadcast_to(delta2[:, :, None], (BH, S, LANES))
-    interp = _INTERPRET and not _on_tpu()
-    has_bias = bias is not None
-    ragged_s = 0 if S % blk_q == 0 else S
-    ragged_sk = 0 if Sk % blk_k == 0 else Sk
-    nq, nk = pl.cdiv(S, blk_q), pl.cdiv(Sk, blk_k)
-    common = dict(sm_scale=sm_scale, causal=mask.causal or bool(window),
-                  blk_q=blk_q, blk_k=blk_k, dropout_rate=dropout_rate,
-                  has_bias=has_bias, window=window)
-    qb = _inner_index(window, blk_k, blk_q, nq, False)  # of K block j
-    kb = _inner_index(window, blk_q, blk_k, nk, True)   # of Q block i
+    return qf, kf, vf, gf, lsef, delta
 
-    kv_specs = [
+
+def _pallas_bwd_dkv(operands, seed, H, sm_scale, mask, blk_q, blk_k,
+                    dropout_rate=0.0):
+    """(dK, dV) [BH, Sk, D | Dv] of ``_bwd_operands``' six, ``H`` heads a
+    sequence (the key-padding bias is a sequence's)."""
+    mask = Mask.of(mask)
+    bias, window = mask.bias, mask.window
+    qf, kf, vf = operands[:3]
+    (BH, S, D), Sk, Dv = qf.shape, kf.shape[1], vf.shape[2]
+    has_bias = bias is not None
+    nq = pl.cdiv(S, blk_q)
+    qb = _inner_index(window, blk_k, blk_q, nq, False)  # of K block j
+    kern = functools.partial(
+        _bwd_kv_kernel, sm_scale=sm_scale, causal=mask.causal or bool(window),
+        blk_q=blk_q, blk_k=blk_k, dropout_rate=dropout_rate,
+        has_bias=has_bias, window=window, n_q_blocks=nq,
+        s_len=0 if S % blk_q == 0 else S, sk_len=0 if Sk % blk_k == 0 else Sk)
+    specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                    # seed
         pl.BlockSpec((1, blk_q, D), lambda b, j, i: (b, qb(j, i), 0)),   # q
         pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),   # k
@@ -614,30 +645,41 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, mask, blk_q, blk_k,
         pl.BlockSpec((1, blk_q, LANES),
                      lambda b, j, i: (b, qb(j, i), 0)),           # delta
     ]
-    kv_args = [seed, qf, kf, vf, gf, lsef, delta]
-    _append_bias_input(kv_specs, kv_args, bias, H, blk_k, k_axis=1)
-
-    dk, dv = pl.pallas_call(
-        _with_optional_bias(
-            functools.partial(_bwd_kv_kernel, s_len=ragged_s,
-                              sk_len=ragged_sk, n_q_blocks=nq, **common),
-            7, has_bias),
-        out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, Dv), v.dtype)),
-        grid=(BH, nk, _window_count(Sk, S, blk_k, blk_q, window, False)
-              if window else nq),
-        in_specs=kv_specs,
+    args = [seed, *operands]
+    _append_bias_input(specs, args, bias, H, blk_k, k_axis=1)
+    return pl.pallas_call(
+        _with_optional_bias(kern, 7, has_bias),
+        out_shape=(jax.ShapeDtypeStruct((BH, Sk, D), kf.dtype),
+                   jax.ShapeDtypeStruct((BH, Sk, Dv), vf.dtype)),
+        grid=(BH, *_grid("flash_bwd_dkv", S, Sk, blk_q, blk_k, window)),
+        in_specs=specs,
         out_specs=(pl.BlockSpec((1, blk_k, D), lambda b, j, i: (b, j, 0)),
                    pl.BlockSpec((1, blk_k, Dv), lambda b, j, i: (b, j, 0))),
         scratch_shapes=[pltpu.VMEM((blk_k, D), jnp.float32),
                         pltpu.VMEM((blk_k, Dv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interp,
+        compiler_params=_compiler_params("flash_bwd_dkv", blk_q, blk_k, D,
+                                         Dv, qf.dtype.itemsize, has_bias),
+        interpret=_INTERPRET and not _on_tpu(),
         name="flash_bwd_dkv",
-    )(*kv_args)
+    )(*args)
 
-    q_specs = [
+
+def _pallas_bwd_dq(operands, seed, H, sm_scale, mask, blk_q, blk_k,
+                   dropout_rate=0.0):
+    """dQ [BH, S, D] of ``_bwd_operands``' six."""
+    mask = Mask.of(mask)
+    bias, window = mask.bias, mask.window
+    qf, kf, vf = operands[:3]
+    (BH, S, D), Sk, Dv = qf.shape, kf.shape[1], vf.shape[2]
+    has_bias = bias is not None
+    nk = pl.cdiv(Sk, blk_k)
+    kb = _inner_index(window, blk_q, blk_k, nk, True)   # of Q block i
+    kern = functools.partial(
+        _bwd_q_kernel, sm_scale=sm_scale, causal=mask.causal or bool(window),
+        blk_q=blk_q, blk_k=blk_k, dropout_rate=dropout_rate,
+        has_bias=has_bias, window=window, n_k_blocks=nk,
+        sk_len=0 if Sk % blk_k == 0 else Sk)
+    specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),                    # seed
         pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),   # q
         pl.BlockSpec((1, blk_k, D), lambda b, i, j: (b, kb(i, j), 0)),   # k
@@ -646,42 +688,63 @@ def _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale, mask, blk_q, blk_k,
         pl.BlockSpec((1, blk_q, LANES), lambda b, i, j: (b, i, 0)),  # lse
         pl.BlockSpec((1, blk_q, LANES), lambda b, i, j: (b, i, 0)),  # delta
     ]
-    q_args = [seed, qf, kf, vf, gf, lsef, delta]
-    _append_bias_input(q_specs, q_args, bias, H, blk_k, 2, kb)
-
-    dq = pl.pallas_call(
-        _with_optional_bias(
-            functools.partial(_bwd_q_kernel, sk_len=ragged_sk,
-                              n_k_blocks=nk, **common),
-            7, has_bias),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        grid=(BH, nq, _window_count(S, Sk, blk_q, blk_k, window, True)
-              if window else nk),
-        in_specs=q_specs,
+    args = [seed, *operands]
+    _append_bias_input(specs, args, bias, H, blk_k, 2, kb)
+    return pl.pallas_call(
+        _with_optional_bias(kern, 7, has_bias),
+        out_shape=jax.ShapeDtypeStruct((BH, S, D), qf.dtype),
+        grid=(BH, *_grid("flash_bwd_dq", S, Sk, blk_q, blk_k, window)),
+        in_specs=specs,
         out_specs=pl.BlockSpec((1, blk_q, D), lambda b, i, j: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((blk_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interp,
+        compiler_params=_compiler_params("flash_bwd_dq", blk_q, blk_k, D,
+                                         Dv, qf.dtype.itemsize, has_bias),
+        interpret=_INTERPRET and not _on_tpu(),
         name="flash_bwd_dq",
-    )(*q_args)
-
-    return (dq.reshape(B, H, S, D), dk.reshape(B, H, Sk, D),
-            dv.reshape(B, H, Sk, Dv))
+    )(*args)
 
 
 # --------------------------------------------------------------------------
-# public entry
+# block geometry
 # --------------------------------------------------------------------------
+KERNELS = ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")
+# Candidate block lengths: each a multiple of Mosaic's (8, 128) tile, so a
+# block is legal at any length (a shorter length takes its exact
+# dimension, legal too).
+BLOCK_Q_CANDIDATES = (128, 256, 512, 1024)
+BLOCK_K_CANDIDATES = (128, 256, 512, 1024, 2048)
+# Most VMEM a grid step's working set (`_working_set`) may take: a quarter
+# of the v5e's 128 MiB. Every pair the sweep found fastest fits (the
+# widest, dK/dV at 1024 x 1024 and D 256, is 22 MiB); 1024 x 2048, slower
+# wherever it was measured, mostly does not.
+VMEM_BUDGET = 32 * 2 ** 20
+# Mosaic's default scoped-VMEM limit on the v5e; a working set above it
+# is asked for by name (`vmem_limit_bytes`), with room for what the
+# estimate does not see (it reads 1.05 to 1.8 times the least limit the
+# chip's compiler accepts, bisected at 14 block pairs a kernel, D 64 and
+# 256: PERF.md §6, PR 33).
+SCOPED_VMEM_DEFAULT = 16 * 2 ** 20
+VMEM_HEADROOM = 1.25
+# The chooser's cost: two constants fitted to the sweep of the cells' four
+# attention calls on the v5e, 70 (shape, pair) rows x three kernels
+# (tools/flash_smoke.py; PERF.md §6, PR 33), and the MXU's own.
+STEP_NS = 430.0      # a grid step, whatever it computes
+ELEMENT_NS = 0.003   # a score element's VPU work: mask, exp, rescale
+MAC_NS = 2 / 197e3   # a multiply-add at the bf16 peak, 197 TFLOP/s
+# multiply-adds a score element: QK^T and PV; S, dP, dV and dK; S, dP, dQ
+MACS = {"flash_fwd": lambda D, Dv: D + Dv,
+        "flash_bwd_dkv": lambda D, Dv: 2 * (D + Dv),
+        "flash_bwd_dq": lambda D, Dv: 2 * D + Dv}
+
 _BLOCK_OVERRIDE = None  # (blk_q, blk_k) set by block_override()
 
 
 @contextlib.contextmanager
 def block_override(blk_q, blk_k):
-    """Pin the kernel block sizes inside the context — the hardware
-    bring-up sweep (tools/flash_smoke.py) uses this to measure
-    blk_q×blk_k configurations; the override applies to forward AND the
-    custom-vjp backward, so wrap the whole grad computation."""
+    """Pin the block sizes of all three kernels inside the context — the
+    sweep on the chip (tools/flash_smoke.py) and the tests measure and
+    check blk_q×blk_k pairs with it; the override applies to forward AND
+    the custom-vjp backward, so wrap the whole grad computation."""
     global _BLOCK_OVERRIDE
     prev = _BLOCK_OVERRIDE
     _BLOCK_OVERRIDE = (int(blk_q), int(blk_k))
@@ -691,31 +754,104 @@ def block_override(blk_q, blk_k):
         _BLOCK_OVERRIDE = prev
 
 
-def _block_sizes(S, Sk):
-    """Ragged S/Sk are supported via in-kernel bounds masking, so blocks
-    need not divide the lengths. Inputs smaller than the default block
-    use the EXACT dimension as the block — a block equal to the array
-    dim is always Mosaic-legal regardless of (8, 128) alignment, so tiny
-    and tiny-ragged shapes lower without padding games."""
-    bq, bk = _BLOCK_OVERRIDE or (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
-    return min(S, bq), min(Sk, bk)
+def _working_set(kernel, blk_q, blk_k, D, Dv, itemsize, has_bias=False):
+    """Bytes of VMEM one grid step of ``kernel`` holds at these blocks:
+    every input and output block twice (the pipeline fetches the next
+    while this one computes), the f32 scratch accumulators, and the f32
+    [blk_q, blk_k] score-sized temporaries the body keeps live (s, p and
+    the cast or keep mask forward; dp and ds as well backward). A minor
+    dimension takes whole 128-lane tiles, so a 64-wide head costs 128.
+    f32 operands reach the MXU as bf16 pieces, up to three an operand
+    at the "highest" matmul precision (the float32 parity programs'):
+    half as much again, bisected like the rest. The one estimate: the
+    chooser's budget, ``vmem_limit_bytes`` and the sweep's
+    ``vmem_kb_est`` all read it."""
+    def lanes(n):
+        return -(-n // LANES) * LANES
+    d, dv, bk = lanes(D), lanes(Dv), lanes(blk_k)
+    q_t, k_t = blk_q * d * itemsize, blk_k * d * itemsize
+    v_t, o_t = blk_k * dv * itemsize, blk_q * dv * itemsize  # o_t: dO too
+    stat = blk_q * LANES * 4                                 # lse, delta
+    bias = 8 * bk * 4 if has_bias else 0
+    if kernel == "flash_fwd":
+        piped = q_t + k_t + v_t + bias + o_t + stat
+        scratch, tiles = blk_q * dv * 4 + 2 * stat, 2
+    elif kernel == "flash_bwd_dkv":
+        piped = q_t + k_t + v_t + o_t + 2 * stat + bias + k_t + v_t
+        scratch, tiles = blk_k * (d + dv) * 4, 3
+    else:
+        piped = q_t + k_t + v_t + o_t + 2 * stat + bias + q_t
+        scratch, tiles = blk_q * d * 4, 2
+    total = 2 * piped + scratch + tiles * blk_q * bk * 4
+    return total if itemsize <= 2 else total * 3 // 2
 
 
+def _compiler_params(kernel, blk_q, blk_k, D, Dv, itemsize, has_bias):
+    """The three kernels' Mosaic parameters: the inner grid axis carries
+    the accumulators; the working set is named where it passes the
+    default scoped limit."""
+    need = int(_working_set(kernel, blk_q, blk_k, D, Dv, itemsize, has_bias)
+               * VMEM_HEADROOM)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=need if need > SCOPED_VMEM_DEFAULT else None)
+
+
+def _cost_ns(kernel, S, Sk, blk_q, blk_k, D, Dv, mask):
+    """What the chooser minimises, ns a head: every step of the grid
+    pays ``STEP_NS`` (a skipped one too), every score element of a block
+    pair that is computed pays ``ELEMENT_NS`` of VPU work (mask, exp,
+    rescale; a masked element as much as a kept one) and the MXU's time
+    for its products over D and Dv. Larger blocks buy fewer steps with
+    more elements computed and thrown away: along a causal diagonal,
+    and far more under a window."""
+    elements = visited_blocks(S, Sk, blk_q, blk_k, mask) * blk_q * blk_k
+    return (grid_steps(S, Sk, blk_q, blk_k, mask, kernel) * STEP_NS
+            + elements * (ELEMENT_NS + MACS[kernel](D, Dv) * MAC_NS))
+
+
+def _block_sizes(kernel, S, Sk, D, Dv, mask=Mask(), itemsize=2):
+    """(blk_q, blk_k) of ``kernel`` for a call of this shape: of the
+    candidate pairs whose working set fits ``VMEM_BUDGET``, the one
+    ``_cost_ns`` reckons cheapest (the larger on a tie). A length
+    shorter than a candidate takes its EXACT dimension — a block equal
+    to the array dim is always Mosaic-legal regardless of (8, 128)
+    alignment, so tiny and tiny-ragged shapes lower without padding
+    games; a length no candidate divides stays on the in-kernel bounds
+    masks. Inside ``block_override`` the pinned pair wins."""
+    if _BLOCK_OVERRIDE:
+        return min(S, _BLOCK_OVERRIDE[0]), min(Sk, _BLOCK_OVERRIDE[1])
+    pairs = sorted({(min(S, bq), min(Sk, bk)) for bq in BLOCK_Q_CANDIDATES
+                    for bk in BLOCK_K_CANDIDATES}, reverse=True)
+    fits = [p for p in pairs
+            if _working_set(kernel, *p, D, Dv, itemsize,
+                            mask.bias is not None) <= VMEM_BUDGET]
+    # the smallest pair is the fallback of a head too wide for the budget
+    return min(fits or pairs[-1:],
+               key=lambda p: _cost_ns(kernel, S, Sk, *p, D, Dv, mask))
+
+
+def _blocks_of(kernel, q, k, v, mask):
+    """``_block_sizes`` of a call's operands [B, H, S, D] and its mask
+    (with its bias, where it has one: its block is in the working set)."""
+    return _block_sizes(kernel, q.shape[2], k.shape[2], q.shape[3],
+                        v.shape[3], Mask.of(mask), q.dtype.itemsize)
+
+
+# --------------------------------------------------------------------------
+# public entry
+# --------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
 def _flash_pallas(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
     """``mask``: a ``Mask`` without its bias (static: a bool stands for
     its causal flag); the key-padding ``bias`` is an operand."""
-    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
-    o, _ = _pallas_fwd(q, k, v, seed, sm_scale,
-                       Mask.of(mask)._replace(bias=bias), blk_q, blk_k,
-                       dropout_rate)
-    return o
+    return _fp_fwd(q, k, v, seed, bias, sm_scale, mask, dropout_rate)[0]
 
 
 def _fp_fwd(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
-    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
-    o, lse = _pallas_fwd(q, k, v, seed, sm_scale,
-                         Mask.of(mask)._replace(bias=bias), blk_q, blk_k,
+    biased = Mask.of(mask)._replace(bias=bias)
+    o, lse = _pallas_fwd(q, k, v, seed, sm_scale, biased,
+                         *_blocks_of("flash_fwd", q, k, v, biased),
                          dropout_rate)
     # residual: the 2-D row stat, not the 128-lane wire form (128× less
     # memory held across fwd→bwd; the bwd re-broadcasts transiently)
@@ -724,13 +860,19 @@ def _fp_fwd(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
 
 def _fp_bwd(sm_scale, mask, dropout_rate, res, g):
     q, k, v, o, lse, seed, bias = res
-    blk_q, blk_k = _block_sizes(q.shape[2], k.shape[2])
-    dq, dk, dv = _pallas_bwd(q, k, v, o, lse, seed, g, sm_scale,
-                             Mask.of(mask)._replace(bias=bias), blk_q, blk_k,
+    operands = _bwd_operands(q, k, v, o, lse, g)
+    biased = Mask.of(mask)._replace(bias=bias)
+    H = q.shape[1]
+    dk, dv = _pallas_bwd_dkv(operands, seed, H, sm_scale, biased,
+                             *_blocks_of("flash_bwd_dkv", q, k, v, biased),
                              dropout_rate)
+    dq = _pallas_bwd_dq(operands, seed, H, sm_scale, biased,
+                        *_blocks_of("flash_bwd_dq", q, k, v, biased),
+                        dropout_rate)
     dseed = np.zeros(seed.shape, jax.dtypes.float0)  # int arg: zero tangent
     dbias = None if bias is None else jnp.zeros_like(bias)  # mask input
-    return dq, dk, dv, dseed, dbias
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), \
+        dseed, dbias
 
 
 _flash_pallas.defvjp(_fp_fwd, _fp_bwd)

@@ -70,24 +70,40 @@ def for_the_chip(monkeypatch):
     ((4, 16, 2048, 64), dict(causal=True)),          # long causal
     ((8, 12, 500, 64), {}),                          # ragged boundary block
     ((1, 16, 4096, 256), dict(causal=True)),         # Qwen3-Next's head
+    ((1, 40, 4096, 64), dict(causal=True, dv=128)),  # Phi's full layer
+    ((1, 40, 4096, 64), dict(window=512, dv=128)),   # Phi's window layer
+    # the float32 parity programs (chip_smoke.py --qwen3-next / --phi4-flash)
+    ((1, 16, 4096, 256), dict(causal=True, f32="highest")),
+    ((1, 40, 4096, 64), dict(causal=True, dv=128, f32="highest")),
 ], ids=["b128_s128", "s512_keypad_dropout", "s2048_causal", "s500_ragged",
-        "s4096_causal_d256"])
+        "s4096_causal_d256", "s4096_causal_dv128", "s4096_w512_dv128",
+        "s4096_causal_d256_f32", "s4096_causal_dv128_f32"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
-    """Forward + dK/dV + dQ kernels of `_flash_pallas`, bf16, Mosaic."""
+    """Forward + dK/dV + dQ kernels of `_flash_pallas`, bf16, Mosaic, each
+    at the blocks `_block_sizes` CHOOSES for the shape: the gate that the
+    chooser's VMEM budget (and the limit the kernels ask for above
+    Mosaic's default) is one the chip's compiler accepts. The f32 cases
+    compile at the "highest" matmul precision, as the float32 parity
+    programs run: the chip refused those once (PR 33: 27.9 MiB asked of
+    a 23.75 MiB limit) where every bf16 case had passed."""
     B, H, S, D = shape
-    qkv = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    dtype = jnp.float32 if kw.get("f32") else jnp.bfloat16
+    qk = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((B, H, S, kw.get("dv", D)), dtype,
+                             sharding=one_chip)
     seed = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
     bias = (jax.ShapeDtypeStruct((B, S), jnp.float32, sharding=one_chip)
             if kw.get("bias") else None)
+    mask = fa.Mask(kw.get("causal", False), kw.get("window", 0))
 
     def loss(q, k, v, seed, bias):
-        o = fa._flash_pallas(q, k, v, seed, bias, 1.0 / np.sqrt(D),
-                             kw.get("causal", False),
+        o = fa._flash_pallas(q, k, v, seed, bias, 1.0 / np.sqrt(D), mask,
                              kw.get("dropout", 0.0))
         return jnp.sum(o.astype(jnp.float32) ** 2)
 
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        qkv, qkv, qkv, seed, bias).compile()
+    with jax.default_matmul_precision(kw.get("f32", "default")):
+        compiled = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2))).lower(qk, qk, v, seed, bias).compile()
     assert compiled.as_text().count(KERNEL) == 3
     assert _kernel_names(compiled.as_text()) == {
         (None, "flash_fwd"): 1, (None, "flash_bwd_dkv"): 1,
@@ -280,8 +296,8 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
     tied embedding read before the first and by the last), it fits the
     chip's 16 GB, each of the three attention layers runs the flash
     kernels (D 64, values 128 wide: forward, again in the recomputed
-    segment, dK/dV and dQ), and the window layer's grids are cut to the
-    blocks its window holds. Start-up runs on the CPU for the shapes
+    segment, dK/dV and dQ) at the blocks `_block_sizes` chooses, and the
+    window layer's grids are cut to the blocks its window holds. Start-up runs on the CPU for the shapes
     alone (8.4 GB of host memory, ~4 s)."""
     from paddle_tpu.fluid import telemetry
     from paddle_tpu.models import phi4_flash
@@ -322,12 +338,25 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
         ("fwd/fused_attention_qkv", "flash_fwd"): 2 * 3,
         ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 3,
         ("fwd/fused_attention_qkv", "flash_bwd_dq"): 3}
-    # block pairs a forward kernel computes: 40 heads x 150 under the
-    # window, x 528 causal (full and cross)
-    blocks = telemetry.REGISTRY.get("attn_kv_blocks_per_step")
+    # block pairs a forward kernel computes and the steps of its grid, at
+    # the blocks chosen for each call: 40 heads x 15 pairs of 512 x 512
+    # under the window (8 row blocks x 2, the first of them one), x 10 of
+    # 1024 x 1024 causal (full and cross); 16 steps a head either way
+    # (6,000 / 21,120 / 21,120 pairs in 6,400 / 40,960 / 40,960 steps at
+    # 128 x 128, before PR 33)
     sites = phi4_flash.attention_sites(main)
-    assert [blocks.value(site=s) for s in sites] == [6000, 21120, 21120]
     assert list(sites.values()) == [512, 0, 0]
+    pairs, steps = [], []
+    for window in sites.values():
+        mask = fa.Mask(True, window)
+        blocks = fa._block_sizes("flash_fwd", 4096, 4096, 64, 128, mask, 2)
+        pairs.append(40 * fa.visited_blocks(4096, 4096, *blocks, mask))
+        steps.append(40 * fa.grid_steps(4096, 4096, *blocks, mask))
+    assert (pairs, steps) == ([600, 400, 400], [640, 640, 640])
+    for name, want in (("attn_kv_blocks_per_step", pairs),
+                       ("attn_grid_steps_per_step", steps)):
+        gauge = telemetry.REGISTRY.get(name)
+        assert [gauge.value(site=s) for s in sites] == want
     mem = compiled.memory_analysis()
     parameters = 697.1e6
     assert abs(mem.argument_size_in_bytes / (12 * parameters) - 1) < 0.01

@@ -284,3 +284,105 @@ def test_fully_masked_rows_emit_zeros_on_both_paths():
     for g in (dq, dk, dv):
         assert np.isfinite(np.asarray(g, np.float32)).all()
     np.testing.assert_array_equal(np.asarray(dq[0], np.float32), 0.0)
+
+
+# ------------------------------------------------- any block pair (PR 33)
+def _reference(q, k, v, sm_scale, mask, rate=0.0, seed=0):
+    """Dense attention under ``mask``; with dropout, under the kernels'
+    own keep mask (a hash of absolute row and column, whatever the
+    tiling)."""
+    if not rate:
+        return _dense_attention(q, k, v, sm_scale, mask)
+    B, H, S, _ = q.shape
+    Sk = k.shape[2]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * sm_scale
+    if mask.bias is not None:
+        s = s + jnp.maximum(mask.bias, fa.NEG_INF)[:, None, None, :]
+    if mask.causal or mask.window:
+        rows, cols = jnp.arange(S)[:, None], jnp.arange(Sk)[None, :]
+        seen = rows >= cols
+        if mask.window:
+            seen = seen & (cols > rows - mask.window)
+        s = jnp.where(seen, s, -jnp.inf)
+    keep = np.stack([
+        fa.keep_mask_reference(seed, bh, np.arange(S), np.arange(Sk), rate)
+        for bh in range(B * H)]).reshape(B, H, S, Sk)
+    p = jax.nn.softmax(s, axis=-1) * jnp.asarray(keep, jnp.float32)
+    return jnp.einsum("bhqk,bhkd->bhqd", p / (1.0 - rate), v)
+
+
+def _keypad(B, Sk, kept):
+    bias = np.zeros((B, Sk), np.float32)
+    for b, n in enumerate(kept):
+        bias[b, n:] = -1e9
+    return jnp.asarray(bias)
+
+
+BLOCK_CASES = {
+    # name: (B, S, Sk, D, Dv, mask, dropout)
+    "causal": (1, 256, 256, 16, 16, fa.Mask(True), 0.0),
+    "window": (1, 256, 256, 16, 16, fa.Mask(True, 40), 0.0),
+    "window_wide_v": (1, 256, 256, 16, 32, fa.Mask(True, 100), 0.0),
+    "keypad": (2, 256, 256, 16, 16,
+               fa.Mask(bias=_keypad(2, 256, (200, 131))), 0.0),
+    "keypad_dropout": (2, 256, 256, 16, 16,
+                       fa.Mask(bias=_keypad(2, 256, (256, 77))), 0.2),
+    "causal_dropout": (1, 256, 256, 16, 16, fa.Mask(True), 0.1),
+    "ragged": (1, 200, 300, 16, 16, fa.Mask(), 0.0),
+    "ragged_causal_wide_v": (1, 200, 200, 16, 24, fa.Mask(True), 0.0),
+}
+
+
+@pytest.mark.parametrize("blocks", [(128, 256), (256, 128), (64, 128),
+                                    (256, 256)],
+                         ids=lambda b: "%dx%d" % b)
+@pytest.mark.parametrize("case", sorted(BLOCK_CASES))
+def test_any_block_pair_gives_dense_attention_and_its_gradients(case,
+                                                                blocks):
+    """Non-square blocks, blocks longer than the window and blocks as
+    long as the sequence, every mask form: the value and the three
+    gradients are dense attention's (`_block_sizes` may choose any
+    pair)."""
+    B, S, Sk, D, Dv, mask, rate = BLOCK_CASES[case]
+    r = np.random.RandomState(21)
+    q = jnp.asarray(r.normal(size=(B, 2, S, D)).astype(np.float32))
+    k = jnp.asarray(r.normal(size=(B, 2, Sk, D)).astype(np.float32))
+    v = jnp.asarray(r.normal(size=(B, 2, Sk, Dv)).astype(np.float32))
+    w = jnp.asarray(r.normal(size=(B, 2, S, Dv)).astype(np.float32))
+    seed = jnp.asarray([31], jnp.int32)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, 0.25, mask, dropout_rate=rate,
+                                  dropout_seed=seed if rate else None)
+
+    def want(q, k, v):
+        return _reference(q, k, v, 0.25, mask, rate, 31)
+
+    with fa.block_override(*blocks):
+        got = flash(q, k, v)
+        grads = jax.grad(lambda *a: jnp.sum(flash(*a) * w),
+                         argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    refs = jax.grad(lambda *a: jnp.sum(want(*a) * w),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(grads, refs, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-4, err_msg=f"d{name}")
+
+
+def test_dropout_is_the_same_mask_at_any_tiling():
+    """The keep mask hashes ABSOLUTE (row, column): two tilings of one
+    call drop the same entries, so their outputs differ by the order of
+    summation alone (and both differ from the call without dropout)."""
+    q, k, v = _rand_qkv(1, 2, 512, 32, seed=16)
+    seed = jnp.asarray([4242], jnp.int32)
+
+    def run(blocks, rate=0.1):
+        with fa.block_override(*blocks):
+            return np.asarray(fa.flash_attention(
+                q, k, v, 0.2, fa.Mask(True), dropout_rate=rate,
+                dropout_seed=seed))
+    small, large = run((128, 128)), run((256, 512))
+    np.testing.assert_allclose(small, large, rtol=1e-5, atol=1e-6)
+    assert np.abs(small - run((128, 128), 0.0)).max() > 1e-2

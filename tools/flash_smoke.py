@@ -1,6 +1,7 @@
 """Flash-attention hardware sweep: compile the Pallas kernels via Mosaic
-(NO interpret mode), check on-chip parity vs the einsum path, and sweep
-block sizes — one JSON row per configuration.
+(NO interpret mode), check on-chip parity against dense attention, and
+sweep block sizes over the shapes the benchmark's cells run — one JSON
+row per configuration.
 
 Everything that can fail on Mosaic contact — scratch shapes, SMEM scalar
 handling, dimension_semantics, VMEM budgets — is exercised here in one
@@ -11,54 +12,54 @@ path; operators/benchmark/op_tester.cc is its measure-don't-assert
 harness.
 
 Usage (needs a TPU; exits non-zero without one):
-    python -m tools.flash_smoke            # full sweep
+    python -m tools.flash_smoke [--shape NAME ...] [--out FILE]
 
-Per-config JSON row fields: seq_len, blk_q, blk_k, dtype, causal,
-dropout, fwd_ms, fwdbwd_ms, tflops_fwd, vmem_kb_est, max_err_fwd,
-max_err_dq/dk/dv, dropout_deterministic, status ('ok' | 'parity_fail' |
-'compile_error'), error.
+Per-config JSON row fields: shape (`SHAPES`' name, if any), batch, heads,
+seq_len, head_dim, v_dim, dtype, causal, window, bias, dropout, blk_q,
+blk_k, fwd_ms, dkv_ms, dq_ms (each kernel alone), fwdbwd_ms (the grad:
+all three), tflops_fwd, vmem_kb_est (the kernels' own estimate,
+`flash_attention._working_set`, the largest of the three), grid_steps
+and kv_blocks (the forward kernel's, a head), max_err_fwd,
+max_err_dq/dk/dv (against the dense computation with the SAME keep mask
+under dropout), status ('ok' | 'parity_fail' | 'compile_error'), error.
 """
 from __future__ import annotations
 
+import argparse
 import contextlib
+import functools
 import json
 import time
 import traceback
 
 import numpy as np
 
-
-def _vmem_kb_estimate(blk_q, blk_k, D, bwd=False):
-    """Analytic resident-VMEM estimate per grid step (f32 working set):
-    fwd: q, k, v tiles + acc[blk_q,D] + m/l[blk_q,128] + o tile.
-    bwd adds do/lse/delta tiles and the dk/dv (or dq) accumulators."""
-    f = 4  # f32 working set (inputs are upcast in-kernel)
-    fwd = (blk_q * D + 2 * blk_k * D) * f            # q,k,v tiles
-    fwd += blk_q * D * f                             # acc scratch
-    fwd += 2 * blk_q * 128 * f                       # m, l scratch
-    fwd += blk_q * D * f                             # o tile
-    if not bwd:
-        return fwd / 1024.0
-    b = blk_q * D * f                                # do tile
-    b += 2 * blk_q * 128 * f                         # lse/delta (LANES)
-    b += 2 * blk_k * D * f                           # dk/dv accumulators
-    return (fwd + b) / 1024.0
+# The attention calls of the benchmark's cells (PERF.md §4): the padded
+# BERT phase-2 cell, Phi's causal and window layers, Qwen's wide head.
+SHAPES = {
+    "bert_s512_pad": dict(B=32, H=12, S=512, D=64, bias=True, dropout=0.1),
+    "phi_causal": dict(B=1, H=40, S=4096, D=64, Dv=128, causal=True),
+    "phi_w512": dict(B=1, H=40, S=4096, D=64, Dv=128, window=512),
+    "qwen_d256": dict(B=1, H=16, S=4096, D=256, causal=True),
+}
 
 
 def _timed_scan(fn, q, k, v, iters):
     """Time ``iters`` executions inside ONE dispatched lax.scan, so the
     per-dispatch host cost stays out of a kernel-sized number. The scan
-    carry threads a tiny data
-    dependency through q so XLA cannot hoist the loop-invariant body out
-    of the loop. Returns ms per iteration."""
+    carry threads a tiny data dependency through q so XLA cannot hoist
+    the loop-invariant body out of the loop. Returns ms per iteration."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     def body(c, _):
-        out = fn(q + c, k, v)
-        leaf = out[0] if isinstance(out, (tuple, list)) else out
-        return (leaf.ravel()[0] * 1e-20).astype(q.dtype), None
+        # EVERY output feeds the carry: a kernel none of whose outputs is
+        # read is dead code to XLA (the sweep of PR 33 timed the grad
+        # without its dK/dV kernel this way: dQ alone was read)
+        first = sum(leaf.ravel()[0].astype(jnp.float32)
+                    for leaf in jax.tree_util.tree_leaves(fn(q + c, k, v)))
+        return (first * 1e-20).astype(q.dtype), None
 
     @jax.jit
     def many():
@@ -71,21 +72,83 @@ def _timed_scan(fn, q, k, v, iters):
     return (time.perf_counter() - t0) / iters * 1e3
 
 
-def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, dtype="bfloat16",
-               causal=False, dropout=0.0, steps=None, interpret=False):
-    """Compile + parity-check + time one (S, blk_q, blk_k) config.
-    ``steps`` overrides the scan-timing iteration count. Returns the
-    JSON row dict (fwd_ms/fwdbwd_ms from the device-side scan,
-    dispatch_ms = single-dispatch wall time); never raises."""
+@functools.lru_cache(maxsize=1)
+def _problem(B, H, S, D, Dv, dtype, causal, window, bias, dropout):
+    """Inputs of one shape and what dense attention makes of them
+    (output and the three gradients of sum(o^2), f32 numpy): computed
+    once a shape, a head at a time under `jax.checkpoint`, so one head's
+    S x S scores are all that is live at s4096. Under dropout the dense
+    side multiplies by the kernels' own keep mask (`_keep_mask` is plain
+    integer arithmetic on absolute positions)."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops.attention_ops import _dense_attention
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    rng = np.random.RandomState(0)
+    jdt = jnp.dtype(dtype)
+    q, k = (jnp.asarray(rng.randn(B, H, S, D) * 0.3, jdt) for _ in range(2))
+    v = jnp.asarray(rng.randn(B, H, S, Dv) * 0.3, jdt)
+    pad = None
+    if bias:  # key-padding form: lengths uniform in S/2..S, -10000 past
+        lengths = rng.randint(S // 2, S + 1, size=(B,))
+        pad = jnp.asarray(np.where(np.arange(S)[None, :] < lengths[:, None],
+                                   0.0, -10000.0), jnp.float32)
+    seed = jnp.asarray([1234], jnp.int32)
+    scale = 1.0 / np.sqrt(D)
+
+    @jax.checkpoint
+    def head(bh, qh, kh, vh):
+        s = jnp.einsum("qd,kd->qk", qh, kh,
+                       preferred_element_type=jnp.float32) * scale
+        if bias:
+            s = s + pad[bh // H][None, :]
+        if causal or window:
+            rows, cols = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+            seen = rows >= cols
+            if window:
+                seen = seen & (cols > rows - window)
+            s = jnp.where(seen, s, fa.NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        if dropout:
+            keep = fa._keep_mask(seed[0], bh, 0, 0, S, S, dropout)
+            p = p * keep.astype(p.dtype) / (1.0 - dropout)
+        return jnp.dot(p.astype(vh.dtype), vh,
+                       preferred_element_type=jnp.float32)
+
+    def loss(q, k, v):
+        o = jax.lax.map(lambda a: head(*a), (
+            jnp.arange(B * H, dtype=jnp.int32), q.reshape(B * H, S, D),
+            k.reshape(B * H, S, D), v.reshape(B * H, S, Dv)))
+        return jnp.sum(o ** 2), o.reshape(B, H, S, Dv)
+
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    want = tuple(np.asarray(t, np.float32) for t in (o, *grads))
+    return q, k, v, pad, seed, want
+
+
+def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, Dv=None,
+               dtype="bfloat16", causal=False, window=0, bias=False,
+               dropout=0.0, steps=None, interpret=False, shape=None):
+    """Compile + parity-check + time one (shape, blk_q, blk_k) config:
+    all three kernels at that pair. ``steps`` overrides the scan-timing
+    iteration count. Returns the JSON row dict; never raises."""
+    import jax
+    import jax.numpy as jnp
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    row = {"seq_len": S, "blk_q": blk_q, "blk_k": blk_k, "dtype": dtype,
-           "batch": B, "heads": H, "head_dim": D, "causal": causal,
-           "dropout": dropout,
-           "vmem_kb_est": round(_vmem_kb_estimate(blk_q, blk_k, D, True), 1)}
+    Dv = Dv or D
+    mask = fa.Mask(causal, window)
+    blocks = (min(S, blk_q), min(S, blk_k))
+    row = {"shape": shape, "batch": B, "heads": H, "seq_len": S,
+           "head_dim": D, "v_dim": Dv, "dtype": dtype, "causal": causal,
+           "window": window, "bias": bias, "dropout": dropout,
+           "blk_q": blk_q, "blk_k": blk_k,
+           "vmem_kb_est": round(max(
+               fa._working_set(kern, *blocks, D, Dv,
+                               jnp.dtype(dtype).itemsize, bias)
+               for kern in fa.KERNELS) / 1024.0, 1),
+           "grid_steps": fa.grid_steps(S, S, *blocks, mask),
+           "kv_blocks": fa.visited_blocks(S, S, *blocks, mask)}
     if S % blk_q or S % blk_k:
         row["ragged"] = True  # boundary blocks masked in-kernel
     # the custom-vjp backward kernels are traced when the grad is built,
@@ -94,72 +157,62 @@ def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, dtype="bfloat16",
     ictx = fa.interpret_guard() if interpret else contextlib.nullcontext()
     try:
         with ictx, fa.block_override(blk_q, blk_k):
-            rng = np.random.RandomState(0)
-            jdt = jnp.dtype(dtype)
-            q, k, v = (jnp.asarray(rng.randn(B, H, S, D) * 0.3, jdt)
-                       for _ in range(3))
+            q, k, v, pad, seed, want = _problem(
+                B, H, S, D, Dv, dtype, causal, window, bias, dropout)
             scale = 1.0 / np.sqrt(D)
-            seed = jnp.asarray([1234], jnp.int32)
+            biased = mask._replace(bias=pad)
 
             def flash(q, k, v):
-                return fa.flash_attention(q, k, v, scale, causal,
+                return fa.flash_attention(q, k, v, scale, biased,
                                           dropout_rate=dropout,
                                           dropout_seed=seed)
 
             def loss(q, k, v):
-                return jnp.sum(flash(q, k, v).astype(jnp.float32) ** 2)
-
-            fwd = jax.jit(flash)
-            grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+                o = flash(q, k, v).astype(jnp.float32)
+                return jnp.sum(o ** 2), o
 
             # --- compile + numerics ---------------------------------
-            o = np.asarray(fwd(q, k, v), np.float32)
-            dq, dk, dv = (np.asarray(t, np.float32)
-                          for t in grad(q, k, v))
-
-            if dropout == 0.0:
-                o_ref = np.asarray(
-                    _dense_attention(q, k, v, scale, causal), np.float32)
-
-                def loss_ref(q, k, v):
-                    return jnp.sum(_dense_attention(
-                        q, k, v, scale, causal) ** 2)
-
-                rq, rk, rv = (np.asarray(t, np.float32) for t in
-                              jax.jit(jax.grad(loss_ref,
-                                               argnums=(0, 1, 2)))(q, k, v))
-                scale_o = max(1.0, float(np.abs(o_ref).max()))
-                row["max_err_fwd"] = float(np.abs(o - o_ref).max()
-                                           / scale_o)
-                for nm, a, b in (("dq", dq, rq), ("dk", dk, rk),
-                                 ("dv", dv, rv)):
-                    s = max(1.0, float(np.abs(b).max()))
-                    row[f"max_err_{nm}"] = float(np.abs(a - b).max() / s)
-                # bf16 inputs, f32 accumulation: 2e-2 relative headroom
-                tol = 2e-2 if jdt == jnp.bfloat16 else 2e-3
-                ok = all(row[f"max_err_{n}"] < tol
-                         for n in ("fwd", "dq", "dk", "dv"))
-            else:
-                # dropout parity has no closed-form twin on-chip; the
-                # checks are determinism (same seed → identical bits)
-                # and finite grads
-                o2 = np.asarray(fwd(q, k, v), np.float32)
-                row["dropout_deterministic"] = bool((o == o2).all())
-                ok = (row["dropout_deterministic"]
-                      and all(np.isfinite(t).all()
-                              for t in (o, dq, dk, dv)))
+            (_, o), grads = jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+            for nm, got, ref in zip(("fwd", "dq", "dk", "dv"),
+                                    (o, *grads), want):
+                unit = max(1.0, float(np.abs(ref).max()))
+                row[f"max_err_{nm}"] = float(
+                    np.abs(np.asarray(got, np.float32) - ref).max() / unit)
+            # bf16 inputs, f32 accumulation: 2e-2 relative headroom
+            tol = 2e-2 if dtype == "bfloat16" else 2e-3
+            ok = all(row[f"max_err_{n}"] < tol
+                     for n in ("fwd", "dq", "dk", "dv"))
 
             # --- timing (device-side scan: one dispatch, many iters) --
             iters = steps or (2 if interpret else 20)
-            row["fwd_ms"] = round(_timed_scan(flash, q, k, v, iters), 3)
-            row["fwdbwd_ms"] = round(_timed_scan(
-                jax.grad(loss, argnums=(0, 1, 2)), q, k, v, iters), 3)
-            # single-dispatch wall time, host overhead included
-            t0 = time.perf_counter()
-            fwd(q, k, v).block_until_ready()
-            row["dispatch_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
-            # 4·B·H·S²·D MACs fwd (QKᵀ + PV) → 2 flops/MAC
-            flops = 4 * B * H * S * S * D * 2 * (0.5 if causal else 1.0)
+            _, lse = fa._pallas_fwd(q, k, v, seed, scale, biased, *blocks,
+                                    dropout)
+            g = (2.0 * o).astype(q.dtype)
+
+            def backward(kernel):
+                def run(q, k, v):
+                    return kernel(
+                        fa._bwd_operands(q, k, v, o.astype(q.dtype),
+                                         lse[:, :, 0], g), seed,
+                        H, scale, biased, *blocks, dropout)
+                return run
+
+            for name, fn in (
+                    ("fwd_ms", flash),
+                    ("dkv_ms", backward(fa._pallas_bwd_dkv)),
+                    ("dq_ms", backward(fa._pallas_bwd_dq)),
+                    ("fwdbwd_ms", jax.grad(lambda *a: loss(*a)[0],
+                                           argnums=(0, 1, 2)))):
+                row[name] = round(_timed_scan(fn, q, k, v, iters), 3)
+            # 2·B·H·S²·(D + Dv) MACs fwd (QKᵀ + PV) over the keys the
+            # mask keeps → 2 flops/MAC
+            kept = S * S
+            if window:
+                kept = sum(min(t + 1, window) for t in range(S))
+            elif causal:
+                kept = S * (S + 1) // 2
+            flops = 2 * B * H * kept * (D + Dv)
             row["tflops_fwd"] = round(flops / (row["fwd_ms"] * 1e-3) / 1e12,
                                       2)
             row["status"] = "ok" if ok else "parity_fail"
@@ -170,54 +223,58 @@ def run_config(S, blk_q, blk_k, *, B=4, H=8, D=64, dtype="bfloat16",
     return row
 
 
-def sweep_plan():
-    """The full config list, as (S, bq, bk, causal, dropout) tuples."""
+def sweep_plan(shapes=None):
+    """The full config list, as `run_config` keyword dicts: every
+    candidate block pair of the kernels' own lists on each of `SHAPES`
+    (a pair longer than the sequence is the same kernel as the sequence
+    itself: once), then a ragged boundary leg at the smallest pair."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
     plan = []
-    # 128/256 first: the headline bench shape (bert seq_len=128, D=64)
-    for S in (128, 256, 512, 1024, 2048):
-        for bq in (128, 256, 512):
-            for bk in (128, 256, 512):
-                if bq > S or bk > S:
-                    continue
-                plan.append((S, bq, bk, False, 0.0))
-    # causal + dropout + ragged legs on the default block config
-    S, bq, bk = 512, 128, 128
-    plan.append((S, bq, bk, True, 0.0))
-    plan.append((S, bq, bk, False, 0.1))
-    # ragged boundary block (S not a multiple of the block)
-    plan.append((S - S // 4 - 3, bq, bk, False, 0.0))
+    for name in shapes or SHAPES:
+        cfg = SHAPES[name]
+        pairs = sorted({(min(bq, cfg["S"]), min(bk, cfg["S"]))
+                        for bq in fa.BLOCK_Q_CANDIDATES
+                        for bk in fa.BLOCK_K_CANDIDATES})
+        plan += [dict(cfg, blk_q=bq, blk_k=bk, shape=name)
+                 for bq, bk in pairs]
+    if not shapes:
+        plan.append(dict(B=4, H=8, S=381, D=64, blk_q=128, blk_k=128))
     return plan
 
 
-def sweep(emit=print):
-    """The full sweep on the chip, one emitted JSON row per config."""
+def sweep(shapes=None, emit=print):
+    """The sweep on the chip, one emitted JSON row per config."""
     rows = []
-    for (S, bq, bk, causal, dropout) in sweep_plan():
-        r = run_config(S, bq, bk, causal=causal, dropout=dropout)
+    for cfg in sweep_plan(shapes):
+        r = run_config(cfg.pop("S"), cfg.pop("blk_q"), cfg.pop("blk_k"),
+                       steps=10, **cfg)
         rows.append(r)
         emit(json.dumps(r))
     return rows
 
 
+SHAPE_KEYS = ("batch", "heads", "seq_len", "head_dim", "v_dim", "causal",
+              "window", "bias", "dropout")
+
+
 def best_blocks(rows):
-    """The fastest (blk_q, blk_k) per "seq_len:head_dim" among ``rows``
-    (training criterion: fwd+bwd ms; clean non-causal/no-dropout/
-    non-ragged rows only). Reported by `summarize`; the kernel's block
-    choice does not read it — whoever measures (ROADMAP S6) commits the
-    table they trust into the package."""
+    """The fastest (blk_q, blk_k) a shape among ``rows`` (training
+    criterion: fwd+bwd ms), keyed by the WHOLE shape: batch, heads,
+    length, head widths, mask and dropout, as "b:h:s:d:dv:causal:window:
+    bias:dropout". Reported by `summarize`; the kernels' block choice
+    (`flash_attention._block_sizes`) does not read it: it is a rule of
+    the shape, fitted to such a sweep (PERF.md §6, PR 33)."""
     best = {}
     for r in rows:
-        if r.get("status") != "ok" or r.get("causal") \
-                or r.get("dropout") or r.get("ragged"):
+        if r.get("status") != "ok" or "fwdbwd_ms" not in r:
             continue
-        if "fwdbwd_ms" not in r:
-            continue
-        key = (int(r["seq_len"]), int(r.get("head_dim", 64)))
+        key = ":".join(str(int(r.get(k, 0)) if k != "dropout"
+                           else r.get(k, 0.0)) for k in SHAPE_KEYS)
         cur = best.get(key)
         if cur is None or r["fwdbwd_ms"] < cur["fwdbwd_ms"]:
             best[key] = r
-    return {f"{s}:{d}": [int(r["blk_q"]), int(r["blk_k"])]
-            for (s, d), r in sorted(best.items())}
+    return {key: [int(r["blk_q"]), int(r["blk_k"])]
+            for key, r in sorted(best.items())}
 
 
 def summarize(rows, backend):
@@ -241,10 +298,22 @@ def summarize(rows, backend):
     return out
 
 
-def main():
+def main(argv=None):
     from tools.device_peaks import require_tpu
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shape", action="append", choices=sorted(SHAPES),
+                    help="sweep these shapes only (default: all + ragged)")
+    ap.add_argument("--out", help="also append each row to this file")
+    args = ap.parse_args(argv)
     chip = require_tpu("tools.flash_smoke")
-    print(json.dumps(summarize(sweep(), chip.platform)))
+
+    def emit(line):
+        print(line, flush=True)
+        if args.out:
+            with open(args.out, "a") as f:
+                f.write(line + "\n")
+
+    emit(json.dumps(summarize(sweep(args.shape, emit), chip.platform)))
 
 
 if __name__ == "__main__":
