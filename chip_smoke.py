@@ -11,6 +11,10 @@
                                        step at its cell's sizes (6 layers,
                                        published widths, 1 x 4096) against
                                        its plain float32 reference
+    python chip_smoke.py --laguna      one chip: Laguna-XS.2's step at its
+                                       cell's sizes (5 layers, published
+                                       widths, 1 x 8192) against its plain
+                                       float32 reference
 
 Main path: BERT-base MLM pretraining at full width (12 x 768 x 12 heads x
 3072, vocab 30522) at b128 x s128 with bf16 matmuls, built by
@@ -268,6 +272,44 @@ PHI4_FLASH_LIMITS = {
 }
 
 
+# Laguna-XS.2: the same two programs against its reference. A sigmoid
+# router over 256 experts lies between, so bf16 operands move a discrete
+# choice as in Qwen's: those limits only fence the readings.
+LAGUNA_LIMITS = {
+    # readings (my chip run, PR 34): the float32 program 0.0 and
+    # 2.5e-6 .. 3.1e-5; bf16 activations 6.9e-5 and 3.0e-2 .. 3.7e-1
+    "float32": {
+        "loss": 1e-5,
+        "layers.0.attn.w_q@GRAD": 1e-3,       # full: 48 heads, YaRN
+        "layers.0.attn.w_g@GRAD": 1e-3,
+        "layers.0.mlp.w_down@GRAD": 1e-3,
+        "layers.1.attn.w_k@GRAD": 1e-3,       # window: 64 heads over 8
+        "layers.1.moe.w_router@GRAD": 1e-3,
+        "layers.1.moe.w_down@GRAD": 1e-3,
+        "layers.1.moe.shared_w_gate_up@GRAD": 1e-3,
+        "layers.4.attn.w_q@GRAD": 1e-3,
+        "layers.4.moe.w_gate_up@GRAD": 1e-3,
+        "embed_tokens@GRAD": 1e-3,
+    },
+    # readings: 4.9e-4 and, in this order, 0.034, 0.040, 0.031, 0.040,
+    # 0.25, 0.45, 0.038, 0.039, 0.31, 0.041: where an expert's rows
+    # changed hands its gradient moves by a third of its scale
+    "bf16_operands": {
+        "loss": 1e-3,
+        "layers.0.attn.w_q@GRAD": 0.12,
+        "layers.0.attn.w_g@GRAD": 0.12,
+        "layers.0.mlp.w_down@GRAD": 0.12,
+        "layers.1.attn.w_k@GRAD": 0.12,
+        "layers.1.moe.w_router@GRAD": 0.7,
+        "layers.1.moe.w_down@GRAD": 0.7,
+        "layers.1.moe.shared_w_gate_up@GRAD": 0.12,
+        "layers.4.attn.w_q@GRAD": 0.12,
+        "layers.4.moe.w_gate_up@GRAD": 0.7,
+        "embed_tokens@GRAD": 0.12,
+    },
+}
+
+
 def _by_path(path):
     import importlib.util
     spec = importlib.util.spec_from_file_location(
@@ -319,6 +361,29 @@ def _phi4_flash_counters(main):
     return [], say
 
 
+def _laguna_counters(main):
+    """(each expert layer's passes to fetch, what to say of them and of
+    the gauges): the rows a step's grouped products run, and a layer's
+    query heads, window, block pairs and grid steps."""
+    from paddle_tpu.models import laguna
+    passes = laguna.expert_passes(main)
+    sites = laguna.attention_sites(main)
+
+    def say(fetched):
+        import numpy as np
+        return dict(
+            passes=[int(np.asarray(g)[0]) for g in fetched],
+            moe_rows_per_step=sum(_gauge_by_site("moe_rows_per_step",
+                                                 passes)),
+            attention_heads_and_windows=list(sites.values()),
+            attn_query_heads=_gauge_by_site("attn_query_heads", sites),
+            attn_kv_blocks_per_step=_gauge_by_site(
+                "attn_kv_blocks_per_step", sites),
+            attn_grid_steps_per_step=_gauge_by_site(
+                "attn_grid_steps_per_step", sites))
+    return list(passes.values()), say
+
+
 PARITY = {
     "qwen3_next": dict(config="qwen3_next_80b_a3b",
                        cell="qwen3_next_80b_a3b.b1_s4096",
@@ -328,6 +393,8 @@ PARITY = {
                        cell="phi4_mini_flash.b1_s4096",
                        limits=PHI4_FLASH_LIMITS,
                        counters=_phi4_flash_counters),
+    "laguna": dict(config="laguna_xs2", cell="laguna_xs2.b1_s8192",
+                   limits=LAGUNA_LIMITS, counters=_laguna_counters),
 }
 
 
@@ -461,6 +528,9 @@ def main(argv=None):
     ap.add_argument("--phi4-flash", action="store_true",
                     help="Phi-4-mini-flash's step against its float32 "
                          "reference and nothing else")
+    ap.add_argument("--laguna", action="store_true",
+                    help="Laguna-XS.2's step against its float32 "
+                         "reference and nothing else")
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal at a toy size on any backend; never ok")
     args = ap.parse_args(argv)
@@ -489,8 +559,9 @@ def main(argv=None):
     t0 = time.perf_counter()
     if args.multichip:
         multichip(size, devices[:4])
-    elif args.qwen3_next or args.phi4_flash:
-        reference_parity("qwen3_next" if args.qwen3_next else "phi4_flash",
+    elif args.qwen3_next or args.phi4_flash or args.laguna:
+        reference_parity("qwen3_next" if args.qwen3_next else
+                         "phi4_flash" if args.phi4_flash else "laguna",
                          args.tiny, clock)
     else:
         train_one_chip(size, dev, clock)

@@ -38,15 +38,26 @@ def rms_norm(input, group_size=None, gate=None, zero_centered=False,
     return out
 
 
-def rotary_embedding(x, num_heads, rotary_dim, theta, name=None):
+def rotary_embedding(x, num_heads, rotary_dim, theta, yarn=None,
+                     cos_sin_scale=1.0, name=None):
     """Rotate-half rotary embedding on the first ``rotary_dim`` dims of
-    each head of x [B, S, num_heads * D]; position = index in S."""
+    each head of x [B, S, num_heads * D]; position = index in S.
+    ``yarn``: {"factor", "original_max_position", "beta_fast",
+    "beta_slow"} scales the dims' frequencies by YaRN
+    (``decoder_ops.yarn_inv_freq``); ``cos_sin_scale`` multiplies cos
+    and sin (YaRN's attention factor)."""
     helper = LayerHelper("rotary_embedding", **locals())
     out = _like(helper, x)
+    attrs = {"num_heads": num_heads, "rotary_dim": rotary_dim,
+             "theta": float(theta), "cos_sin_scale": float(cos_sin_scale)}
+    if yarn:
+        attrs.update(yarn_factor=float(yarn["factor"]),
+                     original_max_position=int(
+                         yarn["original_max_position"]),
+                     beta_fast=float(yarn["beta_fast"]),
+                     beta_slow=float(yarn["beta_slow"]))
     helper.append_op(type="rotary_embedding", inputs={"X": [x]},
-                     outputs={"Out": [out]},
-                     attrs={"num_heads": num_heads, "rotary_dim": rotary_dim,
-                            "theta": float(theta)})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -130,10 +141,12 @@ def differential_combine(x, num_groups, head_dim, lambda_init,
     return out
 
 
-def moe_router(x, num_experts, top_k, param_attr=None, name=None):
-    """Softmax over ``num_experts`` in float32, top-k, weights
-    renormalised: (expert ids [.., k] int32, weights [.., k], the
-    layer's load-balancing auxiliary loss [1])."""
+def moe_router(x, num_experts, top_k, scoring="softmax", scale=1.0,
+               param_attr=None, name=None):
+    """Scores over ``num_experts`` in float32 (``scoring``: "softmax", or
+    "sigmoid" of each logit), top-k, the chosen weights renormalised to
+    sum 1 and multiplied by ``scale``: (expert ids [.., k] int32, weights
+    [.., k], the layer's load-balancing auxiliary loss [1])."""
     helper = LayerHelper("moe_router", **locals())
     w = helper.create_parameter(attr=helper.param_attr,
                                 shape=[x.shape[-1], num_experts],
@@ -146,7 +159,8 @@ def moe_router(x, num_experts, top_k, param_attr=None, name=None):
     helper.append_op(type="moe_router", inputs={"X": [x], "W": [w]},
                      outputs={"TopkIdx": [idx], "TopkWeight": [weight],
                               "AuxLoss": [aux]},
-                     attrs={"top_k": top_k})
+                     attrs={"top_k": top_k, "scoring": scoring,
+                            "scale": float(scale), "site": helper.name})
     return idx, weight, aux
 
 

@@ -2,6 +2,7 @@
 front end (reference: python/paddle/fluid/tests/book/ +
 test_imperative_{resnet,se_resnext,transformer,ptb_rnn}.py)."""
 from . import bert  # noqa: F401
+from . import laguna  # noqa: F401
 from . import phi4_flash  # noqa: F401
 from . import qwen3_next  # noqa: F401
 from . import resnet  # noqa: F401

@@ -26,6 +26,8 @@ from .. import fluid
 from ..fluid import layers
 from ..fluid.initializer import Normal, NumpyArrayInitializer, Uniform
 from ..fluid.param_attr import ParamAttr
+from ._decoder_parts import (attr as _attr, gated_ffn, linear as _linear,
+                             minimize, ops_by_site, synthetic_pretrain_batch)
 from .bert import fused_multihead_attention
 
 __all__ = ["phi4_flash_config", "layer_kinds", "lambda_init",
@@ -63,18 +65,6 @@ def layer_kinds(n):
 
 def lambda_init(published_index):
     return 0.8 - 0.6 * math.exp(-0.3 * published_index)
-
-
-def _attr(name, cfg, initializer=None):
-    return ParamAttr(name=name,
-                     initializer=initializer or Normal(0.0, cfg["init_std"]))
-
-
-def _linear(x, size, name, cfg, bias=None, initializer=None):
-    """x W (+ b, a parameter named ``bias``, from 0)."""
-    return layers.fc(x, size, num_flatten_dims=2,
-                     bias_attr=ParamAttr(name=bias) if bias else False,
-                     param_attr=_attr(name, cfg, initializer))
 
 
 def _layer_norm(x, prefix, cfg):
@@ -162,13 +152,6 @@ def gated_memory_unit(x, m, prefix, cfg):
                    prefix + "w_out", cfg)
 
 
-def gated_mlp(x, prefix, cfg):
-    g, u = layers.split(_linear(x, 2 * cfg["mlp_width"],
-                                prefix + "w_gate_up", cfg), 2, dim=-1)
-    return _linear(layers.elementwise_mul(layers.swish(g), u), cfg["hidden"],
-                   prefix + "w_down", cfg)
-
-
 def decoder_layer(x, i, shared, cfg):
     """x after layer i; ``shared`` gains what later layers read: "m"
     from the memory layer, "k" and "v" from the full-attention layer."""
@@ -194,7 +177,8 @@ def decoder_layer(x, i, shared, cfg):
         raise ValueError(f"layer kind {kind!r}")
     x = layers.elementwise_add(x, y)
     h = _layer_norm(x, prefix + "ln2.", cfg)
-    return layers.elementwise_add(x, gated_mlp(h, prefix + "mlp.", cfg))
+    return layers.elementwise_add(
+        x, gated_ffn(h, cfg["mlp_width"], prefix + "mlp.", cfg))
 
 
 def build_phi4_flash_pretrain_program(cfg=None, seq_len=4096, lr=1e-4,
@@ -224,25 +208,12 @@ def build_phi4_flash_pretrain_program(cfg=None, seq_len=4096, lr=1e-4,
         logits = layers.matmul(x, main.global_block().var("embed_tokens"),
                                transpose_y=True)
         loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
-        opt = fluid.optimizer.Adam(lr)
-        if recompute:
-            opt = fluid.optimizer.RecomputeOptimizer(opt)
-            opt._set_checkpoints(checkpoints)
-        opt.minimize(loss)
+        minimize(loss, lr, recompute, checkpoints)
     return main, startup, [ids, labels], [loss]
 
 
 def attention_sites(program):
     """{an attention op's ``site`` (its gauge's label): its window, 0 for
     none}, in layer order."""
-    return {op.attr("site"): op.attr("window")
-            for op in program.global_block().ops
-            if op.type == "fused_attention_qkv"}
-
-
-def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0):
-    """One feed dict: documents of seq_len + 1 ids uniform over the
-    vocabulary from ``seed``, one a sequence; labels are the next ids."""
-    doc = np.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (batch, seq_len + 1), dtype=np.int64)
-    return {"ids": doc[:, :-1].copy(), "labels": doc[:, 1:, None].copy()}
+    return ops_by_site(program, "fused_attention_qkv",
+                       lambda op: op.attr("window"))

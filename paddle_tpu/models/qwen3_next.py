@@ -19,8 +19,11 @@ import math
 
 from .. import fluid
 from ..fluid import layers
-from ..fluid.initializer import Normal, Uniform
+from ..fluid.initializer import Uniform
 from ..fluid.param_attr import ParamAttr
+from ._decoder_parts import (attr as _attr, linear as _linear, minimize,
+                             ops_by_site, rms_norm as _norm,
+                             synthetic_pretrain_batch)
 from .bert import fused_multihead_attention
 
 __all__ = ["qwen3_next_config", "build_qwen3_next_pretrain_program",
@@ -38,21 +41,6 @@ def qwen3_next_config():
         shared_width=512, eps=1e-6,
         # one rank's share and the training assumptions (not in the source)
         experts_held=512, expert_start=0, aux_coef=0.001, init_std=0.02)
-
-
-def _attr(name, cfg, initializer=None):
-    return ParamAttr(name=name,
-                     initializer=initializer or Normal(0.0, cfg["init_std"]))
-
-
-def _linear(x, size, name, cfg):
-    return layers.fc(x, size, num_flatten_dims=2, bias_attr=False,
-                     param_attr=_attr(name, cfg))
-
-
-def _norm(x, name, cfg, **kw):
-    return layers.rms_norm(x, epsilon=cfg["eps"],
-                           param_attr=ParamAttr(name=name), **kw)
 
 
 def gated_delta_net(x, prefix, cfg):
@@ -158,11 +146,7 @@ def build_qwen3_next_pretrain_program(cfg=None, seq_len=4096, lr=1e-4,
         loss = layers.elementwise_add(
             ce, layers.scale(layers.sums(aux),
                              scale=cfg["aux_coef"] / cfg["layers"]))
-        opt = fluid.optimizer.Adam(lr)
-        if recompute:
-            opt = fluid.optimizer.RecomputeOptimizer(opt)
-            opt._set_checkpoints(checkpoints)
-        opt.minimize(loss)
+        minimize(loss, lr, recompute, checkpoints)
     return main, startup, [ids, labels], [ce]
 
 
@@ -170,15 +154,5 @@ def expert_passes(program):
     """{an expert layer's ``site`` (its gauges' label): the name to fetch
     for the passes of its row bound it ran that step, [1] int32}, in
     layer order; 1 wherever the routing fitted twice the held share."""
-    return {op.attr("site"): op.output("Passes")[0]
-            for op in program.global_block().ops
-            if op.type == "moe_expert_ffn"}
-
-
-def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0):
-    """One feed dict: documents of seq_len + 1 ids uniform over the
-    vocabulary from ``seed``, one a sequence; labels are the next ids."""
-    import numpy as np
-    doc = np.random.default_rng(seed).integers(
-        0, cfg["vocab_size"], (batch, seq_len + 1), dtype=np.int64)
-    return {"ids": doc[:, :-1].copy(), "labels": doc[:, 1:, None].copy()}
+    return ops_by_site(program, "moe_expert_ffn",
+                       lambda op: op.output("Passes")[0])

@@ -148,7 +148,7 @@ def _fused_attention_qkv(ins, attrs):
     kernels. Causal masking is TOP-LEFT aligned (query i sees keys <= i)
     on both paths. On the kernels' path the gauges
     ``attn_kv_blocks_per_step`` and ``attn_grid_steps_per_step`` are set,
-    the op's ``site`` each."""
+    the op's ``site`` each; on either path ``attn_query_heads``."""
     q = first(ins, "Q")
     k = first(ins, "K")
     v = first(ins, "V")
@@ -202,6 +202,13 @@ def _fused_attention_qkv(ins, attrs):
     else:
         o = _dense_attention(qh, kh, vh, sm_scale, mask._replace(bias=bias),
                              drop, attrs.get("_rng"))
+    # set down here: a Pallas kernel's Python call stack is in its
+    # compile-cache key, so nothing is added above the kernels' call
+    from ..fluid import telemetry as _telemetry
+    _telemetry.set_site_gauge(
+        "attn_query_heads",
+        "query heads of the attention op, whichever path it takes: a "
+        "model's layers may differ in it", attrs.get("site", ""), h)
     return out(Out=_merge_heads(o).astype(out_dtype))
 
 

@@ -2,16 +2,20 @@
 Qwen3-Next family; layer equations and departures:
 benchmark/configs/qwen3_next_80b_a3b_reference.py), state-space scans
 and differential attention (the SambaY family:
-benchmark/configs/phi4_mini_flash_reference.py).
+benchmark/configs/phi4_mini_flash_reference.py), sigmoid-scored
+routing and rotary embeddings scaled by YaRN (the Laguna family:
+benchmark/configs/laguna_xs2_reference.py).
 
 ``rms_norm``            RMSNorm over groups of the last dim, zero-centred
                         weight or plain, optionally gated by SiLU(Gate)
-``rotary_embedding``    partial rotate-half rotary embedding a head
+``rotary_embedding``    partial rotate-half rotary embedding a head, its
+                        frequencies plain or YaRN's
 ``causal_conv1d``       depthwise causal convolution along the sequence
 ``gated_delta_rule``    the gated delta rule, in chunks (WY form)
 ``selective_scan``      Mamba's diagonal state-space recurrence, in chunks
 ``differential_combine`` A_1 V - lambda A_2 V of differential attention
-``moe_router``          softmax over all experts, top-k, auxiliary loss
+``moe_router``          softmax or sigmoid scores over all experts, top-k,
+                        auxiliary loss
 ``moe_expert_ffn``      the held experts' part of a routed gated FFN
 
 One pure JAX kernel each; gradients are the registry's vjp of it (the
@@ -23,9 +27,11 @@ delta rule's chunk state and the scan's state and decay stay float32.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .registry import register_op, first, out
@@ -77,22 +83,71 @@ def _rms_norm(ins, attrs):
     return out(Out=y.astype(x.dtype))
 
 
+def yarn_correction_range(rotary_dim, theta, original_max_position,
+                          beta_fast, beta_slow):
+    """(low, high): the dims of a head's ``rotary_dim`` / 2 frequencies
+    between which YaRN's ramp runs, whole numbers: dim c(n) turns n times
+    over the original context, c(n) = rotary_dim ln(original / (2 pi n))
+    / (2 ln theta); low = floor(c(beta_fast)), high = ceil(c(beta_slow)),
+    kept inside the head."""
+    def dim(rotations):
+        return rotary_dim * math.log(
+            original_max_position / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    return (max(math.floor(dim(beta_fast)), 0),
+            min(math.ceil(dim(beta_slow)), rotary_dim - 1))
+
+
+def yarn_inv_freq(rotary_dim, theta, factor, original_max_position,
+                  beta_fast, beta_slow):
+    """YaRN's rotary_dim / 2 inverse frequencies (Peng et al. 2023, as
+    `transformers` computes them, the range truncated to whole dims):
+    dims below ``low`` turn often enough inside the original context and
+    keep theta^(-2i / r), dims above ``high`` are interpolated (divided
+    by ``factor``), a linear ramp between. Made on the host in float64
+    and rounded once to float32: constants of the traced op, the same
+    whatever compiles it."""
+    i = np.arange(rotary_dim // 2, dtype=np.float64)
+    pos = float(theta) ** (2 * i / rotary_dim)
+    low, high = yarn_correction_range(rotary_dim, theta,
+                                      original_max_position, beta_fast,
+                                      beta_slow)
+    ramp = np.clip((i - low) / (high - low if high != low else 0.001), 0, 1)
+    return ((1 - ramp) / pos + ramp / (factor * pos)).astype(np.float32)
+
+
 @register_op("rotary_embedding", inputs=("X",),
-             attr_defaults={"num_heads": 1, "rotary_dim": 0, "theta": 1e4})
+             attr_defaults={"num_heads": 1, "rotary_dim": 0, "theta": 1e4,
+                            "yarn_factor": 0.0, "original_max_position": 0,
+                            "beta_fast": 32.0, "beta_slow": 1.0,
+                            "cos_sin_scale": 1.0})
 def _rotary_embedding(ins, attrs):
     """Rotate-half rotary embedding on the first ``rotary_dim`` dims of
     each of ``num_heads`` heads; X [B, S, H*D], a token's position is
-    its index in the sequence."""
+    its index in the sequence. ``yarn_factor`` > 0: the frequencies are
+    YaRN's (``yarn_inv_freq``; its four numbers are attrs, the dims'
+    frequencies are made here, where the op is traced) and
+    ``cos_sin_scale`` multiplies cos and sin (YaRN's attention factor);
+    the defaults are the plain embedding."""
     x = first(ins, "X")
     b, s, hd = x.shape
     h = attrs.get("num_heads", 1)
     r = attrs.get("rotary_dim", 0) or hd // h
     xh = x.reshape(b, s, h, hd // h)
-    inv = attrs.get("theta", 1e4) ** (
-        -jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    if attrs.get("yarn_factor", 0.0):
+        inv = jnp.asarray(yarn_inv_freq(
+            r, attrs.get("theta", 1e4), attrs["yarn_factor"],
+            attrs["original_max_position"], attrs.get("beta_fast", 32.0),
+            attrs.get("beta_slow", 1.0)))
+    else:
+        inv = attrs.get("theta", 1e4) ** (
+            -jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.tile(jnp.cos(angle), (1, 2))[None, :, None, :]
     sin = jnp.tile(jnp.sin(angle), (1, 2))[None, :, None, :]
+    scale = attrs.get("cos_sin_scale", 1.0)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     rot, rest = xh[..., :r], xh[..., r:]
     half = jnp.concatenate([-rot[..., r // 2:], rot[..., :r // 2]], -1)
     y = jnp.concatenate([rot * cos + half * sin, rest], -1)
@@ -329,24 +384,41 @@ def _differential_combine(ins, attrs):
 
 # --------------------------------------------------------------------------
 @register_op("moe_router", inputs=("X", "W"), diff_inputs=("X", "W"),
-             attr_defaults={"top_k": 1})
+             attr_defaults={"top_k": 1, "scoring": "softmax", "scale": 1.0,
+                            "site": ""})
 def _moe_router(ins, attrs):
     """X [.., D], W [D, E] -> TopkIdx [.., k] int32, TopkWeight [.., k]
-    (the chosen experts' probabilities, renormalised to sum 1), AuxLoss
-    [1] = E * sum_e (assignments_e / tokens) * mean_t p_{t,e}. Logits and
-    softmax in float32 at the highest matmul precision whatever the
-    flags say: a rounded logit changes which expert is chosen."""
+    (the chosen experts' scores, renormalised to sum 1, times ``scale``),
+    AuxLoss [1] = E * sum_e (assignments_e / tokens) * mean_t p_{t,e}.
+    ``scoring``: "softmax" over the experts, or "sigmoid" of each logit
+    (DeepSeek-V3's router: the k largest sigmoids, renormalised among
+    themselves; p of the auxiliary loss is then the sigmoids over their
+    sum). Logits and scores in float32 at the highest matmul precision
+    whatever the flags say: a rounded logit changes which expert is
+    chosen."""
     x, w = first(ins, "X"), first(ins, "W")
     k, e = attrs.get("top_k", 1), w.shape[1]
-    probs = jax.nn.softmax(
-        jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
-                   precision=lax.Precision.HIGHEST), -1)
-    top_p, top_i = lax.top_k(probs, k)
+    logits = jnp.matmul(x.astype(jnp.float32), w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scoring = attrs.get("scoring", "softmax")
+    if scoring == "softmax":
+        scores = probs = jax.nn.softmax(logits, -1)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        probs = scores / jnp.sum(scores, -1, keepdims=True)
+    else:
+        raise ValueError(f"moe_router: scoring {scoring!r}")
+    top_p, top_i = lax.top_k(scores, k)
     top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    if attrs.get("scale", 1.0) != 1.0:
+        top_p = top_p * attrs["scale"]
     tokens = probs.size // e
     share = jnp.zeros((e,), jnp.float32).at[top_i.reshape(-1)].add(1.0) \
         / tokens
     aux = e * jnp.sum(share * jnp.mean(probs.reshape(tokens, e), 0))
+    _gauge("moe_router_width",
+           "experts the router scores a token over, held here or not",
+           attrs.get("site", ""), e)
     return out(TopkIdx=top_i.astype(jnp.int32), TopkWeight=top_p,
                AuxLoss=aux.reshape(1))
 

@@ -75,9 +75,17 @@ def for_the_chip(monkeypatch):
     # the float32 parity programs (chip_smoke.py --qwen3-next / --phi4-flash)
     ((1, 16, 4096, 256), dict(causal=True, f32="highest")),
     ((1, 40, 4096, 64), dict(causal=True, dv=128, f32="highest")),
+    # Laguna's two layer kinds: the first D 128 and S 8192 calls, both
+    # precisions (chip_smoke.py --laguna runs the float32 program too)
+    ((1, 48, 8192, 128), dict(causal=True)),
+    ((1, 64, 8192, 128), dict(window=512)),
+    ((1, 48, 8192, 128), dict(causal=True, f32="highest")),
+    ((1, 64, 8192, 128), dict(window=512, f32="highest")),
 ], ids=["b128_s128", "s512_keypad_dropout", "s2048_causal", "s500_ragged",
         "s4096_causal_d256", "s4096_causal_dv128", "s4096_w512_dv128",
-        "s4096_causal_d256_f32", "s4096_causal_dv128_f32"])
+        "s4096_causal_d256_f32", "s4096_causal_dv128_f32",
+        "s8192_causal_d128", "s8192_w512_d128", "s8192_causal_d128_f32",
+        "s8192_w512_d128_f32"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
     """Forward + dK/dV + dQ kernels of `_flash_pallas`, bf16, Mosaic, each
     at the blocks `_block_sizes` CHOOSES for the shape: the gate that the
@@ -215,6 +223,28 @@ def test_bert_base_width_train_step_compiles_for_v5e(topo, one_chip,
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
 
 
+def _decoder_step(main, startup, feed, fetches, one_chip):
+    """(the executor's compiled block of a decoder's train step, a
+    function that lowers it for ``one_chip``): start-up runs on the CPU
+    for the shapes alone, and its scope is let go before the compile."""
+    scope = core.Scope()
+    fluid.Executor().run(startup, scope=scope)
+    cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
+                        scope, seed=0)
+
+    def state(names):
+        arrays = {n: scope.find_var(n).get_tensor().array for n in names}
+        return {n: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for n, a in arrays.items()}
+    mut, ro = state(cb.mut_state), state(cb.ro_state)
+    del scope
+    feeds = {n: jax.ShapeDtypeStruct(a.shape, jnp.int32, sharding=one_chip)
+             for n, a in feed.items()}
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
+    return cb, lambda: cb._jitted.lower(mut, ro, feeds, rng)
+
+
 def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
     """The step of `qwen3_next_80b_a3b.b1_s4096` as the executor lowers
     it, at the published widths and the cell's cut (4 layers, 32 of 512
@@ -237,26 +267,10 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
         main, startup, _, fetches = \
             qwen3_next.build_qwen3_next_pretrain_program(cfg, seq_len=4096)
         feed = qwen3_next.synthetic_pretrain_batch(cfg, 1, 4096)
-        scope = core.Scope()
-        fluid.Executor().run(startup, scope=scope)
-        cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
-                            scope, seed=0)
+        cb, lower = _decoder_step(main, startup, feed, fetches, one_chip)
         assert cb._remat_plan is not None and len(
             cb._remat_plan.segments) == 5  # four layers and the head
-
-        def state(names):
-            arrays = {n: scope.find_var(n).get_tensor().array for n in names}
-            return {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                            sharding=one_chip)
-                    for n, a in arrays.items()}
-        mut, ro = state(cb.mut_state), state(cb.ro_state)
-        del scope
-        feeds = {n: jax.ShapeDtypeStruct(a.shape, jnp.int32,
-                                         sharding=one_chip)
-                 for n, a in feed.items()}
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
-        compiled = cb._jitted.lower(mut, ro, feeds, rng).compile()
+        compiled = lower().compile()
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", False)
     text = compiled.as_text()
@@ -310,27 +324,11 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
         main, startup, _, fetches = \
             phi4_flash.build_phi4_flash_pretrain_program(cfg, seq_len=4096)
         feed = phi4_flash.synthetic_pretrain_batch(cfg, 1, 4096)
-        scope = core.Scope()
-        fluid.Executor().run(startup, scope=scope)
-        cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
-                            scope, seed=0)
+        cb, lower = _decoder_step(main, startup, feed, fetches, one_chip)
         plan = cb._remat_plan
         assert plan is not None and len(plan.segments) == 7
         assert [len(s.outs) for s in plan.segments] == [1, 1, 2, 3, 1, 1, 1]
-
-        def state(names):
-            arrays = {n: scope.find_var(n).get_tensor().array for n in names}
-            return {n: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                            sharding=one_chip)
-                    for n, a in arrays.items()}
-        mut, ro = state(cb.mut_state), state(cb.ro_state)
-        del scope
-        feeds = {n: jax.ShapeDtypeStruct(a.shape, jnp.int32,
-                                         sharding=one_chip)
-                 for n, a in feed.items()}
-        key = jax.eval_shape(lambda: jax.random.key(0))
-        rng = jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip)
-        compiled = cb._jitted.lower(mut, ro, feeds, rng).compile()
+        compiled = lower().compile()
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", False)
     text = compiled.as_text()
@@ -364,3 +362,63 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
     assert 4e9 < total < 14.5e9, total
+
+
+def test_laguna_train_step_compiles_for_v5e(one_chip, for_the_chip):
+    """The step of `laguna_xs2.b1_s8192` as the executor lowers it, at
+    the published widths and the cell's cut (five layers: the dense one
+    and a whole period; 32 of 256 experts held; 12,544 rows of
+    vocabulary), 1 x 8192 tokens, bf16 matmul operands, recomputation a
+    layer: six segments, it fits the chip's 16 GB (under 15.75 GB; 12
+    bytes a parameter of arguments), every layer runs the flash kernels
+    at D 128 with ITS head count and mask (48 heads causal on layers 0
+    and 4, 64 heads under window 512 on layers 1-3: forward, again in
+    the recomputed segment, dK/dV and dQ), and every expert product is
+    XLA's grouped kernel over `row_bound` = 16,384 rows (twice the 8,192
+    the 32 held experts of 256 expect; never the 65,536 a layer could be
+    sent). Start-up runs on the CPU for the shapes alone (8.3 GB of host
+    memory)."""
+    from paddle_tpu.fluid import telemetry
+    from paddle_tpu.models import laguna
+    from paddle_tpu.ops import decoder_ops
+    cfg = dict(laguna.laguna_config(), vocab_size=12544, experts_held=32,
+               layer_types=["full", "sliding", "sliding", "sliding", "full"],
+               heads_per_layer=[48, 64, 64, 64, 48],
+               mlp_types=["dense"] + ["sparse"] * 4)
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        main, startup, _, fetches = laguna.build_laguna_pretrain_program(
+            cfg, seq_len=8192)
+        feed = laguna.synthetic_pretrain_batch(cfg, 1, 8192)
+        cb, lower = _decoder_step(main, startup, feed, fetches, one_chip)
+        assert cb._remat_plan is not None and len(
+            cb._remat_plan.segments) == 6  # five layers and the head
+        compiled = lower().compile()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    text = compiled.as_text()
+    grouped = [line for line in text.splitlines()
+               if KERNEL in line and "ragged-dot" in line]
+    flash = _kernel_names("\n".join(
+        line for line in text.splitlines() if line not in grouped))
+    assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2 * 5,
+                     ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 5,
+                     ("fwd/fused_attention_qkv", "flash_bwd_dq"): 5}
+    assert decoder_ops.row_bound(8192, 8, 32, 256) == 16384
+    assert "[16384,1024]" in text and "[65536,1024]" not in text
+    sites = laguna.attention_sites(main)
+    assert list(sites.values()) == [(48, 0), (64, 512), (64, 512),
+                                    (64, 512), (48, 0)]
+    heads = telemetry.REGISTRY.get("attn_query_heads")
+    assert [heads.value(site=s) for s in sites] == [48, 64, 64, 64, 48]
+    width = telemetry.REGISTRY.get("moe_router_width")
+    assert [child.value() for child in width.children()][-4:] == [256] * 4
+    mem = compiled.memory_analysis()
+    parameters = 691623936
+    assert abs(mem.argument_size_in_bytes / (12 * parameters) - 1) < 0.01
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    print("laguna step bytes", total, mem.temp_size_in_bytes,
+          mem.generated_code_size_in_bytes)
+    assert 4e9 < total < 15.75e9, total

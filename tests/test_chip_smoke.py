@@ -64,3 +64,22 @@ def test_multichip_rehearsal_passes_its_placement_assertions(tmp_path):
         "ring_attention": "collective-permute", "moe": "all-to-all"}
     assert all(r["devices"] == 4 for r in legs.values())
     assert "steps" not in phase and "window" not in phase  # no one-chip phase
+
+
+def test_laguna_rehearsal_holds_the_program_to_its_reference(tmp_path):
+    """`--laguna --tiny`: both programs (bf16 operands are float32 ones
+    on a CPU) within the float32 limits of the reference, the reference
+    with bf16 ACTIVATIONS outside them, a pass an expert layer, a head
+    count a layer kind."""
+    res, phase, rows = _run(tmp_path, "--laguna", "--tiny")
+    assert res.returncode != 0 and rows[-1]["rehearsal"], res.stderr[-2000:]
+    parity = phase["laguna_parity"]
+    limits = parity["limits"]["float32"]
+    for errors in parity["errors"].values():
+        assert all(errors[n] <= limits[n] for n in limits)
+    assert any(parity["bf16_activations"][n] > limits[n] for n in limits)
+    step = phase["laguna_step"]
+    assert step["passes"] == [1, 1, 1, 1]
+    assert step["attn_query_heads"] == [4, 8, 8, 8, 4]
+    assert step["attention_heads_and_windows"] == [
+        [4, 0], [8, 24], [8, 24], [8, 24], [4, 0]]
