@@ -4,9 +4,11 @@ conv_op.cc + conv_cudnn_op.cu, pool_op.cc, batch_norm_op.cc, layer_norm_op.cc,
 softmax_op.cc, softmax_with_cross_entropy_op.cc, cross_entropy_op.cc,
 lookup_table_op.cc, dropout_op.cc, interpolate_op.cc …).
 
-conv/pool map to lax.conv_general_dilated / lax.reduce_window so XLA tiles
-them onto the MXU; dropout keeps its reference Mask-output contract so its
-grad is mask-multiply (custom grad op below) rather than a replayed RNG.
+conv maps to lax.conv_general_dilated so XLA tiles it onto the MXU; pool2d /
+pool3d are one lax.reduce_window each (_window_pool), differentiated by JAX
+(select_and_scatter for max, a windowed sum for avg); dropout keeps its
+reference Mask-output contract so its grad is mask-multiply (custom grad op
+below) rather than a replayed RNG.
 """
 from __future__ import annotations
 
@@ -586,54 +588,32 @@ def _conv2d_transpose(ins, attrs):
     return out(Output=o)
 
 
-def _avg_pool_slices(x, ksize, strides, pads, exclusive):
-    """NCHW avg pool as sum over kh·kw strided slices, divided by a static
-    valid-element count map (exclusive=True: pad elements don't count)."""
-    n, c, H, W = x.shape
-    kh, kw = ksize
-    sh, sw = strides
-    (pt, pb), (pl_, pr) = pads
-    xp = jnp.pad(x, [(0, 0), (0, 0), (pt, pb), (pl_, pr)])
-    oh = (H + pt + pb - kh) // sh + 1
-    ow = (W + pl_ + pr - kw) // sw + 1
-    o = None
-    for i in range(kh):
-        for j in range(kw):
-            s = lax.slice(xp, (0, 0, i, j),
-                          (n, c, i + (oh - 1) * sh + 1,
-                           j + (ow - 1) * sw + 1), (1, 1, sh, sw))
-            o = s if o is None else o + s
-    if exclusive and (pt or pb or pl_ or pr):
-        ones = np.zeros((H + pt + pb, W + pl_ + pr), np.float32)
-        ones[pt:pt + H, pl_:pl_ + W] = 1.0
-        cnt = np.zeros((oh, ow), np.float32)
-        for i in range(kh):
-            for j in range(kw):
-                cnt += ones[i:i + (oh - 1) * sh + 1:sh,
-                            j:j + (ow - 1) * sw + 1:sw]
-        cnt = np.maximum(cnt, 1.0)
-        return o / jnp.asarray(cnt, x.dtype)
-    return o / float(kh * kw)
+def _window_pool(x, ptype, exclusive, ksize, strides, pads, lead):
+    """Max / avg pooling over the len(ksize) dims that follow the first
+    `lead` ones, as ONE lax.reduce_window: XLA's windowed reduction forward,
+    and under jax.vjp select_and_scatter (max: the whole gradient goes to a
+    window's FIRST maximum in row-major order, never to padding, as the
+    reference's MaxPool2dGradFunctor does) or a padded windowed sum (avg)."""
+    nd = len(ksize)
+    tail = x.ndim - lead - nd
 
+    def spread(pooled, other):
+        return (other,) * lead + tuple(pooled) + (other,) * tail
 
-def _max_pool_slices(x, ksize, strides, pads, init):
-    """NCHW max pool as max over kh·kw strided slices."""
-    n, c, H, W = x.shape
-    kh, kw = ksize
-    sh, sw = strides
-    (pt, pb), (pl_, pr) = pads
-    xp = jnp.pad(x, [(0, 0), (0, 0), (pt, pb), (pl_, pr)],
-                 constant_values=init)
-    oh = (H + pt + pb - kh) // sh + 1
-    ow = (W + pl_ + pr - kw) // sw + 1
-    o = None
-    for i in range(kh):
-        for j in range(kw):
-            s = lax.slice(xp, (0, 0, i, j),
-                          (n, c, i + (oh - 1) * sh + 1,
-                           j + (ow - 1) * sw + 1), (1, 1, sh, sw))
-            o = s if o is None else jnp.maximum(o, s)
-    return o
+    window = (spread(ksize, 1), spread(strides, 1),
+              spread(map(tuple, pads), (0, 0)))
+    if ptype == "max":
+        init = -np.inf if jnp.issubdtype(x.dtype, jnp.floating) \
+            else jnp.iinfo(x.dtype).min
+        return lax.reduce_window(x, np.array(init, x.dtype), lax.max, *window)
+    zero = np.array(0, x.dtype)
+    o = lax.reduce_window(x, zero, lax.add, *window)
+    if not (exclusive and any(sum(p) for p in pads)):
+        return o / float(np.prod(ksize))
+    # exclusive: pad elements don't count; the divisor is the same windowed
+    # sum over ones, a constant of the shapes
+    ones = jnp.ones(spread(x.shape[lead:lead + nd], 1), x.dtype)
+    return o / jnp.maximum(lax.reduce_window(ones, zero, lax.add, *window), 1)
 
 
 def _pool2d_impl(x, attrs):
@@ -663,33 +643,8 @@ def _pool2d_impl(x, attrs):
     pads = _conv_padding(attrs.get("paddings", [0, 0]),
                          attrs.get("padding_algorithm", "EXPLICIT"),
                          2, ksize, strides, [1, 1], hw)
-    if not ch_last:
-        wdims = (1, 1, ksize[0], ksize[1])
-        wstrides = (1, 1, strides[0], strides[1])
-        wpads = [(0, 0), (0, 0), pads[0], pads[1]]
-    else:
-        wdims = (1, ksize[0], ksize[1], 1)
-        wstrides = (1, strides[0], strides[1], 1)
-        wpads = [(0, 0), pads[0], pads[1], (0, 0)]
-    if ptype == "max":
-        # stacked-slices max (differentiable through jnp.max; the
-        # reduce_window max path lacks a vjp under this jax version)
-        init = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) \
-            else jnp.iinfo(x.dtype).min
-        if ch_last:
-            x_nchw = jnp.transpose(x, (0, 3, 1, 2))
-            o = _max_pool_slices(x_nchw, ksize, strides, pads, init)
-            return jnp.transpose(o, (0, 2, 3, 1))
-        return _max_pool_slices(x, ksize, strides, pads, init)
-    # avg: stacked-slices sum (reduce_window(add) also lacks a vjp here);
-    # the per-window divisor is a static constant map
-    if ch_last:
-        x_nchw = jnp.transpose(x, (0, 3, 1, 2))
-        o = _avg_pool_slices(x_nchw, ksize, strides, pads,
-                             attrs.get("exclusive", True))
-        return jnp.transpose(o, (0, 2, 3, 1))
-    return _avg_pool_slices(x, ksize, strides, pads,
-                            attrs.get("exclusive", True))
+    return _window_pool(x, ptype, attrs.get("exclusive", True), ksize,
+                        strides, pads, 1 if ch_last else 2)
 
 
 @register_op("pool2d", inputs=("X",),
@@ -729,48 +684,9 @@ def _pool3d(ins, attrs):
         return out(Out=red(xr, axis=(3, 5, 7)))
     pads = _conv_padding(attrs.get("paddings"), attrs.get("padding_algorithm"),
                          3, ksize, strides, [1, 1, 1], x.shape[2:])
-    wdims = (1, 1) + tuple(ksize)
-    # stacked-slices pooling: differentiable (reduce_window max/add lack a
-    # vjp under this jax version)
-    is_max = attrs.get("pooling_type", "max") == "max"
-    kd, kh, kw = ksize
-    sd, sh, sw = strides
-    n, c, D, H, W = x.shape
-    init = -jnp.inf if is_max else 0.0
-    xp = jnp.pad(x, [(0, 0), (0, 0), pads[0], pads[1], pads[2]],
-                 constant_values=init)
-    od = (D + sum(pads[0]) - kd) // sd + 1
-    oh = (H + sum(pads[1]) - kh) // sh + 1
-    ow = (W + sum(pads[2]) - kw) // sw + 1
-    o = None
-    for a in range(kd):
-        for i in range(kh):
-            for j in range(kw):
-                s = lax.slice(xp, (0, 0, a, i, j),
-                              (n, c, a + (od - 1) * sd + 1,
-                               i + (oh - 1) * sh + 1,
-                               j + (ow - 1) * sw + 1),
-                              (1, 1, sd, sh, sw))
-                if o is None:
-                    o = s
-                else:
-                    o = jnp.maximum(o, s) if is_max else o + s
-    if is_max:
-        return out(Out=o)
-    if attrs.get("exclusive", True) and any(sum(p) for p in pads):
-        ones = np.zeros((D + sum(pads[0]), H + sum(pads[1]),
-                         W + sum(pads[2])), np.float32)
-        ones[pads[0][0]:pads[0][0] + D, pads[1][0]:pads[1][0] + H,
-             pads[2][0]:pads[2][0] + W] = 1.0
-        cnt = np.zeros((od, oh, ow), np.float32)
-        for a in range(kd):
-            for i in range(kh):
-                for j in range(kw):
-                    cnt += ones[a:a + (od - 1) * sd + 1:sd,
-                                i:i + (oh - 1) * sh + 1:sh,
-                                j:j + (ow - 1) * sw + 1:sw]
-        return out(Out=o / jnp.asarray(np.maximum(cnt, 1.0), x.dtype))
-    return out(Out=o / float(kd * kh * kw))
+    return out(Out=_window_pool(x, attrs.get("pooling_type", "max"),
+                                attrs.get("exclusive", True), ksize, strides,
+                                pads, 2))
 
 
 @register_op("max_pool2d_with_index", inputs=("X",),

@@ -118,6 +118,34 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
         (None, "flash_bwd_dq"): 1}
 
 
+def test_stem_max_pool_compiles_to_one_select_and_scatter_for_v5e(one_chip,
+                                                                for_the_chip):
+    """ResNet's stem pool (3x3 stride 2 pad 1 behind a ReLU) at the cell's
+    size, forward + gradient: the chip's compiler keeps ONE reduce-window
+    and ONE select-and-scatter, the padding inside their windows, and no
+    `pad` of the 822 MB tensor (the nine-slice form it replaced compiled
+    to nine: PR 35)."""
+    import re
+    from paddle_tpu.ops import nn_ops
+    attrs = dict(pooling_type="max", ksize=[3, 3], strides=[2, 2],
+                 paddings=[1, 1])
+    x = jax.ShapeDtypeStruct((256, 64, 112, 112), jnp.float32,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((256, 64, 56, 56), jnp.float32,
+                             sharding=one_chip)
+
+    def both(x, g):
+        o, vjp = jax.vjp(
+            lambda x: nn_ops._pool2d_impl(jax.nn.relu(x), attrs), x)
+        return o, vjp(g)[0]
+
+    text = jax.jit(both).lower(x, g).compile().as_text()
+    ops = re.findall(r"= \S+ ([a-z][\w-]*)\(", text)
+    assert ops.count("reduce-window") == 1
+    assert ops.count("select-and-scatter") == 1
+    assert ops.count("pad") == 0
+
+
 def _kernel_names(text):
     """{(Fluid-op scope, kernel name): custom calls} of a compiled
     module's text: what a device trace of the chip is read by
