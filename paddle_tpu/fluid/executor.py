@@ -2216,6 +2216,14 @@ class Executor:
                 # run() frame: the step is traced under the same Python
                 # stack whether or not anything records.
                 stack.enter_context(_telemetry.trace_scope())
+            if _telemetry.open_step() is None:
+                # the parent of the stage spans, and the step record
+                # (telemetry.STEPS) it leaves; a run() re-entered on this
+                # thread (CompiledProgram._run, a window's per-step
+                # fallback) joins the span and the record that are open
+                stack.enter_context(
+                    _profiler.RecordEvent(_telemetry.RUN_SPAN,
+                                          cat="executor"))
             return self._run(program, feed, fetch_list, scope, return_numpy,
                              use_prune, mesh, param_shardings, n_steps)
 
@@ -2233,6 +2241,7 @@ class Executor:
         if scope is None:
             scope = global_scope()
         feed = feed or {}
+        _telemetry.step_block(program)  # until a compiled block is found
         fetch_names = _to_fetch_names(fetch_list)
         # stale trip verdicts must not gate THIS run's auto-checkpoint
         self._last_step_tripped = False
@@ -2357,6 +2366,8 @@ class Executor:
                 cb, hit = self._find_block(
                     program, feed, fetch_names, scope, feed_lods, mesh,
                     param_shardings, compiled_ok)
+                if cb is not None:
+                    _telemetry.step_block(cb)
             span.args = {"hit": hit}
 
         if cb is not None and cb.kind == "compiled":

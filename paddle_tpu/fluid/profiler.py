@@ -9,9 +9,13 @@ TPU layering:
     eager op and each compiled-step dispatch (operator.cc:948-977 hook
     points). stop_profiler prints the reference-style sorted table and
     writes a chrome://tracing JSON that tools/timeline.py merges/views.
-  * device timeline — jax.profiler XPlane trace (TensorBoard/Perfetto),
-    the DeviceTracer/CUPTI replacement; enabled when state includes the
-    accelerator.
+  * device timeline — every span is a `jax.profiler.TraceAnnotation`, so
+    whoever starts a `jax.profiler` trace (the DeviceTracer/CUPTI
+    replacement) finds the spans there, on the device events' clock;
+    this module starts none.
+  * the step record — the executor-stage spans that close inside an
+    `exe:run` span add their seconds to that run's record
+    (`telemetry.STEPS`), whether or not anything else records.
 """
 from __future__ import annotations
 
@@ -70,8 +74,6 @@ class _ProfilerState:
         self.dropped = 0
         self.lock = threading.Lock()
         self.t0 = 0.0
-        self.trace_dir: Optional[str] = None
-        self.device_tracing = False
         self.depth = 0  # nested profiler()/cuda_profiler() contexts
 
 
@@ -103,11 +105,11 @@ def dropped_events() -> int:
         return _prof.dropped
 
 
-def start_profiler(state="All", tracer_option="Default",
-                   trace_dir="/tmp/paddle_tpu_profile"):
-    """reference profiler.py start_profiler / EnableProfiler. ``state``:
-    'CPU' = host spans only; 'GPU'/'All' also starts the device (XPlane)
-    trace."""
+def start_profiler(state="All", tracer_option="Default"):
+    """reference profiler.py start_profiler / EnableProfiler. ``state``
+    is the reference's argument and every value records host spans: the
+    device's events are `jax.profiler`'s to trace, and the spans are in
+    its trace too."""
     if _prof.enabled:
         _prof.depth += 1  # nested enable: inner stop becomes a no-op pair
         return
@@ -117,13 +119,6 @@ def start_profiler(state="All", tracer_option="Default",
         _prof.dropped = 0
     _prof.enabled = True
     _prof.t0 = time.perf_counter()
-    _prof.device_tracing = state in ("GPU", "All")
-    if _prof.device_tracing:
-        _prof.trace_dir = trace_dir
-        try:
-            jax.profiler.start_trace(trace_dir)
-        except RuntimeError:
-            _prof.device_tracing = False
 
 
 def stop_profiler(sorted_key: Optional[str] = None,
@@ -137,10 +132,6 @@ def stop_profiler(sorted_key: Optional[str] = None,
     if _prof.depth > 0:  # inner context of a nested session: keep going
         return
     _prof.enabled = False
-    if _prof.device_tracing:
-        jax.profiler.stop_trace()
-        print(f"[profiler] device XPlane trace in {_prof.trace_dir} "
-              f"(TensorBoard / Perfetto)")
     with _prof.lock:
         events = list(_prof.events)
         dropped = _prof.dropped
@@ -254,10 +245,16 @@ def concurrent_seconds(cat_a: str, cat_b: str, events=None) -> float:
     return total
 
 
+# the categories of the spans a step record reads: the executor's stages
+# and the window and segment dispatches
+_STEP_CATS = frozenset(("executor", "window", "segment"))
+
+
 class RecordEvent:
     """RAII span (reference platform/profiler.h:124). Usable as a context
     manager or decorator; when profiling is off it is a bare
-    `jax.profiler.TraceAnnotation` and records nothing here. ``cat`` groups
+    `jax.profiler.TraceAnnotation` and records nothing here, but for what
+    a stage of `Executor.run` adds to the step record. ``cat`` groups
     spans in the chrome trace — the segmented executor emits its
     per-segment compile/exec and island spans under cat='segment' so the
     compiled/interpreted partition of a step is visible at a glance,
@@ -280,17 +277,30 @@ class RecordEvent:
         self.cat = cat
         self.args = args
         self._start = 0.0
+        self._recording = False
+        self._step = None   # the open step record this span reports to
+        self._opened = False  # ... which this span opened itself
 
     def __enter__(self):
         # always a TraceAnnotation, as JAX instruments its own dispatch
         # path: outside a jax.profiler trace it records nothing (well
-        # under a microsecond), inside one — this module's session, a
-        # benchmark's, an operator's jax.profiler.start_server — the
-        # span is on the device events' clock with no call into the
-        # program. The ring and the FLAGS_trace_dir shard stay gated.
+        # under a microsecond), inside one — a benchmark's, an operator's
+        # jax.profiler.start_server — the span is on the device events'
+        # clock with no call into the program. The ring and the
+        # FLAGS_trace_dir shard stay gated.
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
-        if is_profiling():
+        self._recording = is_profiling()
+        if self.cat in _STEP_CATS:
+            # the step record (telemetry.STEPS): a span that closes
+            # inside an `exe:run` span reports to that run's record; an
+            # `exe:run` span with none open on the thread opens one
+            self._step = telemetry.open_step()
+            if self._step is None and self.name == telemetry.RUN_SPAN:
+                self._step, self._opened = telemetry.begin_step(), True
+        if self._opened:
+            self._start = self._step.t0  # one clock read, the record's
+        elif self._recording or self._step is not None:
             self._start = time.perf_counter()
         return self
 
@@ -299,8 +309,16 @@ class RecordEvent:
         # gate on the per-span state, not the global flag: a span that a
         # stop_profiler lands in is still recorded whole
         if self._start:
-            _record(self.name, self._start, time.perf_counter(), self.cat,
-                    self.args)
+            end = time.perf_counter()
+            if self._recording:
+                _record(self.name, self._start, end, self.cat, self.args)
+            step, self._step = self._step, None
+            if step is not None:
+                if self._opened:
+                    self._opened = False
+                    telemetry.end_step(step, end)
+                else:
+                    step.add(self.name, end - self._start, self.args)
             self._start = 0.0
         return False
 
@@ -390,6 +408,6 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile",
 
 @contextlib.contextmanager
 def cuda_profiler(output_file=None, output_mode=None, config=None):
-    # accelerator profiler alias — same device trace
+    # the reference's accelerator profiler: the same host spans here
     with profiler(state="All", profile_path=output_file or "/tmp/profile"):
         yield

@@ -28,6 +28,8 @@ serving — can depend on it without cycles.
 from __future__ import annotations
 
 import atexit
+import gc
+import itertools
 import json
 import logging
 import os
@@ -35,7 +37,8 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from statistics import median as _median
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from . import core
 
@@ -46,6 +49,7 @@ __all__ = [
     "process_role", "shard_active", "shard_record", "flush_trace_shard",
     "start_metrics_server", "maybe_start_metrics_server",
     "metrics_server_port", "count_compile", "install_jax_compile_listener",
+    "StepRecord", "STEPS", "SLOW_STEPS", "open_step", "step_summary",
 ]
 
 _LOG = logging.getLogger("paddle_tpu.telemetry")
@@ -556,6 +560,12 @@ def install_jax_compile_listener() -> bool:
             REGISTRY.counter(name, help_).inc(float(duration))
             if span == "compile:backend":
                 REGISTRY.counter(*compiles).inc()
+            if span != "compile:lower":
+                with _JAX_TOTALS_LOCK:  # threads may compile at once
+                    if span == "compile:trace":
+                        _JAX_TOTALS[0] += float(duration)
+                    else:
+                        _JAX_TOTALS[1] += 1
             from . import profiler as _profiler
             if _profiler.is_profiling():
                 now = time.perf_counter()
@@ -574,8 +584,275 @@ def install_jax_compile_listener() -> bool:
 
         _mon.register_event_duration_secs_listener(_on_duration)
         _mon.register_event_listener(_on_event)
+        _install_gc_probe()
         _JAX_LISTENER_INSTALLED = True
         return True
+
+
+# ---------------------------------------------------------------------------
+# the step record (docs/OBSERVABILITY.md "Step record")
+# ---------------------------------------------------------------------------
+# One record an `Executor.run`, made by the `exe:run` span
+# (`profiler.RecordEvent`) with no session, shard or flag on: the six
+# stage spans' seconds, the caller's time before the call, the thread's
+# CPU time, the collector's pauses and what JAX traced or compiled
+# meanwhile. The newest STEP_RING of them are kept; a step far over its
+# block's median period says where it sat (`_flag_slow`).
+RUN_SPAN = "exe:run"
+STEP_RING = 4096  # the fastest benchmark cell makes 365 steps a window
+# a step is slow when its period (since_prev_s + run_s) is over this
+# many medians of its block's periods AND over the median by this much
+SLOW_STEP_FACTOR = 3.0
+SLOW_STEP_EXCESS_S = 0.050
+_MEDIAN_AFTER = 8    # a block's first records hold its compiles: not judged
+_MEDIAN_EVERY = 64   # records between two readings of a block's median
+
+STEPS: deque = deque(maxlen=STEP_RING)
+SLOW_STEPS: deque = deque(maxlen=32)  # kept past the ring's wrap
+_STEPS_LOCK = threading.Lock()
+_STEP_TLS = threading.local()  # .open: the open record; .prev: a _Mark
+_SEQ = itertools.count(1)
+_BLOCK_IDS = itertools.count(1)
+# block -> [the median period of its last _MEDIAN_EVERY records (of its
+# first _MEDIAN_AFTER at first; None before that), the periods since]
+_BLOCKS: Dict[int, list] = {}
+_LOG_EXECUTOR = logging.getLogger("paddle_tpu.executor")
+
+# [seconds, collections of generation 0, 1, 2]: the collector's probe is
+# their one writer, and collections do not overlap
+_GC_TOTALS = [0.0, 0, 0, 0]
+# [seconds tracing, backend compiles] as the jax.monitoring listener
+# counts them
+_JAX_TOTALS = [0.0, 0]
+_JAX_TOTALS_LOCK = threading.Lock()
+
+_STAGE_FIELD = {"exe:feed": "feed_s", "exe:lookup": "lookup_s",
+                "exe:place": "place_s", "compiled_step": "dispatch_s",
+                "exe:write_back": "write_back_s", "exe:fetch": "fetch_s"}
+_STAGES = tuple(_STAGE_FIELD.values())
+
+
+class StepRecord:
+    """What one `Executor.run` left (the field table is in
+    docs/OBSERVABILITY.md). Seconds are `time.perf_counter` differences;
+    an absent stage reads 0."""
+
+    __slots__ = ("seq", "block", "t0", "run_s") + _STAGES + (
+        "since_prev_s", "cpu_s", "gc_s", "gc_gen", "jax_trace_s",
+        "compiles", "feed_bytes", "placed")
+
+    def __init__(self, seq: int, t0: float, since_prev_s: float):
+        self.seq, self.t0, self.since_prev_s = seq, t0, since_prev_s
+        self.run_s = self.feed_s = self.lookup_s = self.place_s = \
+            self.dispatch_s = self.write_back_s = self.fetch_s = \
+            self.cpu_s = self.gc_s = self.jax_trace_s = 0.0
+        self.block = self.compiles = self.feed_bytes = self.placed = 0
+        self.gc_gen = -1  # no collection ended in the interval
+
+    def add(self, name: str, seconds: float, args) -> None:
+        """A span that closed inside this run: a stage adds its seconds
+        to its field. The window and segment spans are the dispatch of
+        their paths; `window[K]:fallback` is not, it wraps whole runs
+        that joined this record."""
+        field = _STAGE_FIELD.get(name)
+        if field is None:
+            if not name.startswith(("window[", "segment[")) \
+                    or name.endswith(":fallback"):
+                return
+            field = "dispatch_s"
+        setattr(self, field, getattr(self, field) + seconds)
+        if args:
+            self.feed_bytes += args.get("bytes", 0)
+            self.placed += args.get("placed", 0)
+
+    @property
+    def self_s(self) -> float:
+        """`run_s` less the stages: the time between them."""
+        return self.run_s - sum(getattr(self, f) for f in _STAGES)
+
+    @property
+    def period_s(self) -> float:
+        """From the run before's return to this one's."""
+        return self.since_prev_s + self.run_s
+
+    def as_dict(self) -> Dict[str, Any]:
+        out = {name: getattr(self, name) for name in self.__slots__}
+        out["self_s"] = self.self_s
+        return out
+
+
+# every field a summary takes a median of: all but the three that name
+# the record
+_SUMMARY_FIELDS = StepRecord.__slots__[3:] + ("self_s",)
+
+
+def open_step() -> Optional[StepRecord]:
+    """The record of the `exe:run` span open on THIS thread, or None."""
+    return getattr(_STEP_TLS, "open", None)
+
+
+def step_block(owner) -> None:
+    """Name the open record's block: a number `owner` (the compiled
+    block, or the Program where there is none) keeps for its life, so
+    that a reader tells the step from the start-up program and a new
+    block never inherits another's median."""
+    rec = open_step()
+    if rec is not None:
+        block = owner.__dict__.get("_step_block")
+        if block is None:
+            block = owner.__dict__["_step_block"] = next(_BLOCK_IDS)
+        rec.block = block
+
+
+class _Mark(NamedTuple):
+    """What a thread and the process had counted when a run returned: the
+    next run's record holds the differences."""
+    end: float          # time.perf_counter()
+    cpu: float          # time.thread_time()
+    gc_s: float
+    collections: tuple  # of generation 0, 1, 2
+    jax_trace_s: float
+    compiles: int
+
+
+def _mark(end: float) -> _Mark:
+    return _Mark(end, time.thread_time(), _GC_TOTALS[0],
+                 tuple(_GC_TOTALS[1:]), *_JAX_TOTALS)
+
+
+def begin_step() -> StepRecord:
+    """Open this thread's record (`RecordEvent` does, entering an
+    `exe:run` span where none is open)."""
+    t0 = time.perf_counter()
+    prev = getattr(_STEP_TLS, "prev", None)
+    if prev is None:
+        # the thread's first run: no caller's time before it
+        prev = _STEP_TLS.prev = _mark(t0)
+    rec = _STEP_TLS.open = StepRecord(next(_SEQ), t0, t0 - prev.end)
+    return rec
+
+
+def end_step(rec: StepRecord, end: float) -> None:
+    """Close the record at `end`, the `exe:run` span's own end: what the
+    thread and the process counted since the run before returned goes
+    in, the record into the ring, and a slow one is flagged."""
+    _STEP_TLS.open = None
+    prev = _STEP_TLS.prev
+    now = _STEP_TLS.prev = _mark(end)
+    rec.run_s = end - rec.t0
+    rec.cpu_s = now.cpu - prev.cpu
+    rec.gc_s = now.gc_s - prev.gc_s
+    rec.gc_gen = max((g for g in range(3)
+                      if now.collections[g] > prev.collections[g]),
+                     default=-1)
+    rec.jax_trace_s = now.jax_trace_s - prev.jax_trace_s
+    rec.compiles = now.compiles - prev.compiles
+    period = rec.period_s
+    with _STEPS_LOCK:
+        STEPS.append(rec)
+        stats = _BLOCKS.get(rec.block)
+        if stats is None:
+            stats = _BLOCKS[rec.block] = [None, []]
+        typical, periods = stats
+        periods.append(period)
+        if len(periods) == (_MEDIAN_AFTER if typical is None
+                            else _MEDIAN_EVERY):
+            stats[0] = _median(periods)
+            periods.clear()
+    if typical is not None and period > SLOW_STEP_FACTOR * typical \
+            and period - typical > SLOW_STEP_EXCESS_S:
+        _flag_slow(rec)
+
+
+def _flag_slow(rec: StepRecord) -> None:
+    """Counted, kept, an `exe:slow_step` instant where spans are
+    recorded, and ONE warning line with the record beside its block's
+    medians: an untraced run still leaves the finding on stderr."""
+    REGISTRY.counter(*_SLOW_STEPS_TOTAL).inc()
+    with _STEPS_LOCK:
+        SLOW_STEPS.append(rec)
+    from . import profiler as _profiler
+    _profiler.record_instant("exe:slow_step", cat="executor",
+                             args=rec.as_dict())
+    with _STEPS_LOCK:
+        siblings = [r for r in STEPS if r.block == rec.block]
+    block = _summarise(siblings)
+    _LOG_EXECUTOR.warning(
+        "slow step: %s; medians of block %s over %d records: %s",
+        json.dumps(rec.as_dict()), rec.block, block["n"],
+        json.dumps(block["median"]))
+
+
+def _summarise(records: List[StepRecord]) -> Dict[str, Any]:
+    """Median and nearest-rank 95th percentile of every field."""
+    columns = {f: sorted(getattr(r, f) for r in records)
+               for f in _SUMMARY_FIELDS}
+    rank = max(0, -(-95 * len(records) // 100) - 1)  # ceil(0.95 n) - 1
+    return {"n": len(records),
+            "median": {f: _median(v) for f, v in columns.items()},
+            "p95": {f: v[rank] for f, v in columns.items()}}
+
+
+def step_summary() -> Dict[str, Any]:
+    """Median and nearest-rank 95th percentile of every field over the
+    ring, by block, and the slow records: what `flush_trace_shard`
+    writes into the shard's metadata."""
+    with _STEPS_LOCK:
+        records, slow = list(STEPS), list(SLOW_STEPS)
+    by_block: Dict[int, List[StepRecord]] = {}
+    for r in records:
+        by_block.setdefault(r.block, []).append(r)
+    return {"blocks": {str(block): _summarise(rs)
+                       for block, rs in by_block.items()},
+            "slow": [r.as_dict() for r in slow]}
+
+
+_GC_SECONDS = ("python_gc_seconds_total",
+               "seconds the cyclic collector held the interpreter, by the "
+               "generation it collected")
+_GC_COLLECTIONS = ("python_gc_collections_total",
+                   "collections of the cyclic collector, by generation")
+_SLOW_STEPS_TOTAL = (
+    "executor_slow_steps_total",
+    "Executor.run calls whose period was far over their block's median "
+    "(telemetry.SLOW_STEP_FACTOR, SLOW_STEP_EXCESS_S)")
+
+
+def _install_gc_probe() -> None:
+    """The `gc.callbacks` entry (installed with the jax.monitoring
+    listeners, once a process): times each collection into the registry
+    and the step record's totals, and holds a `TraceAnnotation
+    ("gc:gen<N>")` over the pause, so that a profiler trace shows it on
+    the device events' clock. The probe runs INSIDE the collector, at
+    whatever allocation set it off: it takes no lock another frame of
+    the thread may hold (the registry's children are found here, once;
+    a child's own lock guards no allocation)."""
+    from jax.profiler import TraceAnnotation
+    names = tuple(f"gc:gen{g}" for g in range(3))
+    seconds = [REGISTRY.counter(*_GC_SECONDS, labelnames=("generation",))
+               .labels(generation=g) for g in range(3)]
+    collections = [
+        REGISTRY.counter(*_GC_COLLECTIONS, labelnames=("generation",))
+        .labels(generation=g) for g in range(3)]
+    REGISTRY.counter(*_SLOW_STEPS_TOTAL)  # a scrape before the first: 0
+    pause = [0.0, None]  # the collection in progress: start, annotation
+
+    def _on_gc(phase: str, info: dict) -> None:
+        gen = info["generation"]
+        if phase == "start":
+            pause[1] = TraceAnnotation(names[gen])
+            pause[1].__enter__()
+            pause[0] = time.perf_counter()
+        elif pause[1] is not None:
+            took = time.perf_counter() - pause[0]
+            pause[1].__exit__(None, None, None)
+            pause[1] = None
+            _GC_TOTALS[0] += took
+            _GC_TOTALS[1 + gen] += 1
+            seconds[gen].inc(took)
+            collections[gen].inc()
+
+    gc.callbacks.append(_on_gc)
 
 
 # ---------------------------------------------------------------------------
@@ -736,6 +1013,7 @@ class _ShardWriter:
             "anchor_wall_us": self._anchor_wall * 1e6,
             "anchor_perf_us": self._anchor_perf * 1e6,
             "dropped_events": dropped,
+            "step_summary": step_summary(),
             "peer_offsets": {
                 ep: {"offset_us": off * 1e6, "rtt_us": rtt * 1e6}
                 for ep, (off, rtt) in clock_offsets().items()},

@@ -798,3 +798,271 @@ def test_cluster_timeline_merge_wide_deep_2x2_acceptance(tmp_path):
                 parent["ts"] + parent["dur"] + slack, (tid, parent, h)
     # rounds from BOTH trainers must have linked trainer→pserver traces
     assert linked >= 4, (linked, summary)
+
+
+# ======================================================================
+# the step record (docs/OBSERVABILITY.md "Step record")
+# ======================================================================
+STAGE_FIELDS = ("feed_s", "lookup_s", "place_s", "dispatch_s",
+                "write_back_s", "fetch_s")
+
+
+class HookedFeed:
+    """A feed value that runs `hook` where the executor makes an array
+    of it, inside `exe:feed`."""
+
+    def __init__(self, value, hook):
+        self.value, self.hook = value, hook
+
+    def __array__(self, dtype=None, copy=None):
+        self.hook()
+        return self.value
+
+
+def _step_program(width=8, stateful=False):
+    import paddle_tpu.fluid as fluid
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.data("x", shape=[width], dtype="float32")
+        y = fluid.data("y", shape=[1], dtype="int64")
+        h = fluid.layers.fc(x, 16, act="relu")
+        if stateful:  # no whole-block jit: the step runs in segments
+            arr = fluid.layers.create_array("float32")
+            i = fluid.layers.fill_constant([1], "int64", 0)
+            fluid.layers.array_write(h, i, arr)
+        p = fluid.layers.fc(h, 4, act="softmax")
+        loss = fluid.layers.mean(fluid.layers.cross_entropy(p, y))
+        fluid.optimizer.SGD(0.1).minimize(loss)
+    feed = {"x": np.ones((4, width), np.float32),
+            "y": np.zeros((4, 1), np.int64)}
+    return main, startup, loss, feed
+
+
+def _started(main, startup):
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import core
+    exe, scope = fluid.Executor(), core.Scope()
+    exe.run(startup, scope=scope)
+    return exe, scope
+
+
+def _records_since(seq):
+    from paddle_tpu.fluid import telemetry
+    return [r for r in list(telemetry.STEPS) if r.seq > seq]
+
+
+@pytest.mark.parametrize("path", [
+    "one_dispatch", "window", "window_of_batches", "segmented",
+    "interpreted", "compiled_program", "window_fallback"])
+def test_every_run_leaves_one_step_record_with_nothing_switched_on(path):
+    """No session, no shard, no flag: a record a run on every path
+    through `Executor._run`, the stages' sum inside `run_s`; a run that
+    re-enters `run()` on its thread joins the record that is open."""
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.fluid import core, profiler, telemetry
+    main, startup, loss, feed = _step_program(
+        stateful=path in ("segmented", "window_fallback"))
+    stacked = {k: np.stack([v] * 3) for k, v in feed.items()}
+    kwargs, mode, dispatches = {"feed": feed}, "compiled", True
+    if path == "window":
+        kwargs["n_steps"] = 3
+    elif path == "window_of_batches":
+        kwargs.update(feed=stacked, n_steps=3)
+    elif path == "segmented":
+        mode = "segmented"
+    elif path == "window_fallback":  # three inner runs, one record
+        kwargs.update(feed=stacked, n_steps=3)
+        mode = "segmented"
+    elif path == "interpreted":
+        core.globals_["FLAGS_executor_mode"] = "interpreted"
+        mode, dispatches = "interpreted", False
+    try:
+        exe, scope = _started(main, startup)
+        assert not profiler.is_profiling()
+        startup_record = telemetry.STEPS[-1]
+        program = fluid.CompiledProgram(main) \
+            if path == "compiled_program" else main
+        for _ in range(4):
+            exe.run(program, fetch_list=[loss], scope=scope, **kwargs)
+    finally:
+        core.globals_["FLAGS_executor_mode"] = "compiled"
+    assert exe._last_run_mode == mode
+    records = _records_since(startup_record.seq)
+    assert len(records) == 4
+    assert [r.seq for r in records] == list(range(records[0].seq,
+                                                  records[0].seq + 4))
+    assert len({r.block for r in records}) == 1
+    assert records[0].block != startup_record.block
+    assert records[0].compiles > 0 or path == "interpreted"
+    for prev, r in zip(records, records[1:]):
+        stages = sum(getattr(r, f) for f in STAGE_FIELDS)
+        assert 0 < stages <= r.run_s and r.self_s == r.run_s - stages
+        assert r.since_prev_s >= 0
+        assert r.t0 == pytest.approx(prev.t0 + prev.run_s + r.since_prev_s,
+                                     abs=1e-6)
+        assert 0 <= r.cpu_s and r.gc_s >= 0
+        assert (r.dispatch_s > 0) == dispatches
+        # float32 and, on the device, int32: four bytes an element
+        assert r.feed_s > 0 and r.feed_bytes == sum(
+            4 * v.size for v in kwargs["feed"].values())
+    assert telemetry.open_step() is None
+
+
+def test_the_step_ring_holds_4096_records_and_drops_the_oldest():
+    from paddle_tpu.fluid import profiler, telemetry
+    assert telemetry.STEP_RING == telemetry.STEPS.maxlen == 4096
+    for _ in range(4100):
+        with profiler.RecordEvent(telemetry.RUN_SPAN, cat="executor"):
+            pass
+    records = list(telemetry.STEPS)
+    assert len(records) == 4096
+    assert records[0].seq == records[-1].seq - 4095
+
+
+def test_a_collection_inside_a_step_is_in_its_record_and_the_registry():
+    import gc
+    from paddle_tpu.fluid import telemetry
+    main, startup, loss, feed = _step_program()
+    exe, scope = _started(main, startup)
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+
+    def counters():
+        return [telemetry.REGISTRY.get(name).value(generation=2) for name
+                in ("python_gc_seconds_total",
+                    "python_gc_collections_total")]
+    before = counters()
+    hooked = dict(feed, x=HookedFeed(feed["x"], gc.collect))
+    exe.run(main, feed=hooked, fetch_list=[loss], scope=scope)
+    record = telemetry.STEPS[-1]
+    after = counters()
+    assert record.gc_gen == 2
+    assert 0 < record.gc_s <= record.feed_s <= record.run_s
+    assert after[1] == before[1] + 1
+    assert after[0] - before[0] == pytest.approx(record.gc_s, rel=0.5)
+    # and the step after it held none of generation 2
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    assert telemetry.STEPS[-1].gc_gen < 2
+
+
+def test_a_slow_step_is_flagged_once_and_says_where_it_sat(caplog):
+    """A feed hook sleeps in a block's 3rd step and in its 12th: the
+    first 8 records of a block are never judged, the 12th is flagged —
+    counted, kept, one warning line — with the sleep in `feed_s`."""
+    from paddle_tpu.fluid import profiler, telemetry
+    main, startup, loss, feed = _step_program()
+    exe, scope = _started(main, startup)
+    slow_feed = dict(feed, x=HookedFeed(feed["x"], lambda: time.sleep(0.2)))
+    counter = telemetry.REGISTRY.get("executor_slow_steps_total")
+    before, kept = counter.value(), len(telemetry.SLOW_STEPS)
+    profiler.start_profiler(state="CPU")
+    try:
+        with caplog.at_level("WARNING", logger="paddle_tpu.executor"):
+            for step in range(1, 15):
+                exe.run(main, feed=slow_feed if step in (3, 12) else feed,
+                        fetch_list=[loss], scope=scope)
+        instants = [e for e in profiler.snapshot_events()
+                    if e["name"] == "exe:slow_step"]
+    finally:
+        profiler.stop_profiler(profile_path="")
+    records = list(telemetry.STEPS)[-14:]
+    assert records[2].feed_s >= 0.2 and records[11].feed_s >= 0.2
+    # (a loaded machine may stall another step of the fourteen past the
+    # rule's 50 ms: it is then flagged too, and as truly)
+    flagged = list(telemetry.SLOW_STEPS)[kept:]
+    assert flagged.count(records[11]) == 1
+    assert not set(flagged) & set(records[:8])
+    assert counter.value() == before + len(flagged)
+    lines = [r.getMessage() for r in caplog.records
+             if r.name == "paddle_tpu.executor" and r.levelname == "WARNING"]
+    assert len(lines) == len(flagged)
+    (text,) = [t for t in lines if f'"seq": {records[11].seq},' in t]
+    assert text.startswith("slow step: ") and "\n" not in text
+    assert "medians of block" in text
+    (instant,) = [e for e in instants
+                  if e["args"]["seq"] == records[11].seq]
+    assert instant["cat"] == "executor"
+    assert instant["args"]["feed_s"] == records[11].feed_s
+    summary = telemetry.step_summary()
+    assert records[11].seq in [r["seq"] for r in summary["slow"]]
+    # the caller's own time counts too: it is the step's period that is
+    # judged, and `since_prev_s` that holds it
+    time.sleep(0.2)
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    assert telemetry.SLOW_STEPS[-1] is telemetry.STEPS[-1]
+    assert telemetry.STEPS[-1].since_prev_s >= 0.2
+
+
+def test_two_threads_running_two_executors_keep_their_records_apart():
+    from paddle_tpu.fluid import telemetry
+    steps, widths = 12, (8, 24)
+    programs = [_step_program(width) for width in widths]
+    ready = [_started(main, startup) for main, startup, _, _ in programs]
+    seq = telemetry.STEPS[-1].seq
+    errors = []
+
+    def loop(k):
+        try:
+            (main, _, loss, feed), (exe, scope) = programs[k], ready[k]
+            for _ in range(steps):
+                exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+        except Exception as e:  # surfaced below
+            errors.append(e)
+    threads = [threading.Thread(target=loop, args=(k,)) for k in (0, 1)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not errors and not any(t.is_alive() for t in threads)
+    by_block = {}
+    for r in _records_since(seq):
+        by_block.setdefault(r.block, []).append(r)
+    assert sorted(len(rs) for rs in by_block.values()) == [steps, steps]
+    sizes = set()
+    for rs in by_block.values():
+        (nbytes,) = {r.feed_bytes for r in rs}  # its own feed, every step
+        sizes.add(nbytes)
+        assert rs[0].since_prev_s == 0  # the thread's first run
+        for prev, r in zip(rs, rs[1:]):
+            # each thread's clock: a record starts where the one before
+            # it on THAT thread ended, whatever the other thread did
+            assert r.t0 == pytest.approx(
+                prev.t0 + prev.run_s + r.since_prev_s, abs=1e-6)
+            assert sum(getattr(r, f) for f in STAGE_FIELDS) <= r.run_s
+    assert sizes == {4 * w * 4 + 4 * 4 for w in widths}
+
+
+def test_step_summary_round_trips_through_the_shards_metadata(tmp_path):
+    """`FLAGS_trace_dir`: the shard holds the six stages inside one
+    `exe:run` a step, and its metadata the summary of the ring."""
+    from paddle_tpu.fluid import core, telemetry
+    main, startup, loss, feed = _step_program()
+    exe, scope = _started(main, startup)
+    core.globals_["FLAGS_trace_dir"] = str(tmp_path)
+    for _ in range(5):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    shard = json.load(open(telemetry.flush_trace_shard()))
+    summary = telemetry.step_summary()
+    assert shard["metadata"]["step_summary"] == json.loads(
+        json.dumps(summary))
+    block = summary["blocks"][str(telemetry.STEPS[-1].block)]
+    mine = [r for r in telemetry.STEPS
+            if r.block == telemetry.STEPS[-1].block]
+    assert block["n"] == len(mine) == 5
+    assert block["median"]["run_s"] == sorted(r.run_s for r in mine)[2]
+    assert block["p95"]["run_s"] == max(r.run_s for r in mine)
+    assert set(block["median"]) == set(block["p95"]) \
+        == set(telemetry.StepRecord.__slots__[3:]) | {"self_s"}
+    events = [e for e in shard["traceEvents"] if e["cat"] == "executor"]
+    runs = [e for e in events if e["name"] == "exe:run"]
+    assert len(runs) == 5 and len(events) == 5 * 7
+    for run, record in zip(runs, mine):
+        assert run["ts"] == pytest.approx(record.t0 * 1e6)
+        inside = [e["name"] for e in events if e is not run
+                  and run["ts"] <= e["ts"]
+                  and e["ts"] + e["dur"] <= run["ts"] + run["dur"]]
+        assert inside == ["exe:feed", "exe:lookup", "exe:place",
+                          "compiled_step", "exe:write_back", "exe:fetch"]
+        assert {e["args"]["trace_id"] for e in events
+                if e["ts"] >= run["ts"]
+                and e["ts"] <= run["ts"] + run["dur"]} \
+            == {run["args"]["trace_id"]}

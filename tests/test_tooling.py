@@ -171,7 +171,8 @@ def test_profiler_session_does_not_change_the_lowered_step():
 def test_stage_spans_once_a_step_nested_in_one_trace(tmp_path):
     """The six stage spans of the compiled one-dispatch path, in a
     session's events: once a step, in order, inside one another's gaps
-    and never overlapping, one trace id a step."""
+    and never overlapping, all inside the step's one `exe:run` span, one
+    trace id a step."""
     main, startup, loss, feed = _train_program(fluid.optimizer.SGD(0.1))
     exe, scope = fluid.Executor(), core.Scope()
     exe.run(startup, scope=scope)
@@ -184,6 +185,8 @@ def test_stage_spans_once_a_step_nested_in_one_trace(tmp_path):
             exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
         events = [e for e in profiler.snapshot_events()
                   if e["cat"] == "executor"]
+    runs = [e for e in events if e["name"] == "exe:run"]
+    events = [e for e in events if e["name"] != "exe:run"]
     assert sorted(e["name"] for e in events) == sorted(stages * 3)
     events.sort(key=lambda e: e["start"])
     assert [e["name"] for e in events] == stages * 3
@@ -192,6 +195,14 @@ def test_stage_spans_once_a_step_nested_in_one_trace(tmp_path):
     for e in events:
         by_trace.setdefault(e["trace_id"], []).append(e["name"])
     assert None not in by_trace and list(by_trace.values()) == [stages] * 3
+    # the parent: one a step, around that step's six and no other's
+    runs.sort(key=lambda e: e["start"])
+    assert [r["trace_id"] for r in runs] == list(by_trace)
+    assert all(a["end"] <= b["start"] for a, b in zip(runs, runs[1:]))
+    for i, run in enumerate(runs):
+        inside = [e for e in events
+                  if run["start"] <= e["start"] and e["end"] <= run["end"]]
+        assert inside == events[6 * i:6 * i + 6]
     feed_span = events[0]
     assert feed_span["args"] == {"arrays": 2, "bytes": 4 * 8 * 4 + 4 * 4}
     assert events[1]["args"] == {"hit": True}
@@ -199,6 +210,61 @@ def test_stage_spans_once_a_step_nested_in_one_trace(tmp_path):
     # outside a session nothing is recorded, and nothing syncs
     exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
     assert not profiler.is_profiling()
+
+
+def test_a_jax_profiler_trace_holds_exe_run_its_stages_and_a_gc_pause(
+        tmp_path):
+    """Whoever starts `jax.profiler` finds the step there with no call
+    into the program: `exe:run` around its six stages and a `gc:gen2`
+    annotation over a collection, on one clock; and a step record's
+    `t0`, mapped to wall time by a (wall, perf) anchor pair as the shard
+    takes one, is where the trace has that run's `exe:run` start."""
+    import gc
+    import glob
+    import time
+    import jax
+    from jax.profiler import ProfileData
+    from paddle_tpu.fluid import telemetry
+    main, startup, loss, feed = _train_program(fluid.optimizer.SGD(0.1))
+    exe, scope = fluid.Executor(), core.Scope()
+    exe.run(startup, scope=scope)
+    for _ in range(2):
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+    anchor_wall, anchor_perf = time.time(), time.perf_counter()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for step in range(3):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+            if step == 1:
+                gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    records = list(telemetry.STEPS)[-3:]
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    stages = {"exe:feed", "exe:lookup", "exe:place", "compiled_step",
+              "exe:write_back", "exe:fetch"}
+    events, started_ns = [], None
+    for plane in ProfileData.from_file(path).planes:
+        started_ns = dict(plane.stats).get("profile_start_time", started_ns)
+        for line in plane.lines:
+            events += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                       for e in line.events
+                       if e.name in stages | {"exe:run", "gc:gen2"}]
+    runs = sorted(e for e in events if e[2] == "exe:run")
+    assert len(runs) == 3
+    for (start, end, _), record in zip(runs, records):
+        inside = sorted(e for e in events if e[2] in stages
+                        and start <= e[0] and e[1] <= end)
+        assert {e[2] for e in inside} == stages and len(inside) == 6
+        # an event starts `start` ns after the profile did, by the wall
+        wall = anchor_wall + (record.t0 - anchor_perf)
+        assert abs((started_ns + start) * 1e-9 - wall) < 5e-3
+        assert (end - start) * 1e-9 == pytest.approx(record.run_s, abs=1e-3)
+    (pause,) = [e for e in events if e[2] == "gc:gen2"]
+    assert runs[1][1] <= pause[0] and pause[1] <= runs[2][0]
+    assert (pause[1] - pause[0]) * 1e-9 == pytest.approx(
+        records[2].gc_s, rel=0.5)
+    assert records[2].gc_gen == 2
 
 
 # ----------------------------------------------------------------- timeline
