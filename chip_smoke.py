@@ -344,7 +344,8 @@ def _phi4_flash_counters(main):
     """Nothing further to fetch; the gauges the new ops set: the block
     pairs each attention layer's forward kernel visits (window, full,
     cross) and the steps of its grid, at the blocks chosen for the call,
-    and the chunks each scan steps over."""
+    the chunks each scan steps over, and the steps of its forward
+    kernel's grid (None where the `lax.scan` lowering ran)."""
     from paddle_tpu.models import phi4_flash
     sites = phi4_flash.attention_sites(main)
     scans = [op.attr("site") for op in main.global_block().ops
@@ -357,7 +358,9 @@ def _phi4_flash_counters(main):
                 "attn_kv_blocks_per_step", sites),
             attn_grid_steps_per_step=_gauge_by_site(
                 "attn_grid_steps_per_step", sites),
-            ssm_chunks_per_step=_gauge_by_site("ssm_chunks_per_step", scans))
+            ssm_chunks_per_step=_gauge_by_site("ssm_chunks_per_step", scans),
+            ssm_grid_steps_per_step=_gauge_by_site(
+                "ssm_grid_steps_per_step", scans))
     return [], say
 
 

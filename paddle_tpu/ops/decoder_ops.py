@@ -36,6 +36,7 @@ from jax import lax
 
 from .registry import register_op, first, out
 from .math_ops import mxu_available
+from .pallas import selective_scan
 
 
 def _operands(*xs):
@@ -281,18 +282,26 @@ def _scan_chunk(state, u, dt, b, c, a):
     return state, jnp.moveaxis(y, 0, 1)
 
 
-@jax.custom_vjp
-def _chunked_scan(u, dt, b, c, a):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _chunked_scan(u, dt, b, c, a, kernels=False):
     """s_t = exp(dt_t a) s_{t-1} + (dt_t u_t) b_t, y_t = s_t . c_t from
     s_0 = 0, over chunks [n, B, T, .] of the sequence; float32. Its own
     vjp: the forward keeps the state at each chunk's START only (n of
     them, not S: the whole history at s4096 x 5120 x 16 is 1.34 GB a
-    layer), and the backward walks the chunks last to first, each the
-    vjp of ``_scan_chunk`` made anew from its boundary state."""
-    return _chunked_scan_fwd(u, dt, b, c, a)[0]
+    layer), and the backward walks the chunks last to first, each made
+    anew from its boundary state. Two lowerings of the one recurrence:
+    ``kernels`` (the caller's ``selective_scan.use_kernels()``: a TPU
+    backend, off a mesh) takes the Pallas pair of
+    ``pallas/selective_scan.py``, which keeps the state in VMEM; any
+    other backend takes ``lax.scan`` over ``_scan_chunk`` and its vjp."""
+    return _chunked_scan_fwd(u, dt, b, c, a, kernels)[0]
 
 
-def _chunked_scan_fwd(u, dt, b, c, a):
+def _chunked_scan_fwd(u, dt, b, c, a, kernels=False):
+    if kernels:
+        y, starts = selective_scan.forward(u, dt, b, c, a)
+        return y, (u, dt, b, c, a, starts)
+
     def chunk(state, xs):
         after, y = _scan_chunk(state, *xs, a)
         return after, (state, y)
@@ -302,8 +311,10 @@ def _chunked_scan_fwd(u, dt, b, c, a):
     return y, (u, dt, b, c, a, starts)
 
 
-def _chunked_scan_bwd(residuals, d_y):
+def _chunked_scan_bwd(kernels, residuals, d_y):
     u, dt, b, c, a, starts = residuals
+    if kernels:
+        return selective_scan.backward(u, dt, b, c, a, starts, d_y)
 
     def chunk(carry, xs):
         d_state, d_a = carry
@@ -347,18 +358,29 @@ def _selective_scan(ins, attrs):
         t = jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
         return jnp.moveaxis(t.reshape(batch, n, chunk, -1), 1, 0)
 
+    states = a.shape[1]
+    kernels = selective_scan.use_kernels() and selective_scan._block_sizes(
+        ch, states, n * chunk, chunk) is not None
     y = _chunked_scan(chunks(u), chunks(dt),
                       chunks(first(ins, "B").astype(jnp.float32)),
-                      chunks(first(ins, "C").astype(jnp.float32)), a)
+                      chunks(first(ins, "C").astype(jnp.float32)), a,
+                      kernels)
     y = jnp.moveaxis(y, 0, 1).reshape(batch, n * chunk, ch)[:, :s]
     site = attrs.get("site", "")
+    if kernels:
+        _gauge("ssm_grid_steps_per_step",
+               "steps of the scan's forward Pallas kernel's grid, every "
+               "sequence, channel block and position block; unset where "
+               "the lax.scan lowering ran", site,
+               selective_scan.grid_steps(batch, ch, n * chunk, states,
+                                         chunk))
     _gauge("ssm_chunks_per_step",
            "chunks of the sequence the state-space scan steps over, a "
            "sequence of the batch each", site, batch * n)
     _gauge("ssm_state_bytes",
            "bytes of scan state kept from the forward for the backward: "
            "a float32 [channels, d_state] state a chunk and sequence",
-           site, batch * n * ch * a.shape[1] * 4)
+           site, batch * n * ch * states * 4)
     return out(Out=(y + first(ins, "D") * u).astype(x.dtype))
 
 

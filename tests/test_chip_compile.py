@@ -31,6 +31,7 @@ from paddle_tpu.fluid.executor import _CompiledBlock
 from paddle_tpu.models import bert
 from paddle_tpu.ops import attention_ops
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import selective_scan as ss
 
 KERNEL = 'custom_call_target="tpu_custom_call"'
 
@@ -116,6 +117,33 @@ def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
     assert _kernel_names(compiled.as_text()) == {
         (None, "flash_fwd"): 1, (None, "flash_bwd_dkv"): 1,
         (None, "flash_bwd_dq"): 1}
+
+
+def test_scan_kernels_compile_for_v5e(one_chip, for_the_chip):
+    """`selective_scan` forward + backward at the Phi cell's call (1 x
+    4096 positions x 5120 channels x 16 states, chunks of 64, float32),
+    through the op, at the blocks `_block_sizes` chooses: one Mosaic
+    kernel each way, and none of the `lax.scan` lowering's loops."""
+    from paddle_tpu.ops import decoder_ops
+    names = ("X", "Dt", "B", "C", "ALog", "D", "DtBias")
+    shapes = ((1, 4096, 5120),) * 2 + ((1, 4096, 16),) * 2 \
+        + ((5120, 16), (5120,), (5120,))
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+
+    def loss(*xs):
+        y = decoder_ops._selective_scan(
+            {k: [v] for k, v in zip(names, xs)},
+            {"chunk_size": 64, "site": "compile"})["Out"][0]
+        return jnp.sum(y ** 2)
+
+    assert ss._block_sizes(5120, 16, 4096, 64) == (1280, 64)
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert _kernel_names(text) == {(None, "ssm_scan_fwd"): 1,
+                                   (None, "ssm_scan_bwd"): 1}
+    assert " while(" not in text
 
 
 def test_stem_max_pool_compiles_to_one_select_and_scatter_for_v5e(one_chip,
@@ -338,9 +366,11 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
     tied embedding read before the first and by the last), it fits the
     chip's 16 GB, each of the three attention layers runs the flash
     kernels (D 64, values 128 wide: forward, again in the recomputed
-    segment, dK/dV and dQ) at the blocks `_block_sizes` chooses, and the
-    window layer's grids are cut to the blocks its window holds. Start-up runs on the CPU for the shapes
-    alone (8.4 GB of host memory, ~4 s)."""
+    segment, dK/dV and dQ) at the blocks `_block_sizes` chooses, the
+    window layer's grids are cut to the blocks its window holds, and
+    each of the two Mamba layers runs the scan's kernel pair (forward,
+    again in the recomputed segment, backward). Start-up runs on the CPU
+    for the shapes alone (8.4 GB of host memory, ~4 s)."""
     from paddle_tpu.fluid import telemetry
     from paddle_tpu.models import phi4_flash
     cfg = dict(phi4_flash.phi4_flash_config(), vocab_size=25008,
@@ -363,7 +393,13 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
     assert _kernel_names(text) == {
         ("fwd/fused_attention_qkv", "flash_fwd"): 2 * 3,
         ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 3,
-        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 3}
+        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 3,
+        ("fwd/selective_scan", "ssm_scan_fwd"): 2 * 2,
+        ("fwd/selective_scan", "ssm_scan_bwd"): 2}
+    scans = [op.attr("site") for op in main.global_block().ops
+             if op.type == "selective_scan"]
+    grid = telemetry.REGISTRY.get("ssm_grid_steps_per_step")
+    assert [grid.value(site=s) for s in scans] == [4 * 64] * 2
     # block pairs a forward kernel computes and the steps of its grid, at
     # the blocks chosen for each call: 40 heads x 15 pairs of 512 x 512
     # under the window (8 row blocks x 2, the first of them one), x 10 of

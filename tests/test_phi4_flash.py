@@ -6,6 +6,7 @@ program's loss and gradients against the reference, and the recompute
 lowering carrying the memory, the kept K / V and the tied embedding
 across segments.
 """
+import contextlib
 import importlib.util
 import os
 import warnings
@@ -21,6 +22,7 @@ from paddle_tpu.fluid import core, layers, telemetry
 from paddle_tpu.models import phi4_flash
 from paddle_tpu.ops import attention_ops, decoder_ops
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import selective_scan as ss
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -132,6 +134,104 @@ def test_selective_scan_keeps_one_state_a_chunk_and_says_so():
         site="test") == 2 * 5
     assert telemetry.REGISTRY.get("ssm_state_bytes").value(
         site="test") == 2 * 5 * 12 * 4 * 4
+
+
+
+# ------------------------------------- the scan's Pallas kernels, interpreted
+@pytest.mark.parametrize("seq,chunk,channels,blocks", [
+    (75, 16, 12, (128, 16)), (64, 64, 12, None), (30, 64, 12, None),
+    (33, 1, 12, (128, 8)), (40, 8, 300, (128, 16))],
+    ids=["s75_c16", "s64_c64", "s30_c64", "s33_c1", "300_channels"])
+def test_scan_kernels_are_the_step_by_step_recurrence(seq, chunk, channels,
+                                                      blocks):
+    """The four (seq, chunk) cases of the `lax.scan` lowering above, and
+    300 channels: padded to 384, three blocks of 128 (``blocks`` None:
+    the pair `_block_sizes` chooses)."""
+    case = _scan_case(seq, channels)
+    pinned = ss.block_override(*blocks) if blocks else contextlib.nullcontext()
+    with fa.interpret_guard(), pinned:
+        got = _scan_op(chunk)(**case)
+    _close(got, _scan_reference(**case), 2e-6)
+
+
+def _all_gradients(fn, case, weight):
+    names = sorted(case)
+    grads = jax.grad(lambda *vs: jnp.sum(fn(**dict(zip(names, vs))) * weight),
+                     argnums=tuple(range(len(names))))(
+        *(case[n] for n in names))
+    return dict(zip(names, grads))
+
+
+@pytest.fixture(scope="module")
+def scan_kernel_gradients():
+    """The seven gradients through the kernel pair, five position blocks
+    of a chunk each, and the reference's."""
+    case = _scan_case(75)
+    weight = _normal(np.random.default_rng(1), 2, 75, 12)
+    with fa.interpret_guard(), ss.block_override(128, 16):
+        got = _all_gradients(_scan_op(16), case, weight)
+    return got, _all_gradients(_scan_reference, case, weight)
+
+
+@pytest.mark.parametrize("wrt", ["X", "Dt", "B", "C", "ALog", "D", "DtBias"])
+def test_scan_kernels_backward_matches_the_recurrence_s(
+        scan_kernel_gradients, wrt):
+    got, want = scan_kernel_gradients
+    _close(got[wrt], want[wrt], 2e-5)
+
+
+def test_scan_kernels_and_lax_scan_agree_over_several_blocks():
+    """Three channel blocks x five position blocks, the output and the
+    seven gradients, one lowering against the other."""
+    case = _scan_case(75, channels=300)
+    weight = _normal(np.random.default_rng(2), 2, 75, 300)
+    assert ss.grid_steps(2, 300, 80, 4, 16) == 2 * 1 * 2  # as chosen
+    with fa.interpret_guard(), ss.block_override(128, 16):
+        assert ss.grid_steps(2, 300, 80, 4, 16) == 2 * 3 * 5
+        out = _scan_op(16)(**case)
+        grads = _all_gradients(_scan_op(16), case, weight)
+    _close(out, _scan_op(16)(**case), 2e-6)
+    want = _all_gradients(_scan_op(16), case, weight)
+    for name in want:
+        _close(grads[name], want[name], 2e-5)
+
+
+def test_scan_kernels_keep_one_state_a_chunk_too():
+    case = _scan_case(75)
+    chunks = [jnp.zeros((5, 2, 16, n), jnp.float32) for n in (12, 12, 4, 4)]
+    with fa.interpret_guard(), ss.block_override(128, 16):
+        _, residuals = decoder_ops._chunked_scan_fwd(
+            *chunks, -jnp.exp(case["ALog"]), True)
+        decoder_ops._selective_scan(
+            {k: [v] for k, v in case.items()},
+            {"chunk_size": 16, "site": "kept"})
+    # [B, chunks, N, channels padded to a block]: chunk starts, not S states
+    assert residuals[-1].shape == (2, 5, 4, 128)
+    assert telemetry.REGISTRY.get("ssm_state_bytes").value(
+        site="kept") == 2 * 5 * 12 * 4 * 4
+
+
+def test_the_scan_says_which_lowering_ran():
+    """`ssm_grid_steps_per_step` is set on the kernels' path alone; a
+    step traced under a mesh, a backend without the kernels and a chunk
+    whose states do not fit VMEM keep `lax.scan`."""
+    case = {k: [v] for k, v in _scan_case(16).items()}
+    sites = lambda: {c.labels_dict["site"] for c in telemetry.REGISTRY.get(
+        "ssm_grid_steps_per_step").children()}
+    assert not ss.use_kernels()
+    with fa.interpret_guard():
+        assert ss.use_kernels()
+        with fa.mesh_guard(object()):
+            assert not ss.use_kernels()
+        decoder_ops._selective_scan(case, {"chunk_size": 8, "site": "on"})
+    decoder_ops._selective_scan(case, {"chunk_size": 8, "site": "off"})
+    assert "on" in sites() and "off" not in sites()
+    assert telemetry.REGISTRY.get("ssm_grid_steps_per_step").value(
+        site="on") == 2 * 1 * 1
+    # the Phi cell's call: ten 128-lane tiles a block, one chunk a step
+    assert ss._block_sizes(5120, 16, 4096, 64) == (1280, 64)
+    assert ss.grid_steps(1, 5120, 4096, 16, 64) == 4 * 64
+    assert ss._block_sizes(5120, 16, 8192, 8192) is None
 
 
 # ---------------------------------------------------------- window attention
