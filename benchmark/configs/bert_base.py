@@ -81,3 +81,26 @@ def tiny(config, traffic):
     if traffic.get("input_mask", False):
         traffic["lengths"] = [8, 16]
     return config, traffic
+
+
+def attn_required(config, traffic):
+    """A step's attention maps as the flash kernels run them, forward +
+    backward, all layers: what `attn_roofline_pct` divides by. (That
+    reader says nothing where no kernel ran, as at s128, which takes
+    XLA's dense path: the count needs no threshold of its own.) Every
+    query row of a padded sequence is computed (a prediction may lie on
+    any of them); the input mask keeps a sequence's first L keys, L
+    uniform in `lengths`, so the counts are the EXPECTATION over the
+    lengths (the whole sequence without an input mask). flop: a head's
+    scores and weighted sum over the kept keys (2 d + 2 d a key),
+    backward twice the forward. bytes, in the operands' bf16, each
+    `hidden` wide a token: the forward reads Q and the kept rows of K, V
+    and writes O; the backward reads Q, O, O's gradient and the kept K,
+    V and writes Q's gradient and the kept rows of K's and V's. The
+    dropout mask is made in the kernel and costs no bytes."""
+    s = traffic["seq_len"]
+    kept = sum(traffic["lengths"]) / 2 if traffic.get("input_mask") else s
+    h, layers = config["hidden_size"], config["num_hidden_layers"]
+    return {"flop": 3.0 * traffic["batch"] * layers * s * kept * 4 * h,
+            "bytes": traffic["batch"] * layers * 2 * h * (
+                6 * s + 6 * kept)}

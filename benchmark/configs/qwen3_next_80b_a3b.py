@@ -178,3 +178,22 @@ def tiny(config, traffic):
                   moe_intermediate_size=12,
                   shared_expert_intermediate_size=12)
     return config, dict(traffic, batch=2, seq_len=80, pool=2)
+
+
+def attn_required(config, traffic):
+    """A step's flash kernels, forward + backward, the gated-attention
+    layers, what `attn_roofline_pct` divides by. flop: a query head's
+    scores and weighted sum (2 d + 2 d a key) over the causal half, the
+    diagonal with it, backward twice the forward. bytes, in the
+    operands' bf16: the forward reads Q, K, V (each key and value head
+    once, not once a query head of its group) and writes O; the backward
+    reads Q, K, V, O and O's gradient and writes the three gradients.
+    The output gate is applied outside the kernels and is not counted."""
+    s, tokens = traffic["seq_len"], traffic["batch"] * traffic["seq_len"]
+    _, full_layers = _layer_kinds(config)
+    q = config["num_attention_heads"] * config["head_dim"]
+    kv = config["num_key_value_heads"] * config["head_dim"]
+    return {"flop": 3.0 * traffic["batch"] * full_layers
+            * (s * (s + 1) // 2) * 4 * q,
+            "bytes": full_layers * tokens * 2 * (
+                (q + 2 * kv + q) + (q + 2 * kv + 2 * q) + (q + 2 * kv))}

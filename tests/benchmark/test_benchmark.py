@@ -17,6 +17,7 @@ import re
 import shutil
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -26,6 +27,19 @@ BENCH = os.path.join(REPO, "benchmark")
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+# what a traced rehearsal can read without a chip: the benchmark's own
+# clocks and counters, and (listed since PR 38) the four readers of the
+# program's step records, whose ring a rehearsal's program has too
+HOST_READ = {"build_s", "compile_s", "compiles_in_window",
+             "dispatch_ms.train", "exe_ms.run_p95", "exe_ms.self",
+             "gc_ms_per_step", "slow_steps_in_window"}
+# spans and counters of the program that their readers take through the
+# chip's trace, or only once it is there: silent in a rehearsal
+NEEDS_TRACE = {"exe_ms.feed", "exe_ms.place", "exe_ms.dispatch",
+               "exe_ms.write_back", "trace_lower_s", "compile_cache_misses",
+               "moe_rows_per_step", "attn_kv_blocks_per_step",
+               "attn_grid_steps_per_step"}
 
 
 def load(path):
@@ -73,6 +87,43 @@ def check_rehearsal(res, rows, group, cell):
     return {r["phase"]: r for r in rows if "phase" in r}
 
 
+def rehearsal_names(m, cell, trace):
+    """(must, may): the names a rehearsal's line of `cell` has to carry
+    and the names it may carry, from the manifest `m`. Untraced the two
+    are one set, the cell's end-to-end metrics EXACTLY. Traced: what the
+    host can read is there (`HOST_READ`), and nothing but the cell's
+    per-layer entries that need no chip's trace (not `device_trace`, not
+    `NEEDS_TRACE`); what only a chip's trace holds is left out, not
+    invented. On PR 38's manifest the two are one set here too; an entry
+    a later PR appends may read in a rehearsal or stay silent."""
+    if not trace:
+        names = {e["name"] for e in m["end_to_end"]
+                 if cell in e.get("workloads", [cell])}
+        return names, names
+    return HOST_READ, {p["name"] for p in m["per_layer"]
+                       if cell in p.get("workloads", [cell])
+                       and p["source"] != "device_trace"} - NEEDS_TRACE
+
+
+def test_rehearsal_names_are_exact_untraced_and_leave_room_traced():
+    m = manifest()
+    source = {p["name"]: p["source"] for p in m["per_layer"]}
+    for cell in (w["name"] for w in m["workloads"]):
+        must, may = rehearsal_names(m, cell, 0)
+        assert must == may == {e["name"] for e in m["end_to_end"]}
+        assert not may & set(source)  # no per-layer name in such a line
+        must, may = rehearsal_names(m, cell, 1)
+        assert must <= may and not may & NEEDS_TRACE
+        assert "device_trace" not in {source[n] for n in may}
+    # an entry a later PR appends for one cell may read there, need not,
+    # and has no place in another cell's line
+    m["per_layer"].append({"name": "toy_fetches", "source": "program_span",
+                           "workloads": ["resnet50.b256_i224"]})
+    grown = rehearsal_names(m, "resnet50.b256_i224", 1)
+    assert "toy_fetches" in grown[1] - grown[0]
+    assert "toy_fetches" not in rehearsal_names(m, "bert_base.b128_s128", 1)[1]
+
+
 # ---------------------------------------------------------------- the runs
 @pytest.mark.parametrize("cell,trace", [
     ("bert_base.b128_s128", 0), ("bert_base.b128_s128", 1),
@@ -85,15 +136,15 @@ def test_tiny_rehearsal_runs_the_cell_and_never_reports_correct(
     group = "per_layer" if trace else "end_to_end"
     phase = check_rehearsal(res, rows, group, cell)
     last = rows[-1]
+    must, may = rehearsal_names(manifest(), cell, trace)
+    assert must <= set(last["metrics"]) <= may, sorted(last["metrics"])
     if trace:
-        # what the host can read is there; what only a chip's trace
-        # holds is left out, not invented
-        assert {"build_s", "compile_s", "compiles_in_window",
-                "dispatch_ms.train"} == set(last["metrics"])
         assert phase["trace"]["trace"] is None
     else:
-        assert set(last["metrics"]) == {"samples_per_s", "step_ms_p95",
-                                        "mfu_pct", "setup_s"}
+        # an untraced line carries the end-to-end metrics and no other:
+        # no per-layer reader is loaded, so none can leak into it
+        assert must == may == {"samples_per_s", "step_ms_p95", "mfu_pct",
+                               "setup_s"}
     assert phase["start"]["compile_cache_dir"] == str(tmp_path / "xla_cache")
     assert phase["setup"]["compiles"] > 0
     assert phase["setup"]["devices_holding_parameters"] == 1
@@ -235,6 +286,52 @@ def test_a_new_config_cell_and_metric_are_files_and_manifest_entries(
         r"(?<![\w.])" + re.escape(n) + r"(?![\w.])", text)] == []
 
 
+def test_the_manifest_grows_by_appended_entries_and_every_check_holds(copy):
+    """The room a later PR has, shown and not promised: the REAL manifest
+    grown in memory by a sixth configuration, an eighth cell on four
+    chips, one more per-layer entry and one cell appended to a
+    `workloads` list that is there; every manifest assertion of this
+    directory's six files is a function of a manifest and holds on it,
+    but for the toy's files, which it then supplies in a copy."""
+    m = manifest()
+    sizes = {k: len(m[k]) for k in ("configs", "workloads", "per_layer")}
+    m["configs"].append({"name": "toy", "source": "none", "reduced": [],
+                         "file": "benchmark/configs/toy.json", "why": "t"})
+    m["workloads"].append({"name": "toy.dp4_b8", "config": "toy",
+                           "traffic": "dp4_b8", "chips": 4, "why": "t"})
+    m["per_layer"].append({"name": "toy_fetches", "unit": "count",
+                           "better": "higher", "source": "program_span",
+                           "layer": "Executor", "moves": "samples_per_s",
+                           "workloads": ["toy.dp4_b8"]})
+    listed = next(p for p in m["per_layer"] if p["name"] == "device_ms.attn")
+    listed["workloads"].append("toy.dp4_b8")
+    assert {k: len(m[k]) for k in sizes} == {k: n + 1
+                                             for k, n in sizes.items()}
+    assert sum(w["chips"] == 4 for w in m["workloads"]) == 2
+    checks = [check_manifest_keys_names_and_units,
+              lambda m: check_manifest_names_files_that_exist(m, str(copy))]
+    for sibling in ("test_trace_scopes", "test_step_records",
+                    "test_qwen3_next_cell", "test_phi4_flash_cell",
+                    "test_laguna_xs2_cell"):
+        checks.append(load(os.path.join(
+            REPO, "tests", "benchmark", sibling + ".py")).check_manifest)
+    # the toy's files are not there yet, and that alone fails
+    with pytest.raises(FileNotFoundError, match="toy"):
+        check_manifest_names_files_that_exist(m, str(copy))
+    bench = copy / "benchmark"
+    (bench / "configs" / "toy.json").write_text(json.dumps({
+        "name": "toy", "reduced": [], "width": 16, "classes": 4,
+        "flags": {}, "correct": {"first_loss_rel_tol": 0.2,
+                                 "falling_n": 3}}))
+    (bench / "configs" / "toy.py").write_text(TOY_CONFIG_PY)
+    (bench / "workloads" / "toy.dp4_b8.json").write_text(json.dumps({
+        "name": "toy.dp4_b8", "mesh": {"dp": 4},
+        "traffic": {"batch": 8, "pool": 3}}))
+    (bench / "layer_metrics" / "toy_fetches.py").write_text(TOY_METRIC_PY)
+    for check in checks:
+        check(m)
+
+
 def test_a_four_chip_cell_is_a_file_and_shards_over_four_devices(
         tmp_path, copy):
     """The queued data-parallel cell, rehearsed: `chips: 4` and a mesh in
@@ -280,8 +377,7 @@ def test_exe_run_has_one_call_site():
 
 
 # ------------------------------------------------------------ the manifest
-def test_manifest_keys_names_and_units():
-    m = manifest()
+def check_manifest_keys_names_and_units(m):
     assert set(m) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert m["paths"] == ["benchmark", "tests/benchmark"]
@@ -300,8 +396,8 @@ def test_manifest_keys_names_and_units():
     for p in m["per_layer"]:
         assert set(p) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
-        assert p["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
+        assert p["source"] in SOURCES
+        assert len(p["layer"]) <= 200 and "\n" not in p["layer"]
     names = [x["name"] for key in ("configs", "workloads", "end_to_end",
                                    "per_layer") for x in m[key]]
     assert all(NAME.match(n) for n in names), names
@@ -315,24 +411,29 @@ def test_manifest_keys_names_and_units():
     assert four <= max(1, len(m["workloads"]) // 4)
 
 
-def test_manifest_names_files_that_exist():
-    m = manifest()
+def test_manifest_keys_names_and_units():
+    check_manifest_keys_names_and_units(manifest())
+
+
+def check_manifest_names_files_that_exist(m, root=REPO):
+    """`root` holds `benchmark/` and what the manifest's `file`s name."""
+    bench = os.path.join(root, "benchmark")
     configs = {c["name"]: c for c in m["configs"]}
     assert {w["config"] for w in m["workloads"]} == set(configs)
     assert len({(w["config"], w["traffic"]) for w in m["workloads"]}) \
         == len(m["workloads"])
     for c in m["configs"]:
         assert c["file"].startswith("benchmark/configs/")
-        with open(os.path.join(REPO, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             body = json.load(f)
         assert body["name"] == c["name"] and body["reduced"] == c["reduced"]
         assert body["correct"]["first_loss_rel_tol"] <= 0.2
-        model = load(os.path.join(REPO, c["file"][:-5] + ".py"))
+        model = load(os.path.join(root, c["file"][:-5] + ".py"))
         for fn in ("build", "make_batches", "flops_per_sample", "tiny"):
             assert callable(getattr(model, fn)), (c["name"], fn)
     for w in m["workloads"]:
         assert w["name"] == f"{w['config']}.{w['traffic']}"
-        with open(os.path.join(BENCH, "workloads", w["name"] + ".json")) as f:
+        with open(os.path.join(bench, "workloads", w["name"] + ".json")) as f:
             body = json.load(f)
         assert body["name"] == w["name"]
         assert body["traffic"]["batch"] > 0 and body["traffic"]["pool"] >= 2
@@ -340,15 +441,47 @@ def test_manifest_names_files_that_exist():
     end_to_end = {e["name"] for e in m["end_to_end"]}
     for e in m["end_to_end"]:
         assert callable(load(os.path.join(
-            BENCH, "end_to_end", e["name"] + ".py")).compute)
+            bench, "end_to_end", e["name"] + ".py")).compute)
     for p in m["per_layer"]:
         assert p["moves"] in end_to_end
         assert callable(load(os.path.join(
-            BENCH, "layer_metrics", p["name"] + ".py")).compute)
-    for directory, _, files in os.walk(BENCH):
+            bench, "layer_metrics", p["name"] + ".py")).compute)
+    for directory, _, files in os.walk(bench):
         for f in files:
             if "__pycache__" not in directory:
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", f), f
+
+
+def test_manifest_names_files_that_exist():
+    check_manifest_names_files_that_exist(manifest())
+
+
+@pytest.mark.parametrize("entry", manifest()["per_layer"],
+                         ids=lambda entry: entry["name"])
+def test_every_per_layer_entry_has_a_reader_that_says_none_or_a_number(
+        entry, monkeypatch):
+    """Each entry of the manifest, whoever listed it: a reader file whose
+    `compute`, on a run with no trace, no span and no counter, returns
+    None or a number and does not raise; a unit and a `source` the
+    contract allows; cells the manifest has."""
+    for shared in ("_benchmark_trace_scopes", "_benchmark_scope_union"):
+        monkeypatch.delitem(sys.modules, shared, raising=False)
+    compute = load(os.path.join(BENCH, "layer_metrics",
+                                entry["name"] + ".py")).compute
+    assert callable(compute)
+    stats = load(os.path.join(BENCH, "stats.py"))
+    got = compute(types.SimpleNamespace(
+        trace=None, spans={}, counters={}, step_s=[], losses=[], chips=1,
+        device_kind="cpu", samples_per_step=0, flops_per_sample=0.0,
+        median=stats.median, percentile=stats.percentile,
+        peak=load(os.path.join(BENCH, "peaks.py")).peak))
+    assert got is None or (isinstance(got, (int, float))
+                           and math.isfinite(got)), got
+    assert UNIT.match(entry["unit"]) and entry["source"] in SOURCES
+    assert entry["better"] in ("lower", "higher")
+    cells = {w["name"] for w in manifest()["workloads"]}
+    listed = entry.get("workloads")
+    assert listed is None or (listed and set(listed) <= cells)
 
 
 # ------------------------------------------------------------ the yardstick
@@ -363,6 +496,37 @@ def test_bert_flops_per_sample_is_the_closed_form():
     # the head is the 3.8% that bench.py's formula leaves out
     assert abs(6 * 19 * 768 * 30522 / got - 0.038) < 0.001
     assert math.isclose(math.log(config["classes"]), 10.326, abs_tol=1e-3)
+
+
+def test_bert_attn_required_is_the_expectation_over_the_padded_lengths():
+    """What `attn_roofline_pct` divides by on the padded s512 cell: every
+    query row against the keys the input mask keeps, 384 of 512 in
+    expectation (lengths uniform in 256-512), forward x 3."""
+    config = json.load(open(os.path.join(BENCH, "configs", "bert_base.json")))
+    model = load(os.path.join(BENCH, "configs", "bert_base.py"))
+    padded = {"batch": 32, "seq_len": 512, "input_mask": True,
+              "lengths": [256, 512]}
+    got = model.attn_required(config, padded)
+    # 12 layers x 12 heads x 64: 4 x 64 FLOP a head, query and kept key
+    assert got["flop"] == 3 * 32 * 12 * 512 * 384 * 12 * 4 * 64
+    # bf16, 768 wide: Q, O, dO, dQ + Q, O over all 512 rows (6), K, V
+    # read forward and backward and their gradients over 384 (6)
+    assert got["bytes"] == 32 * 12 * 2 * 768 * (6 * 512 + 6 * 384)
+    assert abs(got["flop"] / 197e12 / 3.532e-3 - 1) < 1e-3
+    assert abs(got["bytes"] / 819e9 / 3.872e-3 - 1) < 1e-3   # the bound
+    # without a mask every key is kept; twice the batch, twice the work
+    whole = model.attn_required(config, dict(padded, input_mask=False,
+                                             batch=64))
+    assert whole["flop"] == 2 * got["flop"] * 512 / 384
+    assert whole["bytes"] == 64 * 12 * 2 * 768 * 12 * 512
+    # where nothing is padded this is `flops_per_sample`'s attention term
+    assert whole["flop"] / 64 == 12 * 12 * 512 * 512 * 768
+    # at s128 the count is there, and the reader, finding no kernel in
+    # the dense path's trace, says nothing
+    with open(os.path.join(BENCH, "workloads",
+                           "bert_base.b128_s128.json")) as f:
+        short = model.attn_required(config, json.load(f)["traffic"])
+    assert short["flop"] == 128 * 12 * 12 * 128 * 128 * 768
 
 
 def test_resnet_flops_per_sample_is_the_count_of_its_shapes():

@@ -60,12 +60,15 @@ def test_tiny_rehearsal_runs_the_cell_and_never_reports_correct(tmp_path,
     assert last["correct"] is False and last["failed"] == 0
     assert last["attempted"] >= 1
     assert all(v["value"] is None for v in last["metrics"].values())
-    if trace:
-        assert {"build_s", "compile_s", "compiles_in_window",
-                "dispatch_ms.train"} == set(last["metrics"])
-    else:
-        assert set(last["metrics"]) == {"samples_per_s", "step_ms_p95",
-                                        "mfu_pct", "setup_s"}
+    # untraced: the end-to-end names and no other; traced: what the host
+    # reads, and none of the cell's readers that need a chip's plane
+    # (`test_benchmark.py::rehearsal_names`, a function of the manifest)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        must, may = load(os.path.join(
+            REPO, "tests", "benchmark", "test_benchmark.py")
+        ).rehearsal_names(json.load(f), CELL, trace)
+    assert must <= set(last["metrics"]) <= may, sorted(last["metrics"])
+    assert not set(READERS) & set(last["metrics"])
     checks = phase["checks"]
     assert checks["losses_finite"] and checks["no_compile_in_window"]
     assert checks["first_loss_near_ln_classes"]
@@ -225,20 +228,31 @@ def test_new_readers_find_nothing_without_a_chips_plane(name):
                              name + ".py")).compute(run) is None
 
 
-def test_new_readers_wait_for_a_benchmark_pr_to_list_them():
-    """As PR 28's six: `tests/benchmark/test_trace_scopes.py` pins the
-    manifest's per-layer tail, so the four readers are files a
-    `benchmark` PR lists (PERF.md §7 has the entries)."""
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    assert not {m["name"] for m in manifest["per_layer"]} & set(READERS)
-    cell = {w["name"]: w for w in manifest["workloads"]}[CELL]
+def check_manifest(m):
+    """The four readers are per-layer entries that list the cell (since
+    PR 38; a later PR may append cells to their lists); the cell and the
+    configuration by name."""
+    by_name = {p["name"]: p for p in m["per_layer"]}
+    for name in READERS + ["device_ms.attn", "attn_grid_steps_per_step",
+                           "flash_ms_per_step"]:
+        entry = by_name[name]
+        assert CELL in entry["workloads"], name
+        assert (entry["layer"], entry["moves"]) == ("Op kernels",
+                                                    "samples_per_s")
+        assert entry["better"] == ("higher" if name.endswith("_pct")
+                                   else "lower")
+    cell = {w["name"]: w for w in m["workloads"]}[CELL]
     assert cell["chips"] == 1 and cell["config"] == "phi4_mini_flash"
-    entry = {c["name"]: c for c in manifest["configs"]}["phi4_mini_flash"]
+    entry = {c["name"]: c for c in m["configs"]}["phi4_mini_flash"]
     assert entry["source"] == SOURCE
     assert entry["reduced"] == config()["reduced"]
     with open(os.path.join(BENCH, "workloads", CELL + ".json")) as f:
         assert json.load(f)["traffic"] == TRAFFIC
+
+
+def test_the_manifest_lists_the_four_readers_with_the_cell():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        check_manifest(json.load(f))
 
 
 def test_block_counter_is_read_from_the_programs_registry(monkeypatch):

@@ -68,14 +68,17 @@ def test_tiny_rehearsal_runs_the_cell_and_never_reports_correct(tmp_path,
     assert last["correct"] is False and last["failed"] == 0
     assert last["attempted"] >= 1
     assert all(v["value"] is None for v in last["metrics"].values())
+    # untraced: the end-to-end names and no other; traced: what the host
+    # reads, and none of the cell's readers that need a chip's plane
+    # (`test_benchmark.py::rehearsal_names`, a function of the manifest)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        must, may = load(os.path.join(
+            REPO, "tests", "benchmark", "test_benchmark.py")
+        ).rehearsal_names(json.load(f), CELL, trace)
+    assert must <= set(last["metrics"]) <= may, sorted(last["metrics"])
+    assert not set(READERS) & set(last["metrics"])
     if trace:
-        # the new readers find no chip's plane: left out, not invented
-        assert {"build_s", "compile_s", "compiles_in_window",
-                "dispatch_ms.train"} == set(last["metrics"])
         assert "scope_union" not in phase  # the helper raised nothing
-    else:
-        assert set(last["metrics"]) == {"samples_per_s", "step_ms_p95",
-                                        "mfu_pct", "setup_s"}
     checks = phase["checks"]
     assert checks["losses_finite"] and checks["no_compile_in_window"]
     # the toy's first loss is near ln(96); a cycled pool is memorised (a
@@ -204,22 +207,51 @@ def test_new_readers_find_nothing_without_a_chips_plane(name):
                              name + ".py")).compute(run) is None
 
 
-def test_new_readers_wait_for_a_benchmark_pr_to_list_them():
-    """`tests/benchmark/test_trace_scopes.py` pins the manifest's last
-    eleven per-layer entries and its one `workloads` list, and a PR of
-    this kind may not edit that file: the six readers are files a
-    `benchmark` PR lists (PERF.md §7 has the entries), and until then a
-    traced line of the cell carries the accepted metrics alone."""
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    listed = {m["name"] for m in manifest["per_layer"]}
-    assert not listed & set(READERS)
-    cells = {w["name"]: w for w in manifest["workloads"]}
+def check_manifest(m):
+    """The six readers are per-layer entries that list the cell (since PR
+    38; a later PR may append cells to their lists), `Op kernels` moving
+    `samples_per_s`; the cell and the four-chip cell by name."""
+    by_name = {p["name"]: p for p in m["per_layer"]}
+    for name in READERS:
+        entry = by_name[name]
+        assert CELL in entry["workloads"], name
+        assert (entry["layer"], entry["moves"]) == ("Op kernels",
+                                                    "samples_per_s")
+        assert entry["better"] == ("higher" if name.endswith("_pct")
+                                   else "lower")
+    # the flash kernels' share of their roofline reads here too, through
+    # this configuration's `attn_required`
+    assert CELL in by_name["attn_roofline_pct"]["workloads"]
+    cells = {w["name"]: w for w in m["workloads"]}
     assert cells[CELL]["chips"] == 1
     assert cells["bert_base.dp4_b512_s128"]["chips"] == 4
     # what every cell must report is still there for the new ones
-    everywhere = [m for m in manifest["per_layer"] if "workloads" not in m]
-    assert {m["moves"] for m in everywhere} >= {"samples_per_s", "setup_s"}
+    everywhere = [p for p in m["per_layer"] if "workloads" not in p]
+    assert {p["moves"] for p in everywhere} >= {"samples_per_s", "setup_s"}
+
+
+def test_the_manifest_lists_the_six_readers_with_the_cell():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        check_manifest(json.load(f))
+
+
+def test_attn_required_is_the_causal_half_at_the_published_heads():
+    """16 query heads x 256 over 2 key/value heads, one gated-attention
+    layer of four: FLOP over the causal half with its diagonal, forward
+    x 3; bytes of Q, K, V, O and their gradients in bf16."""
+    got = MODEL.attn_required(config(), TRAFFIC)
+    causal = 4096 * 4097 // 2
+    assert got["flop"] == 3 * causal * 16 * (2 * 256 + 2 * 256)
+    q, kv = 16 * 256, 2 * 256
+    assert got["bytes"] == 4096 * 2 * ((2 * q + 2 * kv) + (3 * q + 2 * kv)
+                                       + (q + 2 * kv))
+    # bound by FLOP: 2.09 ms at the bf16 peak against 0.28 ms of bytes
+    assert got["flop"] / 197e12 > 7 * got["bytes"] / 819e9
+    assert abs(got["flop"] / 0.4124e12 - 1) < 1e-3
+    # two sequences, two such layers of eight: four times the maps
+    twice = MODEL.attn_required(dict(config(), num_hidden_layers=8),
+                                dict(TRAFFIC, batch=2))
+    assert twice == {"flop": 4 * got["flop"], "bytes": 4 * got["bytes"]}
 
 
 def test_rows_counter_is_read_from_the_programs_registry(monkeypatch):

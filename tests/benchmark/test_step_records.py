@@ -1,18 +1,14 @@
 """The four readers of the program's step records (`exe_ms.run_p95`,
 `exe_ms.self`, `gc_ms_per_step`, `slow_steps_in_window`) and
 `benchmark/step_records.py` under them, off the chip: the window found
-by the clock on rings made by hand, and a `--tiny --trace 1` rehearsal
-that names the four. `tests/benchmark/test_trace_scopes.py` pins the
-manifest's per-layer tail and `test_benchmark.py` the names a traced
-rehearsal prints, so the four are reader FILES the manifest does not
-list yet: the rehearsal reads them from a copy of the manifest with the
-four appended, as a traced run on the chip does.
+by the clock on rings made by hand, the four entries the manifest
+lists for every cell (since PR 38), and a `--tiny --trace 1` rehearsal
+through the real manifest that names the four.
 """
 import importlib.util
 import json
 import os
 import re
-import shutil
 import subprocess
 import sys
 import types
@@ -128,37 +124,36 @@ def test_a_reader_finds_nothing_and_says_none(program, monkeypatch, name):
     assert compute(a_run(20)) is None
 
 
-def test_the_four_are_files_the_manifest_does_not_list_yet():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        listed = {m["name"] for m in json.load(f)["per_layer"]}
-    assert not listed & set(READERS)
-    for name in READERS:
+def check_manifest(m):
+    """The four are listed, for every cell (a program of any cell has
+    the ring), with the unit, source and end-to-end metric each reads
+    for; their files are there."""
+    by_name = {p["name"]: p for p in m["per_layer"]}
+    for name, (unit, source, moves) in READERS.items():
+        assert by_name[name] == {
+            "name": name, "unit": unit, "better": "lower", "source": source,
+            "layer": "Executor", "moves": moves}
         assert os.path.isfile(os.path.join(BENCH, "layer_metrics",
                                            name + ".py"))
 
 
-def test_a_traced_rehearsal_names_the_four_with_no_value(tmp_path):
-    """`run.py --tiny --trace 1` on a copy of the benchmark whose
-    manifest lists the four: a rehearsal's program has the ring, so each
-    reader finds its window, and `run.py` blanks the values."""
-    root = tmp_path / "copy"
-    shutil.copytree(BENCH, root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+def test_the_four_are_entries_the_manifest_lists_for_every_cell():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    manifest["per_layer"] += [
-        {"name": name, "unit": unit, "better": "lower", "source": source,
-         "layer": "Executor", "moves": moves}
-        for name, (unit, source, moves) in READERS.items()]
-    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+        check_manifest(json.load(f))
+
+
+def test_a_traced_rehearsal_names_the_four_with_no_value(tmp_path):
+    """`run.py --tiny --trace 1` through the real manifest: a
+    rehearsal's program has the ring, so each reader finds its window,
+    and `run.py` blanks the values."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla_cache"),
                XLA_FLAGS="--xla_force_host_platform_device_count=1")
     res = subprocess.run(
-        [sys.executable, str(root / "benchmark" / "run.py"), "--workload",
+        [sys.executable, os.path.join(BENCH, "run.py"), "--workload",
          "bert_base.b128_s128", "--seed", str(2 ** 31 + 36), "--seconds",
          "1", "--trace", "1", "--tiny"],
-        capture_output=True, text=True, env=env, timeout=600, cwd=root)
+        capture_output=True, text=True, env=env, timeout=600, cwd=REPO)
     assert res.returncode != 0 and "rehearsal" in res.stderr, \
         res.stderr[-2000:]
     last = json.loads(res.stdout.splitlines()[-1])

@@ -370,10 +370,90 @@ def test_the_counter_readers_read_the_programs_registry(monkeypatch):
     assert readers["trace_lower_s"].compute(empty_run()) is None
 
 
-def test_the_manifest_has_the_eleven_entries_last():
+def check_manifest(m):
+    """The eleven are in the manifest, each `better: lower`, wherever a
+    later PR's entries put them; a `workloads` list is never empty and
+    names cells the manifest has, and later PRs may append to it."""
+    by_name = {p["name"]: p for p in m["per_layer"]}
+    assert set(NEW_READERS) <= set(by_name)
+    assert all(by_name[n]["better"] == "lower" for n in NEW_READERS)
+    cells = {w["name"] for w in m["workloads"]}
+    for p in m["per_layer"]:
+        if "workloads" in p:
+            assert p["workloads"] and set(p["workloads"]) <= cells, p["name"]
+    # the flash kernels' milliseconds are read where the kernels run:
+    # not at s128, which is XLA's dense path since PR 27
+    flash = by_name["flash_ms_per_step"]["workloads"]
+    assert "bert_base.b128_s128" not in flash
+    assert {"qwen3_next_80b_a3b.b1_s4096", "phi4_mini_flash.b1_s4096",
+            "bert_base.b32_s512_pad", "laguna_xs2.b1_s8192"} <= set(flash)
+
+
+def test_the_manifest_has_the_eleven_and_its_workloads_lists_name_cells():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        per_layer = json.load(f)["per_layer"]
-    assert [p["name"] for p in per_layer[-11:]] == NEW_READERS
-    assert all(p["better"] == "lower" for p in per_layer[-11:])
-    only = {p["name"]: p["workloads"] for p in per_layer if "workloads" in p}
-    assert only == {"flash_ms_per_step": ["bert_base.b128_s128"]}
+        check_manifest(json.load(f))
+
+
+# ------------------------------------------------- the Mesh layer's reader
+def test_collective_reader_takes_the_union_by_xla_s_own_names(monkeypatch):
+    """Two chips' planes, two steps, window 0..20: an all-reduce in two
+    halves on each chip, an all-gather nested in a reduce-scatter on one,
+    and a fusion that is no collective; the union by instruction name,
+    mean of the planes, a step."""
+    collective = reader("device_ms.collective")
+    union = collective.helper()
+    step = "jit__step(9)"
+    device = {
+        "/device:TPU:0": [
+            (1.0, 2.0, "%fusion.1 = f32[2] fusion()", step),
+            (2.0, 3.0, "%all-reduce-start.1 = f32[2] all-reduce-start()",
+             step),
+            (5.0, 6.0, "%all-reduce-done.1 = f32[2] all-reduce-done()",
+             step),
+            (11.0, 14.0, "%reduce-scatter.3 = f32[2] reduce-scatter()",
+             step),
+            (12.0, 13.0, "%all-gather.2 = f32[2] all-gather()", step)],
+        "/device:TPU:1": [
+            (2.0, 4.0, "%all-reduce.7 = f32[2] all-reduce()", step),
+            (30.0, 31.0, "%all-to-all.4 = f32[2] all-to-all()", step)]}
+    host = [(0.0, 9.0, "exe.run"), (9.0, 10.0, "fetch"),
+            (10.0, 19.0, "exe.run"), (19.0, 20.0, "fetch")]
+    found = union.intervals(device, host, {step: ({}, {})})
+    # chip 0: 1 + 1 + 3 (the nested all-gather counts once) = 5 s; chip
+    # 1: 2 s (the all-to-all lies past the window); over 2 steps
+    assert union.union_ms_per_step(found, (), collective.COLLECTIVES) \
+        == pytest.approx((5.0 + 2.0) / 2 / 2 * 1e3)
+    monkeypatch.setattr(union, "_state", dict(union._state, last=found))
+    assert collective.compute(None) == pytest.approx(1750.0)
+    # off a mesh no collective runs: nothing, not a zero
+    alone = union.intervals({"/device:TPU:0": device["/device:TPU:0"][:1]},
+                            host, {step: ({}, {})})
+    monkeypatch.setattr(union, "_state", dict(union._state, last=alone))
+    assert collective.compute(None) is None
+    monkeypatch.setattr(union, "_state", dict(union._state, last=None))
+    assert collective.compute(None) is None
+    # the limit PERF.md section 3 names: an exchange under another NAME (a
+    # fusion that wraps an all-reduce, an async wrapper, a permute's send
+    # and recv) is not seen; the dp4 cell has none, a later cell checks
+    hidden = union.intervals({"/device:TPU:0": [
+        (1.0, 2.0, "%fusion.2 = f32[2] fusion(), calls=%all-reduce.9", step),
+        (2.0, 3.0, "%async-collective-start.1 = f32[2] async-start()", step),
+        (3.0, 4.0, "%send.1 = f32[2] send()", step),
+        (4.0, 5.0, "%recv-done.1 = f32[2] recv-done()", step)]},
+        host, {step: ({}, {})})
+    monkeypatch.setattr(union, "_state", dict(union._state, last=hidden))
+    assert collective.compute(None) is None
+
+
+def test_step_mfu_is_required_flop_over_busy_device_time():
+    step_mfu = reader("step_mfu")
+    run = types.SimpleNamespace(
+        trace={"busy_s": 2.0, "steps": 10}, chips=4, device_kind="k",
+        flops_per_sample=1e9, samples_per_step=512,
+        peak=lambda kind, what: 1e12)
+    # 512e9 FLOP a step over 0.2 s x 4 chips x 1e12
+    assert step_mfu.compute(run) == pytest.approx(64.0)
+    for trace in (None, {"busy_s": 0.0, "steps": 10},
+                  {"busy_s": 2.0, "steps": 0}):
+        run.trace = trace
+        assert step_mfu.compute(run) is None
