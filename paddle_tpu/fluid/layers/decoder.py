@@ -166,11 +166,14 @@ def moe_router(x, num_experts, top_k, scoring="softmax", scale=1.0,
 
 def moe_expert_ffn(x, topk_idx, topk_weight, experts_held, expert_width,
                    expert_start=0, num_experts=0, gate_up_attr=None,
-                   down_attr=None, name=None):
+                   down_attr=None, activation="silu", name=None):
     """The part of a routed gated FFN that experts ``expert_start ..
-    expert_start + experts_held - 1`` give, with no assignment dropped;
-    one parameter a projection, [held, D, 2 * width] (gate then up) and
-    [held, width, D].
+    expert_start + experts_held - 1`` give, with no assignment dropped:
+    each expert (act(x W_gate) * x W_up) W_down, act the ``activation``
+    ("silu", or "relu" for ReGLU experts); one parameter a projection,
+    [held, D, 2 * width] (gate then up) and [held, width, D].
+    ``topk_idx`` and ``topk_weight`` may come from a router that read
+    another tensor than ``x`` (the layer's input, say).
 
     ``num_experts`` is the width of the router that chose ``topk_idx``.
     Given it, the grouped products run over ``decoder_ops.row_bound``
@@ -178,9 +181,11 @@ def moe_expert_ffn(x, topk_idx, topk_weight, experts_held, expert_width,
     expect (tokens * k * held / num_experts), far above what a balanced
     router sends, instead of the tokens * min(k, held) a no-drop layer
     could be sent. A step routed more than the bound is exact all the
-    same: it costs further passes, as many as its rows need. The op's
-    int32 output ``Passes`` [1] (``<layer's name>.passes``, fetchable)
-    says how many ran. 0 = unknown: one pass over the most."""
+    same: it costs further passes, as many as its rows need. (Where
+    twice the share is half of the most or more, the bound is the most:
+    one pass, whatever is routed.) The op's int32 output ``Passes`` [1]
+    (``<layer's name>.passes``, fetchable) says how many ran. 0 =
+    unknown: one pass over the most."""
     helper = LayerHelper("moe_expert_ffn", **locals())
     d = x.shape[-1]
     w_gate_up = helper.create_parameter(
@@ -197,5 +202,5 @@ def moe_expert_ffn(x, topk_idx, topk_weight, experts_held, expert_width,
                 "WGateUp": [w_gate_up], "WDown": [w_down]},
         outputs={"Out": [out], "Passes": [passes]},
         attrs={"expert_start": expert_start, "num_experts": num_experts,
-               "site": helper.name})
+               "activation": activation, "site": helper.name})
     return out

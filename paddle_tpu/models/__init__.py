@@ -6,6 +6,7 @@ from . import laguna  # noqa: F401
 from . import phi4_flash  # noqa: F401
 from . import qwen3_next  # noqa: F401
 from . import resnet  # noqa: F401
+from . import smallthinker  # noqa: F401
 from . import transformer  # noqa: F401
 from . import word2vec  # noqa: F401
 from . import ptb_lm  # noqa: F401

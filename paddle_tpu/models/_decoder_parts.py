@@ -1,9 +1,10 @@
 """What the decoder LMs' builders share (qwen3_next.py, phi4_flash.py,
-laguna.py): a parameter's attribute, a bias-free or biased projection,
-RMSNorm by a named weight, the packed gated FFN, the training tail and
-the synthetic batch. Private to ``paddle_tpu.models``: each builder's
-parameter names, initialisers and op order are its own and its
-reference's, so nothing here names a parameter itself."""
+laguna.py, smallthinker.py): a parameter's attribute, a bias-free or
+biased projection, RMSNorm by a named weight, the packed gated FFN, the
+training tail, the synthetic batch and what a built program says of
+its attention and expert layers. Private to ``paddle_tpu.models``: each
+builder's parameter names, initialisers and op order are its own and
+its reference's, so nothing here names a parameter itself."""
 from __future__ import annotations
 
 import numpy as np
@@ -56,6 +57,22 @@ def ops_by_site(program, op_type, value):
     ops of one type, in layer order."""
     return {op.attr("site"): value(op)
             for op in program.global_block().ops if op.type == op_type}
+
+
+def attention_sites(program):
+    """{an attention op's ``site`` (its gauges' label): (query heads,
+    window, 0 for none)}, in layer order."""
+    return ops_by_site(
+        program, "fused_attention_qkv",
+        lambda op: (op.attr("num_heads"), op.attr("window")))
+
+
+def expert_passes(program):
+    """{an expert layer's ``site`` (its gauges' label): the name to fetch
+    for the passes of its row bound it ran that step, [1] int32}, in
+    layer order; 1 wherever the routing fitted twice the held share."""
+    return ops_by_site(program, "moe_expert_ffn",
+                       lambda op: op.output("Passes")[0])
 
 
 def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0):

@@ -22,8 +22,9 @@ from __future__ import annotations
 
 from .. import fluid
 from ..fluid import layers
-from ._decoder_parts import (attr, gated_ffn, linear, minimize, ops_by_site,
-                             rms_norm, synthetic_pretrain_batch)
+from ._decoder_parts import (attention_sites, attr, expert_passes, gated_ffn,
+                             linear, minimize, rms_norm,
+                             synthetic_pretrain_batch)
 from .bert import fused_multihead_attention
 
 __all__ = ["laguna_config", "build_laguna_pretrain_program",
@@ -128,18 +129,3 @@ def build_laguna_pretrain_program(cfg=None, seq_len=8192, lr=1e-4,
         loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
         minimize(loss, lr, recompute, checkpoints)
     return main, startup, [ids, labels], [loss]
-
-
-def attention_sites(program):
-    """{an attention op's ``site`` (its gauges' label): (query heads,
-    window, 0 for none)}, in layer order."""
-    return ops_by_site(
-        program, "fused_attention_qkv",
-        lambda op: (op.attr("num_heads"), op.attr("window")))
-
-
-def expert_passes(program):
-    """{an expert layer's ``site``: the name to fetch for the passes of
-    its row bound it ran that step, [1] int32}, in layer order."""
-    return ops_by_site(program, "moe_expert_ffn",
-                       lambda op: op.output("Passes")[0])

@@ -26,8 +26,9 @@ from .. import fluid
 from ..fluid import layers
 from ..fluid.initializer import Normal, NumpyArrayInitializer, Uniform
 from ..fluid.param_attr import ParamAttr
-from ._decoder_parts import (attr as _attr, gated_ffn, linear as _linear,
-                             minimize, ops_by_site, synthetic_pretrain_batch)
+from ._decoder_parts import (attention_sites, attr as _attr, gated_ffn,
+                             linear as _linear, minimize,
+                             synthetic_pretrain_batch)
 from .bert import fused_multihead_attention
 
 __all__ = ["phi4_flash_config", "layer_kinds", "lambda_init",
@@ -210,10 +211,3 @@ def build_phi4_flash_pretrain_program(cfg=None, seq_len=4096, lr=1e-4,
         loss = layers.mean(layers.softmax_with_cross_entropy(logits, labels))
         minimize(loss, lr, recompute, checkpoints)
     return main, startup, [ids, labels], [loss]
-
-
-def attention_sites(program):
-    """{an attention op's ``site`` (its gauge's label): its window, 0 for
-    none}, in layer order."""
-    return ops_by_site(program, "fused_attention_qkv",
-                       lambda op: op.attr("window"))

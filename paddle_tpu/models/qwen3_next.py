@@ -21,8 +21,8 @@ from .. import fluid
 from ..fluid import layers
 from ..fluid.initializer import Uniform
 from ..fluid.param_attr import ParamAttr
-from ._decoder_parts import (attr as _attr, linear as _linear, minimize,
-                             ops_by_site, rms_norm as _norm,
+from ._decoder_parts import (attr as _attr, expert_passes, linear as _linear,
+                             minimize, rms_norm as _norm,
                              synthetic_pretrain_batch)
 from .bert import fused_multihead_attention
 
@@ -148,11 +148,3 @@ def build_qwen3_next_pretrain_program(cfg=None, seq_len=4096, lr=1e-4,
                              scale=cfg["aux_coef"] / cfg["layers"]))
         minimize(loss, lr, recompute, checkpoints)
     return main, startup, [ids, labels], [ce]
-
-
-def expert_passes(program):
-    """{an expert layer's ``site`` (its gauges' label): the name to fetch
-    for the passes of its row bound it ran that step, [1] int32}, in
-    layer order; 1 wherever the routing fitted twice the held share."""
-    return ops_by_site(program, "moe_expert_ffn",
-                       lambda op: op.output("Passes")[0])

@@ -147,8 +147,8 @@ def _fused_attention_qkv(ins, attrs):
     whose score tile fits one kernel block, and a backend without the
     kernels. Causal masking is TOP-LEFT aligned (query i sees keys <= i)
     on both paths. On the kernels' path the gauges
-    ``attn_kv_blocks_per_step`` and ``attn_grid_steps_per_step`` are set,
-    the op's ``site`` each; on either path ``attn_query_heads``."""
+    ``attn_kv_blocks_per_step`` and ``attn_grid_steps_per_step`` are set;
+    either way ``attn_query_heads``, ``attn_window``, ``attn_kv_repeat``."""
     q = first(ins, "Q")
     k = first(ins, "K")
     v = first(ins, "V")
@@ -209,6 +209,14 @@ def _fused_attention_qkv(ins, attrs):
         "attn_query_heads",
         "query heads of the attention op, whichever path it takes: a "
         "model's layers may differ in it", attrs.get("site", ""), h)
+    _telemetry.set_site_gauge(
+        "attn_window",
+        "keys a query of the attention op sees under its sliding window, "
+        "0 where it has none", attrs.get("site", ""), mask.window)
+    _telemetry.set_site_gauge(
+        "attn_kv_repeat",
+        "query heads that read one key head of the attention op (1: no "
+        "grouped queries)", attrs.get("site", ""), h // h_kv)
     return out(Out=_merge_heads(o).astype(out_dtype))
 
 

@@ -472,10 +472,15 @@ def row_bound(tokens, k, held, num_experts):
     return min(full, tiles * ROW_TILE)
 
 
-def _window(o, x, weight, w_gate_up, w_down, order, lo, sizes, k):
+ACTIVATIONS = {"silu": _silu, "relu": jax.nn.relu}
+
+
+def _window(o, x, weight, w_gate_up, w_down, order, lo, sizes, k,
+            activation="silu"):
     """``o`` [T, D] + what the sorted assignments lo .. lo + len(order)
     - 1 give: ``order`` their indices into the T*k assignments, ``sizes``
-    [held] the whole layer's group sizes, clipped here to the window."""
+    [held] the whole layer's group sizes, clipped here to the window;
+    ``activation`` of the gate's half, a name of ``ACTIVATIONS``."""
     bound, f = order.shape[0], w_down.shape[1]
     ends = jnp.cumsum(sizes)
     valid = (lo + jnp.arange(bound) < ends[-1])[:, None]
@@ -484,7 +489,7 @@ def _window(o, x, weight, w_gate_up, w_down, order, lo, sizes, k):
     token = order // k
     x_rows = jnp.where(valid, x[token], 0)
     h = jnp.where(valid, _ragged(x_rows, w_gate_up, in_window), 0.0)
-    act = _silu(h[:, :f]) * h[:, f:]
+    act = ACTIVATIONS[activation](h[:, :f]) * h[:, f:]
     y = jnp.where(valid, _ragged(act, w_down, in_window), 0.0) \
         * weight[order][:, None]
     return o.at[token].add(y)
@@ -494,8 +499,8 @@ def _passes_run(sizes, bound):
     return -(-jnp.sum(sizes) // bound)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _windows(x, weight, w_gate_up, w_down, order, sizes, k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _windows(x, weight, w_gate_up, w_down, order, sizes, k, activation):
     """The windows ``order`` [passes, bound] of ``_window``, as many as
     hold a routed assignment, one after the other: a loop of that many
     trips, so a window past the routed count costs nothing. Its own
@@ -508,15 +513,16 @@ def _windows(x, weight, w_gate_up, w_down, order, sizes, k):
     return lax.fori_loop(
         0, _passes_run(sizes, order.shape[1]),
         lambda p, o: _window(o, x, weight, w_gate_up, w_down, order[p],
-                             p * order.shape[1], sizes, k), zero)
+                             p * order.shape[1], sizes, k, activation), zero)
 
 
-def _windows_fwd(x, weight, w_gate_up, w_down, order, sizes, k):
-    return (_windows(x, weight, w_gate_up, w_down, order, sizes, k),
+def _windows_fwd(x, weight, w_gate_up, w_down, order, sizes, k, activation):
+    return (_windows(x, weight, w_gate_up, w_down, order, sizes, k,
+                     activation),
             (x, weight, w_gate_up, w_down, order, sizes))
 
 
-def _windows_bwd(k, residuals, d_out):
+def _windows_bwd(k, activation, residuals, d_out):
     x, weight, w_gate_up, w_down, order, sizes = residuals
     primals = (x, weight, w_gate_up, w_down)
     zero = jnp.zeros(x.shape, jnp.float32)
@@ -524,7 +530,7 @@ def _windows_bwd(k, residuals, d_out):
     def one_pass(p, sums):
         _, vjp = jax.vjp(
             lambda *args: _window(zero, *args, order[p], p * order.shape[1],
-                                  sizes, k), *primals)
+                                  sizes, k, activation), *primals)
         return tuple(map(jnp.add, sums, vjp(d_out)))
 
     # summed as a window's gradient comes, in its primal's dtype: an
@@ -541,15 +547,18 @@ _windows.defvjp(_windows_fwd, _windows_bwd)
 @register_op("moe_expert_ffn",
              inputs=("X", "TopkIdx", "TopkWeight", "WGateUp", "WDown"),
              diff_inputs=("X", "TopkWeight", "WGateUp", "WDown"),
-             attr_defaults={"expert_start": 0, "num_experts": 0, "site": ""})
+             attr_defaults={"expert_start": 0, "num_experts": 0, "site": "",
+                            "activation": "silu"})
 def _moe_expert_ffn(ins, attrs):
     """The held experts' part of a routed gated FFN, no assignment
     dropped: Out[t] = sum over the k experts e chosen for token t that
     are held here, ``expert_start <= e < expert_start + held``, of
-    TopkWeight[t, e] * (SiLU(x W_g,e) * x W_u,e) W_d,e. WGateUp
-    [held, D, 2F] (gate then up), WDown [held, F, D]; what the experts
-    held elsewhere would add is left out. Passes [1] int32: the passes
-    that ran (below), 1 wherever the routing fits the bound.
+    TopkWeight[t, e] * (act(x W_g,e) * x W_u,e) W_d,e, act the
+    ``activation`` attr: "silu" (SwiGLU experts) or "relu" (ReGLU
+    ones); any other name raises. WGateUp [held, D, 2F] (gate then up),
+    WDown [held, F, D]; what the experts held elsewhere would add is
+    left out. Passes [1] int32: the passes that ran (below), 1 wherever
+    the routing fits the bound.
 
     Static shapes: the T*k assignments are sorted by held expert (those
     of absent experts last); the first T * min(k, held) of them are the
@@ -566,6 +575,9 @@ def _moe_expert_ffn(ins, attrs):
     x, idx, weight = (first(ins, "X"), first(ins, "TopkIdx"),
                       first(ins, "TopkWeight"))
     w_gate_up, w_down = first(ins, "WGateUp"), first(ins, "WDown")
+    activation = attrs.get("activation", "silu")
+    if activation not in ACTIVATIONS:
+        raise ValueError(f"moe_expert_ffn: activation {activation!r}")
     held, d = w_gate_up.shape[0], x.shape[-1]
     k = idx.shape[-1]
     tokens = idx.size // k
@@ -583,10 +595,11 @@ def _moe_expert_ffn(ins, attrs):
     operands = (x_rows, weight.reshape(-1), w_gate_up, w_down)
     if passes == 1:
         o = _window(jnp.zeros((tokens, d), jnp.float32), *operands, order,
-                    0, sizes, k)
+                    0, sizes, k, activation)
     else:
         order = jnp.pad(order, (0, passes * bound - full))
-        o = _windows(*operands, order.reshape(passes, bound), sizes, k)
+        o = _windows(*operands, order.reshape(passes, bound), sizes, k,
+                     activation)
     site = attrs.get("site", "")
     _gauge("moe_experts_held", "experts whose weights the layer holds",
            site, held)
@@ -598,6 +611,9 @@ def _moe_expert_ffn(ins, attrs):
     _gauge("moe_row_passes_max",
            "passes of moe_rows_per_step rows the most a no-drop layer "
            "can be sent would take", site, passes)
+    _gauge("moe_activation_relu",
+           "1 where the experts' gate is ReLU (ReGLU), 0 where SiLU",
+           site, int(activation == "relu"))
     return out(Out=o.reshape(x.shape).astype(x.dtype),
                Passes=jnp.maximum(1, _passes_run(sizes, bound))
                .astype(jnp.int32).reshape(1))

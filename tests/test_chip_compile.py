@@ -82,11 +82,18 @@ def for_the_chip(monkeypatch):
     ((1, 64, 8192, 128), dict(window=512)),
     ((1, 48, 8192, 128), dict(causal=True, f32="highest")),
     ((1, 64, 8192, 128), dict(window=512, f32="highest")),
+    # SmallThinker's two layer kinds: the first S 16384 calls and the
+    # first window wider than a block (4096), both precisions
+    ((1, 28, 16384, 128), dict(causal=True)),
+    ((1, 28, 16384, 128), dict(window=4096)),
+    ((1, 28, 16384, 128), dict(causal=True, f32="highest")),
+    ((1, 28, 16384, 128), dict(window=4096, f32="highest")),
 ], ids=["b128_s128", "s512_keypad_dropout", "s2048_causal", "s500_ragged",
         "s4096_causal_d256", "s4096_causal_dv128", "s4096_w512_dv128",
         "s4096_causal_d256_f32", "s4096_causal_dv128_f32",
         "s8192_causal_d128", "s8192_w512_d128", "s8192_causal_d128_f32",
-        "s8192_w512_d128_f32"])
+        "s8192_w512_d128_f32", "s16384_causal_d128", "s16384_w4096_d128",
+        "s16384_causal_d128_f32", "s16384_w4096_d128_f32"])
 def test_flash_fwd_bwd_compiles_for_v5e(one_chip, for_the_chip, shape, kw):
     """Forward + dK/dV + dQ kernels of `_flash_pallas`, bf16, Mosaic, each
     at the blocks `_block_sizes` CHOOSES for the shape: the gate that the
@@ -407,9 +414,9 @@ def test_phi4_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
     # (6,000 / 21,120 / 21,120 pairs in 6,400 / 40,960 / 40,960 steps at
     # 128 x 128, before PR 33)
     sites = phi4_flash.attention_sites(main)
-    assert list(sites.values()) == [512, 0, 0]
+    assert list(sites.values()) == [(40, 512), (40, 0), (40, 0)]
     pairs, steps = [], []
-    for window in sites.values():
+    for _, window in sites.values():
         mask = fa.Mask(True, window)
         blocks = fa._block_sizes("flash_fwd", 4096, 4096, 64, 128, mask, 2)
         pairs.append(40 * fa.visited_blocks(4096, 4096, *blocks, mask))
@@ -486,3 +493,71 @@ def test_laguna_train_step_compiles_for_v5e(one_chip, for_the_chip):
     print("laguna step bytes", total, mem.temp_size_in_bytes,
           mem.generated_code_size_in_bytes)
     assert 4e9 < total < 15.75e9, total
+
+
+def test_smallthinker_train_step_compiles_for_v5e(one_chip, for_the_chip):
+    """The step of `smallthinker_21b_a3b.b1_s16384` as the executor
+    lowers it, at the published widths and the cell's cut (four layers,
+    one whole period; 16 of 64 experts held; 18,992 rows of vocabulary),
+    1 x 16384 tokens, bf16 matmul operands, recomputation a layer: five
+    segments (the router's choice crosses the attention block INSIDE a
+    layer's segment), it fits the chip's 16 GB (under 15.75 GB; 12 bytes
+    a parameter of arguments), every layer runs the flash kernels at
+    D 128 with 28 query heads over 4 (causal in full on layer 0, under
+    window 4096 on layers 1-3: forward, again in the recomputed segment,
+    dK/dV and dQ), only the three window layers rotate, and every expert
+    product is XLA's grouped kernel over passes of 49,152 rows
+    (`row_bound`: twice the 24,576 the 16 held experts of 64 expect, of
+    the 98,304 a layer could be sent: two windows at most, the second
+    inside the op's loop). Start-up runs on the CPU for the shapes alone
+    (6.7 GB of host memory)."""
+    from paddle_tpu.fluid import telemetry
+    from paddle_tpu.models import smallthinker
+    from paddle_tpu.ops import decoder_ops
+    cfg = dict(smallthinker.smallthinker_config(), vocab_size=18992,
+               experts_held=16, rope_layout=[0, 1, 1, 1],
+               window_layout=[0, 1, 1, 1])
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        main, startup, _, fetches = \
+            smallthinker.build_smallthinker_pretrain_program(cfg,
+                                                             seq_len=16384)
+        feed = smallthinker.synthetic_pretrain_batch(cfg, 1, 16384)
+        cb, lower = _decoder_step(main, startup, feed, fetches, one_chip)
+        assert cb._remat_plan is not None and len(
+            cb._remat_plan.segments) == 5  # four layers and the head
+        assert [len(s.outs) for s in cb._remat_plan.segments] == [1] * 5
+        compiled = lower().compile()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    text = compiled.as_text()
+    grouped = [line for line in text.splitlines()
+               if KERNEL in line and "ragged-dot" in line]
+    flash = _kernel_names("\n".join(
+        line for line in text.splitlines() if line not in grouped))
+    assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2 * 4,
+                     ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 4,
+                     ("fwd/fused_attention_qkv", "flash_bwd_dq"): 4}
+    assert decoder_ops.row_bound(16384, 6, 16, 64) == 49152
+    assert "[49152,1536]" in text and "[98304,1536]" not in text
+    sites = smallthinker.attention_sites(main)
+    assert list(sites.values()) == [(28, 0), (28, 4096), (28, 4096),
+                                    (28, 4096)]
+    for name, want in (("attn_window", [0, 4096, 4096, 4096]),
+                       ("attn_kv_repeat", [7] * 4)):
+        gauge = telemetry.REGISTRY.get(name)
+        assert [gauge.value(site=s) for s in sites] == want
+    passes = smallthinker.expert_passes(main)
+    relu = telemetry.REGISTRY.get("moe_activation_relu")
+    assert [relu.value(site=s) for s in passes] == [1] * 4
+    assert sum(op.type == "rotary_embedding"
+               for op in main.global_block().ops) == 2 * 3
+    mem = compiled.memory_analysis()
+    parameters = 559290880
+    assert abs(mem.argument_size_in_bytes / (12 * parameters) - 1) < 0.01
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+             + mem.generated_code_size_in_bytes)
+    print("smallthinker step bytes", total, mem.temp_size_in_bytes,
+          mem.generated_code_size_in_bytes)
+    assert 4e9 < total < 15.0e9, total

@@ -83,3 +83,30 @@ def test_laguna_rehearsal_holds_the_program_to_its_reference(tmp_path):
     assert step["attn_query_heads"] == [4, 8, 8, 8, 4]
     assert step["attention_heads_and_windows"] == [
         [4, 0], [8, 24], [8, 24], [8, 24], [4, 0]]
+
+
+def test_smallthinker_rehearsal_holds_the_program_to_its_reference(tmp_path):
+    """`--smallthinker --tiny`: both programs (bf16 operands are float32
+    ones on a CPU) within the float32 limits of the reference, the
+    reference with bf16 ACTIVATIONS outside them, a pass an expert
+    layer, and what the ops say of the model's mechanisms: 14 query
+    heads over 2 (a repeat of 7), window 24 on three layers of four,
+    ReLU-gated experts."""
+    res, phase, rows = _run(tmp_path, "--smallthinker", "--tiny")
+    assert res.returncode != 0 and rows[-1]["rehearsal"], res.stderr[-2000:]
+    parity = phase["smallthinker_parity"]
+    limits = parity["limits"]["float32"]
+    assert {"loss", "layers.0.attn.w_q@GRAD", "layers.1.attn.w_q@GRAD",
+            "layers.0.moe.w_router@GRAD", "layers.1.moe.w_gate_up@GRAD",
+            "layers.1.moe.w_down@GRAD", "lm_head@GRAD",
+            "embed_tokens@GRAD"} <= set(limits)
+    for errors in parity["errors"].values():
+        assert all(errors[n] <= limits[n] for n in limits)
+    assert any(parity["bf16_activations"][n] > limits[n] for n in limits)
+    step = phase["smallthinker_step"]
+    assert step["passes"] == [1, 1, 1, 1]
+    assert step["attention_heads_and_windows"] == [
+        [14, 0], [14, 24], [14, 24], [14, 24]]
+    assert step["attn_window"] == [0, 24, 24, 24]
+    assert step["attn_kv_repeat"] == [7] * 4
+    assert step["moe_activation_relu"] == [1] * 4
