@@ -36,7 +36,7 @@ from jax import lax
 
 from .registry import register_op, first, out
 from .math_ops import mxu_available
-from .pallas import selective_scan
+from .pallas import grouped_matmul, selective_scan
 
 
 def _operands(*xs):
@@ -475,17 +475,44 @@ def row_bound(tokens, k, held, num_experts):
 ACTIVATIONS = {"silu": _silu, "relu": jax.nn.relu}
 
 
+def _in_window(ends, sizes, lo, bound):
+    """The whole layer's group sizes (``ends`` their running sum),
+    clipped to the sorted assignments lo .. lo + bound - 1."""
+    return jnp.clip(jnp.minimum(ends, lo + bound)
+                    - jnp.maximum(ends - sizes, lo), 0)
+
+
+def _kernel_blocks(bound, x, w_gate_up):
+    """The blocks of ``pallas/grouped_matmul.py``'s kernels for a pass of
+    ``bound`` rows of these operands, or None where ``lax.ragged_dot``
+    runs it: a backend without the kernels, a step traced under a mesh,
+    a shape ``_block_sizes`` does not take."""
+    if not grouped_matmul.use_kernels():
+        return None
+    held, d, f2 = w_gate_up.shape
+    return grouped_matmul._block_sizes(bound, d, f2 // 2, held,
+                                       _operands(x)[0].dtype.itemsize)
+
+
 def _window(o, x, weight, w_gate_up, w_down, order, lo, sizes, k,
             activation="silu"):
     """``o`` [T, D] + what the sorted assignments lo .. lo + len(order)
     - 1 give: ``order`` their indices into the T*k assignments, ``sizes``
     [held] the whole layer's group sizes, clipped here to the window;
-    ``activation`` of the gate's half, a name of ``ACTIVATIONS``."""
+    ``activation`` of the gate's half, a name of ``ACTIVATIONS``. Two
+    lowerings of the one pass: the Pallas kernels where
+    ``_kernel_blocks`` gives them blocks, which bring their own vjp,
+    else ``lax.ragged_dot`` between masks, differentiated by JAX."""
+    if _kernel_blocks(order.shape[0], x, w_gate_up):
+        return _window_kernels(o, x, weight, w_gate_up, w_down, order, lo,
+                               sizes, k, activation)
+    # the operands' cast before the gather: the same values, half the rows'
+    # bytes
+    x, w_gate_up, w_down = _operands(x, w_gate_up, w_down)
     bound, f = order.shape[0], w_down.shape[1]
     ends = jnp.cumsum(sizes)
     valid = (lo + jnp.arange(bound) < ends[-1])[:, None]
-    in_window = jnp.clip(jnp.minimum(ends, lo + bound)
-                         - jnp.maximum(ends - sizes, lo), 0)
+    in_window = _in_window(ends, sizes, lo, bound)
     token = order // k
     x_rows = jnp.where(valid, x[token], 0)
     h = jnp.where(valid, _ragged(x_rows, w_gate_up, in_window), 0.0)
@@ -493,6 +520,77 @@ def _window(o, x, weight, w_gate_up, w_down, order, lo, sizes, k,
     y = jnp.where(valid, _ragged(act, w_down, in_window), 0.0) \
         * weight[order][:, None]
     return o.at[token].add(y)
+
+
+def _kernel_rows(x, weight, w_gate_up, order, lo, sizes, k):
+    """What both ways of a kernel pass start from: its blocks, the
+    sorted assignments padded to whole row tiles (a padded row lies past
+    every group), their tokens, and the operands of
+    ``grouped_matmul.forward``'s rows, routing weights and sizes."""
+    bound = order.shape[0]
+    blocks = _kernel_blocks(bound, x, w_gate_up)
+    order = jnp.pad(order, (0, -bound % blocks.tm))
+    token = order // k
+    return blocks, order, token, (x[token], weight[order]), \
+        _in_window(jnp.cumsum(sizes), sizes, lo, bound)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _window_kernels(o, x, weight, w_gate_up, w_down, order, lo, sizes, k,
+                    activation):
+    """``_window`` on the kernels of ``pallas/grouped_matmul.py``: no
+    mask (the kernels walk the routed row tiles and write zero past the
+    last group), the activation and the routing weight in the products'
+    epilogues. Its own vjp, ``_window_kernels_bwd``."""
+    x, w_gate_up, w_down = _operands(x, w_gate_up, w_down)
+    blocks, _, token, rows, in_window = _kernel_rows(
+        x, weight, w_gate_up, order, lo, sizes, k)
+    return o.at[token].add(grouped_matmul.forward(
+        *rows, w_gate_up, w_down, in_window, activation, blocks))
+
+
+def _window_kernels_fwd(o, x, weight, w_gate_up, w_down, order, lo, sizes,
+                        k, activation):
+    return (_window_kernels(o, x, weight, w_gate_up, w_down, order, lo,
+                            sizes, k, activation),
+            (x, weight, w_gate_up, w_down, order, lo, sizes))
+
+
+def _window_kernels_back(sums, x, weight, w_gate_up, w_down, order, lo,
+                         sizes, k, activation, g):
+    """``sums`` = the gradients of (x, weight, w_gate_up, w_down) so
+    far, plus this pass's: x, w_gate_up, w_down the MXU's operands
+    (``_operands``), ``g`` [T, D] the output's gradient as one. The
+    rows' and routing weights' sums are float32; the weights' are kept
+    as their operands are (as the other lowering's: they stay alive
+    until the optimizer reads them) and added where they lie
+    (``grouped_matmul.backward``): a pass writes the experts it holds."""
+    blocks, order, token, rows, in_window = _kernel_rows(
+        x, weight, w_gate_up, order, lo, sizes, k)
+    d_rows, d_row_weight, d_w_gate_up, d_w_down = grouped_matmul.backward(
+        *rows, w_gate_up, w_down, in_window, activation, blocks, g[token],
+        sums[2:])
+    return (sums[0].at[token].add(d_rows),
+            sums[1].at[order].add(d_row_weight), d_w_gate_up, d_w_down)
+
+
+def _float32_zeros(*xs):
+    return tuple(jnp.zeros(x.shape, jnp.float32) for x in xs)
+
+
+def _window_kernels_bwd(k, activation, residuals, d_out):
+    *primals, order, lo, sizes = residuals
+    x, w_gate_up, w_down = _operands(primals[0], *primals[2:])
+    # no weights' gradient so far: the kernels write this pass's whole
+    sums = _window_kernels_back(
+        _float32_zeros(*primals[:2]) + (None, None), x, primals[1],
+        w_gate_up, w_down, order, lo, sizes, k, activation,
+        d_out.astype(x.dtype))
+    return (d_out,) + tuple(s.astype(p.dtype) for s, p in zip(
+        sums, primals)) + (None, None, None)
+
+
+_window_kernels.defvjp(_window_kernels_fwd, _window_kernels_bwd)
 
 
 def _passes_run(sizes, bound):
@@ -510,6 +608,8 @@ def _windows(x, weight, w_gate_up, w_down, order, sizes, k, activation):
     cell's sizes). The backward runs the same trips, each the vjp of
     one window made anew, and sums them."""
     zero = jnp.zeros(x.shape, jnp.float32)
+    # the weights' cast before the passes: once, not a pass
+    x, w_gate_up, w_down = _operands(x, w_gate_up, w_down)
     return lax.fori_loop(
         0, _passes_run(sizes, order.shape[1]),
         lambda p, o: _window(o, x, weight, w_gate_up, w_down, order[p],
@@ -523,22 +623,36 @@ def _windows_fwd(x, weight, w_gate_up, w_down, order, sizes, k, activation):
 
 
 def _windows_bwd(k, activation, residuals, d_out):
-    x, weight, w_gate_up, w_down, order, sizes = residuals
-    primals = (x, weight, w_gate_up, w_down)
-    zero = jnp.zeros(x.shape, jnp.float32)
+    *primals, order, sizes = residuals
+    x, w_gate_up, w_down = _operands(primals[0], *primals[2:])
+    bound = order.shape[1]
+    if _kernel_blocks(bound, x, w_gate_up):
+        g = d_out.astype(x.dtype)
 
-    def one_pass(p, sums):
-        _, vjp = jax.vjp(
-            lambda *args: _window(zero, *args, order[p], p * order.shape[1],
-                                  sizes, k, activation), *primals)
-        return tuple(map(jnp.add, sums, vjp(d_out)))
+        def one_pass(p, sums):
+            return _window_kernels_back(
+                sums, x, primals[1], w_gate_up, w_down, order[p], p * bound,
+                sizes, k, activation, g)
 
-    # summed as a window's gradient comes, in its primal's dtype: an
-    # expert's rows lie side by side, so all but the two windows its
-    # group may straddle add exact zeros to its weights' gradient
-    return lax.fori_loop(
-        0, _passes_run(sizes, order.shape[1]), one_pass,
-        tuple(map(jnp.zeros_like, primals))) + (None, None)
+        sums = _float32_zeros(*primals[:2]) + (
+            jnp.zeros_like(w_gate_up), jnp.zeros_like(w_down))
+    else:
+        operands = (x, primals[1], w_gate_up, w_down)
+        zero = jnp.zeros(x.shape, jnp.float32)
+
+        def one_pass(p, sums):
+            _, vjp = jax.vjp(
+                lambda *args: _window(zero, *args, order[p], p * bound,
+                                      sizes, k, activation), *operands)
+            return tuple(map(jnp.add, sums, vjp(d_out)))
+
+        # summed as a window's gradient comes, in its operand's dtype: an
+        # expert's rows lie side by side, so all but the two windows its
+        # group may straddle add exact zeros to its weights' gradient
+        sums = tuple(map(jnp.zeros_like, operands))
+    sums = lax.fori_loop(0, _passes_run(sizes, bound), one_pass, sums)
+    return tuple(s.astype(p.dtype) for s, p in zip(sums, primals)) \
+        + (None, None)
 
 
 _windows.defvjp(_windows_fwd, _windows_bwd)
@@ -588,11 +702,7 @@ def _moe_expert_ffn(ins, attrs):
     passes = -(-full // bound)
     order = jnp.argsort(key, stable=True)[:full]
     sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
-    # the operands' cast before the gather: the same values, half the rows'
-    # bytes; the weights' before the passes: once, not a pass
-    x_rows, w_gate_up, w_down = _operands(x.reshape(tokens, d), w_gate_up,
-                                          w_down)
-    operands = (x_rows, weight.reshape(-1), w_gate_up, w_down)
+    operands = (x.reshape(tokens, d), weight.reshape(-1), w_gate_up, w_down)
     if passes == 1:
         o = _window(jnp.zeros((tokens, d), jnp.float32), *operands, order,
                     0, sizes, k, activation)
@@ -611,6 +721,17 @@ def _moe_expert_ffn(ins, attrs):
     _gauge("moe_row_passes_max",
            "passes of moe_rows_per_step rows the most a no-drop layer "
            "can be sent would take", site, passes)
+    blocks = _kernel_blocks(bound, operands[0], w_gate_up)
+    if blocks:
+        _gauge("moe_row_tile",
+               "rows a tile of the expert products' Pallas kernels, chosen "
+               "from the rows a held expert is expected to see; unset where "
+               "lax.ragged_dot ran", site, blocks.tm)
+        _gauge("moe_grid_row_tiles_per_step",
+               "row tiles the grids of the expert products' Pallas kernels "
+               "span over moe_row_passes_max passes: the most a routing "
+               "can make live; unset where lax.ragged_dot ran", site,
+               passes * -(-bound // blocks.tm))
     _gauge("moe_activation_relu",
            "1 where the experts' gate is ReLU (ReGLU), 0 where SiLU",
            site, int(activation == "relu"))

@@ -13,10 +13,25 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+@pytest.fixture(params=["ragged_dot", "kernels"])
+def expert_lowering(request):
+    """Both lowerings of a pass of `moe_expert_ffn` (ops/decoder_ops.
+    _window): `lax.ragged_dot` between masks, what the CPU runs, and the
+    Pallas kernels of ops/pallas/grouped_matmul.py through the
+    interpreter, at a row tile of 8 and whatever the toy widths are."""
+    if request.param == "ragged_dot":
+        yield request.param
+        return
+    from paddle_tpu.ops.pallas import flash_attention, grouped_matmul
+    with flash_attention.interpret_guard(), grouped_matmul.block_override(8):
+        yield request.param
 
 
 def pytest_configure(config):

@@ -153,6 +153,53 @@ def test_scan_kernels_compile_for_v5e(one_chip, for_the_chip):
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("tokens,k,held,width,d,f,activation,kernels", [
+    (4096, 10, 32, 512, 2048, 512, "silu", True),
+    (8192, 8, 32, 256, 2048, 512, "silu", True),
+    (16384, 6, 16, 64, 2560, 768, "relu", False),
+], ids=["qwen3_next", "laguna", "smallthinker"])
+def test_expert_kernels_compile_for_v5e_in_float32(
+        one_chip, for_the_chip, tokens, k, held, width, d, f, activation,
+        kernels):
+    """`moe_expert_ffn` forward + backward at the three expert cells'
+    calls with float32 operands at the "highest" matmul precision, as
+    the float32 parity programs run it (`chip_smoke.py --qwen3-next /
+    --laguna / --smallthinker`; the bf16 calls compile inside the cells'
+    steps below): the seven kernels of `grouped_matmul.py` at the blocks
+    `_block_sizes` chooses, inside the VMEM the chip's compiler allows,
+    and no `ragged-dot`; SmallThinker's 2560 x 768 blocks do not fit the
+    budget in float32, the chooser declines and the op keeps
+    `lax.ragged_dot`."""
+    from paddle_tpu.ops import decoder_ops
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    from paddle_tpu.ops.registry import OPS
+    rows = decoder_ops.row_bound(tokens, k, held, width)
+    assert (gm._block_sizes(rows, d, f, held, 4) is not None) == kernels
+    shapes = (((1, tokens, d), jnp.float32), ((1, tokens, k), jnp.int32),
+              ((1, tokens, k), jnp.float32), ((held, d, 2 * f), jnp.float32),
+              ((held, f, d), jnp.float32))
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    names = ("X", "TopkIdx", "TopkWeight", "WGateUp", "WDown")
+
+    def loss(x, idx, *rest):
+        out = OPS.get("moe_expert_ffn").kernel(
+            {n: [v] for n, v in zip(names, (x, idx) + rest)},
+            {"num_experts": width, "site": "compile",
+             "activation": activation})["Out"][0]
+        return jnp.sum(out ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        text = jax.jit(jax.grad(loss, (0, 2, 3, 4))).lower(
+            *args).compile().as_text()
+    if not kernels:
+        assert "ragged-dot" in text and "pallas_call" not in text
+        return
+    assert "ragged-dot" not in text
+    assert _kernel_names(text) == {
+        (None, name): 1 for _, name in _expert_kernels(1)}
+
+
 def test_stem_max_pool_compiles_to_one_select_and_scatter_for_v5e(one_chip,
                                                                 for_the_chip):
     """ResNet's stem pool (3x3 stride 2 pad 1 behind a ReLU) at the cell's
@@ -197,6 +244,19 @@ def _kernel_names(text):
             key = (scope and scope.group(1), kernel)
             found[key] = found.get(key, 0) + 1
     return found
+
+
+def _expert_kernels(layers):
+    """The kernels of ``layers`` expert layers' passes in a step
+    (ops/pallas/grouped_matmul.py), each once a layer, inside the loops
+    over the windows: the two products with their epilogues forward (the
+    recomputed segment's are dead code: the grad op's vjp makes what it
+    needs itself), the gate/up product again, the backward of both
+    products and the two weights' gradients in the grad op."""
+    return {("fwd/moe_expert_ffn", name): layers for name in (
+        "moe_gmm_gate_up", "moe_gmm_down", "moe_gmm_project",
+        "moe_gmm_down_bwd", "moe_gmm_rows_bwd", "moe_tgmm_gate_up",
+        "moe_tgmm_down")}
 
 
 def _bert_base_width_step(layers, batch, seq_len, one_chip, mesh=None,
@@ -337,19 +397,14 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", False)
     text = compiled.as_text()
-    # XLA's own grouped-product kernels carry no Pallas name
-    grouped = [line for line in text.splitlines()
-               if KERNEL in line and "ragged-dot" in line]
-    flash = _kernel_names("\n".join(
-        line for line in text.splitlines() if line not in grouped))
-    # 4 layers x 2 projections x (forward, again in the backward's own
-    # loop, backward's two)
-    assert sum("ragged-dot-none" in line for line in grouped) == 4 * 2 * 4
+    assert "ragged-dot" not in text
     assert "[5120,1024]" in text and "[40960,1024]" not in text \
         and "[40960,2048]" not in text
-    assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2,
-                     ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
-                     ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1}
+    assert _kernel_names(text) == {
+        ("fwd/fused_attention_qkv", "flash_fwd"): 2,
+        ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
+        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1,
+        **_expert_kernels(4)}
     import re
     # 3 chunk scans x (forward, again, backward) + 4 expert layers x
     # (forward, backward): the loops over the windows of `row_bound` rows
@@ -468,14 +523,18 @@ def test_laguna_train_step_compiles_for_v5e(one_chip, for_the_chip):
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", False)
     text = compiled.as_text()
-    grouped = [line for line in text.splitlines()
-               if KERNEL in line and "ragged-dot" in line]
-    flash = _kernel_names("\n".join(
-        line for line in text.splitlines() if line not in grouped))
-    assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2 * 5,
-                     ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 5,
-                     ("fwd/fused_attention_qkv", "flash_bwd_dq"): 5}
+    assert "ragged-dot" not in text
+    assert _kernel_names(text) == {
+        ("fwd/fused_attention_qkv", "flash_fwd"): 2 * 5,
+        ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 5,
+        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 5,
+        **_expert_kernels(4)}
     assert decoder_ops.row_bound(8192, 8, 32, 256) == 16384
+    passes = laguna.expert_passes(main)
+    for name, want in (("moe_row_tile", 256),
+                       ("moe_grid_row_tiles_per_step", 4 * 64)):
+        gauge = telemetry.REGISTRY.get(name)
+        assert [gauge.value(site=s) for s in passes] == [want] * 4
     assert "[16384,1024]" in text and "[65536,1024]" not in text
     sites = laguna.attention_sites(main)
     assert list(sites.values()) == [(48, 0), (64, 512), (64, 512),
@@ -531,13 +590,12 @@ def test_smallthinker_train_step_compiles_for_v5e(one_chip, for_the_chip):
     finally:
         core.set_flag("FLAGS_use_bf16_matmul", False)
     text = compiled.as_text()
-    grouped = [line for line in text.splitlines()
-               if KERNEL in line and "ragged-dot" in line]
-    flash = _kernel_names("\n".join(
-        line for line in text.splitlines() if line not in grouped))
-    assert flash == {("fwd/fused_attention_qkv", "flash_fwd"): 2 * 4,
-                     ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 4,
-                     ("fwd/fused_attention_qkv", "flash_bwd_dq"): 4}
+    assert "ragged-dot" not in text
+    assert _kernel_names(text) == {
+        ("fwd/fused_attention_qkv", "flash_fwd"): 2 * 4,
+        ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 4,
+        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 4,
+        **_expert_kernels(4)}
     assert decoder_ops.row_bound(16384, 6, 16, 64) == 49152
     assert "[49152,1536]" in text and "[98304,1536]" not in text
     sites = smallthinker.attention_sites(main)
@@ -548,8 +606,10 @@ def test_smallthinker_train_step_compiles_for_v5e(one_chip, for_the_chip):
         gauge = telemetry.REGISTRY.get(name)
         assert [gauge.value(site=s) for s in sites] == want
     passes = smallthinker.expert_passes(main)
-    relu = telemetry.REGISTRY.get("moe_activation_relu")
-    assert [relu.value(site=s) for s in passes] == [1] * 4
+    for name, want in (("moe_activation_relu", 1), ("moe_row_tile", 256),
+                       ("moe_grid_row_tiles_per_step", 2 * 192)):
+        gauge = telemetry.REGISTRY.get(name)
+        assert [gauge.value(site=s) for s in passes] == [want] * 4
     assert sum(op.type == "rotary_embedding"
                for op in main.global_block().ops) == 2 * 3
     mem = compiled.memory_analysis()

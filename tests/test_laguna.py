@@ -315,7 +315,7 @@ def _routed(x, p, start, held):
                   WDown=p["w_down"][start:start + held])["Out"]
 
 
-def test_the_eight_ranks_shares_add_up_to_the_uncut_layer():
+def test_the_eight_ranks_shares_add_up_to_the_uncut_layer(expert_lowering):
     """THE SHARE TEST, at the cell's ratios (32 experts, top 8, 8 ranks
     of 4): the routed parts the eight ranks compute under the sigmoid
     router (`expert_start` 0, 4, ..., 28), plus the shared expert

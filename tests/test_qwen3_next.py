@@ -231,7 +231,9 @@ def _routed(x, p, start, held, **attrs):
 
 
 def _gauge(name, site):
-    return telemetry.REGISTRY.get(name).value(site=site)
+    """The gauge an op set at ``site``; 0 where none did."""
+    family = telemetry.REGISTRY.get(name)
+    return family.value(site=site) if family else 0
 
 
 def _scalar(f):
@@ -289,7 +291,7 @@ def test_held_experts_part_and_its_gradients(start, held):
                          ids=["width_unknown", "bounded"])
 @pytest.mark.parametrize("ranks", [16, 4, 1])
 def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(
-        ranks, num_experts, monkeypatch):
+        ranks, num_experts, monkeypatch, expert_lowering):
     """THE SHARE TEST: the routed parts that `ranks` expert-parallel
     ranks compute, each holding E / ranks experts, plus the shared
     expert counted once, are what the reference gives for the whole
@@ -299,15 +301,22 @@ def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(
     monkeypatch.setattr(decoder_ops, "ROW_TILE", 4)
     x, p = normal(60, 2, 10, D), _moe_weights(61)
     held = E // ranks
+    site = "t_share_" + expert_lowering
     parts = [_routed(x, p, r * held, held, num_experts=num_experts,
-                     site="t_share")[0] for r in range(ranks)]
+                     site=site)[0] for r in range(ranks)]
     whole, _ = ref.moe(p, x, {"experts_per_tok": K_TOP, "expert_start": 0})
     close(sum(parts) + _shared(x, p), whole, OP_TOL)
     full = 20 * min(K_TOP, held)
-    assert _gauge("moe_rows_per_step", "t_share") == (
-        {16: 8, 4: 32, 1: full}[ranks] if num_experts else full)
-    assert _gauge("moe_row_passes_max", "t_share") == (
-        {16: 3, 4: 2, 1: 1}[ranks] if num_experts else 1)
+    rows = {16: 8, 4: 32, 1: full}[ranks] if num_experts else full
+    passes = {16: 3, 4: 2, 1: 1}[ranks] if num_experts else 1
+    assert _gauge("moe_rows_per_step", site) == rows
+    assert _gauge("moe_row_passes_max", site) == passes
+    # the kernels' gauges say which lowering ran: a tile of 8 rows, the
+    # tiles all the passes span (rows padded to whole tiles); else unset
+    kernels = expert_lowering == "kernels"
+    assert _gauge("moe_row_tile", site) == 8 * kernels
+    assert _gauge("moe_grid_row_tiles_per_step", site) == \
+        passes * -(-rows // 8) * kernels
     # and a rank whose experts nobody chose adds exactly nothing
     x = x.at[..., 0].set(1.0)  # a constant feature: a bias on the logits
     nobody = dict(p, w_router=p["w_router"].at[:, :4].set(0.0)
@@ -316,7 +325,8 @@ def test_the_shares_of_all_ranks_add_up_to_the_uncut_layer(
 
 
 @pytest.mark.parametrize("held", [1, 4])
-def test_a_router_forced_onto_one_expert_loses_no_assignment(held):
+def test_a_router_forced_onto_one_expert_loses_no_assignment(
+        held, expert_lowering):
     """Every token sent to expert 5 (and to two more): the layer that
     holds it computes all 64 of them, far past 64 x 3 / 16 a fair
     share: no capacity, no drop."""
@@ -360,7 +370,7 @@ def test_row_bound_is_twice_the_held_share_in_whole_tiles(
 @pytest.mark.parametrize("held,tile,ran,most", [(1, 8, 3, 3), (2, 16, 2, 3),
                                                 (4, 32, 2, 2)])
 def test_a_forced_overflow_runs_further_passes_and_loses_no_assignment(
-        held, tile, ran, most, checkpoint, monkeypatch):
+        held, tile, ran, most, checkpoint, monkeypatch, expert_lowering):
     """Every token sent to expert 5, the layer told the router's width:
     64 rows and more where 12 a held expert are expected and twice that
     is the bound. The windows past the first run (three of 24 rows for
@@ -378,7 +388,8 @@ def test_a_forced_overflow_runs_further_passes_and_loses_no_assignment(
                     w_down=p["w_down"][5:5 + held])
 
     def program(x, p):
-        r, o = _expert_layer(x, p, 5, held, num_experts=E, site="t_forced")
+        r, o = _expert_layer(x, p, 5, held, num_experts=E,
+                             site="t_forced_" + expert_lowering)
         return o["Out"] + _shared(x, p), r["AuxLoss"][0]
 
     def reference(x, p):
@@ -405,6 +416,8 @@ def test_a_forced_overflow_runs_further_passes_and_loses_no_assignment(
     close(got[0], want[0], OP_TOL)
     for name in p:
         close(got[1][name], want[1][name], OP_TOL)
+    assert _gauge("moe_row_tile", "t_forced_" + expert_lowering) == \
+        8 * (expert_lowering == "kernels")
 
 
 @pytest.mark.parametrize("tile", [4, 512])

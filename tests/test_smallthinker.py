@@ -432,7 +432,7 @@ def _routed(r, g, p, start, held):
                   WDown=p["w_down"][start:start + held])["Out"]
 
 
-def test_the_four_ranks_shares_add_up_to_the_uncut_layer():
+def test_the_four_ranks_shares_add_up_to_the_uncut_layer(expert_lowering):
     """THE SHARE TEST, at the cell's counts (64 experts, top 6, 4 ranks
     of 16): the parts the four ranks compute (`expert_start` 0, 16, 32,
     48) from the router's input r and the experts' input g add up to

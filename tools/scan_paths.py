@@ -50,19 +50,23 @@ def _programs(chunk):
     return op, step
 
 
-def _device_ms(compiled, args, iters):
+def device_ms(compiled, args, iters, top=4, keep=None):
+    """(device ms a call, the ``top`` device operations by name, ms a
+    call) over ``iters`` traced calls; ``keep``: a directory that keeps
+    the trace."""
     import jax
     import trace_reduce
-    trace_dir = tempfile.mkdtemp(prefix="scan_paths_")
+    trace_dir = keep or tempfile.mkdtemp(prefix="scan_paths_")
     try:
         jax.profiler.start_trace(trace_dir)
         for _ in range(iters):
             jax.block_until_ready(compiled(*args))
         jax.profiler.stop_trace()
         red = trace_reduce.reduce(*trace_reduce.read(trace_dir, ()), iters,
-                                  top=4)
+                                  top=top)
     finally:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        if not keep:
+            shutil.rmtree(trace_dir, ignore_errors=True)
     return red["busy_s"] / iters * 1e3, \
         [[name, s / iters * 1e3] for name, s in red["device_ops"]]
 
@@ -101,8 +105,8 @@ def measure(blocks, *, batch, seq, channels, states, chunk, iters, on_chip):
     outs = jax.block_until_ready(both(g, *args))
     jax.block_until_ready(fwd(*args))
     if on_chip:
-        row["fwd_device_ms"], _ = _device_ms(fwd, args, iters)
-        row["fwd_bwd_device_ms"], row["device_ops_ms"] = _device_ms(
+        row["fwd_device_ms"], _ = device_ms(fwd, args, iters)
+        row["fwd_bwd_device_ms"], row["device_ops_ms"] = device_ms(
             both, (g,) + args, iters)
     return row, [np.asarray(o, np.float32) for o in outs]
 
