@@ -592,7 +592,7 @@ def reference_parity(which, tiny, clock):
     import jax.numpy as jnp
     import numpy as np
     import paddle_tpu.fluid as fluid
-    from paddle_tpu.fluid import core
+    from paddle_tpu.fluid import core, telemetry
 
     spec = PARITY[which]
     limits = spec["limits"]
@@ -636,8 +636,10 @@ def reference_parity(which, tiny, clock):
         said = say(got[len(names):])
         got = dict(zip(names, (np.asarray(g) for g in got)))
         stats = jax.devices()[0].memory_stats() or {}
+        kept = telemetry.REGISTRY.get("remat_kept_bytes")
         emit(phase=which + "_step", bf16_operands=bf16_operands,
              loss=float(got["loss"].ravel()[0]), **said,
+             remat_kept_bytes=kept and int(kept.value()),
              parameters=int(sum(w.size for w in weights.values())),
              seconds=round(time.perf_counter() - t0, 1),
              peak_bytes_in_use=stats.get("peak_bytes_in_use"),

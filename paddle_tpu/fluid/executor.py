@@ -720,15 +720,21 @@ class _CompiledBlock:
         env.update(feeds)
         lod_env: Dict[str, tuple] = dict(self._init_lods)
         from ..ops.pallas.flash_attention import mesh_guard
+        kept = 0
         with mesh_guard(self.mesh):  # Mosaic kernels partition themselves
             if self._pipeline_plan is not None:
                 from .pipeline_lowering import exec_plan
                 exec_plan(self, self._pipeline_plan, env, lod_env, rng)
             elif self._remat_plan is not None:
                 from .recompute_lowering import exec_plan as exec_remat
-                exec_remat(self, self._remat_plan, env, lod_env, rng)
+                kept = exec_remat(self, self._remat_plan, env, lod_env, rng)
             else:
                 self._exec_ops(self.ops, env, lod_env, rng)
+        _telemetry.REGISTRY.gauge(
+            "remat_kept_bytes",
+            "bytes of the values the traced step's recomputed segments "
+            "keep by name (recompute_lowering.KEPT) instead of recomputing "
+            "them, summed over segments; 0 without checkpoints").set(kept)
         fetches = []
         for i, n in enumerate(self.fetch_names):
             if n not in env:

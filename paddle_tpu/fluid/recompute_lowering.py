@@ -39,7 +39,27 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
+from ..ops.pallas import flash_attention as _flash
+
+# The names op code gives the values a recomputed segment keeps from its
+# forward for its backward (``keep``); every other value is recomputed,
+# as under a checkpoint with no policy. The flash kernels' output and
+# log-sum-exp: kept, the backward does not run the forward kernel again.
+KEPT = (_flash.FLASH_OUT, _flash.FLASH_LSE)
+
+_kept_bytes: Optional[List[int]] = None  # open while a segment is traced
+
+
+def keep(x, name):
+    """``x`` under ``name``, one of ``KEPT``: a value a recomputed segment
+    keeps rather than recomputes. Outside a segment the name changes
+    nothing; inside one its bytes are counted (``remat_kept_bytes``)."""
+    assert name in KEPT, name
+    if _kept_bytes is not None:
+        _kept_bytes.append(x.size * x.dtype.itemsize)
+    return checkpoint_name(x, name)
 
 
 class Segment:
@@ -276,24 +296,33 @@ def build_plan(cb, ckpt_names) -> Optional[RematPlan]:
     return plan
 
 
-def exec_plan(cb, plan: RematPlan, env: Dict[str, Any], lod_env, rng):
-    """One rematerialized step into ``env`` (called inside jit)."""
+def exec_plan(cb, plan: RematPlan, env: Dict[str, Any], lod_env,
+              rng) -> int:
+    """One rematerialized step into ``env`` (called inside jit); the bytes
+    of the values its segments keep by name (``KEPT``)."""
+    global _kept_bytes
     cb._exec_ops(plan.pre_ops, env, lod_env, rng)
 
     vjps = []
-    for seg in plan.segments:
-        ins = [env[n] for n in seg.ins]
+    policy = jax.checkpoint_policies.save_only_these_names(*KEPT)
+    _kept_bytes = []
+    try:
+        for seg in plan.segments:
+            ins = [env[n] for n in seg.ins]
 
-        def seg_fn(vals, _seg=seg):
-            e = {n: v for n, v in zip(_seg.ins, vals)}
-            cb._exec_ops(_seg.ops, e, dict(lod_env), rng)
-            return tuple(e[n] for n in _seg.outs)
+            def seg_fn(vals, _seg=seg):
+                e = {n: v for n, v in zip(_seg.ins, vals)}
+                cb._exec_ops(_seg.ops, e, dict(lod_env), rng)
+                return tuple(e[n] for n in _seg.outs)
 
-        wrapped = jax.checkpoint(seg_fn)
-        outs, vjp_fn = jax.vjp(wrapped, ins)
-        for n, v in zip(seg.outs, outs):
-            env[n] = v
-        vjps.append(vjp_fn)
+            wrapped = jax.checkpoint(seg_fn, policy=policy)
+            outs, vjp_fn = jax.vjp(wrapped, ins)
+            for n, v in zip(seg.outs, outs):
+                env[n] = v
+            vjps.append(vjp_fn)
+        kept = sum(_kept_bytes)
+    finally:
+        _kept_bytes = None
 
     cb._exec_ops(plan.rest_head, env, lod_env, rng)
 
@@ -325,3 +354,4 @@ def exec_plan(cb, plan: RematPlan, env: Dict[str, Any], lod_env, rng):
         cb._exec_ops(after, env, lod_env, rng)
 
     cb._exec_ops(plan.tail_ops, env, lod_env, rng)
+    return kept

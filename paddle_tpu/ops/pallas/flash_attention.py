@@ -841,21 +841,43 @@ def _blocks_of(kernel, q, k, v, mask):
 # --------------------------------------------------------------------------
 # public entry
 # --------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+# The names a call gives the two values its backward reads beside q, k, v:
+# the output and the one-column log-sum-exp [B·H, S] (never the 128-lane
+# wire form). A recomputed segment keeps values by these names
+# (fluid/recompute_lowering.KEPT), so its backward does not run the
+# forward kernel again; anywhere else a name changes nothing.
+FLASH_OUT = "flash_out"
+FLASH_LSE = "flash_lse"
+
+
 def _flash_pallas(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
     """``mask``: a ``Mask`` without its bias (static: a bool stands for
-    its causal flag); the key-padding ``bias`` is an operand."""
-    return _fp_fwd(q, k, v, seed, bias, sm_scale, mask, dropout_rate)[0]
-
-
-def _fp_fwd(q, k, v, seed, bias, sm_scale, mask, dropout_rate):
-    biased = Mask.of(mask)._replace(bias=bias)
-    o, lse = _pallas_fwd(q, k, v, seed, sm_scale, biased,
+    its causal flag); the key-padding ``bias`` is an operand. The forward
+    kernel runs as a plain call on operands that carry no gradient, and
+    ``_flash_grad`` attaches the gradient, its inputs the kernel's
+    results: so the names are on plain values of the caller's trace,
+    given once a trace, which a checkpoint's policy sees whatever jax
+    does inside a custom_vjp (a name on a custom_vjp's OUTPUT would not
+    do: its forward rule would run again for the residuals)."""
+    from ...fluid.recompute_lowering import keep
+    fq, fk, fv, fbias = jax.lax.stop_gradient((q, k, v, bias))
+    biased = Mask.of(mask)._replace(bias=fbias)
+    o, lse = _pallas_fwd(fq, fk, fv, seed, sm_scale, biased,
                          *_blocks_of("flash_fwd", q, k, v, biased),
                          dropout_rate)
-    # residual: the 2-D row stat, not the 128-lane wire form (128× less
-    # memory held across fwd→bwd; the bwd re-broadcasts transiently)
-    return o, (q, k, v, o, lse[:, :, 0], seed, bias)
+    o, lse = keep(o, FLASH_OUT), keep(lse[:, :, 0], FLASH_LSE)
+    return _flash_grad(q, k, v, o, lse, seed, bias, sm_scale, mask,
+                       dropout_rate)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _flash_grad(q, k, v, o, lse, seed, bias, sm_scale, mask, dropout_rate):
+    """``o`` itself, with the flash gradient of q, k and v attached."""
+    return o
+
+
+def _fp_fwd(q, k, v, o, lse, seed, bias, sm_scale, mask, dropout_rate):
+    return o, (q, k, v, o, lse, seed, bias)
 
 
 def _fp_bwd(sm_scale, mask, dropout_rate, res, g):
@@ -871,11 +893,12 @@ def _fp_bwd(sm_scale, mask, dropout_rate, res, g):
                         dropout_rate)
     dseed = np.zeros(seed.shape, jax.dtypes.float0)  # int arg: zero tangent
     dbias = None if bias is None else jnp.zeros_like(bias)  # mask input
+    # o and lse come from operands under stop_gradient: nothing to send
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape), \
-        dseed, dbias
+        jnp.zeros_like(o), jnp.zeros_like(lse), dseed, dbias
 
 
-_flash_pallas.defvjp(_fp_fwd, _fp_bwd)
+_flash_grad.defvjp(_fp_fwd, _fp_bwd)
 
 _MESH = None  # the device mesh whose step is being traced (mesh_guard)
 
