@@ -6,9 +6,11 @@ node; BasicEngine::Execute (imperative/basic_engine.cc:159) which sweeps the
 grad DAG with GradientAccumulators.
 
 TPU design: ops execute eagerly as jax calls (async dispatch gives the
-pipelining the reference gets from CUDA streams); the tape stores (op, ins,
-outs, attrs) and backward replays it with the same vjp machinery the static
-executor uses (ops/registry.py run_generic_grad) — one grad semantics for
+pipelining the reference gets from CUDA streams) through the one dispatch
+the static executor uses, ops/registry.py ``run_op``; the tape stores (op,
+ins, outs, attrs) with the forward's key in ``_rng``, and backward runs
+``<op>_grad`` through ``run_op`` too (a registered grad kernel or the vjp
+of the forward, drawing what the forward drew) — one grad semantics for
 both modes. ``dygraph.jit`` re-traces functions into jax.jit for the
 compiled path (reference dygraph_to_static / TracedLayer)."""
 from __future__ import annotations
@@ -23,7 +25,7 @@ import jax.numpy as jnp
 
 from .. import core, framework, unique_name
 from ..core import VarDesc, convert_np_dtype_to_dtype_, dtype_to_jnp
-from ...ops.registry import OPS, run_generic_grad, GRAD_SUFFIX
+from ...ops.registry import OPS, GRAD_SUFFIX, own_seed, run_op, with_rng
 
 __all__ = ["guard", "to_variable", "enabled", "no_grad", "grad", "VarBase",
            "Tracer", "enable_dygraph", "disable_dygraph",
@@ -206,17 +208,16 @@ class Tracer:
                              for v in vals]
                       for slot, vals in ins_vb.items()}
         if info.needs_rng:
-            if attrs.get("fix_seed", False) or attrs.get("seed", 0):
-                attrs["_rng"] = jax.random.key(int(attrs.get("seed", 0)))
-            else:
-                attrs["_rng"] = jax.random.fold_in(
-                    jax.random.key(core.globals_["FLAGS_seed"]),
-                    self._rng_counter)
+            # the key goes on the tape: backward and replay draw the same
+            attrs = with_rng(info, attrs,
+                             jax.random.key(core.globals_["FLAGS_seed"]),
+                             self._rng_counter)
+            if not own_seed(attrs):
                 self._rng_counter += 1
         if info.stateful:
             raise RuntimeError(
                 f"op {op_type} is host-stateful and has no dygraph path")
-        outs_arrays = info.kernel(ins_arrays, attrs)
+        outs_arrays = run_op(op_type, ins_arrays, attrs, (), (), None, 0)
         outs_vb: Dict[str, List[VarBase]] = {}
         fresh: List[VarBase] = []
         for slot, vals in (outputs or {}).items():
@@ -285,7 +286,6 @@ class Tracer:
                         prev = grads.get(id(v))
                         grads[id(v)] = g if prev is None else prev + g
                 continue
-            info = OPS.get(entry.op_type)
             ins = {slot: [v._array for v in vals]
                    for slot, vals in entry.ins.items()}
             for slot, vals in entry.outs.items():
@@ -298,13 +298,8 @@ class Tracer:
                     wanted.append(slot + GRAD_SUFFIX)
             if not wanted:
                 continue
-            grad_kernel_type = entry.op_type + "_grad"
-            if OPS.has(grad_kernel_type):
-                gouts = OPS.get(grad_kernel_type).kernel(ins, entry.attrs)
-            else:
-                gouts = run_generic_grad(entry.op_type, ins, entry.attrs,
-                                         wanted,
-                                         list(entry.ins.keys()))
+            gouts = run_op(entry.op_type + "_grad", ins, entry.attrs, wanted,
+                           entry.ins, None, 0)
             for slot, vals in entry.ins.items():
                 gvals = (gouts or {}).get(slot + GRAD_SUFFIX)
                 if gvals is None:
@@ -527,7 +522,7 @@ def _replayable_fn(tape, inputs, outputs, no_grad_ids):
                 continue
             ins = {slot: [env.get(id(v), v._array) for v in vals]
                    for slot, vals in entry.ins.items()}
-            outs = OPS.get(entry.op_type).kernel(ins, entry.attrs)
+            outs = run_op(entry.op_type, ins, entry.attrs, (), (), None, 0)
             for slot, vals in entry.outs.items():
                 produced = (outs or {}).get(slot)
                 if produced is None:

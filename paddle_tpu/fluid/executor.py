@@ -45,7 +45,7 @@ from . import telemetry as _telemetry
 from . import analysis as _analysis
 from .core import LoDTensor, Scope, global_scope
 from .framework import Program, Variable, default_main_program
-from ..ops.registry import (OPS, run_generic_grad, GRAD_SUFFIX,
+from ..ops.registry import (GRAD_SUFFIX, run_op,
                             resolve_base_info as _resolve_base_info)
 
 __all__ = ["Executor", "global_scope", "scope_guard", "FetchHandler"]
@@ -239,11 +239,8 @@ def _window_feed_names(program, feed, n_steps) -> Tuple[str, ...]:
 def _op_reads_host_values(op) -> bool:
     """Ops whose kernels read input VALUES host-side (registry
     host_inputs) cannot take those values as traced jit arguments."""
-    if OPS.has(op.type):
-        return bool(OPS.get(op.type).host_inputs)
-    if op.type.endswith("_grad") and OPS.has(op.type[:-5]):
-        return bool(OPS.get(op.type[:-5]).host_inputs)
-    return False
+    info = _resolve_base_info(op.type)
+    return bool(info is not None and info.host_inputs)
 
 
 def _op_is_stateful(op) -> bool:
@@ -261,7 +258,7 @@ _LOWERED_CONTROL = frozenset({"while", "conditional_block",
 
 def _op_needs_rng(op_type: str) -> bool:
     info = _resolve_base_info(op_type)
-    return info.needs_rng if info is not None else False
+    return info is not None and info.needs_rng
 
 
 def _ops_compilable(ops, in_cond=False) -> bool:
@@ -310,11 +307,8 @@ def _op_scope(op) -> str:
 
 
 def _op_needs_lod(op) -> bool:
-    if OPS.has(op.type):
-        return OPS.get(op.type).needs_lod
-    if op.type.endswith("_grad") and OPS.has(op.type[:-5]):
-        return OPS.get(op.type[:-5]).needs_lod
-    return False
+    info = _resolve_base_info(op.type)
+    return info is not None and info.needs_lod
 
 
 def _collect_in_lods(op, lookup):
@@ -868,45 +862,7 @@ class _CompiledBlock:
         if _op_needs_lod(op):
             attrs = dict(attrs)
             attrs["_lod"] = in_lods
-        if OPS.has(otype):
-            info = OPS.get(otype)
-            if info.needs_rng:
-                attrs = dict(attrs)
-                if attrs.get("fix_seed", False) or attrs.get("seed", 0):
-                    attrs["_rng"] = jax.random.key(int(attrs.get("seed", 0)))
-                else:
-                    attrs["_rng"] = jax.random.fold_in(rng, idx)
-            outs = info.kernel(ins, attrs)
-        elif otype.endswith("_grad") and OPS.has(otype[:-5]):
-            base = OPS.get(otype[:-5])
-            if base.needs_rng:
-                # same key as the forward op (stamped _fwd_idx) so the
-                # vjp re-run samples identically
-                attrs = dict(attrs)
-                attrs["_rng"] = jax.random.fold_in(
-                    rng, int(attrs.get("_fwd_idx", idx)))
-            outs = run_generic_grad(
-                otype[:-5], ins, attrs,
-                wanted_grad_slots=list(op.outputs.keys()),
-                fwd_input_slots=attrs.get("_fwd_in", list(op.inputs.keys())))
-        elif otype.endswith("_grad_grad") and OPS.has(otype[:-10]):
-            # static double grad: vjp THROUGH the generic grad of the
-            # base op (gradient-penalty losses differentiate *_grad
-            # ops; reference imperative/partial_grad_engine.cc role)
-            from ..ops.registry import run_generic_grad_grad
-            if OPS.get(otype[:-10]).needs_rng:
-                # same key as the forward op, like the *_grad branch:
-                # the doubly-nested vjp must replay the SAME draws
-                attrs = dict(attrs)
-                attrs["_rng"] = jax.random.fold_in(
-                    rng, int(attrs.get("_fwd_idx", idx)))
-            outs = run_generic_grad_grad(
-                otype[:-10], ins, attrs,
-                wanted_grad_slots=list(op.outputs.keys()),
-                gradop_slots=attrs.get("_fwd_in",
-                                       list(op.inputs.keys())))
-        else:
-            raise NotImplementedError(f"op {otype} not registered")
+        outs = run_op(otype, ins, attrs, op.outputs, op.inputs, rng, idx)
         for slot, names in op.outputs.items():
             vals = outs.get(slot)
             if vals is None:
@@ -2737,17 +2693,10 @@ class Executor:
 
     def _run_op_eager_impl(self, op, scope: Scope, rng_base, idx: int = 0,
                            check_nan: Optional[bool] = None):
-        otype = op.type
-        stateful = _op_is_stateful(op)
         attrs = op.attrs
-        if stateful:
-            if not OPS.has(otype):
-                raise NotImplementedError(f"op '{otype}' is not implemented")
-            info = OPS.get(otype)
+        if _op_is_stateful(op):
             attrs = dict(attrs)
             attrs["_ctx"] = ExecContext(scope, self, op, self.place, rng_base)
-            if info.needs_rng:
-                attrs["_rng"] = jax.random.fold_in(rng_base, idx)
         ins = {}
         for slot, names in op.inputs.items():
             vals = []
@@ -2771,38 +2720,8 @@ class Executor:
         if _op_needs_lod(op):
             attrs = dict(attrs)
             attrs["_lod"] = in_lods
-        if OPS.has(otype):
-            info = OPS.get(otype)
-            if info.needs_rng and "_rng" not in attrs:
-                attrs = dict(attrs)
-                if attrs.get("fix_seed", False) or attrs.get("seed", 0):
-                    attrs["_rng"] = jax.random.key(int(attrs.get("seed", 0)))
-                else:
-                    attrs["_rng"] = jax.random.fold_in(rng_base, idx)
-            outs = info.kernel(ins, attrs)
-        elif otype.endswith("_grad") and OPS.has(otype[:-5]):
-            base = OPS.get(otype[:-5])
-            if base.needs_rng:
-                attrs = dict(attrs)
-                attrs["_rng"] = jax.random.fold_in(
-                    rng_base, int(attrs.get("_fwd_idx", idx)))
-            outs = run_generic_grad(
-                otype[:-5], ins, attrs,
-                wanted_grad_slots=list(op.outputs.keys()),
-                fwd_input_slots=op.attrs.get("_fwd_in", list(op.inputs.keys())))
-        elif otype.endswith("_grad_grad") and OPS.has(otype[:-10]):
-            from ..ops.registry import run_generic_grad_grad
-            if OPS.get(otype[:-10]).needs_rng:
-                attrs = dict(attrs)
-                attrs["_rng"] = jax.random.fold_in(
-                    rng_base, int(attrs.get("_fwd_idx", idx)))
-            outs = run_generic_grad_grad(
-                otype[:-10], ins, attrs,
-                wanted_grad_slots=list(op.outputs.keys()),
-                gradop_slots=op.attrs.get("_fwd_in",
-                                          list(op.inputs.keys())))
-        else:
-            raise NotImplementedError(f"op '{otype}' is not implemented")
+        outs = run_op(op.type, ins, attrs, op.outputs, op.inputs, rng_base,
+                      idx)
         if check_nan is None:
             check_nan = (core.globals_["FLAGS_check_nan_inf"]
                          and core.globals_["FLAGS_nan_inf_action"]

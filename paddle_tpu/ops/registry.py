@@ -21,11 +21,14 @@ integer-indexed scatters, …) register custom grad ops / grad makers.
 Kernel calling convention:
     ins:   dict slot_name -> list of jnp arrays (or None for absent
            dispensable slots). Duplicable slots hold len>1 lists.
-    attrs: dict of python attr values. The executor injects:
-           ``_rng``   (jax PRNG key) if the op declared needs_rng,
-           ``_ctx``   (ExecContext) if the op declared needs_ctx —
-                      such ops are stateful and break pure tracing.
+    attrs: dict of python attr values. ``run_op`` injects ``_rng`` (jax
+           PRNG key) if the op declared needs_rng; the executor injects
+           ``_ctx`` (ExecContext) for stateful ops, which break pure
+           tracing, and ``_lod`` for needs_lod ones.
     returns: dict slot_name -> list of jnp arrays.
+
+``run_op`` is the one dispatch: the compiled trace, the interpreter and
+the dygraph tracer all ask it which kernel runs and with which key.
 """
 from __future__ import annotations
 
@@ -71,6 +74,10 @@ class OpInfo:
         self.host_inputs: Sequence[str] = ()
 
 
+def _not_registered(op_type: str) -> NotImplementedError:
+    return NotImplementedError(f"operator '{op_type}' is not registered")
+
+
 class OpInfoMap:
     def __init__(self):
         self._map: Dict[str, OpInfo] = {}
@@ -78,7 +85,7 @@ class OpInfoMap:
     def get(self, type_: str) -> OpInfo:
         info = self._map.get(type_)
         if info is None:
-            raise KeyError(f"operator '{type_}' is not registered")
+            raise _not_registered(type_)
         return info
 
     def has(self, type_: str) -> bool:
@@ -109,6 +116,56 @@ def resolve_base_info(op_type: str):
         if OPS.has(t):
             return OPS.get(t)
     return None
+
+
+def own_seed(attrs: Dict) -> bool:
+    """The op draws from a seed of its own (``fix_seed`` or a non-zero
+    ``seed``), not from the program's random stream."""
+    return bool(attrs.get("fix_seed", False) or attrs.get("seed", 0))
+
+
+def with_rng(info: OpInfo, attrs: Dict, rng, idx: int) -> Dict:
+    """``attrs`` with the key an op of ``info`` draws from in ``_rng``. A
+    key already there is kept. Else, where the op declares needs_rng, its
+    own seed gives ``key(seed)``, and the stream gives ``fold_in(rng, i)``
+    at ``i`` = the forward op's ``_fwd_idx`` for a grad op (its vjp
+    replays the forward's draws), the op's own ``idx`` otherwise. Copies
+    ``attrs`` only where it adds the key."""
+    if not info.needs_rng or "_rng" in attrs:
+        return attrs
+    attrs = dict(attrs)
+    if own_seed(attrs):
+        attrs["_rng"] = jax.random.key(int(attrs.get("seed", 0)))
+    else:
+        attrs["_rng"] = jax.random.fold_in(
+            rng, int(attrs.get("_fwd_idx", idx)))
+    return attrs
+
+
+def run_op(op_type: str, ins: Dict[str, List], attrs: Dict,
+           out_slots, in_slots, rng, idx: int) -> Dict[str, List]:
+    """Run op ``op_type``: its registered kernel (a registered
+    ``<op>_grad`` included); else ``<base>_grad`` through the vjp of the
+    base kernel, producing the ``out_slots`` grads of the forward inputs
+    ``_fwd_in`` (default ``in_slots``); else ``<base>_grad_grad``
+    through the vjp of that. ``rng`` and ``idx`` give the key
+    (``with_rng``)."""
+    info = OPS._map.get(op_type)
+    if info is not None:
+        return info.kernel(ins, with_rng(info, attrs, rng, idx))
+    if op_type.endswith("_grad"):
+        info = OPS._map.get(op_type[:-5])
+        if info is not None:
+            return run_generic_grad(
+                op_type[:-5], ins, with_rng(info, attrs, rng, idx),
+                out_slots, attrs.get("_fwd_in", in_slots))
+        info = (OPS._map.get(op_type[:-10])
+                if op_type.endswith("_grad_grad") else None)
+        if info is not None:
+            return run_generic_grad_grad(
+                op_type[:-10], ins, with_rng(info, attrs, rng, idx),
+                out_slots, attrs.get("_fwd_in", in_slots))
+    raise _not_registered(op_type)
 
 
 def register_op(type_: str, *, no_grad: bool = False, needs_rng: bool = False,
