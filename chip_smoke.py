@@ -25,6 +25,13 @@
                                        its plain float32 reference: both
                                        losses, a gradient of every kind,
                                        the routers' biases after the step
+    python chip_smoke.py --ling3       one chip: Ling-3.0-flash-VL's step at
+                                       its cell's sizes (6 layers,
+                                       published widths, 1 x 4096) against
+                                       its plain float32 reference: the
+                                       loss, a block of logits, a gradient
+                                       of every kind, the routers' biases;
+                                       and the reference's two KDA controls
 
 Main path: BERT-base MLM pretraining at full width (12 x 768 x 12 heads x
 3072, vocab 30522) at b128 x s128 with bf16 matmuls, built by
@@ -432,6 +439,73 @@ JOYAI_LIMITS["bf16_operands"] = {
     for n in JOYAI_LIMITS["float32"]}
 
 
+# Ling-3.0-flash-VL: the same two programs against its reference, and
+# also the logits of the first LOGIT_ROWS positions and each router's
+# bias after the step (by the share of entries, as JoyAI's: a bias here
+# is 512 wide and a held expert expects 64 rows, so 0.05 is 25 entries).
+# - Float32 against float32: a loss at 1e-5, logits and a gradient of
+#   every kind (each KDA parameter, each latent-attention one, the dense
+#   FFN, the grouped router, the experts, the shared embedding's and
+#   head's) at 1e-3 of their scale, the other decoders' limits. The
+#   reference with bf16 ACTIVATIONS must fail them, and so must each of
+#   its two controls (`CONTROLS`): the KDA state kept in bf16 between
+#   tokens, and each head's 128 decays replaced by their mean (a decay a
+#   head); that is asserted.
+# - bf16 operands move the router's discrete choice, in its group stage
+#   too, as in the other expert cells: those limits only fence the
+#   readings, ~1.4 x the largest of each kind. Readings (TPU v5 lite,
+#   seed 28): the float32 program, loss 0.0, logits 1.2e-5,
+#   gradients 5.6e-6 .. 6.0e-5, biases equal; bf16 operands, loss 9.0e-5,
+#   logits 0.056, gradients 0.012 .. 0.22 outside the experts (the
+#   largest KDA layer 4's W_qkva: the bf16-activation reference reads
+#   0.17 there too) and 0.30 .. 0.62 on the routers and experts, biases
+#   3.5-4.9% of entries; over the float32 limits, bf16 ACTIVATIONS
+#   (loss 1.8e-4, up to 0.58) and the KDA state in bf16 (2.1e-4, up to
+#   0.53) at 31 of 33, a decay a head (0.0105, up to 1.29) at all 33.
+LING3_LIMITS = {
+    "float32": {
+        "loss": 1e-5,
+        "logits": 1e-3,
+        "embed_tokens@GRAD": 1e-3,
+        "lm_head@GRAD": 1e-3,
+        "final_norm@GRAD": 1e-3,
+        "layers.0.input_norm@GRAD": 1e-3,
+        "layers.0.attn.w_qkva@GRAD": 1e-3,    # q, k, v and the decay's W_a
+        "layers.0.attn.conv_w@GRAD": 1e-3,
+        "layers.0.attn.w_b@GRAD": 1e-3,
+        "layers.0.attn.a_log@GRAD": 1e-3,
+        "layers.0.attn.dt_bias@GRAD": 1e-3,
+        "layers.0.attn.norm@GRAD": 1e-3,
+        "layers.0.attn.w_g@GRAD": 1e-3,
+        "layers.0.attn.w_o@GRAD": 1e-3,
+        "layers.0.post_norm@GRAD": 1e-3,
+        "layers.0.mlp.w_gate_up@GRAD": 1e-3,
+        "layers.0.mlp.w_down@GRAD": 1e-3,
+        "layers.2.moe.w_router@GRAD": 1e-3,   # the grouped router
+        "layers.2.moe.w_gate_up@GRAD": 1e-3,
+        "layers.2.moe.w_down@GRAD": 1e-3,
+        "layers.2.moe.shared_w_gate_up@GRAD": 1e-3,
+        "layers.2.moe.shared_w_down@GRAD": 1e-3,
+        "layers.4.attn.w_qkva@GRAD": 1e-3,
+        "layers.4.attn.a_log@GRAD": 1e-3,
+        "layers.5.attn.w_q@GRAD": 1e-3,       # latent_qkv, no query latent
+        "layers.5.attn.w_kv_a@GRAD": 1e-3,
+        "layers.5.attn.kv_a_norm@GRAD": 1e-3,
+        "layers.5.attn.w_kv_b@GRAD": 1e-3,
+        "layers.5.attn.w_g@GRAD": 1e-3,
+        "layers.5.attn.w_o@GRAD": 1e-3,
+        "layers.5.moe.w_router@GRAD": 1e-3,
+        "layers.2.moe.router_bias": 0.05,
+        "layers.5.moe.router_bias": 0.05,
+    },
+}
+LING3_LIMITS["bf16_operands"] = {
+    n: (1e-3 if n == "loss" else 0.08 if n == "logits"
+        else 0.07 if n.endswith("router_bias")
+        else 0.85 if ".moe." in n else 0.3)
+    for n in LING3_LIMITS["float32"]}
+
+
 def _by_path(path):
     import importlib.util
     spec = importlib.util.spec_from_file_location(
@@ -557,6 +631,23 @@ def _joyai_counters(main):
     return list(passes.values()) + ids, say
 
 
+def _ling3_counters(main):
+    """JoyAI's, and the chunks each KDA layer's scan steps over and the
+    groups each router keeps."""
+    further, say_routed = _joyai_counters(main)
+    ops = main.global_block().ops
+    kdas = [op.attr("site") for op in ops if op.type == "kda"]
+    routers = [op.attr("site") for op in ops if op.type == "moe_router"]
+
+    def say(fetched):
+        return dict(say_routed(fetched),
+                    kda_chunks_per_step=_gauge_by_site(
+                        "kda_chunks_per_step", kdas),
+                    moe_router_groups_kept=_gauge_by_site(
+                        "moe_router_groups_kept", routers))
+    return further, say
+
+
 PARITY = {
     "qwen3_next": dict(config="qwen3_next_80b_a3b",
                        cell="qwen3_next_80b_a3b.b1_s4096",
@@ -574,6 +665,8 @@ PARITY = {
                          counters=_smallthinker_counters),
     "joyai": dict(config="joyai_llm_flash", cell="joyai_llm_flash.b1_s8192",
                   limits=JOYAI_LIMITS, counters=_joyai_counters),
+    "ling3": dict(config="ling3_flash_vl", cell="ling3_flash_vl.b1_s4096",
+                  limits=LING3_LIMITS, counters=_ling3_counters),
 }
 
 
@@ -593,6 +686,7 @@ def reference_parity(which, tiny, clock):
     import numpy as np
     import paddle_tpu.fluid as fluid
     from paddle_tpu.fluid import core, telemetry
+    from paddle_tpu.models._decoder_parts import head_logits
 
     spec = PARITY[which]
     limits = spec["limits"]
@@ -625,9 +719,11 @@ def reference_parity(which, tiny, clock):
             for p in main.global_block().all_parameters()}
         further, say = spec["counters"](main)
         t0 = time.perf_counter()
-        # "mtp_loss" is the second fetch; any other name a variable's
-        # (a gradient, or a bias after the step)
+        # "mtp_loss" is the second fetch, "logits" the head's output; any
+        # other name a variable's (a gradient, or a bias after the step)
         by_name = {"mtp_loss": fetches[-1].name}
+        if "logits" in wanted:
+            by_name["logits"] = head_logits(main)
         with (contextlib.nullcontext() if bf16_operands
               else jax.default_matmul_precision("highest")):
             got = exe.run(main, feed=feed, scope=scope,
@@ -635,6 +731,8 @@ def reference_parity(which, tiny, clock):
                           + [by_name.get(n, n) for n in wanted] + further)
         said = say(got[len(names):])
         got = dict(zip(names, (np.asarray(g) for g in got)))
+        if "logits" in got:  # the positions the reference gives
+            got["logits"] = got["logits"][:, :reference.LOGIT_ROWS]
         stats = jax.devices()[0].memory_stats() or {}
         kept = telemetry.REGISTRY.get("remat_kept_bytes")
         emit(phase=which + "_step", bf16_operands=bf16_operands,
@@ -657,16 +755,20 @@ def reference_parity(which, tiny, clock):
 
     params = {n: jnp.asarray(w) for n, w in weights.items()}
     ids, labels = jnp.asarray(feed["ids"]), jnp.asarray(feed["labels"][..., 0])
+    controls = getattr(reference, "CONTROLS", ())
 
     @jax.jit
-    def read(params, rounded):
+    def read(params, rounded, flags):
         """The reference's loss and gradients (and what else it reads, by
-        name, where it has `readings`); `rounded` (a traced flag, so that
-        both readings share one compile) rounds every activation to
-        bf16."""
+        name, where it has `readings`); `rounded` rounds every activation
+        to bf16 and `flags` turn on the reference's CONTROLS, one each
+        (traced flags, so that all readings share one compile)."""
         # not a pair of converts: XLA may drop those as excess precision
         round_to = lambda x: jnp.where(  # noqa: E731
             rounded, jax.lax.reduce_precision(x, 8, 7), x)
+        if controls:
+            return reference.readings(params, feed, cfg, names, round_to,
+                                      dict(zip(controls, flags)))
         if hasattr(reference, "readings"):
             return reference.readings(params, feed, cfg, names, round_to)
         loss, grads = reference.loss_and_grads(
@@ -687,17 +789,22 @@ def reference_parity(which, tiny, clock):
         return {n: error(n, a[n], b[n]) for n in names}
 
     t0 = time.perf_counter()
-    ref = {n: np.asarray(v) for n, v in read(params, False).items()}
+    off = (False,) * len(controls)
+    ref = {n: np.asarray(v) for n, v in read(params, False, off).items()}
     got = {kind: errors(programs[kind], ref) for kind in programs}
-    below = errors(read(params, True), ref)
+    below = errors(read(params, True, off), ref)
+    control = {c: errors(read(params, False, tuple(n == c for n in controls)),
+                         ref) for c in controls}
     emit(phase=which + "_parity", reference_loss=float(ref["loss"]),
-         errors=got, bf16_activations=below, limits=limits,
-         seconds=round(time.perf_counter() - t0, 1), **clock.take())
+         errors=got, bf16_activations=below, controls=control,
+         limits=limits, seconds=round(time.perf_counter() - t0, 1),
+         **clock.take())
     over = {f"{kind}: {n}": e for kind in got for n, e in got[kind].items()
             if e > limits[kind][n]}
     assert not over, f"over their limits: {over}"
-    assert any(e > limits["float32"][n] for n, e in below.items()), \
-        "bf16 activations pass every float32 limit: the limits tell nothing"
+    for name, errs in [("bf16 activations", below)] + list(control.items()):
+        assert any(e > limits["float32"][n] for n, e in errs.items()), \
+            f"{name} pass every float32 limit: the limits tell nothing"
 
 
 def multichip(size, devices):
@@ -733,6 +840,9 @@ def main(argv=None):
                          "reference and nothing else")
     ap.add_argument("--joyai", action="store_true",
                     help="JoyAI-LLM-Flash's step against its float32 "
+                         "reference and nothing else")
+    ap.add_argument("--ling3", action="store_true",
+                    help="Ling-3.0-flash-VL's step against its float32 "
                          "reference and nothing else")
     ap.add_argument("--tiny", action="store_true",
                     help="rehearsal at a toy size on any backend; never ok")

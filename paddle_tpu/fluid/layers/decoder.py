@@ -7,7 +7,8 @@ from ..initializer import Constant
 from ..layer_helper import LayerHelper
 
 __all__ = ["rms_norm", "rotary_embedding", "causal_conv1d",
-           "gated_delta_rule", "selective_scan", "differential_combine",
+           "gated_delta_rule", "kda", "selective_scan",
+           "differential_combine",
            "latent_qkv", "moe_router", "moe_expert_ffn"]
 
 
@@ -97,6 +98,31 @@ def gated_delta_rule(q, k, v, a, b, num_key_heads, num_value_heads,
     return out
 
 
+def kda(q, k, v, a, b, lower_bound, chunk_size=64, a_log_attr=None,
+        dt_bias_attr=None, name=None):
+    """Kimi delta attention over q, k, v [B, S, H*d] with the decay's
+    pre-activation a [B, S, H*dk] (a key channel) and the write
+    strength's b [B, S, H], the log decay bounded below by
+    ``lower_bound``; creates A_log [H] and dt_bias [H*dk] (from 0).
+    -> [B, S, H*dv]."""
+    helper = LayerHelper("kda", **locals())
+    heads = b.shape[-1]
+    a_log = helper.create_parameter(attr=a_log_attr, shape=[heads],
+                                    dtype=v.dtype)
+    dt_bias = helper.create_parameter(attr=dt_bias_attr,
+                                      shape=[a.shape[-1]], dtype=v.dtype,
+                                      default_initializer=Constant(0.0))
+    out = _like(helper, v)
+    helper.append_op(
+        type="kda",
+        inputs={"Q": [q], "K": [k], "V": [v], "A": [a], "B": [b],
+                "ALog": [a_log], "DtBias": [dt_bias]},
+        outputs={"Out": [out]},
+        attrs={"lower_bound": float(lower_bound), "chunk_size": chunk_size,
+               "site": helper.name})
+    return out
+
+
 def selective_scan(x, dt, b, c, chunk_size=64, a_log_attr=None, d_attr=None,
                    dt_bias_attr=None, name=None):
     """Mamba's selective scan over x, dt [B, S, C] (dt the step's
@@ -142,14 +168,17 @@ def differential_combine(x, num_groups, head_dim, lambda_init,
 
 
 def latent_qkv(c_q, c_kv, k_rope, num_heads, nope_dim, rope_dim, v_dim,
-               theta, q_attr=None, kv_attr=None, name=None):
+               theta, q_attr=None, kv_attr=None, rope_interleave=True,
+               name=None):
     """Latent attention's Q, K, V from the normed query latent c_q
-    [B, S, Lq], the normed key-value latent c_kv [B, S, Lkv] and the one
+    [B, S, Lq] (or the normed layer input, where the query has no
+    latent), the normed key-value latent c_kv [B, S, Lkv] and the one
     rotary key k_rope [B, S, rope_dim] all heads share; creates the
     up-projections W_UQ [Lq, heads * (nope_dim + rope_dim)] and W_UKV
     [Lkv, heads * (nope_dim + v_dim)]. -> (q, k [B, S, heads * (nope_dim
     + rope_dim)], v [B, S, heads * v_dim]), the attention op's layout,
-    rotary on pairs of dims (2i, 2i + 1) at ``theta``."""
+    rotary at ``theta`` on pairs of dims (2i, 2i + 1) where
+    ``rope_interleave``, else on (i, i + rope_dim / 2)."""
     helper = LayerHelper("latent_qkv", **locals())
     w_uq = helper.create_parameter(
         attr=q_attr, shape=[c_q.shape[-1], num_heads * (nope_dim + rope_dim)],
@@ -168,13 +197,14 @@ def latent_qkv(c_q, c_kv, k_rope, num_heads, nope_dim, rope_dim, v_dim,
         outputs={"Q": [q], "K": [k], "V": [v]},
         attrs={"num_heads": num_heads, "nope_dim": nope_dim,
                "rope_dim": rope_dim, "v_dim": v_dim, "theta": float(theta),
+               "rope_interleave": bool(rope_interleave),
                "site": helper.name})
     return q, k, v
 
 
 def moe_router(x, num_experts, top_k, scoring="softmax", scale=1.0,
                param_attr=None, bias_update_rate=None, bias_name=None,
-               name=None):
+               n_group=1, topk_group=1, name=None):
     """Scores over ``num_experts`` in float32 (``scoring``: "softmax", or
     "sigmoid" of each logit), top-k, the chosen weights renormalised to
     sum 1 and multiplied by ``scale``: (expert ids [.., k] int32, weights
@@ -184,7 +214,11 @@ def moe_router(x, num_experts, top_k, scoring="softmax", scale=1.0,
     persistable from 0 named ``bias_name`` and no parameter: the top-k
     is taken of scores + bias, the weights stay the unbiased scores, and
     the op writes bias + gamma * sign(mean load - load_e) back into it
-    once a step, outside the gradient (DeepSeek-V3 §2.1.2)."""
+    once a step, outside the gradient (DeepSeek-V3 §2.1.2).
+
+    ``n_group`` > 1: the experts are that many groups in order, and a
+    token's k are chosen inside its ``topk_group`` groups whose two
+    largest (biased) scores sum highest."""
     helper = LayerHelper("moe_router", **locals())
     w = helper.create_parameter(attr=helper.param_attr,
                                 shape=[x.shape[-1], num_experts],
@@ -198,6 +232,11 @@ def moe_router(x, num_experts, top_k, scoring="softmax", scale=1.0,
     outputs = {"TopkIdx": [idx], "TopkWeight": [weight], "AuxLoss": [aux]}
     attrs = {"top_k": top_k, "scoring": scoring, "scale": float(scale),
              "site": helper.name}
+    if n_group > 1:
+        if num_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError(f"moe_router: {num_experts} experts in "
+                             f"{n_group} groups, {topk_group} kept")
+        attrs.update(n_group=n_group, topk_group=topk_group)
     if bias_update_rate is not None:
         bias = helper.create_or_get_global_variable(
             bias_name or helper.name + ".bias", shape=(num_experts,),

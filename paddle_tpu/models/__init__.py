@@ -4,6 +4,7 @@ test_imperative_{resnet,se_resnext,transformer,ptb_rnn}.py)."""
 from . import bert  # noqa: F401
 from . import joyai_flash  # noqa: F401
 from . import laguna  # noqa: F401
+from . import ling3_flash  # noqa: F401
 from . import phi4_flash  # noqa: F401
 from . import qwen3_next  # noqa: F401
 from . import resnet  # noqa: F401

@@ -1,10 +1,12 @@
 """What the decoder LMs' builders share (qwen3_next.py, phi4_flash.py,
-laguna.py, smallthinker.py): a parameter's attribute, a bias-free or
-biased projection, RMSNorm by a named weight, the packed gated FFN, the
-training tail, the synthetic batch and what a built program says of
-its attention and expert layers. Private to ``paddle_tpu.models``: each
-builder's parameter names, initialisers and op order are its own and
-its reference's, so nothing here names a parameter itself."""
+laguna.py, smallthinker.py, joyai_flash.py, ling3_flash.py): a
+parameter's attribute, a bias-free or biased projection, RMSNorm by a
+named weight, the packed gated FFN, the training tail, the synthetic
+batch and what a built program says of its attention and expert layers,
+its routers' biases and where its head's logits are. Private to
+``paddle_tpu.models``: each builder's parameter names, initialisers and
+op order are its own and its reference's, so nothing here names a
+parameter itself."""
 from __future__ import annotations
 
 import numpy as np
@@ -73,6 +75,22 @@ def expert_passes(program):
     layer order; 1 wherever the routing fitted twice the held share."""
     return ops_by_site(program, "moe_expert_ffn",
                        lambda op: op.output("Passes")[0])
+
+
+def router_biases(program):
+    """{a router's ``site``: the name of its bias persistable}, in layer
+    order: what to fetch to read the biases after a step."""
+    return {op.attr("site"): op.input("Bias")[0]
+            for op in program.global_block().ops
+            if op.type == "moe_router" and op.input("Bias")}
+
+
+def head_logits(program):
+    """The name of the head's logits [B, S, vocab] in ``program`` (the
+    product with the parameter ``lm_head``): what to fetch to read them
+    after a step."""
+    return next(op.output("Out")[0] for op in program.global_block().ops
+                if op.type == "mul" and op.input("Y") == ["lm_head"])
 
 
 def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0):

@@ -28,7 +28,7 @@ from __future__ import annotations
 from .. import fluid
 from ..fluid import layers, telemetry
 from ._decoder_parts import (attention_sites, attr, expert_passes, gated_ffn,
-                             linear, minimize, rms_norm)
+                             linear, minimize, rms_norm, router_biases)
 from .bert import fused_multihead_attention
 
 __all__ = ["joyai_flash_config", "build_joyai_flash_pretrain_program",
@@ -162,14 +162,6 @@ def build_joyai_flash_pretrain_program(cfg=None, seq_len=8192, lr=1e-4,
             loss_main, layers.scale(loss_mtp, scale=cfg["mtp_weight"]))
         minimize(loss, lr, recompute, checkpoints)
     return main, startup, [ids, labels, mtp_labels], [loss_main, loss_mtp]
-
-
-def router_biases(program):
-    """{a router's ``site``: the name of its bias persistable}, in layer
-    order: what to fetch to read the biases after a step."""
-    return {op.attr("site"): op.input("Bias")[0]
-            for op in program.global_block().ops
-            if op.type == "moe_router" and op.input("Bias")}
 
 
 def synthetic_pretrain_batch(cfg, batch, seq_len, seed=0):

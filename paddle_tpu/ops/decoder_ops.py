@@ -6,7 +6,9 @@ benchmark/configs/phi4_mini_flash_reference.py), sigmoid-scored
 routing and rotary embeddings scaled by YaRN (the Laguna family:
 benchmark/configs/laguna_xs2_reference.py), latent attention's
 up-projections and a router's choice-only bias (the DeepSeek-V3 family:
-benchmark/configs/joyai_llm_flash_reference.py).
+benchmark/configs/joyai_llm_flash_reference.py), the delta rule with a
+decay a key channel and a router's group stage (the Ling family:
+benchmark/configs/ling3_flash_vl_reference.py).
 
 ``rms_norm``            RMSNorm over groups of the last dim, zero-centred
                         weight or plain, optionally gated by SiLU(Gate)
@@ -14,13 +16,16 @@ benchmark/configs/joyai_llm_flash_reference.py).
                         frequencies plain or YaRN's
 ``causal_conv1d``       depthwise causal convolution along the sequence
 ``gated_delta_rule``    the gated delta rule, in chunks (WY form)
+``kda``                 Kimi delta attention: the same rule, its decay a
+                        key channel, bounded below
 ``selective_scan``      Mamba's diagonal state-space recurrence, in chunks
 ``differential_combine`` A_1 V - lambda A_2 V of differential attention
 ``latent_qkv``          Q, K, V of latent attention from the normed
-                        latents and the shared rotary key (interleaved)
+                        latents and the shared rotary key (interleaved or
+                        rotate-half)
 ``moe_router``          softmax or sigmoid scores over all experts, top-k,
-                        auxiliary loss; optionally a choice-only bias and
-                        its load-balancing update
+                        auxiliary loss; optionally a group stage, a
+                        choice-only bias and its load-balancing update
 ``moe_expert_ffn``      the held experts' part of a routed gated FFN
 
 One pure JAX kernel each; gradients are the registry's vjp of it (the
@@ -175,54 +180,72 @@ def _causal_conv1d(ins, attrs):
 
 
 # --------------------------------------------------------------------------
-def _chunked_delta_rule(q, k, v, g, beta, chunk):
+def _chunked_delta_rule(q, k, v, g, beta, chunk, block=None):
     """The gated delta rule in chunks. q, k [B, H, S, dk] (normalised, q
-    scaled), v [B, H, S, dv], g [B, H, S] the log of the decay (<= 0),
-    beta [B, H, S]; -> [B, H, S, dv].
+    scaled), v [B, H, S, dv], beta [B, H, S], g the log of the decay (<=
+    0): [B, H, S], one a head (Gated DeltaNet), or [B, H, S, dk], one a
+    key channel (Kimi delta attention, whose decays are then factored in
+    blocks of ``block`` positions: ``_channel_pairs``); -> [B, H, S, dv].
 
-    Token form: S_t = a_t S_{t-1} + k_t u_t^T, u_t = beta_t (v_t -
-    a_t S_{t-1}^T k_t), o_t = S_t^T q_t. Within a chunk of C positions
-    with G_i = sum_{j<=i} g_j and D_ij = exp(G_i - G_j) (i >= j), the
-    u_i solve (I + L) U = beta V - (beta K e^G) S_0, L_ij = beta_i
-    (k_i . k_j) D_ij (i > j): one triangular solve gives T = (I + L)^-1,
-    the WY form's two products T (beta V) and T (beta K e^G) are made
-    for every chunk at once, and only the chunk states are scanned, in
-    float32: S_0 -> S_C = e^{G_C} S_0 + (K e^{G_C - G})^T U."""
+    Token form: S_t = Diag(a_t) S_{t-1} + k_t u_t^T, u_t = beta_t (v_t -
+    (Diag(a_t) S_{t-1})^T k_t), o_t = S_t^T q_t. Within a chunk of C
+    positions with G_i = sum_{j<=i} g_j and D_ij = exp(G_i - G_j) (i >=
+    j; over the key channels where the decay is one a channel), the u_i
+    solve (I + L) U = beta V - (beta K e^G) S_0, L_ij = beta_i (k_i . D_ij
+    k_j) (i > j): one triangular solve gives T = (I + L)^-1, the WY
+    form's two products T (beta V) and T (beta K e^G) are made for every
+    chunk at once, and only the chunk states are scanned, in float32:
+    S_0 -> S_C = e^{G_C} S_0 + (K e^{G_C - G})^T U."""
     b, h, s, dk = q.shape
     dv = v.shape[-1]
+    channels = g.ndim == 4
     pad = -s % chunk
     if pad:  # padded positions: k = v = beta = 0 write nothing
         q, k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
                    for t in (q, k, v))
-        g, beta = (jnp.pad(t, ((0, 0), (0, 0), (0, pad))) for t in (g, beta))
+        g, beta = (jnp.pad(t, ((0, 0), (0, 0), (0, pad))
+                           + ((0, 0),) * (t.ndim - 3)) for t in (g, beta))
     n = (s + pad) // chunk
     q, k, v = (t.reshape(b, h, n, chunk, -1) for t in (q, k, v))
-    g, beta = (t.reshape(b, h, n, chunk) for t in (g, beta))
-    gc = jnp.cumsum(g, -1)
+    g, beta = (t.reshape((b, h, n, chunk) + t.shape[3:]) for t in (g, beta))
+    gc = jnp.cumsum(g, 3)
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))
     strictly_lower = jnp.tril(lower, -1)
-    # exp of a masked difference: the upper triangle would overflow
-    decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
-                              -jnp.inf))
+    if channels:
+        pairs = _channel_pairs(k, gc, block)
+
+        def col(t):
+            return t
+    else:
+        # exp of a masked difference: the upper triangle would overflow
+        decay = jnp.exp(jnp.where(lower, gc[..., :, None] - gc[..., None, :],
+                                  -jnp.inf))
+
+        def pairs(a):
+            return _einsum("bhnid,bhnjd->bhnij", a, k) * decay
+
+        def col(t):
+            return t[..., None]
     k_beta = k * beta[..., None]
-    kk = _einsum("bhnid,bhnjd->bhnij", k_beta, k) * decay
+    kk = pairs(k_beta)
     eye = jnp.eye(chunk, dtype=jnp.float32)
     system = eye + jnp.where(strictly_lower, kk, 0.0)
     t_inv = jax.scipy.linalg.solve_triangular(
         system, jnp.broadcast_to(eye, system.shape), lower=True)
     u = _einsum("bhnij,bhnjd->bhnid", t_inv, v * beta[..., None])
-    w = _einsum("bhnij,bhnjd->bhnid", t_inv, k_beta * jnp.exp(gc)[..., None])
-    qk = jnp.where(lower, _einsum("bhnid,bhnjd->bhnij", q, k) * decay, 0.0)
-    q_in = q * jnp.exp(gc)[..., None]
-    k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]
-    carry_decay = jnp.exp(gc[..., -1])
+    w = _einsum("bhnij,bhnjd->bhnid", t_inv, k_beta * col(jnp.exp(gc)))
+    qk = jnp.where(lower, pairs(q), 0.0)
+    q_in = q * col(jnp.exp(gc))
+    k_out = k * col(jnp.exp(gc[:, :, :, -1:] - gc))
+    carry_decay = jnp.exp(gc[:, :, :, -1])
 
     def step(state, xs):
         qk_i, u_i, w_i, q_i, k_i, decay_i = xs
         v_new = u_i - _einsum("bhid,bhdv->bhiv", w_i, state)
         o = _einsum("bhid,bhdv->bhiv", q_i, state) \
             + _einsum("bhij,bhjv->bhiv", qk_i, v_new)
-        state = state * decay_i[..., None, None] \
+        state = state * (decay_i[..., None] if channels
+                         else decay_i[..., None, None]) \
             + _einsum("bhid,bhiv->bhdv", k_i, v_new)
         return state, o
 
@@ -230,6 +253,39 @@ def _chunked_delta_rule(q, k, v, g, beta, chunk):
                for t in (qk, u, w, q_in, k_out, carry_decay))
     _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
     return jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)[:, :, :s]
+
+
+def _channel_pairs(k, gc, block):
+    """For a decay a key channel: a -> [.., C, C], entry (i, j) = sum_c
+    a_i[c] k_j[c] exp(G_i[c] - G_j[c]) for j up to the end of i's block
+    of ``block`` positions, 0 past it; k, gc [.., C, dk], a like k. The
+    decay is never made [C, C, dk] (4.3 GB a layer at S 4096): with R
+    the middle of G over i's block (the mean of its first and last G, a
+    channel), exp(G_i - G_j) = exp(G_i - R) exp(R - G_j), the first
+    factor within exp(+-h), h = |bound| (block - 1) / 2 (``kda_block``
+    keeps h <= EXP_LIMIT), the second too for j in the block and <= 1
+    before it; past the block the EXPONENT is masked to -inf, not the
+    product. Balanced so, because the MXU's passes flush what falls
+    under float32's normal range: against the block's first position a
+    factor reaches exp(-75), and its products with a gradient's ~1e-9
+    terms would be lost. R is a constant of the gradient: the product
+    does not depend on it. Two MXU products a block, as the scalar
+    form's one [C, C] product."""
+    *lead, c, dk = k.shape
+    nb = c // block
+    ends = gc.reshape(*lead, nb, block, dk)
+    ref = lax.stop_gradient((ends[..., 0, :] + ends[..., -1, :]) / 2)
+    ahead = jnp.arange(c)[None, :] >= (jnp.arange(nb)[:, None] + 1) * block
+    k_ref = k[..., None, :, :] * jnp.exp(jnp.where(
+        ahead[:, :, None], -jnp.inf,
+        ref[..., :, None, :] - gc[..., None, :, :]))
+
+    def pairs(a):
+        a_ref = a.reshape(*lead, nb, block, dk) * jnp.exp(
+            ends - ref[..., :, None, :])
+        return _einsum("bhnxid,bhnxjd->bhnxij", a_ref, k_ref).reshape(
+            *lead, c, c)
+    return pairs
 
 
 @register_op("gated_delta_rule",
@@ -262,6 +318,65 @@ def _gated_delta_rule(ins, attrs):
                             jnp.moveaxis(beta, 1, 2), chunk)
     _gauge("gdn_chunks_per_step",
            "chunks of the sequence the delta rule's state scan steps "
+           "over, a sequence of the batch each", attrs.get("site", ""),
+           b * -(-s // chunk))
+    return out(Out=jnp.moveaxis(o, 1, 2).reshape(b, s, -1).astype(v.dtype))
+
+
+# The largest log a factor of ``_channel_pairs`` may carry either way:
+# e^+-40 is 2.4e17 / 4.2e-18, so a factor times a gradient's ~1e-10 stays
+# far inside float32's normal range (1.2e-38 .. 3.4e38).
+EXP_LIMIT = 40.0
+
+
+def kda_block(lower_bound, chunk):
+    """Positions of the blocks ``_channel_pairs`` factors a chunk's decays
+    in: the largest power-of-two part of ``chunk`` over which the
+    log-decay, each step above ``lower_bound``, moves at most EXP_LIMIT
+    either way of its middle: -lower_bound (block - 1) / 2 <= EXP_LIMIT
+    (16 at the bound -5). An unbounded gate has no such block and
+    raises."""
+    if not lower_bound < 0:
+        raise ValueError(f"kda: lower_bound {lower_bound} is not below 0")
+    block = chunk
+    while block > 1 and -lower_bound * (block - 1) / 2 > EXP_LIMIT:
+        block //= 2
+    return block
+
+
+@register_op("kda", inputs=("Q", "K", "V", "A", "B", "ALog", "DtBias"),
+             attr_defaults={"lower_bound": -5.0, "chunk_size": 64,
+                            "site": ""})
+def _kda(ins, attrs):
+    """Kimi delta attention (arXiv:2510.26692): the delta rule with a
+    decay a KEY CHANNEL. Q, K, V [B, S, H*d] (after their convolution
+    and SiLU), A [B, S, H*dk] (the decay's pre-activation, a channel), B
+    [B, S, H] (the write strength's, a head), ALog [H], DtBias [H*dk] ->
+    Out [B, S, H*dv]. q and k L2-normalised a head, q scaled by
+    dk^-1/2, beta = sigmoid(B), log decay g = ``lower_bound`` *
+    sigmoid(exp(ALog) (A + DtBias)) in (lower_bound, 0) (the bounded
+    "safe" gate); then ``_chunked_delta_rule`` over the key channels, its
+    chunks factored in ``kda_block`` positions. Float32 inside."""
+    q, k, v = first(ins, "Q"), first(ins, "K"), first(ins, "V")
+    h = first(ins, "B").shape[-1]
+    bound = attrs.get("lower_bound", -5.0)
+    chunk = attrs.get("chunk_size", 64)
+    b, s, _ = q.shape
+
+    def heads(t):
+        return jnp.moveaxis(t.astype(jnp.float32).reshape(b, s, h, -1), 1, 2)
+
+    q, k = (t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+            for t in (heads(q), heads(k)))
+    q = q * q.shape[-1] ** -0.5
+    beta = jax.nn.sigmoid(first(ins, "B").astype(jnp.float32))
+    g = bound * jax.nn.sigmoid(
+        jnp.exp(first(ins, "ALog").astype(jnp.float32))[:, None, None]
+        * heads(first(ins, "A") + first(ins, "DtBias")))
+    o = _chunked_delta_rule(q, k, heads(v), g, jnp.moveaxis(beta, 1, 2),
+                            chunk, kda_block(bound, chunk))
+    _gauge("kda_chunks_per_step",
+           "chunks of the sequence Kimi delta attention's state scan steps "
            "over, a sequence of the batch each", attrs.get("site", ""),
            b * -(-s // chunk))
     return out(Out=jnp.moveaxis(o, 1, 2).reshape(b, s, -1).astype(v.dtype))
@@ -409,66 +524,102 @@ def _differential_combine(ins, attrs):
     return out(Out=o.reshape(x.shape[:2] + (-1,)))
 
 
-def interleaved_rotary(x, theta):
-    """Rotary embedding over the whole last dim of x [B, S, .., r] that
-    turns each pair of dims (2i, 2i + 1) by position x theta^(-2i / r)
-    (``rope_interleave``); a token's position is its index in S. Float32."""
+def _rotary_angles(x, theta):
+    """cos, sin [1, S, 1.., r / 2] of the angles position x theta^(-2i /
+    r) for x [B, S, .., r]; a token's position is its index in S."""
     r, s = x.shape[-1], x.shape[1]
     inv = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
     angle = (jnp.arange(s, dtype=jnp.float32)[:, None] * inv).reshape(
         (1, s) + (1,) * (x.ndim - 3) + (r // 2,))
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def interleaved_rotary(x, theta):
+    """Rotary embedding over the whole last dim of x [B, S, .., r] that
+    turns each pair of dims (2i, 2i + 1) by position x theta^(-2i / r)
+    (``rope_interleave``); a token's position is its index in S. Float32."""
+    r = x.shape[-1]
+    cos, sin = _rotary_angles(x, theta)
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
     even, odd = pairs[..., 0], pairs[..., 1]
     return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
                      -1).reshape(x.shape)
 
 
+def half_split_rotary(x, theta):
+    """The same turns on the pairs of dims (i, i + r / 2) (rotate-half:
+    ``rope_interleave`` false). Float32."""
+    cos, sin = _rotary_angles(x, theta)
+    lo, hi = jnp.split(x.astype(jnp.float32), 2, -1)
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin], -1)
+
+
 @register_op("latent_qkv", inputs=("CQ", "CKV", "KRope", "WUQ", "WUKV"),
              attr_defaults={"num_heads": 1, "nope_dim": 128, "rope_dim": 64,
-                            "v_dim": 128, "theta": 1e4, "site": ""})
+                            "v_dim": 128, "theta": 1e4,
+                            "rope_interleave": True, "site": ""})
 def _latent_qkv(ins, attrs):
     """Latent attention's heads (DeepSeek-V2 §2.1): CQ [B, S, Lq] and CKV
-    [B, S, Lkv] the normed query and key-value latents, KRope [B, S, r]
+    [B, S, Lkv] the normed query and key-value latents (or, where the
+    query has no latent, CQ the normed layer input), KRope [B, S, r]
     the one rotary key all heads share, WUQ [Lq, H (n + r)] (a head's
     [no-rotary n | rotary r] columns), WUKV [Lkv, H (n + v)] (a head's
     [K n | V v]) -> Q, K [B, S, H (n + r)], V [B, S, H v], the attention
     op's layout: Q = [CQ WUQ_n | rope(CQ WUQ_r)], K = [CKV WUK |
     rope(KRope)], the same rotated key in every head, V = CKV WUV.
-    Rotary pairs dims (2i, 2i + 1) (``interleaved_rotary``). The vjp
-    sums KRope's gradient over the heads."""
+    Rotary pairs dims (2i, 2i + 1) (``interleaved_rotary``) where
+    ``rope_interleave``, else (i, i + r / 2) (``half_split_rotary``).
+    The vjp sums KRope's gradient over the heads."""
     cq, ckv, kr = first(ins, "CQ"), first(ins, "CKV"), first(ins, "KRope")
     h, n, r, dv = (attrs.get("num_heads", 1), attrs.get("nope_dim", 128),
                    attrs.get("rope_dim", 64), attrs.get("v_dim", 128))
     theta = attrs.get("theta", 1e4)
+    interleave = attrs.get("rope_interleave", True)
+    rope = interleaved_rotary if interleave else half_split_rotary
     b, s = cq.shape[:2]
     q = _einsum("bsl,lf->bsf", cq, first(ins, "WUQ")).reshape(b, s, h, n + r)
     kv = _einsum("bsl,lf->bsf", ckv, first(ins, "WUKV")).reshape(
         b, s, h, n + dv)
-    q = jnp.concatenate([q[..., :n], interleaved_rotary(q[..., n:], theta)],
-                        -1)
-    k_rope = jnp.broadcast_to(interleaved_rotary(kr, theta)[:, :, None],
-                              (b, s, h, r))
+    q = jnp.concatenate([q[..., :n], rope(q[..., n:], theta)], -1)
+    k_rope = jnp.broadcast_to(rope(kr, theta)[:, :, None], (b, s, h, r))
     k = jnp.concatenate([kv[..., :n], k_rope], -1)
     site = attrs.get("site", "")
     _gauge("mla_heads", "heads latent attention's Q, K and V are made for",
            site, h)
-    _gauge("mla_q_latent_width", "width of the query latent c_Q", site,
-           cq.shape[-1])
+    _gauge("mla_q_latent_width",
+           "width of the query latent c_Q (of the layer's input where the "
+           "query has no latent)", site, cq.shape[-1])
     _gauge("mla_kv_latent_width", "width of the key-value latent c_KV",
            site, ckv.shape[-1])
     _gauge("mla_rope_dim",
            "rotary dims of a head: one rotary key all heads share", site, r)
+    _gauge("mla_rope_interleaved",
+           "1 where rotary pairs dims (2i, 2i + 1), 0 where (i, i + r / 2)",
+           site, int(interleave))
     return out(Q=q.reshape(b, s, -1).astype(cq.dtype),
                K=k.reshape(b, s, -1).astype(cq.dtype),
                V=kv[..., n:].reshape(b, s, -1).astype(cq.dtype))
 
 
 # --------------------------------------------------------------------------
+def _in_kept_groups(choice, groups, kept):
+    """``choice`` [.., E] where its expert's group of E / ``groups`` is
+    among the ``kept`` groups of largest score, -inf elsewhere; a group
+    scores the sum of its two largest entries (DeepSeek-V3's
+    ``noaux_tc``)."""
+    by_group = choice.reshape(choice.shape[:-1] + (groups, -1))
+    score = jnp.sum(lax.top_k(by_group, 2)[0], -1)
+    top_groups = lax.top_k(score, kept)[1]
+    keep = jnp.any(top_groups[..., :, None] == jnp.arange(groups), -2)
+    return jnp.where(keep[..., None], by_group, -jnp.inf).reshape(
+        choice.shape)
+
+
 @register_op("moe_router", inputs=("X", "W", "Bias", "TopkIdx"),
              diff_inputs=("X", "W"),
              attr_defaults={"top_k": 1, "scoring": "softmax", "scale": 1.0,
-                            "bias_update_rate": 0.0, "site": ""})
+                            "bias_update_rate": 0.0, "n_group": 1,
+                            "topk_group": 1, "site": ""})
 def _moe_router(ins, attrs):
     """X [.., D], W [D, E] -> TopkIdx [.., k] int32, TopkWeight [.., k]
     (the chosen experts' scores, renormalised to sum 1, times ``scale``),
@@ -480,9 +631,14 @@ def _moe_router(ins, attrs):
     whatever the flags say: a rounded logit changes which expert is
     chosen.
 
-    With Bias [E] (DeepSeek-V3's ``noaux_tc`` with one expert group:
-    ``n_group`` = ``topk_group`` = 1 is no group stage, so there is
-    none): the k experts are chosen by scores + Bias, the weights are the
+    ``n_group`` > 1 (DeepSeek-V3's ``noaux_tc``, §2.1.2): the experts
+    are ``n_group`` groups in order, each scored by the sum of its two
+    largest choice scores, and the k experts are chosen inside the
+    ``topk_group`` best groups alone (``_in_kept_groups``); 1 is no group
+    stage, the choice of all E as before.
+
+    With Bias [E]: the k experts are chosen by scores + Bias (in the
+    group stage too), the weights are the
     chosen experts' UNBIASED scores, and BiasOut [E] = Bias +
     ``bias_update_rate`` * sign(mean load - load_e) over all E experts,
     the loads counted from this step's TopkIdx: the persistable the op
@@ -502,12 +658,18 @@ def _moe_router(ins, attrs):
         probs = scores / jnp.sum(scores, -1, keepdims=True)
     else:
         raise ValueError(f"moe_router: scoring {scoring!r}")
-    if bias is None:
+    groups = attrs.get("n_group", 1)
+    if bias is None and groups == 1:
         top_p, top_i = lax.top_k(scores, k)
     else:
         top_i = first(ins, "TopkIdx")
         if top_i is None:
-            top_i = lax.top_k(scores + bias.astype(jnp.float32), k)[1]
+            choice = scores if bias is None \
+                else scores + bias.astype(jnp.float32)
+            if groups > 1:
+                choice = _in_kept_groups(choice, groups,
+                                         attrs.get("topk_group", 1))
+            top_i = lax.top_k(choice, k)[1]
         top_p = jnp.take_along_axis(scores, top_i, -1)
     top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
     if attrs.get("scale", 1.0) != 1.0:
@@ -532,6 +694,14 @@ def _moe_router(ins, attrs):
                "the step of a router's choice-only bias update, "
                "gamma x sign(mean load - load); unset where the router "
                "has no bias", site, rate)
+    if groups > 1:
+        _gauge("moe_router_groups",
+               "groups of experts a router's group stage scores; unset "
+               "where it has none", site, groups)
+        _gauge("moe_router_groups_kept",
+               "groups a token's experts are chosen from; unset where the "
+               "router has no group stage", site,
+               attrs.get("topk_group", 1))
     _gauge("moe_router_biased",
            "1 where the router chooses by scores + a bias it keeps out of "
            "the gradient, 0 where by the scores alone", site,

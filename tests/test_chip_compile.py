@@ -401,8 +401,10 @@ def _step_bytes(name, compiled, parameters):
              - mem.alias_size_in_bytes + mem.temp_size_in_bytes
              + mem.generated_code_size_in_bytes)
     print(name, "step bytes", total, "more than recomputing:",
-          total - RECOMPUTING_STEP_BYTES[name], "temporaries",
-          mem.temp_size_in_bytes, "code", mem.generated_code_size_in_bytes)
+          total - RECOMPUTING_STEP_BYTES[name]
+          if name in RECOMPUTING_STEP_BYTES else "(never measured)",
+          "temporaries", mem.temp_size_in_bytes, "code",
+          mem.generated_code_size_in_bytes)
     return total
 
 
@@ -704,3 +706,62 @@ def test_joyai_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
         gauge = telemetry.REGISTRY.get(name)
         assert [gauge.value(site=s) for s in routers] == [want] * 5
     assert 4e9 < _step_bytes("joyai_flash", compiled, 680439808) < 15.0e9
+
+
+def test_ling3_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
+    """The step of `ling3_flash_vl.b1_s4096` as the executor lowers it,
+    at the published widths and the cell's cut (six layers: five KDA and
+    one latent attention, two dense and four sparse; 8 of 512 experts
+    held; 19,648 rows of vocabulary), 1 x 4096 tokens, bf16 matmul
+    operands, recomputation a layer: seven segments (six layers and the
+    head), it fits under the 15.0e9 bytes a step that fits the chip
+    leaves room beside (12 bytes a parameter of arguments; the CPU
+    compile reads 13.09e9), the KDA op is in all five KDA layers, the
+    one latent-attention layer runs the flash kernels at D 192 / Dv 128,
+    causal (forward once, dK/dV and dQ), and every expert product is
+    the grouped kernels over `row_bound` = 1,024 rows (twice the 512 the
+    8 held experts of 512 expect)."""
+    from paddle_tpu.fluid import telemetry
+    from paddle_tpu.models import ling3_flash
+    from paddle_tpu.ops import decoder_ops
+    cfg = dict(ling3_flash.ling3_flash_config(), vocab_size=19648,
+               experts_held=8, mlp_types=["dense"] * 2 + ["sparse"] * 4)
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        main, startup, _, fetches = \
+            ling3_flash.build_ling3_flash_pretrain_program(cfg, seq_len=4096)
+        feed = ling3_flash.synthetic_pretrain_batch(cfg, 1, 4096)
+        scope = _shapes_scope(startup)
+        cb = _CompiledBlock(main, tuple(sorted(feed)), (fetches[0].name,),
+                            scope, seed=0)
+        assert cb._remat_plan is not None and len(
+            cb._remat_plan.segments) == 7  # 6 layers, the head
+        compiled = _lower(cb, scope, feed, one_chip)().compile()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    assert _kernel_names(text) == {
+        ("fwd/fused_attention_qkv", "flash_fwd"): 1,
+        ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
+        ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1,
+        **_expert_kernels(4)}
+    assert "fwd/latent_qkv" in text and "fwd/kda" in text
+    assert decoder_ops.row_bound(4096, 8, 8, 512) == 1024
+    kdas = [op.attr("site") for op in main.global_block().ops
+            if op.type == "kda"]
+    assert [telemetry.REGISTRY.get("kda_chunks_per_step").value(site=s)
+            for s in kdas] == [64] * 5
+    routers = ling3_flash.router_biases(main)
+    assert len(routers) == 4
+    for name, want in (("moe_router_groups", 8),
+                       ("moe_router_groups_kept", 4),
+                       ("moe_router_biased", 1)):
+        gauge = telemetry.REGISTRY.get(name)
+        assert [gauge.value(site=s) for s in routers] == [want] * 4
+    latent = [op.attr("site") for op in main.global_block().ops
+              if op.type == "latent_qkv"]
+    assert [telemetry.REGISTRY.get(n).value(site=latent[0])
+            for n in ("mla_q_latent_width", "mla_rope_interleaved")] \
+        == [2560, 0]
+    assert 4e9 < _step_bytes("ling3_flash", compiled, 707798176) < 15.0e9

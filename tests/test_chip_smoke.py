@@ -137,3 +137,34 @@ def test_joyai_rehearsal_holds_the_program_to_its_reference(tmp_path):
     assert step["moe_combine_kernel"] == [0] * 5  # lax.ragged_dot's path
     assert len(step["held_share"]) == 5
     assert all(0 < share < 1 for share in step["held_share"])
+
+
+def test_ling3_rehearsal_holds_the_program_to_its_reference(tmp_path):
+    """`--ling3 --tiny`: both programs (bf16 operands are float32 ones on
+    a CPU) within the float32 limits of the reference: the loss, a
+    block of logits, a gradient of every kind and each router's bias
+    after the step; the reference with bf16 ACTIVATIONS and under each
+    of its two KDA controls outside them; a pass an expert layer, the
+    chunks each KDA layer scans, the groups each router keeps, and the
+    held experts' share of each router's assignments (4 of 16 held at
+    the toy size)."""
+    res, phase, rows = _run(tmp_path, "--ling3", "--tiny")
+    assert res.returncode != 0 and rows[-1]["rehearsal"], res.stderr[-2000:]
+    parity = phase["ling3_parity"]
+    limits = parity["limits"]["float32"]
+    assert {"loss", "logits", "layers.0.attn.a_log@GRAD",
+            "layers.0.attn.dt_bias@GRAD", "layers.5.attn.w_q@GRAD",
+            "layers.2.moe.w_router@GRAD",
+            "layers.2.moe.router_bias"} <= set(limits)
+    for errors in parity["errors"].values():
+        assert all(errors[n] <= limits[n] for n in limits)
+    assert any(parity["bf16_activations"][n] > limits[n] for n in limits)
+    assert set(parity["controls"]) == {"kda_state_bf16",
+                                       "kda_gate_head_mean"}
+    for errors in parity["controls"].values():
+        assert any(errors[n] > limits[n] for n in limits)
+    step = phase["ling3_step"]
+    assert step["passes"] == [1] * 4
+    assert step["kda_chunks_per_step"] == [2 * 2] * 5  # 2 x ceil(80 / 64)
+    assert step["moe_router_groups_kept"] == [2] * 4
+    assert all(0 < share < 1 for share in step["held_share"])

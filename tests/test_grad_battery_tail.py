@@ -1053,6 +1053,7 @@ def test_grad_tail_batch3(case):
 
 
 
+_kda_rng = np.random.RandomState(46)
 STRAGGLERS = [
     ("index_sample",
      {"X": X, "Index": np.asarray([[2, 0], [1, 1]], np.int32)}, {},
@@ -1113,6 +1114,21 @@ STRAGGLERS = [
       "DtBias": np.ones((2,), np.float32)},
      {"num_key_heads": 1, "num_value_heads": 2, "chunk_size": 4},
      {"wrt": ["Q", "K", "V", "A", "B", "ALog", "DtBias"]}),
+    # Kimi delta attention: a decay a key channel, the bound -40 factored
+    # in blocks of 2 positions, two a chunk of 4 (inputs from a generator
+    # of its own: the shared one's draws for the cases after it stay);
+    # the gates' inputs and Q here, all seven against the token
+    # recurrence in tests/test_ling3_flash.py
+    ("kda",
+     {"Q": _kda_rng.uniform(-1, 1, (1, 6, 8)).astype(np.float32),
+      "K": _kda_rng.uniform(-1, 1, (1, 6, 8)).astype(np.float32),
+      "V": _kda_rng.uniform(-1, 1, (1, 6, 8)).astype(np.float32),
+      "A": _kda_rng.uniform(-1, 1, (1, 6, 8)).astype(np.float32),
+      "B": _kda_rng.uniform(-1, 1, (1, 6, 2)).astype(np.float32),
+      "ALog": np.asarray([-3.0, -2.0], np.float32),
+      "DtBias": _kda_rng.uniform(-0.5, 0.5, (8,)).astype(np.float32)},
+     {"lower_bound": -40.0, "chunk_size": 4},
+     {"wrt": ["Q", "A", "B", "ALog", "DtBias"]}),
     ("selective_scan",
      {"X": rng.uniform(-1, 1, (1, 6, 3)).astype(np.float32),
       "Dt": rng.uniform(-1, 1, (1, 6, 3)).astype(np.float32),
