@@ -523,10 +523,14 @@ def _gauge_by_site(name, sites):
 
 def _qwen3_next_counters(main):
     """(further names to fetch, what to say of them and of the gauges):
-    each expert layer's passes, the rows a step's products run, and
-    whether the combine kernel adds a layer's rows back."""
+    each expert layer's passes, the rows a step's products run, whether
+    the combine kernel adds a layer's rows back, and the steps of each
+    Gated DeltaNet layer's forward kernel's grid (None where the
+    `lax.scan` lowering ran)."""
     from paddle_tpu.models import qwen3_next
     passes = qwen3_next.expert_passes(main)
+    gdns = [op.attr("site") for op in main.global_block().ops
+            if op.type == "gated_delta_rule"]
 
     def say(fetched):
         import numpy as np
@@ -534,7 +538,9 @@ def _qwen3_next_counters(main):
             passes=[int(np.asarray(g)[0]) for g in fetched],
             moe_rows_per_step=sum(_gauge_by_site("moe_rows_per_step",
                                                  passes)),
-            moe_combine_kernel=_gauge_by_site("moe_combine_kernel", passes))
+            moe_combine_kernel=_gauge_by_site("moe_combine_kernel", passes),
+            delta_grid_steps_per_step=_gauge_by_site(
+                "delta_grid_steps_per_step", gdns))
     return list(passes.values()), say
 
 
@@ -632,8 +638,9 @@ def _joyai_counters(main):
 
 
 def _ling3_counters(main):
-    """JoyAI's, and the chunks each KDA layer's scan steps over and the
-    groups each router keeps."""
+    """JoyAI's, and the chunks each KDA layer's scan steps over, the
+    steps of its forward kernel's grid (None where the `lax.scan`
+    lowering ran) and the groups each router keeps."""
     further, say_routed = _joyai_counters(main)
     ops = main.global_block().ops
     kdas = [op.attr("site") for op in ops if op.type == "kda"]
@@ -643,6 +650,8 @@ def _ling3_counters(main):
         return dict(say_routed(fetched),
                     kda_chunks_per_step=_gauge_by_site(
                         "kda_chunks_per_step", kdas),
+                    delta_grid_steps_per_step=_gauge_by_site(
+                        "delta_grid_steps_per_step", kdas),
                     moe_router_groups_kept=_gauge_by_site(
                         "moe_router_groups_kept", routers))
     return further, say

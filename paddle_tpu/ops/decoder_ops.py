@@ -46,7 +46,7 @@ from jax import lax
 
 from .registry import register_grad_maker, register_op, first, out
 from .math_ops import mxu_available
-from .pallas import grouped_matmul, selective_scan
+from .pallas import delta_rule, grouped_matmul, selective_scan
 
 
 def _operands(*xs):
@@ -180,7 +180,7 @@ def _causal_conv1d(ins, attrs):
 
 
 # --------------------------------------------------------------------------
-def _chunked_delta_rule(q, k, v, g, beta, chunk, block=None):
+def _chunked_delta_rule(q, k, v, g, beta, chunk, block=None, kernels=None):
     """The gated delta rule in chunks. q, k [B, H, S, dk] (normalised, q
     scaled), v [B, H, S, dv], beta [B, H, S], g the log of the decay (<=
     0): [B, H, S], one a head (Gated DeltaNet), or [B, H, S, dk], one a
@@ -195,7 +195,11 @@ def _chunked_delta_rule(q, k, v, g, beta, chunk, block=None):
     k_j) (i > j): one triangular solve gives T = (I + L)^-1, the WY
     form's two products T (beta V) and T (beta K e^G) are made for every
     chunk at once, and only the chunk states are scanned, in float32:
-    S_0 -> S_C = e^{G_C} S_0 + (K e^{G_C - G})^T U."""
+    S_0 -> S_C = e^{G_C} S_0 + (K e^{G_C - G})^T U. Two lowerings of
+    that recurrence: ``kernels`` (the (heads, chunks) a grid step of
+    ``_delta_blocks``) takes the Pallas pair of ``pallas/delta_rule.py``,
+    which makes T and keeps the state in VMEM; None takes
+    ``solve_triangular`` and ``lax.scan``, differentiated by jax."""
     b, h, s, dk = q.shape
     dv = v.shape[-1]
     channels = g.ndim == 4
@@ -228,6 +232,17 @@ def _chunked_delta_rule(q, k, v, g, beta, chunk, block=None):
             return t[..., None]
     k_beta = k * beta[..., None]
     kk = pairs(k_beta)
+    if kernels:
+        carry = jnp.exp(gc[:, :, :, -1])
+        o = delta_rule.recurrence(
+            jnp.where(strictly_lower, kk, 0.0),
+            *_operands(jnp.where(lower, pairs(q), 0.0), v * beta[..., None],
+                       k_beta * col(jnp.exp(gc)), q * col(jnp.exp(gc)),
+                       k * col(jnp.exp(gc[:, :, :, -1:] - gc))),
+            carry if channels else jnp.broadcast_to(carry[..., None],
+                                                    carry.shape + (dk,)),
+            kernels)
+        return o.reshape(b, h, n * chunk, dv)[:, :, :s]
     eye = jnp.eye(chunk, dtype=jnp.float32)
     system = eye + jnp.where(strictly_lower, kk, 0.0)
     t_inv = jax.scipy.linalg.solve_triangular(
@@ -253,6 +268,29 @@ def _chunked_delta_rule(q, k, v, g, beta, chunk, block=None):
                for t in (qk, u, w, q_in, k_out, carry_decay))
     _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
     return jnp.moveaxis(o, 0, 2).reshape(b, h, n * chunk, dv)[:, :, :s]
+
+
+def _delta_blocks(b, h, s, chunk, dk, dv):
+    """(heads, chunks) a grid step of the delta rule's kernels for this
+    call, or None: the ``lax.scan`` lowering (a backend without the
+    kernels, a mesh, a chunk or widths they do not serve)."""
+    if not delta_rule.use_kernels():
+        return None
+    itemsize = _operands(jnp.zeros((), jnp.float32))[0].dtype.itemsize
+    return delta_rule.block_sizes(b * h, -(-s // chunk), chunk, dk, dv,
+                                  itemsize)
+
+
+def _delta_gauges(name, help_, site, b, h, s, chunk, blocks):
+    """The chunks the recurrence steps over, and where the kernels ran,
+    the steps of the forward kernel's grid."""
+    _gauge(name, help_, site, b * -(-s // chunk))
+    if blocks:
+        _gauge("delta_grid_steps_per_step",
+               "steps of the delta rule's forward Pallas kernel's grid, "
+               "every sequence and head and chunk block; unset where the "
+               "lax.scan lowering ran", site,
+               delta_rule.grid_steps(b * h, -(-s // chunk), blocks))
 
 
 def _channel_pairs(k, gc, block):
@@ -314,12 +352,13 @@ def _gated_delta_rule(ins, attrs):
     beta = jax.nn.sigmoid(first(ins, "B").astype(jnp.float32))
     g = -jnp.exp(first(ins, "ALog").astype(jnp.float32)) * jax.nn.softplus(
         first(ins, "A").astype(jnp.float32) + first(ins, "DtBias"))
+    blocks = _delta_blocks(b, hv, s, chunk, q.shape[-1], v.shape[-1] // hv)
     o = _chunked_delta_rule(q, k, heads(v, hv), jnp.moveaxis(g, 1, 2),
-                            jnp.moveaxis(beta, 1, 2), chunk)
-    _gauge("gdn_chunks_per_step",
-           "chunks of the sequence the delta rule's state scan steps "
-           "over, a sequence of the batch each", attrs.get("site", ""),
-           b * -(-s // chunk))
+                            jnp.moveaxis(beta, 1, 2), chunk, kernels=blocks)
+    _delta_gauges("gdn_chunks_per_step",
+                  "chunks of the sequence the delta rule's state scan "
+                  "steps over, a sequence of the batch each",
+                  attrs.get("site", ""), b, hv, s, chunk, blocks)
     return out(Out=jnp.moveaxis(o, 1, 2).reshape(b, s, -1).astype(v.dtype))
 
 
@@ -373,12 +412,13 @@ def _kda(ins, attrs):
     g = bound * jax.nn.sigmoid(
         jnp.exp(first(ins, "ALog").astype(jnp.float32))[:, None, None]
         * heads(first(ins, "A") + first(ins, "DtBias")))
+    blocks = _delta_blocks(b, h, s, chunk, q.shape[-1], v.shape[-1] // h)
     o = _chunked_delta_rule(q, k, heads(v), g, jnp.moveaxis(beta, 1, 2),
-                            chunk, kda_block(bound, chunk))
-    _gauge("kda_chunks_per_step",
-           "chunks of the sequence Kimi delta attention's state scan steps "
-           "over, a sequence of the batch each", attrs.get("site", ""),
-           b * -(-s // chunk))
+                            chunk, kda_block(bound, chunk), blocks)
+    _delta_gauges("kda_chunks_per_step",
+                  "chunks of the sequence Kimi delta attention's state "
+                  "scan steps over, a sequence of the batch each",
+                  attrs.get("site", ""), b, h, s, chunk, blocks)
     return out(Out=jnp.moveaxis(o, 1, 2).reshape(b, s, -1).astype(v.dtype))
 
 

@@ -158,6 +158,57 @@ def test_scan_kernels_compile_for_v5e(one_chip, for_the_chip):
     assert " while(" not in text
 
 
+@pytest.mark.parametrize("op_type", ["gated_delta_rule", "kda"])
+@pytest.mark.parametrize("precision", ["bf16", "f32"])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, for_the_chip, op_type,
+                                            precision):
+    """`gated_delta_rule` (Qwen3-Next's call: 16 key heads repeated to 32
+    value heads) and `kda` (Ling's: 32 heads, the decay a key channel)
+    forward + backward at the cells' shapes (1 x 4096 positions, heads
+    of 128, chunks of 64), through the op, at the blocks `block_sizes`
+    chooses: bf16 operands as the cells run them, and float32 ones at
+    the "highest" precision as the parity programs do. One Mosaic kernel
+    each way, no triangular-solve custom call and none of the `lax.scan`
+    lowering's loops."""
+    from paddle_tpu.ops import decoder_ops
+    from paddle_tpu.ops.pallas import delta_rule as dr
+    from paddle_tpu.ops.registry import OPS
+    channel = op_type == "kda"
+    key_heads = 32 if channel else 16
+    names = ("Q", "K", "V", "A", "B", "ALog", "DtBias")
+    shapes = ((1, 4096, key_heads * 128),) * 2 + ((1, 4096, 4096),
+                                                  (1, 4096, 4096 if channel
+                                                   else 32),
+                                                  (1, 4096, 32), (32,),
+                                                  (4096 if channel else 32,))
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    attrs = {"lower_bound": -5.0} if channel else {
+        "num_key_heads": 16, "num_value_heads": 32}
+
+    def loss(*xs):
+        y = OPS.get(op_type).kernel(
+            {k: [v] for k, v in zip(names, xs)},
+            dict(attrs, chunk_size=64, site="compile"))["Out"][0]
+        return jnp.sum(y ** 2)
+
+    bf16 = precision == "bf16"
+    core.set_flag("FLAGS_use_bf16_matmul", bf16)
+    try:
+        assert decoder_ops._delta_blocks(1, 32, 4096, 64, 128, 128) == (
+            (4, 8) if bf16 else (4, 4))
+        with jax.default_matmul_precision("default" if bf16 else "highest"):
+            text = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+                *args).compile().as_text()
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    assert _kernel_names(text) == {(None, "delta_fwd"): 1,
+                                   (None, "delta_bwd"): 1}
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert " while(" not in text
+    assert dr.grid_steps(32, 64, (4, 8)) == 64
+
+
 @pytest.mark.parametrize("tokens,k,held,width,d,f,activation,kernels", [
     (4096, 10, 32, 512, 2048, 512, "silu", True),
     (8192, 8, 32, 256, 2048, 512, "silu", True),
@@ -418,10 +469,13 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
     forward once: the recomputed segment keeps its output and
     log-sum-exp; then dK/dV and dQ), every expert product is XLA's own grouped
     kernel over `row_bound` = 5,120 rows (twice the 2,560 the 32 held
-    experts of 512 expect; never the 40,960 a layer could be sent), and
-    the only loops are the chunk scans and the expert layers' windows.
+    experts of 512 expect; never the 40,960 a layer could be sent), each
+    of the three Gated DeltaNet layers runs the delta rule's kernel pair
+    (forward, again in the recomputed segment, backward) and no
+    triangular solve, and the only loops are the expert layers' windows.
     Start-up runs on the CPU for the shapes alone (7.5 GB of host
     memory, ~5 s)."""
+    from paddle_tpu.fluid import telemetry
     from paddle_tpu.models import qwen3_next
     cfg = dict(qwen3_next.qwen3_next_config(), layers=4, experts_held=32,
                vocab_size=18992)
@@ -444,12 +498,19 @@ def test_qwen3_next_train_step_compiles_for_v5e(one_chip, for_the_chip):
         ("fwd/fused_attention_qkv", "flash_fwd"): 1,
         ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
         ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1,
+        ("fwd/gated_delta_rule", "delta_fwd"): 3 * 2,
+        ("fwd/gated_delta_rule", "delta_bwd"): 3,
         **_expert_kernels(4)}
+    assert "InvertDiagBlocksLowerTriangular" not in text
     import re
-    # 3 chunk scans x (forward, again, backward) + 4 expert layers x
-    # (forward, backward): the loops over the windows of `row_bound` rows
-    # (the recomputed segment's is dead code: nothing reads its output)
-    assert len(re.findall(r" while\(", text)) == 9 + 8
+    # 4 expert layers x (forward, backward): the loops over the windows
+    # of `row_bound` rows (the recomputed segment's is dead code: nothing
+    # reads its output)
+    assert len(re.findall(r" while\(", text)) == 8
+    gdns = [op.attr("site") for op in main.global_block().ops
+            if op.type == "gated_delta_rule"]
+    assert [telemetry.REGISTRY.get("delta_grid_steps_per_step").value(
+        site=s) for s in gdns] == [8 * 8] * 3
     assert 4e9 < _step_bytes("qwen3_next", compiled, 625.7e6) < 14e9
 
 
@@ -716,8 +777,11 @@ def test_ling3_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
     operands, recomputation a layer: seven segments (six layers and the
     head), it fits under the 15.0e9 bytes a step that fits the chip
     leaves room beside (12 bytes a parameter of arguments; the CPU
-    compile reads 13.09e9), the KDA op is in all five KDA layers, the
-    one latent-attention layer runs the flash kernels at D 192 / Dv 128,
+    compile reads 11.57e9, 13.09e9 with the `lax.scan` lowering), the KDA
+    op is in all five KDA layers, each running the delta rule's kernel
+    pair (forward, again in the recomputed segment, backward) and no
+    triangular solve, the one latent-attention layer runs the flash
+    kernels at D 192 / Dv 128,
     causal (forward once, dK/dV and dQ), and every expert product is
     the grouped kernels over `row_bound` = 1,024 rows (twice the 512 the
     8 held experts of 512 expect)."""
@@ -745,13 +809,18 @@ def test_ling3_flash_train_step_compiles_for_v5e(one_chip, for_the_chip):
         ("fwd/fused_attention_qkv", "flash_fwd"): 1,
         ("fwd/fused_attention_qkv", "flash_bwd_dkv"): 1,
         ("fwd/fused_attention_qkv", "flash_bwd_dq"): 1,
+        ("fwd/kda", "delta_fwd"): 5 * 2,
+        ("fwd/kda", "delta_bwd"): 5,
         **_expert_kernels(4)}
+    assert "InvertDiagBlocksLowerTriangular" not in text
     assert "fwd/latent_qkv" in text and "fwd/kda" in text
     assert decoder_ops.row_bound(4096, 8, 8, 512) == 1024
     kdas = [op.attr("site") for op in main.global_block().ops
             if op.type == "kda"]
     assert [telemetry.REGISTRY.get("kda_chunks_per_step").value(site=s)
             for s in kdas] == [64] * 5
+    assert [telemetry.REGISTRY.get("delta_grid_steps_per_step").value(
+        site=s) for s in kdas] == [8 * 8] * 5
     routers = ling3_flash.router_biases(main)
     assert len(routers) == 4
     for name, want in (("moe_router_groups", 8),

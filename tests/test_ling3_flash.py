@@ -16,6 +16,7 @@ product against a loop over experts, blocks of attention rows against
 their like); the parity limits are the chip's own (a loss 1e-5
 relative, a gradient 1e-3 of its scale).
 """
+import contextlib
 import importlib.util
 import os
 import warnings
@@ -30,6 +31,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import core, layers, telemetry
 from paddle_tpu.models import ling3_flash
 from paddle_tpu.ops import decoder_ops
+from paddle_tpu.ops.pallas import delta_rule as dr
+from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.registry import OPS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -141,6 +144,47 @@ def test_kda_is_the_token_recurrence_forward_and_backward(bound, chunk, s,
         close(g, r, 1e-4)
     assert telemetry.REGISTRY.get("kda_chunks_per_step").value(
         site="kda_t") == 2 * -(-s // chunk)
+
+
+@pytest.mark.parametrize("bound,chunk,s,pinned", [
+    (-5.0, 64, 150, None),     # the cell's chunk: padded, three chunks
+    (-5.0, 64, 150, (2, 1)),   # the state and its gradient across steps
+    (-20.0, 32, 70, (3, 3))],  # eight blocks of 4 factored, one merge
+    ids=["bound5_chunk64", "bound5_chunk64_steps", "bound20_chunk32"])
+def test_kda_kernels_are_the_token_recurrence_and_the_scan(bound, chunk, s,
+                                                           pinned):
+    """The Pallas pair of the chunk recurrence (interpreted), handed the
+    factored decay's L, qk and the channel decays, against the token
+    recurrence and the `lax.scan` lowering, forward and backward; the
+    kernels' grid steps counted at the op's site."""
+    ins = _kda_inputs(11, s)
+    attrs = {"lower_bound": bound, "chunk_size": chunk, "site": "kda_k"}
+    names, args = list(ins), tuple(ins.values())
+    mix = normal(21, 2, s, H * DK)
+
+    def both(fn):
+        return jax.jit(lambda *xs: (fn(*xs), jax.grad(
+            lambda *ys: jnp.sum(fn(*ys) * mix), range(7))(*xs)))
+
+    def op(*xs):
+        return kernel("kda", attrs, **dict(zip(names, xs)))["Out"]
+
+    with jax.default_matmul_precision("highest"):
+        with fa.interpret_guard(), (dr.block_override(*pinned) if pinned
+                                    else contextlib.nullcontext()):
+            got, got_grads = both(op)(*args)
+        scan, scan_grads = both(op)(*args)
+        want, want_grads = both(lambda *xs: _kda_by_tokens(
+            dict(zip(names, xs)), bound))(*args)
+    close(got, want, OP_TOL)
+    close(got, scan, OP_TOL)
+    for g, r, sc in zip(got_grads, want_grads, scan_grads):
+        close(g, r, 1e-4)
+        close(g, sc, 1e-4)
+    hb, nc = pinned or dr.block_sizes(2 * H, -(-s // chunk), chunk, DK, DK,
+                                      4)
+    assert telemetry.REGISTRY.get("delta_grid_steps_per_step").value(
+        site="kda_k") == 2 * H // hb * (-(-s // chunk) // nc)
 
 
 def test_one_decay_a_head_is_the_gated_delta_rule_s_core():

@@ -9,6 +9,7 @@ loop over experts. 2e-5 of a tensor's largest entry is ~100 float32
 roundings, what a sum over a few hundred terms in another order may
 move; the whole model crosses four layers and gets 1e-4.
 """
+import contextlib
 import importlib.util
 import os
 import warnings
@@ -23,6 +24,8 @@ import paddle_tpu.fluid as fluid
 from paddle_tpu.fluid import core, telemetry
 from paddle_tpu.models import qwen3_next as qn
 from paddle_tpu.ops import decoder_ops
+from paddle_tpu.ops.pallas import delta_rule as dr
+from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.registry import OPS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -172,6 +175,145 @@ def test_delta_rule_counts_its_chunks_by_site():
                                 "chunk_size": 64, "site": "t_site"}, **ins)
     assert telemetry.REGISTRY.get("gdn_chunks_per_step").value(
         site="t_site") == 2 * 2  # batch 2 x ceil(80 / 64)
+
+
+# ------------------------------- the delta rule's Pallas pair, interpreted
+def _gdn_both_paths(ins, attrs, cot, pinned=None):
+    """(output, gradients) of the op through the kernel pair (the Pallas
+    interpreter, ``pinned`` (hb, nc) or the op's choice) and through the
+    `lax.scan` lowering."""
+    def run(ins):
+        return jax.jit(lambda ins: (
+            kernel("gated_delta_rule", attrs, **ins)["Out"],
+            jax.grad(lambda i: jnp.sum(kernel(
+                "gated_delta_rule", attrs, **i)["Out"] * cot))(ins)))(ins)
+
+    with fa.interpret_guard(), (dr.block_override(*pinned) if pinned
+                                else contextlib.nullcontext()):
+        got = run(ins)
+    return got, run(ins)
+
+
+@pytest.mark.parametrize("s,chunk,pinned", [
+    (64, 64, None), (130, 64, None), (130, 64, (2, 1)), (37, 16, (4, 3)),
+    (150, 32, (1, 5))],
+    ids=["s64", "s130_padded", "s130_three_steps", "c16_one_block",
+         "c32_one_merge"])
+def test_delta_kernels_are_the_token_recurrence_and_the_scan(s, chunk,
+                                                             pinned):
+    """Forward and every gradient of the kernel pair against the token
+    recurrence (the tolerances of the lax.scan lowering's own test
+    above) and against that lowering (the same sums in another order);
+    a sequence not a multiple of the chunk runs padded chunks, and a
+    grid of several steps carries the state and its gradient across
+    them."""
+    ins = _gdn_inputs(10, s)
+    attrs = {"num_key_heads": 2, "num_value_heads": 4, "chunk_size": chunk}
+    cot = normal(99, 2, s, 32)
+    (got, got_grads), (scan, scan_grads) = _gdn_both_paths(ins, attrs, cot,
+                                                           pinned)
+    close(got, _gdn_reference(**ins), OP_TOL)
+    close(got, scan, OP_TOL)
+    want = jax.grad(lambda i: jnp.sum(_gdn_reference(**i) * cot))(ins)
+    for name in ins:
+        close(got_grads[name], want[name], 1e-3)
+        close(got_grads[name], scan_grads[name], 1e-4)
+
+
+def test_delta_kernels_take_bf16_operands_where_the_scan_does(monkeypatch):
+    """Under FLAGS_use_bf16_matmul on a backend with an MXU both
+    lowerings round the same six products' operands to bf16: the kernel
+    pair is handed them so and agrees with the scan to bf16's rounding."""
+    monkeypatch.setattr(decoder_ops, "mxu_available", lambda: True)
+    core.set_flag("FLAGS_use_bf16_matmul", True)
+    try:
+        ins = _gdn_inputs(12, 100)
+        attrs = {"num_key_heads": 2, "num_value_heads": 4, "chunk_size": 64}
+        with fa.interpret_guard():
+            assert decoder_ops._delta_blocks(2, 4, 100, 64, 8, 8) == (4, 2)
+        (got, got_grads), (scan, scan_grads) = _gdn_both_paths(
+            ins, attrs, normal(98, 2, 100, 32))
+    finally:
+        core.set_flag("FLAGS_use_bf16_matmul", False)
+    close(got, scan, 2e-2)
+    for name in ins:
+        close(got_grads[name], scan_grads[name], 5e-2)
+    close(got, _gdn_reference(**ins), 5e-2)
+
+
+def _doubling_inverse(lower):
+    """(I + N)^-1 as the product (I - N)(I + N^2)(I + N^4)..: what the
+    kernels must not do."""
+    n = lower.shape[-1]
+    t, power = np.eye(n, dtype=np.float32), -lower
+    for _ in range(int(np.log2(n))):
+        t, power = t @ (np.eye(n, dtype=np.float32) + power), power @ power
+    return t
+
+
+def test_delta_kernels_solve_nearly_parallel_keys_at_full_strength():
+    """beta -> 1 and keys within 1e-3 of one direction: the chunk's
+    system I + L has L_ij ~ 1 below the diagonal, whose powers reach
+    ~C(64, 32) while the inverse stays O(1). The doubling product loses
+    every digit there; the kernels' substitution and merges match the
+    token recurrence in float64."""
+    b, h, s, dk, dv = 1, 2, 128, 8, 8
+    rng = np.random.default_rng(7)
+    k = rng.normal(size=(b, h, 1, dk)) + 1e-3 * rng.normal(size=(b, h, s, dk))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    q = rng.normal(size=(b, h, s, dk))
+    q /= np.linalg.norm(q, axis=-1, keepdims=True) * dk ** 0.5
+    v = rng.normal(size=(b, h, s, dv))
+    beta, g = np.full((b, h, s), 0.999), np.full((b, h, s), -1e-3)
+    state, want = np.zeros((b, h, dk, dv)), np.zeros((b, h, s, dv))
+    for t in range(s):  # the recurrence in float64
+        state = state * np.exp(g[:, :, t])[..., None, None]
+        u = beta[:, :, t, None] * (v[:, :, t] - np.einsum(
+            "bhd,bhdv->bhv", k[:, :, t], state))
+        state = state + k[:, :, t, :, None] * u[..., None, :]
+        want[:, :, t] = np.einsum("bhd,bhdv->bhv", q[:, :, t], state)
+    args = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
+    with fa.interpret_guard():
+        got = decoder_ops._chunked_delta_rule(
+            *args, 64, kernels=dr.block_sizes(b * h, 2, 64, dk, dv, 4))
+    close(got, want, OP_TOL)
+    lower = np.tril(0.999 * np.asarray(args[1][0, 0, :64] @ args[1][0, 0,
+                                                                    :64].T),
+                    -1)
+    exact = np.linalg.inv(np.eye(64) + lower.astype(np.float64))
+    assert np.abs(exact).max() < 2
+    assert np.abs(_doubling_inverse(lower) - exact).max() > 1e3
+
+
+def test_the_delta_rule_says_which_lowering_ran():
+    """`delta_grid_steps_per_step` is set on the kernels' path alone; a
+    step traced under a mesh, a backend without the kernels and a chunk
+    the inverse's blocks do not divide keep `lax.scan`."""
+    ins = _gdn_inputs(20, 80)
+    attrs = {"num_key_heads": 2, "num_value_heads": 4, "chunk_size": 16}
+
+    def sites():
+        family = telemetry.REGISTRY.get("delta_grid_steps_per_step")
+        return {c.labels_dict["site"] for c in family.children()} \
+            if family else set()
+
+    assert not dr.use_kernels()
+    with fa.interpret_guard():
+        assert dr.use_kernels()
+        with fa.mesh_guard(object()):
+            assert not dr.use_kernels()
+        kernel("gated_delta_rule", dict(attrs, site="dr_on"), **ins)
+        kernel("gated_delta_rule", dict(attrs, site="dr_48", chunk_size=48),
+               **ins)
+    kernel("gated_delta_rule", dict(attrs, site="dr_off"), **ins)
+    assert "dr_on" in sites() and not {"dr_off", "dr_48"} & sites()
+    # 2 x 4 heads in blocks of 4, 5 chunks of 16 in one block of 5
+    assert telemetry.REGISTRY.get("delta_grid_steps_per_step").value(
+        site="dr_on") == 2 * 1
+    assert dr.block_sizes(8, 5, 48, 8, 8, 4) is None
+    # the two cells' calls: 32 heads x 64 chunks of 64, heads of 128
+    assert dr.block_sizes(32, 64, 64, 128, 128, 2) == (4, 8)
+    assert dr.grid_steps(32, 64, (4, 8)) == 8 * 8
 
 
 # ---------------------------------------------------- grouped attention
